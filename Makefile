@@ -24,7 +24,7 @@ lint: lockmap
 lockmap:
 	python scripts/lockmap_report.py --check
 
-# TSan/ASan/UBSan builds of native/*.cpp into the same mtime-keyed .so
+# TSan/ASan/UBSan builds of native/*.cpp into the same hash-keyed .so
 # cache `make native` uses; the TSan variants load under
 # TSAN_OPTIONS=suppressions=native/tsan.supp (tests/test_tsan.py)
 sanitize:
@@ -94,9 +94,9 @@ chaos:
 	GUBER_CHAOS_SEED=$$seed python -m pytest tests/ -q -s -m chaos
 
 # rebuild both native components (keydir.cpp, peerlink.cpp) plus their
-# tsan variants from source into the mtime-keyed .so cache names the
+# tsan variants from source into the hash-keyed .so cache names the
 # loaders expect; stale caches are deleted. tests/test_native_build.py is
-# the tier-1 drift check (a cached .so older than its source fails).
+# the tier-1 drift check (a cached .so built from other source fails).
 native:
 	python scripts/build_native.py
 
@@ -108,6 +108,4 @@ docker:
 
 clean:
 	find . -name __pycache__ -type d -exec rm -rf {} + 2>/dev/null; true
-	rm -f gubernator_tpu/native/_keydir_*.so \
-	      gubernator_tpu/native/_peerlink_*.so \
-	      gubernator_tpu/native/_tsan_*.so
+	rm -f gubernator_tpu/native/_*.so gubernator_tpu/native/.build.lock

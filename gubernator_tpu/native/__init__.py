@@ -1,7 +1,9 @@
 """Native (C++) host-path components, loaded via ctypes.
 
-Builds `keydir.cpp` into a cached shared library on first use (g++ -O2,
-~2 s, cached beside the source keyed by source mtime). Everything here has a
+Builds `keydir.cpp` / `peerlink.cpp` into cached shared libraries on first
+use (g++ -O2, ~2 s each). The cache name is a hash of the source bytes plus
+the compiler flags, so a binary is only ever loaded for the source it was
+built from, whatever a copy of the tree did to mtimes. Everything here has a
 pure-Python fallback — `NativeKeyDirectory` mirrors
 models/keyspace.KeyDirectory exactly and the engines accept either.
 """
@@ -9,74 +11,119 @@ models/keyspace.KeyDirectory exactly and the engines accept either.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
+import logging
 import os
 import subprocess
-import threading
-from typing import List, Optional, Sequence, Tuple
+import sysconfig
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from gubernator_tpu.obs import witness
 
+log = logging.getLogger("gubernator_tpu.native")
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_HERE, "keydir.cpp")
 _LIB_LOCK = witness.make_lock("native.loader")
 _LIB: Optional[ctypes.CDLL] = None
-_LIB_ERR: Optional[str] = None
+
+# component -> (source file, component flags). Python.h is for keydir's
+# prep_pack fast path; its symbols resolve from the host interpreter at
+# load time (no -lpython needed on Linux)
+COMPONENTS: Dict[str, Tuple[str, List[str]]] = {
+    "keydir": ("keydir.cpp", [f"-I{sysconfig.get_paths()['include']}"]),
+    "peerlink": ("peerlink.cpp", ["-pthread"]),
+}
+# flavor -> flags. "" is what the daemon loads; the sanitizer flavors are
+# built by scripts/build_native.py and tests/test_tsan.py. TSan and ASan
+# are mutually exclusive instrumentation, hence separate flavors;
+# -fno-omit-frame-pointer keeps ASan stacks honest at -O1.
+FLAVORS: Dict[str, List[str]] = {
+    "": ["-O2"],
+    "tsan": ["-O1", "-g", "-fsanitize=thread", "-pthread"],
+    "asan": ["-O1", "-g", "-fsanitize=address", "-fno-omit-frame-pointer",
+             "-pthread"],
+    "ubsan": ["-O1", "-g", "-fsanitize=undefined", "-pthread"],
+}
 
 
-def _build_lib(src: str, prefix: str, extra_flags: Sequence[str] = ()) -> str:
-    """Compile `src` into a cached .so keyed by source mtime; atomic vs
-    concurrent builders; stale builds dropped. Shared by every native
-    component (keydir, peerlink)."""
-    mtime = int(os.stat(src).st_mtime)
-    path = os.path.join(_HERE, f"{prefix}{mtime}.so")
+class NativeBuildError(RuntimeError):
+    """g++ refused a native source; the message carries its stderr."""
+
+
+# cache path -> compiler stderr. Only a compiler verdict is remembered (it
+# is a function of the cache key); a transient OSError is retried.
+_BUILD_ERRS: Dict[str, str] = {}
+
+
+def cache_prefix(component: str, flavor: str = "") -> str:
+    return f"_{flavor}_{component}_" if flavor else f"_{component}_"
+
+
+def _command(component: str, flavor: str) -> Tuple[str, List[str]]:
+    src_name, extra = COMPONENTS[component]
+    return (os.path.join(_HERE, src_name),
+            ["g++", *FLAVORS[flavor], *extra, "-shared", "-fPIC",
+             "-std=c++17"])
+
+
+def source_key(component: str, flavor: str = "") -> str:
+    """Hash of the source bytes and the full compile command."""
+    src, cmd = _command(component, flavor)
+    h = hashlib.sha256("\0".join(cmd).encode())
+    with open(src, "rb") as f:
+        h.update(f.read())
+    return h.hexdigest()[:16]
+
+
+def cache_path(component: str, flavor: str = "") -> str:
+    return os.path.join(
+        _HERE,
+        f"{cache_prefix(component, flavor)}{source_key(component, flavor)}.so")
+
+
+def build_component(component: str, flavor: str = "") -> str:
+    """Compile a native component into its hash-keyed cache and return the
+    path. One builder at a time (flock beside the sources): the rest wait
+    and then find the result. Other-hash siblings are pruned under the
+    same lock."""
+    path = cache_path(component, flavor)
     if os.path.exists(path):
         return path
-    tmp = path + ".tmp"
-    subprocess.run(
-        ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-         *extra_flags, "-o", tmp, src],
-        check=True, capture_output=True,
-    )
-    os.replace(tmp, path)  # atomic vs concurrent builders
-    for name in os.listdir(_HERE):
-        if name.startswith(prefix) and name.endswith(".so") and \
-                os.path.join(_HERE, name) != path:
-            try:
+    if path in _BUILD_ERRS:
+        raise NativeBuildError(_BUILD_ERRS[path])
+    src, cmd = _command(component, flavor)
+    with open(os.path.join(_HERE, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if os.path.exists(path):
+            return path
+        tmp = f"{path}.{os.getpid()}.tmp"
+        r = subprocess.run([*cmd, "-o", tmp, src],
+                           capture_output=True, text=True)
+        if r.returncode != 0:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+            _BUILD_ERRS[path] = (
+                f"{' '.join(cmd)} {src} exited {r.returncode}:\n{r.stderr}")
+            raise NativeBuildError(_BUILD_ERRS[path])
+        os.replace(tmp, path)
+        prefix = cache_prefix(component, flavor)
+        for name in os.listdir(_HERE):
+            if name.startswith(prefix) and name.endswith(".so") and \
+                    os.path.join(_HERE, name) != path:
                 os.unlink(os.path.join(_HERE, name))
-            except OSError:
-                pass
     return path
-
-
-def _lib_path() -> str:
-    mtime = int(os.stat(_SRC).st_mtime)
-    return os.path.join(_HERE, f"_keydir_{mtime}.so")
-
-
-def _build() -> str:
-    import sysconfig
-
-    # Python.h for the prep_pack fast path; symbols resolve from the
-    # host interpreter at load time (no -lpython needed on Linux)
-    return _build_lib(
-        _SRC, "_keydir_", [f"-I{sysconfig.get_paths()['include']}"])
 
 
 def load_library() -> ctypes.CDLL:
     """Build (if needed) and load the native library; raises on failure."""
-    global _LIB, _LIB_ERR
+    global _LIB
     with _LIB_LOCK:
         if _LIB is not None:
             return _LIB
-        if _LIB_ERR is not None:
-            raise RuntimeError(_LIB_ERR)
-        try:
-            lib = ctypes.CDLL(_build())
-        except Exception as e:  # noqa: BLE001
-            _LIB_ERR = f"native keydir unavailable: {e}"
-            raise RuntimeError(_LIB_ERR) from e
+        lib = ctypes.CDLL(build_component("keydir"))
         c = ctypes
         lib.keydir_new.restype = c.c_void_p
         lib.keydir_new.argtypes = [c.c_int64]
@@ -165,9 +212,7 @@ def load_library() -> ctypes.CDLL:
         return lib
 
 
-_PL_SRC = os.path.join(_HERE, "peerlink.cpp")
 _PL_LIB: Optional[ctypes.CDLL] = None
-_PL_ERR: Optional[str] = None
 
 
 def load_peerlink() -> ctypes.CDLL:
@@ -175,17 +220,11 @@ def load_peerlink() -> ctypes.CDLL:
 
     CDLL on purpose: pls_next_batch blocks in C waiting for frames, and the
     GIL must be released for the whole wait."""
-    global _PL_LIB, _PL_ERR
+    global _PL_LIB
     with _LIB_LOCK:
         if _PL_LIB is not None:
             return _PL_LIB
-        if _PL_ERR is not None:
-            raise RuntimeError(_PL_ERR)
-        try:
-            lib = ctypes.CDLL(_build_lib(_PL_SRC, "_peerlink_", ["-pthread"]))
-        except Exception as e:  # noqa: BLE001
-            _PL_ERR = f"native peerlink unavailable: {e}"
-            raise RuntimeError(_PL_ERR) from e
+        lib = ctypes.CDLL(build_component("peerlink"))
         c = ctypes
         lib.pls_start.restype = c.c_void_p
         lib.pls_start.argtypes = [c.c_int, c.POINTER(c.c_int)]
@@ -267,7 +306,7 @@ def load_pydll() -> ctypes.PyDLL:
     with _LIB_LOCK:
         if _PYLIB is None:
             c = ctypes
-            lib = ctypes.PyDLL(_lib_path())
+            lib = ctypes.PyDLL(cache_path("keydir"))
             lib.keydir_prep_pack_fast.restype = c.c_int32
             lib.keydir_prep_pack_fast.argtypes = [
                 c.c_void_p, c.py_object, c.c_void_p, c.c_int32, c.c_int64,
@@ -538,10 +577,12 @@ def prep_route_sharded(directories, requests, greg_mask: int):
 
 
 def available() -> bool:
+    """Whether the native library builds and loads here (no compiler, or
+    a source it refuses: False)."""
     try:
         load_library()
         return True
-    except Exception:  # noqa: BLE001
+    except (NativeBuildError, OSError):
         return False
 
 
@@ -754,8 +795,11 @@ def make_key_directory(capacity: int, prefer_native: bool = True):
     if prefer_native and not os.environ.get("GUBER_NO_NATIVE"):
         try:
             return NativeKeyDirectory(capacity)
-        except Exception:  # noqa: BLE001
-            pass
+        except (NativeBuildError, OSError) as e:
+            log.warning(
+                "native key directory unavailable, serving from the Python "
+                "directory (set GUBER_NO_NATIVE=1 to choose it on purpose): "
+                "%s", e)
     from gubernator_tpu.models.keyspace import KeyDirectory
 
     return KeyDirectory(capacity)

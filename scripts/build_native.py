@@ -1,44 +1,37 @@
 #!/usr/bin/env python
 """Rebuild every native component from source (`make native`).
 
-Produces the exact mtime-keyed cache names the runtime loaders
-(gubernator_tpu/native/__init__.py) and the TSan suite (tests/test_tsan.py)
-expect, deleting stale caches — so after editing keydir.cpp or peerlink.cpp
-one command restores a verifiable binary set:
+Goes through the runtime's own builder
+(gubernator_tpu/native/__init__.py build_component), so the cache names
+are exactly the ones the loaders and the TSan suite (tests/test_tsan.py)
+look for: `_<flavor>_<component>_<hash>.so`, the hash taken over the
+source bytes and the compile command. Other-hash siblings are deleted, so
+after editing keydir.cpp or peerlink.cpp one command restores a
+verifiable binary set:
 
-    _keydir_<mtime>.so          g++ -O2            (runtime)
-    _peerlink_<mtime>.so        g++ -O2            (runtime)
-    _tsan_keydir_<mtime>.so     g++ -O1 -g -fsanitize=thread
-    _tsan_peerlink_<mtime>.so   g++ -O1 -g -fsanitize=thread
+    _keydir_<hash>.so          g++ -O2            (runtime)
+    _peerlink_<hash>.so        g++ -O2            (runtime)
+    _tsan_keydir_<hash>.so     g++ -O1 -g -fsanitize=thread
+    _tsan_peerlink_<hash>.so   g++ -O1 -g -fsanitize=thread
 
 `--sanitize` (`make sanitize`) builds the full sanitizer matrix instead:
-the TSan pair above (pre-warming the exact cache tests/test_tsan.py
-keys on) plus ASan and UBSan variants of both sources —
-
-    _asan_keydir_<mtime>.so     g++ -O1 -g -fsanitize=address
-    _asan_peerlink_<mtime>.so   g++ -O1 -g -fsanitize=address
-    _ubsan_keydir_<mtime>.so    g++ -O1 -g -fsanitize=undefined
-    _ubsan_peerlink_<mtime>.so  g++ -O1 -g -fsanitize=undefined
-
-(TSan and ASan are mutually exclusive instrumentation, hence separate
-.so flavors; all share the mtime cache keying so a rebuild is a no-op
-until the source changes.)
+the TSan pair above plus `_asan_*` (-fsanitize=address) and `_ubsan_*`
+(-fsanitize=undefined) variants of both sources.
 
 tests/test_native_build.py is the matching drift check: it fails when a
-cached .so predates its source or misses the exported symbol surface.
+cached .so was built from other source than the tree's or misses the
+exported symbol surface.
 """
 
 import os
 import subprocess
 import sys
-import sysconfig
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-NATIVE = os.path.join(ROOT, "gubernator_tpu", "native")
-PYINC = f"-I{sysconfig.get_paths()['include']}"
+from gubernator_tpu import native  # noqa: E402
 
 # warnings are errors for the native tier: the sources must stay clean
 # under the same -Wall -Wextra sweep guberlint's native-warnings rule
@@ -46,64 +39,27 @@ PYINC = f"-I{sysconfig.get_paths()['include']}"
 # in lockstep
 WARN = ["-Wall", "-Wextra", "-Werror"]
 
-# (source, cache prefix, extra flags) for each build flavor
-TSAN_BUILDS = [
-    ("keydir.cpp", "_tsan_keydir_",
-     [*WARN, "-O1", "-g", "-fsanitize=thread", "-pthread", PYINC]),
-    ("peerlink.cpp", "_tsan_peerlink_",
-     [*WARN, "-O1", "-g", "-fsanitize=thread", "-pthread"]),
-]
 
-BUILDS = [
-    ("keydir.cpp", "_keydir_", [*WARN, "-O2", PYINC]),
-    ("peerlink.cpp", "_peerlink_", [*WARN, "-O2"]),
-    *TSAN_BUILDS,
-]
-
-# ASan catches what TSan structurally cannot (heap overflow,
-# use-after-free on the single-threaded paths); UBSan the arithmetic /
-# alignment traps in the frame codecs. -fno-omit-frame-pointer keeps
-# ASan stacks honest at -O1.
-SANITIZE_BUILDS = [
-    *TSAN_BUILDS,
-    ("keydir.cpp", "_asan_keydir_",
-     [*WARN, "-O1", "-g", "-fsanitize=address", "-fno-omit-frame-pointer",
-      "-pthread", PYINC]),
-    ("peerlink.cpp", "_asan_peerlink_",
-     [*WARN, "-O1", "-g", "-fsanitize=address", "-fno-omit-frame-pointer",
-      "-pthread"]),
-    ("keydir.cpp", "_ubsan_keydir_",
-     [*WARN, "-O1", "-g", "-fsanitize=undefined", "-pthread", PYINC]),
-    ("peerlink.cpp", "_ubsan_peerlink_",
-     [*WARN, "-O1", "-g", "-fsanitize=undefined", "-pthread"]),
-]
+def warn_check(component: str) -> None:
+    src, cmd = native._command(component, "")
+    subprocess.run([*cmd, *WARN, "-fsyntax-only", src], check=True)
 
 
-def build(src_name: str, prefix: str, flags) -> str:
-    src = os.path.join(NATIVE, src_name)
-    mtime = int(os.stat(src).st_mtime)
-    path = os.path.join(NATIVE, f"{prefix}{mtime}.so")
-    fresh = not os.path.exists(path)
-    if fresh:
-        tmp = path + ".tmp"
-        subprocess.run(
-            ["g++", *flags, "-shared", "-fPIC", "-std=c++17",
-             "-o", tmp, src],
-            check=True)
-        os.replace(tmp, path)
-    for name in os.listdir(NATIVE):
-        if name.startswith(prefix) and name.endswith(".so") and \
-                os.path.join(NATIVE, name) != path:
-            os.unlink(os.path.join(NATIVE, name))
+def build(component: str, flavor: str) -> str:
+    fresh = not os.path.exists(native.cache_path(component, flavor))
+    path = native.build_component(component, flavor)
     print(f"{'built' if fresh else 'cached'}  {os.path.relpath(path, ROOT)}")
     return path
 
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
-    builds = SANITIZE_BUILDS if "--sanitize" in argv else BUILDS
-    for src, prefix, flags in builds:
-        build(src, prefix, flags)
+    flavors = ("tsan", "asan", "ubsan") if "--sanitize" in argv \
+        else ("", "tsan")
+    for component in native.COMPONENTS:
+        warn_check(component)
+        for flavor in flavors:
+            build(component, flavor)
     return 0
 
 

@@ -34,26 +34,12 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 NATIVE = os.path.join(HERE, "..", "gubernator_tpu", "native")
 
 
-def _tsan_lib(src_name: str, prefix: str, extra=()):
-    """Build the TSan variant of a native source (cached by mtime)."""
-    src = os.path.join(NATIVE, src_name)
-    mtime = int(os.stat(src).st_mtime)
-    path = os.path.join(NATIVE, f"{prefix}{mtime}.so")
-    if not os.path.exists(path):
-        tmp = path + ".tmp"
-        subprocess.run(
-            ["g++", "-O1", "-g", "-shared", "-fPIC", "-std=c++17",
-             "-fsanitize=thread", "-pthread", *extra, "-o", tmp, src],
-            check=True, capture_output=True)
-        os.replace(tmp, path)
-        for name in os.listdir(NATIVE):
-            if name.startswith(prefix) and name.endswith(".so") and \
-                    os.path.join(NATIVE, name) != path:
-                try:
-                    os.unlink(os.path.join(NATIVE, name))
-                except OSError:
-                    pass
-    return path
+def _tsan_lib(component: str) -> str:
+    """Build the TSan variant of a native component (hash-keyed cache,
+    the runtime's own builder)."""
+    from gubernator_tpu import native
+
+    return native.build_component(component, "tsan")
 
 
 def _find_libtsan():
@@ -362,17 +348,13 @@ _GRPC_FRONT_FUZZ = textwrap.dedent("""
 
 
 @pytest.mark.skipif(LIBTSAN is None, reason="libtsan not installed")
-@pytest.mark.parametrize("name,src,prefix,extra,script,sentinel", [
-    ("peerlink", "peerlink.cpp", "_tsan_peerlink_", (),
-     _PEERLINK_STRESS, "PEERLINK_STRESS_OK"),
-    ("keydir", "keydir.cpp", "_tsan_keydir_",
-     ("-I" + __import__("sysconfig").get_paths()["include"],),
-     _KEYDIR_STRESS, "KEYDIR_STRESS_OK"),
-    ("grpc_front", "peerlink.cpp", "_tsan_peerlink_", (),
-     _GRPC_FRONT_FUZZ, "GRPC_FRONT_FUZZ_OK"),
+@pytest.mark.parametrize("name,component,script,sentinel", [
+    ("peerlink", "peerlink", _PEERLINK_STRESS, "PEERLINK_STRESS_OK"),
+    ("keydir", "keydir", _KEYDIR_STRESS, "KEYDIR_STRESS_OK"),
+    ("grpc_front", "peerlink", _GRPC_FRONT_FUZZ, "GRPC_FRONT_FUZZ_OK"),
 ])
-def test_tsan_clean(tmp_path, name, src, prefix, extra, script, sentinel):
-    lib = _tsan_lib(src, prefix, extra)
+def test_tsan_clean(tmp_path, name, component, script, sentinel):
+    lib = _tsan_lib(component)
     worker = tmp_path / f"stress_{name}.py"
     worker.write_text(script)
     env = dict(os.environ)
