@@ -69,10 +69,9 @@ def build_backend(conf: DaemonConfig):
             min_width=conf.min_batch_width,
             max_width=conf.max_batch_width,
             loader=_make_loader(conf),
-            collectives=conf.collectives,
         )
-        log.info("backend: sharded over %d devices, %d slots/shard (%s)",
-                 n_dev, cap, conf.collectives)
+        log.info("backend: sharded over %d devices, %d slots/shard",
+                 n_dev, cap)
         return eng
     if conf.device_directory:
         # on-chip key directory: zero host round trips per key; no

@@ -229,8 +229,8 @@ class DaemonConfig:
     # are bit-identical to the ledger removed)
     ledger_enabled: bool = True
     # GLOBAL-sync collective implementation for the sharded backend:
-    # "psum" (XLA, default) or "ring" (Pallas ICI ring — TPU-compiled only,
-    # single-region meshes; see ops/ring.py)
+    # "psum" (XLA) is the only one; the knob stays so that a deployment
+    # still asking for the removed Pallas ring fails at boot, not silently
     collectives: str = "psum"
     # multi-host device process group (parallel/multihost.py); num_hosts <= 1
     # means single-host, no group formed
@@ -423,10 +423,11 @@ def config_from_env(args: Optional[List[str]] = None) -> DaemonConfig:
         fault_spec=_env_str("GUBER_FAULT_SPEC"),
         debug=opts.debug or bool(os.environ.get("GUBER_DEBUG")),
     )
-    if conf.collectives not in ("psum", "ring"):
+    if conf.collectives != "psum":
         raise ValueError(
             f"'GUBER_COLLECTIVES={conf.collectives}' is invalid; "
-            "choices are ['psum', 'ring']")
+            "choices are ['psum'] (the Pallas ring was removed: the TPU "
+            "compiler refuses it)")
     if conf.pipeline_scan < 1:
         raise ValueError(
             f"'GUBER_PIPELINE_SCAN={conf.pipeline_scan}' is invalid; "

@@ -61,42 +61,18 @@ class GlobalConfig(NamedTuple):
     fresh: jax.Array  # bool[G] owner slot newly assigned
 
 
-def make_global_sync(plan: MeshPlan, donate: bool = False,
-                     collectives: str = "psum"):
+def make_global_sync(plan: MeshPlan, donate: bool = False):
     """Compile the one-step GLOBAL sync over the plan's mesh.
 
     Returns fn(state, delta, cfg, now) -> (state, mirror, zeroed delta):
     - state: sharded TableState [R, S, C]
     - delta: i64[R, S, G] — each device's local hit deltas (sharded)
     - cfg: GlobalConfig of replicated [G] arrays
-
-    `collectives` picks the reduction implementation: "psum" (XLA's
-    collective schedule, the default — optimal for these ~8 KB payloads) or
-    "ring" (the explicit Pallas ICI ring of ops/ring.py; single-region
-    meshes only — the ring circles the shard axis, so a second region would
-    silently sum region-locally). The ring variant compiles only on real
-    TPU meshes: the CPU Pallas interpreter's remote DMA supports a single
-    named mesh axis, so the CPU test mesh (2-D region×shard) cannot execute
-    it — tests/test_ring.py instead holds the ring kernel bit-equal to psum
-    on a 1-D mesh.
     """
-    if collectives not in ("psum", "ring"):
-        raise ValueError(f"unknown collectives '{collectives}'")
-    if collectives == "ring" and plan.n_regions != 1:
-        raise ValueError(
-            "ring collectives support single-region meshes only (the ring "
-            "reduces over the shard axis; psum handles multi-region)")
     S = plan.n_shards
     state_spec = P(REGION_AXIS, SHARD_AXIS, None, None)
     delta_spec = P(REGION_AXIS, SHARD_AXIS, None)
     rep = P()
-
-    def _ring(length: int, collective_id: int):
-        from gubernator_tpu.ops.ring import make_ring_all_reduce
-
-        return make_ring_all_reduce(
-            S, length, dtype=I64, axis_name=SHARD_AXIS,
-            mesh_axes=(REGION_AXIS, SHARD_AXIS), collective_id=collective_id)
 
     def _step(
         state: TableState, delta: jax.Array, cfg: GlobalConfig, now: jax.Array
@@ -104,10 +80,7 @@ def make_global_sync(plan: MeshPlan, donate: bool = False,
         local_state = state.reshape(state.shape[-2:])  # i64[C, 8]
         local_delta = delta.reshape(delta.shape[-1:])  # i64[G]
 
-        if collectives == "psum":
-            total = jax.lax.psum(local_delta, (REGION_AXIS, SHARD_AXIS))
-        else:
-            total = _ring(local_delta.shape[0], 0)(local_delta)
+        total = jax.lax.psum(local_delta, (REGION_AXIS, SHARD_AXIS))
         my_id = (
             jax.lax.axis_index(REGION_AXIS) * S + jax.lax.axis_index(SHARD_AXIS)
         ).astype(I32)
@@ -130,22 +103,11 @@ def make_global_sync(plan: MeshPlan, donate: bool = False,
         # contribute zeros)
         cols = (resp.status.astype(jnp.int64), resp.limit,
                 resp.remaining, resp.reset_time)
-        if collectives == "psum":
-            summed = [
-                jax.lax.psum(jnp.where(mine, c, jnp.zeros_like(c)),
-                             (REGION_AXIS, SHARD_AXIS))
-                for c in cols
-            ]
-        else:
-            # one stacked ring pass (distinct collective_id from the delta
-            # ring above: the two have a data dependence through `resp`, but
-            # sharing a barrier-semaphore group across pallas_calls is not
-            # something to rely on)
-            stacked = jnp.concatenate(
-                [jnp.where(mine, c, jnp.zeros_like(c)) for c in cols])
-            out = _ring(stacked.shape[0], 1)(stacked)
-            g = cols[0].shape[0]
-            summed = [out[i * g:(i + 1) * g] for i in range(4)]
+        summed = [
+            jax.lax.psum(jnp.where(mine, c, jnp.zeros_like(c)),
+                         (REGION_AXIS, SHARD_AXIS))
+            for c in cols
+        ]
         mirror = GlobalMirror(
             status=summed[0].astype(I32),
             limit=summed[1],
@@ -160,9 +122,5 @@ def make_global_sync(plan: MeshPlan, donate: bool = False,
         mesh=plan.mesh,
         in_specs=(state_spec, delta_spec, rep, rep),
         out_specs=(state_spec, rep, delta_spec),
-        # the pallas ring's out_shape carries no varying-mesh-axes metadata,
-        # so the static VMA checker can't type it; the kernel itself is
-        # device-symmetric (every device runs the same N-1 hops)
-        check_vma=(collectives == "psum"),
     )
     return jax.jit(mapped, donate_argnums=(0,) if donate else ())

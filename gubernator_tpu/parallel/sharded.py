@@ -296,7 +296,6 @@ class ShardedEngine:
         donate: Optional[bool] = None,
         loader=None,
         store=None,
-        collectives: str = "psum",
         global_idle_ms: int = 60_000,
     ):
         if mesh is None:
@@ -317,8 +316,7 @@ class ShardedEngine:
         # eligible windows on the 4 B/lane lean wire; wide pins i64[9]
         self._staging = staging_policy()
         self._lean_ok = lean_capacity_ok(capacity_per_shard)
-        self._sync = make_global_sync(self.plan, donate=donate,
-                                      collectives=collectives)
+        self._sync = make_global_sync(self.plan, donate=donate)
         self.store = store
         if store is not None:
             self._gather = make_gather_sharded(self.plan)

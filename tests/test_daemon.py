@@ -110,8 +110,8 @@ def test_start_profiling_noop_by_default():
 def test_collectives_env_parsing(monkeypatch):
     from gubernator_tpu.cmd.envconf import config_from_env
 
-    monkeypatch.setenv("GUBER_COLLECTIVES", "ring")
-    assert config_from_env([]).collectives == "ring"
+    monkeypatch.setenv("GUBER_COLLECTIVES", "psum")
+    assert config_from_env([]).collectives == "psum"
     monkeypatch.delenv("GUBER_COLLECTIVES")
     assert config_from_env([]).collectives == "psum"
 
@@ -121,9 +121,11 @@ def test_collectives_env_validation(monkeypatch):
 
     from gubernator_tpu.cmd.envconf import config_from_env
 
-    monkeypatch.setenv("GUBER_COLLECTIVES", "rings")
-    with pytest.raises(ValueError, match="GUBER_COLLECTIVES"):
-        config_from_env([])
+    # "ring" named the Pallas ring all-reduce the TPU compiler refused
+    for bad in ("rings", "ring"):
+        monkeypatch.setenv("GUBER_COLLECTIVES", bad)
+        with pytest.raises(ValueError, match="GUBER_COLLECTIVES"):
+            config_from_env([])
 
 
 def test_etcd_env_parsing(monkeypatch):

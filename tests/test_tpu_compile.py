@@ -163,7 +163,7 @@ class TestFourChips:
             algorithm=r(I32), behavior=r(I32), greg_expire=r(I64),
             greg_interval=r(I64), fresh=r(jnp.bool_))
         now = jax.ShapeDtypeStruct((), I64, sharding=rep)
-        sync = make_global_sync(plan, donate=True, collectives="psum")
+        sync = make_global_sync(plan, donate=True)
         compiled = sync.lower(state, delta, cfg, now).compile()
         assert "all-reduce" in compiled.as_text()
         mem = compiled.memory_analysis()
