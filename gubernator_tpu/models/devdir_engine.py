@@ -87,20 +87,16 @@ class DevDirEngine(Engine):
         self._prep_fast = None
         self.fps = make_fingerprints(capacity)
         self.touch = make_touch(capacity)
-        if donate is None:
-            from gubernator_tpu.utils.platform import donation_supported
-
-            donate = donation_supported()
-        self._devdir_step = _jit_devdir_decide(donate)
-        self._refresh = _jit_refresh(donate)
+        self.device["key_directory"] = "device"
+        self._devdir_step = _jit_devdir_decide(self.donate)
+        self._refresh = _jit_refresh(self.donate)
         self._rounds_since_sweep = 0
         self._probe_seq = 0  # per-dispatch eviction epoch (starts > 0)
-        try:  # C fingerprint batch; python twin otherwise
-            from gubernator_tpu import native
+        from gubernator_tpu import native
 
-            native.load_library()
+        if native.available():  # C fingerprint batch; python twin otherwise
             self._fingerprints = native.fingerprint_batch
-        except Exception:  # noqa: BLE001
+        else:
             self._fingerprints = lambda keys: np.fromiter(
                 (key_fingerprint(k) for k in keys), np.int64,
                 count=len(keys))

@@ -234,6 +234,7 @@ class Engine:
             from gubernator_tpu.utils.platform import donation_supported
 
             donate = donation_supported()
+        self.donate = donate
         self._decide_packed = _jit_decide_packed(donate)
         self._decide_scan = _jit_decide_scan(donate)
         self._decide_packed_compact = _jit_decide_packed_compact(donate)
@@ -254,6 +255,12 @@ class Engine:
                 self.load_snapshot_slabs(loader.load_slabs())
             else:
                 self.load_snapshot(loader.load())
+        # boot line + /v1/debug/vars (utils/platform.py); placement never
+        # changes after construction, so it is read once
+        from gubernator_tpu.utils.platform import device_facts
+
+        self.device = device_facts(
+            self, "native" if self._prep_fast is not None else "python")
 
     # ------------------------------------------------------------------ API
 

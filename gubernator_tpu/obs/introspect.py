@@ -44,6 +44,12 @@ def _backend_vars(backend) -> dict:
         v = getattr(backend, attr, None)
         if isinstance(v, int):
             out[attr] = v
+    # platform / device kind / count / table bytes per device / donation /
+    # key directory, as the daemon's boot line prints them
+    # (utils/platform.py device_facts); absent on stub backends
+    device = getattr(backend, "device", None)
+    if isinstance(device, dict):
+        out["device"] = dict(device)
     occ = key_table_size(backend)
     if occ is not None:
         out["key_table_size"] = occ

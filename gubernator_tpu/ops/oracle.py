@@ -176,3 +176,85 @@ def oracle_decide(
     return OracleResp(
         Status.OVER_LIMIT if over else Status.UNDER_LIMIT, limit, rem, now + rate
     )
+
+
+# --------------------------------------------------------------- service
+# The oracle at the request surface, shared by chip_smoke.py (a daemon
+# that reads its own wall clock) and __graft_entry__.dryrun_multichip
+# (explicit clock). Pure Python: it must stay importable by a process
+# that never initialises a JAX backend.
+
+
+def oracle_answer(table: Dict[str, Row], req, now: int):
+    """One RateLimitReq against `table` at `now`, as the service answers
+    it: validation errors first, calendar fields for
+    DURATION_IS_GREGORIAN, then the bucket math. GLOBAL is an instruction
+    to the cluster tier, not to the bucket: the owner applies the request
+    as a plain one."""
+    import datetime as _dt
+
+    from gubernator_tpu.types import (
+        ERR_EMPTY_NAME, ERR_EMPTY_UNIQUE_KEY, RateLimitResp)
+    from gubernator_tpu.utils.gregorian import (
+        GregorianError, gregorian_duration, gregorian_expiration)
+
+    if not req.unique_key:
+        return RateLimitResp(error=ERR_EMPTY_UNIQUE_KEY)
+    if not req.name:
+        return RateLimitResp(error=ERR_EMPTY_NAME)
+    ge = gi = 0
+    if int(req.behavior) & Behavior.DURATION_IS_GREGORIAN:
+        local_now = _dt.datetime.fromtimestamp(now / 1000.0)
+        try:
+            ge = gregorian_expiration(local_now, req.duration)
+            gi = gregorian_duration(local_now, req.duration)
+        except GregorianError as e:
+            return RateLimitResp(error=str(e))
+    r = oracle_decide(
+        table, req.hash_key(), hits=req.hits, limit=req.limit,
+        duration=req.duration, algorithm=int(req.algorithm),
+        behavior=int(req.behavior), now=now, greg_expire=ge,
+        greg_interval=gi)
+    return RateLimitResp(status=int(r.status), limit=r.limit,
+                         remaining=r.remaining, reset_time=r.reset_time)
+
+
+class BracketOracle:
+    """Expected answers of a server whose clock cannot be pinned.
+
+    The caller notes the time just before it sends a batch (`t0`) and
+    just after it receives the answers (`t1`); the server decided at some
+    instant in between. Two tables replay every batch, one at `t0` and one
+    at `t1`. A field the clock does not reach (status, limit, remaining
+    of a token bucket inside its duration) comes out the same from both
+    and must be matched exactly; a field it does reach (`reset_time`,
+    leaky `remaining` around a leak tick) must lie between the two. With
+    `t0 == t1` (an engine driven with an explicit clock) every field is
+    exact."""
+
+    FIELDS = ("status", "limit", "remaining", "reset_time")
+
+    def __init__(self):
+        self.early: Dict[str, Row] = {}
+        self.late: Dict[str, Row] = {}
+
+    def check(self, reqs, resps, t0: int, t1: int) -> list:
+        """Replay `reqs` in order and compare; returns the mismatches as
+        strings (empty = every response is one the oracle allows)."""
+        bad = []
+        if len(reqs) != len(resps):
+            return [f"{len(reqs)} requests, {len(resps)} responses"]
+        for i, (req, got) in enumerate(zip(reqs, resps)):
+            a = oracle_answer(self.early, req, t0)
+            b = oracle_answer(self.late, req, t1)
+            if got.error != a.error or a.error != b.error:
+                bad.append(f"#{i} {req.hash_key()!r}: error {got.error!r}, "
+                           f"oracle {a.error!r}")
+                continue
+            for f in self.FIELDS:
+                lo, hi = sorted((int(getattr(a, f)), int(getattr(b, f))))
+                if not lo <= int(getattr(got, f)) <= hi:
+                    bad.append(
+                        f"#{i} {req.hash_key()!r}: {f}={getattr(got, f)}, "
+                        f"oracle [{lo}, {hi}] (clock {t0}..{t1})")
+        return bad

@@ -305,6 +305,7 @@ class ShardedEngine:
             from gubernator_tpu.utils.platform import donation_supported
 
             donate = donation_supported()
+        self.donate = donate
         self.state = make_sharded_table(self.plan)
         self._decide = make_decide_sharded(self.plan, donate=donate)
         self._decide_scan = make_decide_sharded_scan(self.plan, donate=donate)
@@ -384,6 +385,12 @@ class ShardedEngine:
 
         if loader is not None:
             self.load_snapshot(loader.load())
+        # boot line + /v1/debug/vars: one entry per device the table's
+        # shards actually sit on (utils/platform.py)
+        from gubernator_tpu.utils.platform import device_facts
+
+        self.device = device_facts(
+            self, "native" if self._prep_fast is not None else "python")
 
     # ------------------------------------------------------------------ API
 

@@ -25,7 +25,12 @@ if not os.environ.get("GUBER_TEST_TPU"):
 # poison HealthCheck via the 5-minute peer-error TTL.
 import jax as _jax  # noqa: E402
 
-_cache_dir = os.path.join(os.path.dirname(__file__), ".jax_cache")
+# Same rule as the daemon's utils/platform.compile_cache_dir (not imported:
+# nothing of the package may load before the witness env below is set):
+# JAX_COMPILATION_CACHE_DIR wins, otherwise a fixed directory.
+_cache_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+_cache_dir = _cache_env or os.path.join(os.path.dirname(__file__),
+                                        ".jax_cache")
 
 # Self-healing for a poisoned cache: a run killed mid-write (OOM kill,
 # watchdog SIGKILL, ctrl-C at the wrong instant) can leave a truncated
@@ -65,7 +70,8 @@ os.makedirs(_cache_dir, exist_ok=True)
 with open(_sentinel, "w") as _f:
     _f.write(str(os.getpid()))
 
-_jax.config.update("jax_compilation_cache_dir", _cache_dir)
+if not _cache_env:
+    _jax.config.update("jax_compilation_cache_dir", _cache_dir)
 _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 # ---------------------------------------------------------------------------

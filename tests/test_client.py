@@ -108,7 +108,9 @@ class TestClusterBinary:
             os.environ, JAX_PLATFORMS="cpu",
             # reuse the suite's persistent compile cache — a cold subprocess
             # otherwise recompiles every width bucket (~2 min)
-            JAX_COMPILATION_CACHE_DIR=os.path.join(repo, "tests", ".jax_cache"),
+            JAX_COMPILATION_CACHE_DIR=os.environ.get(
+                "JAX_COMPILATION_CACHE_DIR",
+                os.path.join(repo, "tests", ".jax_cache")),
             JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0.5",
         )
 
