@@ -39,7 +39,7 @@ bench-skew:
 	python bench.py --skew
 
 # wire contract v1 vs v2 over a loopback peerlink, bare CPU rig plus a
-# link-emulated (BENCH_r05-class tunnel latency) regime (BENCH_r10)
+# link-emulated (slow host<->device link) regime
 bench-wire:
 	python bench.py --wire
 

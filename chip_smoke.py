@@ -82,6 +82,29 @@ def cache_entries(path: str) -> int:
         return 0
 
 
+def rss_mb(pid) -> dict:
+    """Resident set now and at its peak (VmHWM), MB, of one process."""
+    out = {}
+    with open(f"/proc/{pid}/status") as f:
+        for line in f:
+            if line.startswith(("VmRSS", "VmHWM")):
+                out[line[:5]] = int(line.split()[1]) // 1024
+    return {"rss_mb": out.get("VmRSS"), "peak_rss_mb": out.get("VmHWM")}
+
+
+def host_memory_mb() -> int:
+    """What this machine lets us use: physical memory, or the cgroup's
+    limit where that is lower."""
+    with open("/proc/meminfo") as f:
+        total = int(f.readline().split()[1]) // 1024
+    try:
+        with open("/sys/fs/cgroup/memory.max") as f:
+            total = min(total, int(f.read()) >> 20)
+    except (OSError, ValueError):
+        pass
+    return total
+
+
 # ------------------------------------------------------------------ daemon
 
 
@@ -120,7 +143,7 @@ class Daemon:
             os.path.join(REPO, ".jax_cache")
         os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
         self.log_path = os.path.join(
-            REPO, "chiprun_out", f"chip_smoke_daemon_{chips}.log")
+            REPO, "chiprun_out", f"chip_smoke_daemon_{chips}_{int(time.time())}.log")
         entries_before = cache_entries(self.cache_dir)
         t0 = time.time()
         self.proc = subprocess.Popen(
@@ -142,6 +165,7 @@ class Daemon:
                     "start", f"daemon exited {self.proc.returncode} before "
                     f"Ready (JAX_PLATFORMS={env['JAX_PLATFORMS']}):\n"
                     + self.log_tail())
+            self.check_memory("start")
             if time.time() > deadline:
                 self.kill()
                 raise StepFailed(
@@ -157,7 +181,18 @@ class Daemon:
              table_slots=slots, min_batch_width=min_width,
              max_batch_width=8192, compile_cache_dir=self.cache_dir,
              cache_entries_before=entries_before,
-             cache_entries_after=cache_entries(self.cache_dir))
+             cache_entries_after=cache_entries(self.cache_dir),
+             daemon_memory=rss_mb(self.proc.pid),
+             host_memory_mb=host_memory_mb())
+
+    def check_memory(self, step: str) -> dict:
+        """The daemon's resident set; fails the step while there is still
+        room to say so (a host out of memory kills the run with no word)."""
+        mem = rss_mb(self.proc.pid)
+        if mem["rss_mb"] > 0.8 * host_memory_mb():
+            raise StepFailed(step, f"daemon resident set {mem} MB is over "
+                             f"80% of the host's {host_memory_mb()} MB")
+        return mem
 
     def log_tail(self, n: int = 40) -> str:
         with open(self.log_path) as f:
@@ -219,7 +254,8 @@ class Asker:
         return resps
 
 
-def load(asker: Asker, rng: random.Random, n_keys: int, kept):
+def load(asker: Asker, daemon: Daemon, rng: random.Random, n_keys: int,
+         kept):
     """Fill the table: n_keys distinct keys in batches of BATCH, half over
     HTTP and half over gRPC, TOKEN_BUCKET and LEAKY_BUCKET alternating.
     The `kept` requests go in last, through the long-lived oracle tables,
@@ -244,6 +280,7 @@ def load(asker: Asker, rng: random.Random, n_keys: int, kept):
                 if failed:
                     return
                 asker.ask("load", via, reqs, oracle=BracketOracle())
+                daemon.check_memory("load")
         except Exception as e:  # noqa: BLE001 — re-raised on the main thread
             failed.append(e)
 
@@ -259,7 +296,9 @@ def load(asker: Asker, rng: random.Random, n_keys: int, kept):
          batches=len(batches) + 1, batch_size=BATCH,
          transports=["http", "grpc"], algorithms=["TOKEN_BUCKET",
                                                   "LEAKY_BUCKET"],
-         seconds=round(time.time() - t0, 1), answers_compared=asker.compared)
+         seconds=round(time.time() - t0, 1), answers_compared=asker.compared,
+         daemon_memory=daemon.check_memory("load"),
+         parent_memory=rss_mb(os.getpid()))
 
 
 def answer(asker: Asker, rng: random.Random, kept, global_keys: bool):
@@ -384,6 +423,7 @@ def inspect(daemon: Daemon, chips: int, rehearse: bool) -> dict:
         or rehearse,
     }
     emit(step="inspect", device_rounds=dispatches,
+         daemon_memory=daemon.check_memory("inspect"),
          engine_kernel_dispatch_total=launches, device=dev,
          key_table_size=eng.get("key_table_size"),
          engine_type=eng["type"], engine_errors=eng["stats"].get("errors"),
@@ -453,7 +493,7 @@ def main() -> int:
     daemon = Daemon(args.chips, args.rehearse, min_width, args.ready_timeout)
     try:
         asker = Asker(daemon)
-        load(asker, rng, n_keys, kept)
+        load(asker, daemon, rng, n_keys, kept)
         answer(asker, rng, kept, global_keys=args.chips > 1)
         dev = inspect(daemon, args.chips, args.rehearse)
         daemon.stop()
@@ -476,10 +516,12 @@ def main() -> int:
     return 0
 
 
-# bottom of the width ladder the daemon compiles at boot, per --chips (the
-# top stays 8192 and the table stays whole; see CHANGES.md PR 22 for the
-# cold-start times that chose these)
-MIN_WIDTH = {1: 64, 4: 64}
+# GUBER_MIN_BATCH_WIDTH per --chips: the bottom of the width ladder the
+# daemon compiles at boot. The shipped 64 was run once on the chip (PR 22:
+# 845 s to Ready cold, 208 s of it the three 256-wide programs), which
+# leaves too little of the 1200 s this script may take; from 512 up a cold
+# start fits. The top stays 8192 and the table stays whole.
+MIN_WIDTH = {1: 512, 4: 512}
 
 
 if __name__ == "__main__":

@@ -84,6 +84,7 @@ from gubernator_tpu.types import (
     has_behavior,
 )
 from gubernator_tpu.utils.interval import millisecond_now
+from gubernator_tpu.utils.platform import release_compile_memory
 
 from gubernator_tpu.native import PREP_OVERCOMMIT
 
@@ -417,6 +418,7 @@ class ShardedEngine:
                     self.state, resp = self._decide_lean(
                         self.state, jnp.asarray(ln[0]),
                         jnp.asarray(ln[1]), 0)
+                release_compile_memory()
             k = 2
             while k <= self._MAX_SCAN:
                 packed = np.zeros((R, S, k, 9, self.min_width), np.int64)
@@ -427,6 +429,7 @@ class ShardedEngine:
                     self.state, resp = self._decide_scan_lean(
                         self.state, jnp.asarray(ln[0]),
                         jnp.asarray(ln[1]), 0)
+                release_compile_memory()
                 k *= 2
             if self.store is not None:
                 # the Store path adds two gathers + an inject per window

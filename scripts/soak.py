@@ -48,10 +48,12 @@ def main(argv=None) -> int:
 
     import jax
 
-    # CPU by default: the soak measures the serving stack, and merely
-    # probing the default backend would initialize a possibly-absent TPU.
-    # Set SOAK_PLATFORM=tpu (or any JAX platform) to override.
-    jax.config.update("jax_platforms", os.environ.get("SOAK_PLATFORM", "cpu"))
+    # the ambient device (JAX_PLATFORMS picks another); SOAK_PLATFORM
+    # still pins one for this script alone
+    if os.environ.get("SOAK_PLATFORM"):
+        jax.config.update("jax_platforms", os.environ["SOAK_PLATFORM"])
+    print(f"soak platform: {jax.devices()[0].platform} "
+          f"({jax.devices()[0].device_kind})", flush=True)
 
     import grpc
 
