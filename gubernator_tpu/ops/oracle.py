@@ -41,7 +41,21 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional, Tuple
 
-from gubernator_tpu.types import Algorithm, Behavior, Status
+import datetime as _dt
+
+from gubernator_tpu.types import (
+    ERR_EMPTY_NAME,
+    ERR_EMPTY_UNIQUE_KEY,
+    Algorithm,
+    Behavior,
+    RateLimitResp,
+    Status,
+)
+from gubernator_tpu.utils.gregorian import (
+    GregorianError,
+    gregorian_duration,
+    gregorian_expiration,
+)
 
 VACANT = -1
 
@@ -191,13 +205,6 @@ def oracle_answer(table: Dict[str, Row], req, now: int):
     DURATION_IS_GREGORIAN, then the bucket math. GLOBAL is an instruction
     to the cluster tier, not to the bucket: the owner applies the request
     as a plain one."""
-    import datetime as _dt
-
-    from gubernator_tpu.types import (
-        ERR_EMPTY_NAME, ERR_EMPTY_UNIQUE_KEY, RateLimitResp)
-    from gubernator_tpu.utils.gregorian import (
-        GregorianError, gregorian_duration, gregorian_expiration)
-
     if not req.unique_key:
         return RateLimitResp(error=ERR_EMPTY_UNIQUE_KEY)
     if not req.name:
@@ -238,6 +245,11 @@ class BracketOracle:
         self.early: Dict[str, Row] = {}
         self.late: Dict[str, Row] = {}
 
+    def replay(self, req, t0: int, t1: int):
+        """Apply one request to both tables; returns (early, late)."""
+        return (oracle_answer(self.early, req, t0),
+                oracle_answer(self.late, req, t1))
+
     def check(self, reqs, resps, t0: int, t1: int) -> list:
         """Replay `reqs` in order and compare; returns the mismatches as
         strings (empty = every response is one the oracle allows)."""
@@ -245,8 +257,7 @@ class BracketOracle:
         if len(reqs) != len(resps):
             return [f"{len(reqs)} requests, {len(resps)} responses"]
         for i, (req, got) in enumerate(zip(reqs, resps)):
-            a = oracle_answer(self.early, req, t0)
-            b = oracle_answer(self.late, req, t1)
+            a, b = self.replay(req, t0, t1)
             if got.error != a.error or a.error != b.error:
                 bad.append(f"#{i} {req.hash_key()!r}: error {got.error!r}, "
                            f"oracle {a.error!r}")

@@ -32,7 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from gubernator_tpu.ops.decide import I32, ReqBatch, TableState, decide
 from gubernator_tpu.parallel.mesh import (
-    MeshPlan, REGION_AXIS, SHARD_AXIS, shard_map as _shard_map)
+    MeshPlan, REGION_AXIS, SHARD_AXIS)
 
 
 class GlobalMirror(NamedTuple):
@@ -117,7 +117,7 @@ def make_global_sync(plan: MeshPlan, donate: bool = False):
         new_state = new_local.reshape((1, 1) + new_local.shape)
         return new_state, mirror, jnp.zeros_like(delta)
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         _step,
         mesh=plan.mesh,
         in_specs=(state_spec, delta_spec, rep, rep),

@@ -42,32 +42,6 @@ REGION_AXIS = "region"
 SHARD_AXIS = "shard"
 
 
-def shard_map():
-    """jax.shard_map across jax versions: top-level since 0.6 (kwarg
-    `check_vma`), under jax.experimental.shard_map before that (kwarg
-    `check_rep`) — the mesh tier is otherwise version-portable, so
-    resolve the symbol and the kwarg rename in one place.
-
-    The legacy fallback pins check_rep=False: 0.4.x's replication-
-    inference rewrite intermittently aborts the process DURING TRACING
-    (SIGABRT under partial_eval -> _standard_rewrite_rule, reproduced
-    ~2/3 runs by tests/test_fuzz.py's mesh differential). The flag only
-    controls that static inference — out_specs still define the output
-    shardings — so disabling it is behavior-neutral and keeps the
-    interpreter alive."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map as sm
-
-    def compat(f, **kwargs):
-        kwargs.pop("check_vma", None)
-        kwargs["check_rep"] = False
-        return sm(f, **kwargs)
-
-    return compat
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshPlan:
     """A mesh plus the table geometry sharded over it."""

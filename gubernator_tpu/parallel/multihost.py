@@ -36,7 +36,6 @@ from typing import Optional
 import jax
 import numpy as np
 
-from gubernator_tpu.parallel.mesh import shard_map as _shard_map
 
 log = logging.getLogger("gubernator_tpu.multihost")
 
@@ -132,7 +131,7 @@ class CollectiveGlobalChannel:
 
         spec_r = jax.sharding.PartitionSpec(NODE_AXIS, None)
         spec_r3 = jax.sharding.PartitionSpec(NODE_AXIS, None, None)
-        self._step = jax.jit(_shard_map()(
+        self._step = jax.jit(jax.shard_map(
             _exchange, mesh=self.mesh,
             in_specs=(spec_r, spec_r, spec_r3),
             out_specs=(jax.sharding.PartitionSpec(),) * 5,
@@ -221,7 +220,7 @@ class CrossHostHitSync:
             # each shard_map block is ONE device's (1, G) row slice
             return jax.lax.psum(delta[0], NODE_AXIS)
 
-        self._step = jax.jit(_shard_map()(
+        self._step = jax.jit(jax.shard_map(
             _psum, mesh=self.mesh,
             in_specs=jax.sharding.PartitionSpec(NODE_AXIS, None),
             out_specs=jax.sharding.PartitionSpec(),

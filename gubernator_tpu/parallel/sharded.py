@@ -73,7 +73,6 @@ from gubernator_tpu.parallel.mesh import (
     MeshPlan,
     make_mesh,
     make_sharded_table,
-    shard_map as _shard_map,
     shard_of_key,
 )
 from gubernator_tpu.types import (
@@ -116,7 +115,7 @@ def make_decide_sharded(plan: MeshPlan, donate: bool = False):
             out.reshape(1, 1, *out.shape),
         )
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         _step, mesh=plan.mesh,
         in_specs=(spec_state, spec_io, P()),
         out_specs=(spec_state, spec_io),
@@ -148,7 +147,7 @@ def make_decide_sharded_scan(plan: MeshPlan, donate: bool = False):
             out.reshape(1, 1, *out.shape),
         )
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         _step, mesh=plan.mesh,
         in_specs=(spec_state, spec_io, P()),
         out_specs=(spec_state, spec_io),
@@ -180,7 +179,7 @@ def make_decide_sharded_lean(plan: MeshPlan, donate: bool = False):
             out.reshape(1, 1, *out.shape),
         )
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         _step, mesh=plan.mesh,
         in_specs=(spec_state, spec_lanes, P(), P()),
         out_specs=(spec_state, spec_out),
@@ -206,7 +205,7 @@ def make_decide_sharded_scan_lean(plan: MeshPlan, donate: bool = False):
             out.reshape(1, 1, *out.shape),
         )
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         _step, mesh=plan.mesh,
         in_specs=(spec_state, spec_lanes, P(), P()),
         out_specs=(spec_state, spec_out),
@@ -234,7 +233,7 @@ def make_gather_sharded(plan: MeshPlan):
         rows = local[g][:, :7].T
         return rows.reshape(1, 1, *rows.shape)
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         _step, mesh=plan.mesh,
         in_specs=(spec_state, spec_slot), out_specs=spec_out,
     )
@@ -262,7 +261,7 @@ def make_inject_sharded(plan: MeshPlan, donate: bool = False):
         new = local.at[s].set(w8, mode="drop")
         return new.reshape((1, 1) + new.shape)
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         _step, mesh=plan.mesh,
         in_specs=(spec_state, spec_slot, spec_rows), out_specs=spec_state,
     )

@@ -8,7 +8,6 @@ from __future__ import annotations
 import ctypes
 import functools
 import os
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,17 +33,17 @@ def donation_supported() -> bool:
     return True
 
 
-def compile_cache_dir(default: Optional[str] = None) -> str:
+def compile_cache_dir() -> str:
     """Place JAX's persistent compile cache; call before the first compile.
 
     JAX_COMPILATION_CACHE_DIR wins: JAX reads it itself and no other
     directory is set in code. Without it the cache goes to a FIXED
-    directory (`<repo>/.jax_cache` unless the caller names another) — the
-    path is part of every entry's key, so a temporary name never hits."""
+    directory, `<repo>/.jax_cache` — the path is part of every entry's
+    key, so a temporary name never hits."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    path = default or os.path.join(_REPO, ".jax_cache")
+    path = os.path.join(_REPO, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
 

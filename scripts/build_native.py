@@ -41,7 +41,7 @@ WARN = ["-Wall", "-Wextra", "-Werror"]
 
 
 def warn_check(component: str) -> None:
-    src, cmd = native._command(component, "")
+    src, cmd = native.compile_command(component, "")
     subprocess.run([*cmd, *WARN, "-fsyntax-only", src], check=True)
 
 

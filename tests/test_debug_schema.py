@@ -89,6 +89,18 @@ def test_no_unknown_sections(instance):
     )
 
 
+def test_engine_section_names_the_device(instance):
+    """Platform, device kind and count, table bytes per device, donation
+    and key directory: what the daemon's boot line prints, where the
+    backend is already reported (utils/platform.py device_facts)."""
+    dev = debug_vars(instance)["engine"]["device"]
+    assert {"platform", "device_kind", "device_count",
+            "visible_device_count", "devices", "table_bytes_per_device",
+            "donation", "key_directory"} <= set(dev)
+    assert dev["platform"] == "cpu"
+    assert dev["table_bytes_per_device"] == [256 * 64]
+
+
 def test_flight_recorder_and_anomaly_shapes(instance):
     dv = debug_vars(instance)
     assert {"enabled", "capacity", "size", "dropped",
