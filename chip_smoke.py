@@ -516,11 +516,14 @@ def main() -> int:
 
 
 # GUBER_MIN_BATCH_WIDTH per --chips: the bottom of the width ladder the
-# daemon compiles at boot. The shipped 64 was run once on the chip (PR 22:
-# 845 s to Ready cold, 208 s of it the three 256-wide programs), which
-# leaves too little of the 1200 s this script may take; from 512 up a cold
-# start fits. The top stays 8192 and the table stays whole.
-MIN_WIDTH = {1: 512, 4: 512}
+# daemon compiles at boot. Measured on the v5e host, cold, 10M rows (PR 22,
+# CHANGES.md): the shipped 64 takes 845 s to Ready (208 s of it the three
+# 256-wide programs); 512 takes over 1000 s (every scan program is
+# compiled at the bottom width, ~31 s each there against ~5 s at 64); a
+# one-width ladder at 8192 takes 442 s. Only the last leaves room inside
+# the 1200 s this script may take, so every window here is 8192 wide. The
+# top width and the table are the shipped ones.
+MIN_WIDTH = {1: 8192, 4: 8192}
 
 
 if __name__ == "__main__":

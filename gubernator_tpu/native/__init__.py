@@ -724,15 +724,23 @@ class NativeKeyDirectory:
         b = key.encode("utf-8")
         return int(self._lib.keydir_peek(self._kd, b, len(b)))
 
+    _DUMP_HEADROOM = 16384  # two 8192-wide windows of fresh keys
+
     def items_raw(self) -> Tuple[bytes, np.ndarray, np.ndarray]:
         """(key_blob, offsets i64[n+1], slots i32[n]) without per-key
         decode — the streamed binary snapshot's directory walk (10M
         python tuples/str decodes would dominate the save otherwise)."""
-        n = len(self)
-        if n == 0:
+        if len(self) == 0:
             return b"", np.zeros(1, np.int64), np.empty(0, np.int32)
         buf_cap = 1 << 16
         while True:
+            # the directory keeps serving while it is dumped: size both
+            # buffers from what it holds NOW, with headroom for the keys a
+            # window may insert before the dump takes the mutex. (A count
+            # read once, outside the loop, made every retry fail the same
+            # way while the key buffer doubled without bound — the 40 GiB
+            # host of PR 22's first chip runs.)
+            n = len(self) + self._DUMP_HEADROOM
             key_buf = ctypes.create_string_buffer(buf_cap)
             offsets = np.empty(n + 1, np.int64)
             slots = np.empty(n, np.int32)
@@ -742,7 +750,9 @@ class NativeKeyDirectory:
             )
             if count >= 0:
                 break
-            buf_cap = max(buf_cap * 2, -count)
+            # -count is the key bytes held at that instant, whichever
+            # bound was short
+            buf_cap = max(buf_cap, -count + (-count >> 3) + (1 << 16))
         count = int(count)
         return (key_buf.raw[:int(offsets[count])], offsets[:count + 1],
                 slots[:count])

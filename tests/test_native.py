@@ -133,3 +133,65 @@ def test_sustained_eviction_churn_terminates():
     slots1, _ = d.lookup(["churn_499_0", "churn_499_63"])
     slots2, fresh2 = d.lookup(["churn_499_0", "churn_499_63"])
     assert slots1 == slots2 and fresh2 == [False, False]
+
+
+class TestDumpWhileServing:
+    """items_raw() against a directory that gains keys between the size
+    read and the dump (the ledger audit's resolve_slots during a load):
+    the retry must re-read the size, not double the key buffer forever."""
+
+    def test_stale_size_retries_with_a_fresh_one(self, monkeypatch):
+        from gubernator_tpu import native
+
+        d = native.NativeKeyDirectory(100_000)
+        d.lookup([f"key:{i}" for i in range(60_000)])
+        real_len = native.NativeKeyDirectory.__len__
+        calls = []
+
+        def stale_then_real(self):
+            calls.append(1)
+            # the first two reads (emptiness check, first sizing) see the
+            # directory before a 50k-key burst
+            return 10 if len(calls) <= 2 else real_len(self)
+
+        biggest = []
+        real_buf = native.ctypes.create_string_buffer
+
+        def bounded(cap):
+            biggest.append(cap)
+            assert cap < 1 << 26, "key buffer growing without bound"
+            return real_buf(cap)
+
+        monkeypatch.setattr(native.NativeKeyDirectory, "__len__",
+                            stale_then_real)
+        monkeypatch.setattr(native.ctypes, "create_string_buffer", bounded)
+        blob, off, slots = d.items_raw()
+        assert len(slots) == 60_000 and len(off) == 60_001
+        assert sorted(slots.tolist()) == list(range(60_000))
+        assert len(biggest) <= 3
+
+    def test_dump_under_concurrent_inserts(self):
+        import threading
+
+        from gubernator_tpu import native
+
+        d = native.NativeKeyDirectory(400_000)
+        d.lookup([f"seed:{i}" for i in range(1000)])
+        stop = threading.Event()
+
+        def insert():
+            b = 0
+            while not stop.is_set() and b < 300:
+                d.lookup([f"k:{b}:{i}" for i in range(1000)])
+                b += 1
+
+        t = threading.Thread(target=insert)
+        t.start()
+        try:
+            for _ in range(20):
+                blob, off, slots = d.items_raw()
+                assert len(off) == len(slots) + 1
+                assert int(off[-1]) == len(blob)
+        finally:
+            stop.set()
+            t.join()
