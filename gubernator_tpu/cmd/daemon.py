@@ -546,6 +546,12 @@ def main(argv=None) -> int:
     if device is not None:
         device["front"] = "grpcio" if server is not None else "native-h2"
         log.info("device: %s", json.dumps(device, sort_keys=True))
+    # every program serving needs is compiled by now: one compiled from
+    # here on runs inside a request (engine.device.compiles, and a
+    # profile.compile event in the flight recorder)
+    from gubernator_tpu.utils.platform import CompileWatch
+
+    backend.compile_watch = CompileWatch(recorder)
     print("Ready", flush=True)  # startup sentinel (reference: cmd/gubernator-cluster/main.go:52)
     stop.wait()
 

@@ -540,11 +540,14 @@ class Engine:
         w = _bucket_width(len(requests), self.min_width, self.max_width)
         packed = np.zeros((9, w), np.int64)
         prof = self.profiler
+        seams = prof.seams()  # host spans, while a capture runs
         tq = time.perf_counter_ns() if prof.enabled else 0
+        seams("lock_wait")
         with self._lock:
             t0 = time.perf_counter_ns()  # excludes the lock wait
             if tq:
                 prof.lock_wait("fast_window", t0 - tq)
+            seams("prep")
             n0, lane_item, leftover, inject = self._prep_fast(
                 self.directory, requests, packed, _GREG_MASK)
             if n0 == PREP_OVERCOMMIT:
@@ -567,10 +570,13 @@ class Engine:
             responses: List[Optional[RateLimitResp]] = [None] * len(requests)
             if n0:
                 self.stats.rounds += 1
+                seams("dispatch")
                 staged = self._dispatch_staged(packed, now_ms)
                 td = time.perf_counter_ns()
+                seams("readback")
                 out = self._fetch_staged(staged)
                 t2 = time.perf_counter_ns()
+                seams("demux")
                 stage["device"] += t2 - t1
                 self._obs_device(t2 - t1, n0)
                 prof.observe("dispatch", td - t1)
@@ -591,6 +597,7 @@ class Engine:
                 led = self.ledger
                 if led is not None and led.enabled:
                     led.note_slots(packed, out, n0)
+            seams(None)
         if len(leftover):
             idxs = leftover.tolist()
             tail = self._slow_window(
@@ -889,11 +896,14 @@ class Engine:
         w = _bucket_width(n, self.min_width, self.max_width)
         packed = np.zeros((9, w), np.int64)
         prof = self.profiler
+        seams = prof.seams()  # host spans, while a capture runs
         tq = time.perf_counter_ns() if prof.enabled else 0
+        seams("lock_wait")
         with self._lock:
             t0 = time.perf_counter_ns()  # excludes the lock wait
             if tq:
                 prof.lock_wait("submit_columnar", t0 - tq)
+            seams("prep")
             n0, lane_item, leftover, inject = native.prep_pack_columnar(
                 self.directory, n, keys, key_off, name_len, hits, limit,
                 duration, algorithm, behavior, slow_mask, packed)
@@ -914,6 +924,7 @@ class Engine:
             stash = None
             if n0:
                 self.stats.rounds += 1
+                seams("dispatch")
                 handle = self._dispatch_staged(packed, now_ms)
                 td = time.perf_counter_ns()
                 self.stats.stage_ns["device"] += td - t1
@@ -921,6 +932,7 @@ class Engine:
                 led = self.ledger
                 if led is not None and led.enabled:
                     stash = led.stash_columns(packed, n0)
+            seams(None)
         return (handle, lane_item, leftover, n0, stash)
 
     def complete_columnar(self, handle, out_status, out_limit,
@@ -931,9 +943,12 @@ class Engine:
         Returns the leftover item indices."""
         staged, lane_item, leftover, n0, stash = handle
         if n0:
+            seams = self.profiler.seams()
+            seams("readback")
             t0 = time.perf_counter_ns()
             rows = self._fetch_staged(staged)  # device sync for THIS window
             t1 = time.perf_counter_ns()
+            seams("demux")
             led = self.ledger
             if led is not None and led.enabled:
                 led.note_slots_deferred(stash, rows, n0)
@@ -943,6 +958,7 @@ class Engine:
             out_reset[lane_item] = rows[3, :n0]
             over = int(np.count_nonzero(rows[0, :n0] == 1))
             t2 = time.perf_counter_ns()
+            seams(None)
             self._obs_device(t1 - t0, n0)
             prof = self.profiler
             prof.observe("readback", t1 - t0)
@@ -1016,11 +1032,14 @@ class Engine:
         if led is not None and not led.enabled:
             led = None
         stashes: List[Optional[tuple]] = []
+        seams = prof.seams()  # host spans, while a capture runs
         tq = time.perf_counter_ns() if prof.enabled else 0
+        seams("lock_wait")
         with self._lock:
             t0 = time.perf_counter_ns()  # excludes the lock wait
             if tq:
                 prof.lock_wait("launch_columnar_windows", t0 - tq)
+            seams("prep")
             total = 0
             rounds = 0
             for k, wc in enumerate(windows):
@@ -1067,6 +1086,7 @@ class Engine:
             self.stats.rounds += rounds
             staged = None
             scanned = False
+            seams("dispatch" if total else None)
             if total:
                 if m == 1:
                     staged = self._dispatch_staged(buf[0], now_ms)
@@ -1083,6 +1103,7 @@ class Engine:
                 if led is not None:
                     stashes = [led.stash_columns(buf[kk], metas[kk][0])
                                for kk in range(m)]
+                seams(None)
         return (metas, failed, staged, scanned, stashes)
 
     def collect_columnar_windows(self, handle, outs):
@@ -1097,9 +1118,12 @@ class Engine:
         led = self.ledger
         if led is not None and not led.enabled:
             led = None
+        seams = self.profiler.seams()
+        seams("readback")
         t0 = time.perf_counter_ns()
         rows_all = self._fetch_staged(staged) if staged is not None else None
         t1 = time.perf_counter_ns()
+        seams("demux")
         over = 0
         lanes = 0
         leftovers = []
@@ -1118,6 +1142,7 @@ class Engine:
                     led.note_slots_deferred(stashes[k], rows, n0)
             leftovers.append(leftover)
         t2 = time.perf_counter_ns()
+        seams(None)
         if lanes:
             self._obs_device(t1 - t0, lanes)
         prof = self.profiler

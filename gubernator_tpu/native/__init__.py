@@ -268,6 +268,9 @@ def load_peerlink() -> ctypes.CDLL:
         lib.pls_partial_posts.argtypes = [c.c_void_p]
         lib.pls_v2_conns.restype = c.c_longlong
         lib.pls_v2_conns.argtypes = [c.c_void_p]
+        lib.pls_profile.restype = c.c_int
+        lib.pls_profile.argtypes = [c.c_void_p, c.POINTER(c.c_longlong),
+                                    c.c_int]
         # ---- gRPC/HTTP/2 front ----
         lib.pls_start_grpc.restype = c.c_int
         lib.pls_start_grpc.argtypes = [c.c_void_p, c.c_int, c.c_char_p]

@@ -73,6 +73,7 @@
 #include <arpa/inet.h>
 #include <atomic>
 #include <cerrno>
+#include <chrono>
 #include <cstdint>
 #include <cstring>
 #include <cstdio>
@@ -110,11 +111,42 @@ using NativeDecideFn = int (*)(void*, const char*, int32_t, int64_t,
                                int64_t, int64_t, int32_t, int32_t, int64_t,
                                int64_t*);
 
+// CLOCK_MONOTONIC, the clock Python's time.perf_counter_ns() reads on
+// Linux: the front's stamps lay beside the engine's without translation.
+inline int64_t mono_ns() {
+  return std::chrono::duration_cast<std::chrono::nanoseconds>(
+             std::chrono::steady_clock::now().time_since_epoch())
+      .count();
+}
+
+// obs/profile.py PhaseHist in C: bucket i holds observations
+// <= 2^(i+kHistShift) ns, so Python reads the counts as its own. Every
+// writer and pls_profile hold s->mu.
+constexpr int kHistShift = 10;
+constexpr int kHistBuckets = 28;
+struct Hist {
+  int64_t counts[kHistBuckets] = {};
+  int64_t n = 0, total_ns = 0, max_ns = 0;
+  void observe(int64_t ns) {
+    if (ns < 0) ns = 0;
+    int idx = ns ? 64 - __builtin_clzll((unsigned long long)ns) - kHistShift
+                 : 0;
+    if (idx < 0) idx = 0;
+    if (idx >= kHistBuckets) idx = kHistBuckets - 1;
+    counts[idx]++;
+    n++;
+    total_ns += ns;
+    if (ns > max_ns) max_ns = ns;
+  }
+};
+
 struct Frame {
   uint64_t conn_token;
   uint64_t rid;
   uint8_t method;
   uint16_t count = 0;
+  int64_t arrive_ns = 0;  // IO thread, when the frame's last byte was parsed
+  int64_t parse_ns = 0;   // wire bytes -> these columns
   // columnar request payload, exactly as parsed off the wire
   std::vector<uint16_t> name_len, ukey_len;
   std::string keys;  // name_i + ukey_i concatenated in item order
@@ -123,6 +155,7 @@ struct Frame {
 };
 
 struct PendingReply {
+  int64_t arrive_ns = 0;  // the frame's stamp (the frame moves out at the pull)
   uint8_t method = 0;
   uint16_t expected = 0;
   uint16_t got = 0;
@@ -669,6 +702,28 @@ struct Server {
   int wire_v2_max = 1;
   std::atomic<long long> partial_posts{0};  // v2 partial frames streamed
   std::atomic<long long> v2_conns{0};       // conns that upgraded to v2
+
+  // ---- profile (pls_profile; hists and counters under mu) ----
+  Hist front_wait;   // parsed -> popped by a puller, per frame
+  Hist front_call;   // parsed -> reply written, per frame
+  Hist front_parse;  // wire bytes -> columns, per queued frame
+  Hist front_write;  // reply serialise + send, per frame
+  int64_t pulls = 0, frames_pulled = 0, items_pulled = 0;
+  // frames answered in the IO thread never reach the queue:
+  // frames_pulled + frames_native is every frame that arrived
+  std::atomic<long long> frames_native{0};
+
+  // caller holds mu
+  void enqueue(Frame&& f) {
+    front_parse.observe(f.parse_ns);
+    queue.push_back(std::move(f));
+  }
+  // caller holds mu; t0 = when serialising the reply began
+  void note_reply(int64_t arrive_ns, int64_t t0) {
+    const int64_t now = mono_ns();
+    front_call.observe(now - arrive_ns);
+    front_write.observe(now - t0);
+  }
 };
 
 bool direct_send(Server* s, Conn* c, const std::string& frame);
@@ -699,6 +754,7 @@ bool native_decide_frame(Server* s, const Frame& f, int64_t out4[4]) {
     return false;  // cold/invalidated mirror: kernel path + re-seed
   }
   s->native_hits.fetch_add(1, std::memory_order_relaxed);
+  s->frames_native.fetch_add(1, std::memory_order_relaxed);
   return true;
 }
 
@@ -792,6 +848,7 @@ bool drain_inbuf(Server* s, Conn* c) {
     if (c->inbuf.size() - off - 4 < len) break;
     const char* p = c->inbuf.data() + off + 4;
     const char* end = p + len;
+    const int64_t t_parse = mono_ns();
     Frame f;
     f.conn_token = c->token;
     if (!rd(p, end, &f.rid)) return false;
@@ -833,10 +890,13 @@ bool drain_inbuf(Server* s, Conn* c) {
     if (!rd_vec(p, end, &f.behavior, count)) return false;
     if (p != end) return false;
     off += 4 + len;
+    f.arrive_ns = mono_ns();
+    f.parse_ns = f.arrive_ns - t_parse;
     if (try_native_single(s, c, f)) continue;  // answered in-thread
     {
       std::lock_guard<std::mutex> g(s->mu);
       PendingReply& pr = c->pending[f.rid];
+      pr.arrive_ns = f.arrive_ns;
       pr.method = f.method;
       pr.expected = count;
       pr.got = 0;
@@ -849,7 +909,7 @@ bool drain_inbuf(Server* s, Conn* c) {
       pr.err.assign(count, std::string());
       pr.meta.assign(count, std::string());
       pr.filled.assign(count, 0);
-      s->queue.push_back(std::move(f));
+      s->enqueue(std::move(f));
       enqueued = true;
     }
   }
@@ -1075,6 +1135,7 @@ bool try_native_single_h2(Server* s, Conn* c, uint32_t sid,
 // Route one complete (headers + body) stream. Returns false only on
 // connection-fatal conditions.
 bool h2_route_complete(Server* s, Conn* c, uint32_t sid) {
+  const int64_t t_parse = mono_ns();  // gRPC framing + protobuf -> columns
   H2Stream st = std::move(c->streams[sid]);
   c->streams.erase(sid);
   const size_t held = st.body.size() + st.hdr_block.size();
@@ -1144,10 +1205,13 @@ bool h2_route_complete(Server* s, Conn* c, uint32_t sid) {
     s->raw_cv.notify_one();
     return true;
   }
+  f.arrive_ns = mono_ns();
+  f.parse_ns = f.arrive_ns - t_parse;
   if (try_native_single_h2(s, c, sid, f)) return true;
   {
     std::lock_guard<std::mutex> g(s->mu);
     PendingReply& rep = c->pending[f.rid];
+    rep.arrive_ns = f.arrive_ns;
     rep.method = f.method;
     rep.h2_stream = sid;
     rep.expected = f.count;
@@ -1161,7 +1225,7 @@ bool h2_route_complete(Server* s, Conn* c, uint32_t sid) {
     rep.err.assign(f.count, std::string());
     rep.meta.assign(f.count, std::string());
     rep.filled.assign(f.count, 0);
-    s->queue.push_back(std::move(f));
+    s->enqueue(std::move(f));
   }
   s->cv.notify_all();
   return true;
@@ -1728,11 +1792,14 @@ int pls_next_batch(void* h, long long timeout_us, char* keys, int key_cap,
   if (s->stopping) return -1;
   int n = 0, koff = 0;
   key_off[0] = 0;
+  const int64_t now = s->queue.empty() ? 0 : mono_ns();
   while (!s->queue.empty()) {
     Frame& f = s->queue.front();
     int count = f.count;
     if (n + count > max_n) break;
     if (koff + (int)f.keys.size() > key_cap) break;
+    s->front_wait.observe(now - f.arrive_ns);
+    s->frames_pulled++;
     // columnar frame -> columnar caller buffers: bulk copies
     memcpy(keys + koff, f.keys.data(), f.keys.size());
     for (int i = 0; i < count; i++) {
@@ -1753,6 +1820,10 @@ int pls_next_batch(void* h, long long timeout_us, char* keys, int key_cap,
     s->queue.pop_front();
     if (n == max_n) break;
   }
+  if (n) {
+    s->pulls++;
+    s->items_pulled += n;
+  }
   return n;
 }
 
@@ -1769,6 +1840,8 @@ void pls_send_responses(void* h, int n, const unsigned long long* conn_token,
   // coalesce: all of this call's completed replies to one conn leave in
   // ONE send() (a 100-wide herd pays 1 syscall per conn, not 100)
   std::map<Conn*, std::string> acc;
+  std::vector<int64_t> done;  // arrive_ns of the rids this call completes
+  int64_t t0 = 0;             // the first of them starts to serialise
   for (int i = 0; i < n; i++) {
     auto cit = s->conns.find(conn_token[i]);
     if (cit == s->conns.end()) continue;  // client vanished
@@ -1790,10 +1863,24 @@ void pls_send_responses(void* h, int n, const unsigned long long* conn_token,
       const int mlen = meta_off[i + 1] - meta_off[i];
       pr.meta[j].assign(meta_buf + meta_off[i], (size_t)mlen);
     }
-    if (pr.got == pr.expected) finish_pending(s, c, pit, &acc[c]);
+    if (pr.got == pr.expected) {
+      if (done.empty()) t0 = mono_ns();
+      done.push_back(pr.arrive_ns);
+      finish_pending(s, c, pit, &acc[c]);
+    }
   }
   for (auto& [c, bytes] : acc) {
     if (!bytes.empty()) direct_send(s, c, bytes);
+  }
+  if (!done.empty()) {
+    // the replies left in coalesced sends: each frame gets an equal part
+    // of the call's serialise + write time
+    const int64_t now = mono_ns();
+    const int64_t each = (now - t0) / (int64_t)done.size();
+    for (int64_t arrive : done) {
+      s->front_call.observe(now - arrive);
+      s->front_write.observe(each);
+    }
   }
 }
 
@@ -1831,6 +1918,8 @@ void pls_send_partial(void* h, unsigned long long conn_token,
       }
     }
     if (fresh == 0) return;  // span already streamed
+    const int64_t t0 = mono_ns();
+    const int64_t arrive = pr.arrive_ns;
     const uint16_t cnt = (uint16_t)n;
     const uint16_t seq = pr.next_seq++;
     const uint16_t b16 = (uint16_t)base;
@@ -1859,6 +1948,7 @@ void pls_send_partial(void* h, unsigned long long conn_token,
     if (ebytes) frame.append(err_buf + err_off[0], ebytes);
     if (fin) c->pending.erase(pit);
     direct_send(s, c, frame);
+    if (fin) s->note_reply(arrive, t0);
     s->partial_posts.fetch_add(1, std::memory_order_relaxed);
     return;
   }
@@ -1879,9 +1969,12 @@ void pls_send_partial(void* h, unsigned long long conn_token,
     }
   }
   if (pr.got == pr.expected) {
+    const int64_t t0 = mono_ns();
+    const int64_t arrive = pr.arrive_ns;
     std::string out;
     finish_pending(s, c, pit, &out);
     if (!out.empty()) direct_send(s, c, out);
+    s->note_reply(arrive, t0);
   }
 }
 
@@ -1904,6 +1997,31 @@ long long pls_v2_conns(void* h) {
 }
 
 int pls_port(void* h) { return ((Server*)h)->port; }
+
+// Copy the front's profile into out[0..kProfileLen): four histograms in the
+// order front_wait, front_call, front_parse, front_write, each kHistBuckets
+// counts then n, total_ns, max_ns; then pulls, frames_pulled, items_pulled,
+// frames_native. Called at scrape time only. Returns the values written,
+// -1 when cap is too small.
+int pls_profile(void* h, long long* out, int cap) {
+  constexpr int kProfileLen = 4 * (kHistBuckets + 3) + 4;
+  if (cap < kProfileLen) return -1;
+  auto* s = (Server*)h;
+  std::lock_guard<std::mutex> g(s->mu);
+  int k = 0;
+  for (const Hist* hist : {&s->front_wait, &s->front_call, &s->front_parse,
+                           &s->front_write}) {
+    for (int i = 0; i < kHistBuckets; i++) out[k++] = hist->counts[i];
+    out[k++] = hist->n;
+    out[k++] = hist->total_ns;
+    out[k++] = hist->max_ns;
+  }
+  out[k++] = s->pulls;
+  out[k++] = s->frames_pulled;
+  out[k++] = s->items_pulled;
+  out[k++] = s->frames_native.load(std::memory_order_relaxed);
+  return k;
+}
 
 // Enable the native lone-request fast path: `fn` is keydir_decide_one's
 // address, `kd` the engine's KeyDir handle, `slow_mask` the behavior bits

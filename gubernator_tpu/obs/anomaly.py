@@ -65,6 +65,7 @@ from typing import Dict, List, Optional
 
 from gubernator_tpu.obs import witness
 from gubernator_tpu.obs.history import MetricsHistory
+from gubernator_tpu.obs.profile import background_of
 
 log = logging.getLogger("gubernator_tpu.anomaly")
 
@@ -195,6 +196,10 @@ class AnomalyEngine:
     def check(self, now: Optional[float] = None) -> Dict[str, bool]:
         """One detector sweep; returns the active map. Thread-safe but
         sweeps are serialized — concurrent callers coalesce."""
+        with background_of(self.instance, "anomaly.check"):
+            return self._check(now)
+
+    def _check(self, now: Optional[float]) -> Dict[str, bool]:
         now = time.monotonic() if now is None else now
         cur = self.history.collect(now)
         with self._lock:

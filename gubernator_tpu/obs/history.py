@@ -33,6 +33,7 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from gubernator_tpu.obs import witness
+from gubernator_tpu.obs.profile import background_of
 
 log = logging.getLogger("gubernator_tpu.history")
 
@@ -214,7 +215,8 @@ class MetricsHistory:
             if self._samples and now - self._samples[-1]["t"] \
                     < self.tick_s * 0.9:
                 return False
-        return self.record(now, self.collect(now))
+        with background_of(self.instance, "history.sample"):
+            return self.record(now, self.collect(now))
 
     def window_snap(self, t_floor: float) -> Optional[Dict[str, float]]:
         """Newest sample at/older than t_floor, else the oldest held —

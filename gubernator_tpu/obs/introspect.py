@@ -50,6 +50,18 @@ def _backend_vars(backend) -> dict:
     device = getattr(backend, "device", None)
     if isinstance(device, dict):
         out["device"] = dict(device)
+        # two live facts beside the boot-time ones: the allocator's
+        # account of each device, and the compiles since Ready (the
+        # daemon hangs a CompileWatch on its backend; nulls without one)
+        from gubernator_tpu.utils.platform import device_memory
+
+        try:
+            out["device"]["memory"] = device_memory(backend)
+        except Exception:  # noqa: BLE001 — introspection must not raise
+            out["device"]["memory"] = None
+        watch = getattr(backend, "compile_watch", None)
+        out["device"]["compiles"] = watch.facts() if watch is not None \
+            else {"count": None, "seconds": None}
     occ = key_table_size(backend)
     if occ is not None:
         out["key_table_size"] = occ

@@ -44,6 +44,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from gubernator_tpu.obs import witness
+from gubernator_tpu.obs.profile import background_of
 from gubernator_tpu.obs.introspect import (
     eviction_count,
     key_table_size,
@@ -322,6 +323,10 @@ class KeyspaceCartographer:
     def harvest(self, now: Optional[float] = None) -> Optional[dict]:
         """One full scan; returns the fresh report (None on failure).
         Serialized: concurrent callers coalesce onto one scan."""
+        with background_of(self.instance, "keyspace.harvest"):
+            return self._harvest(now)
+
+    def _harvest(self, now: Optional[float]) -> Optional[dict]:
         now = time.monotonic() if now is None else now
         backend = getattr(self.instance, "backend", None)
         if backend is None:

@@ -63,6 +63,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from gubernator_tpu.obs import witness
+from gubernator_tpu.obs.profile import background_of
 
 LEDGER_SCHEMA_VERSION = 1
 
@@ -352,6 +353,10 @@ class DecisionLedger:
         open windows judged too), and hold a sample of keys against the
         device table's lifetime col-7 attempted counters as ground
         truth. Returns the audit report also served by endpoint_body."""
+        with background_of(engine, "ledger.audit"):
+            return self._audit(engine, now_ms, force)
+
+    def _audit(self, engine, now_ms: Optional[int], force: bool) -> dict:
         self._last_audit = time.monotonic()
         if now_ms is None:
             now_ms = int(time.time() * 1000)
@@ -364,7 +369,8 @@ class DecisionLedger:
                 want.update(int(s) for s in sh[0].tolist())
             want.discard(-1)
             try:
-                resolved = engine.resolve_slots(want)
+                with background_of(engine, "ledger.resolve_slots"):
+                    resolved = engine.resolve_slots(want)
             except Exception:  # noqa: BLE001 — audit never raises
                 resolved = {}
         with self._lock:

@@ -25,6 +25,18 @@ Consumers:
   totals through `serving_decomposition()` below — one source of truth
   (tests/test_profile_plane.py pins live-vs-offline agreement).
 
+Beside the serving cycle it meters what surrounds it, on the same
+clock (CLOCK_MONOTONIC): the native front's own histograms (frame
+residency before the pull, whole-call residency, parse and write time —
+native/peerlink.cpp, read at scrape time through `attach_front`) and the
+background tickers' units of work (`background(site)`: the anomaly
+sweep, the ledger audit, history samples, keyspace harvests), so "what
+stopped serving for a second" has an answer inside the daemon. While a
+deep capture runs the same seams write `jax.profiler.TraceAnnotation`
+spans into the capture, named as the phases are: host and device share
+one timeline there, so a device idle gap can be put down to what the
+host was doing.
+
 `GUBER_PROFILE=0` turns every observation site into a single attribute
 test; the off path is bit-identical (differential-tested) because the
 profiler only ever *reads* clocks.
@@ -33,6 +45,7 @@ profiler only ever *reads* clocks.
 from __future__ import annotations
 
 import collections
+import contextlib
 import hashlib
 import json
 import os
@@ -43,22 +56,111 @@ from typing import Dict, Optional, Tuple
 
 from gubernator_tpu.obs import witness
 
-PROFILE_SCHEMA_VERSION = 1
+# v2: phases gains front_wait/front_call/front_parse/front_write (the
+# native front's histograms) and the body gains `front` (its counters),
+# `bg_sites` (background tickers per site) and capture.options.
+PROFILE_SCHEMA_VERSION = 2
 KERNELS_SCHEMA_VERSION = 1
 
-# The serving-cycle phases, in cycle order. queue_wait (combiner/peerlink
-# residency before launch) overlaps the serial phases of OTHER windows,
-# so decomposition shares are computed over the serial set only;
-# queue_wait's "share" is reported against the same denominator as a
-# residency ratio (can exceed 1 under deep pipelining).
+# The serving-cycle phases, in cycle order. queue_wait overlaps the
+# serial phases of OTHER windows, so decomposition shares are computed
+# over the serial set only; queue_wait's "share" is reported against the
+# same denominator as a residency ratio (can exceed 1 under deep
+# pipelining). Its feed differs by path: behind the Python combiner it is
+# a ticket's residency before launch; behind the native front
+# (service/peerlink.py columnar path) it is ONLY the drain that a full
+# pipeline forces before the next launch — the queue there is the C++
+# frame queue, metered as front_wait.
 PHASES = ("queue_wait", "lock_wait", "prep", "dispatch", "readback", "demux")
 SERIAL_PHASES = ("lock_wait", "prep", "dispatch", "readback", "demux")
+# The native front's own histograms (native/peerlink.cpp pls_profile), per
+# frame: parsed -> pulled, parsed -> reply written, wire bytes -> columns,
+# reply serialise + send. Outside the serial cycle: a frame waits while
+# other windows run.
+FRONT_PHASES = ("front_wait", "front_call", "front_parse", "front_write")
+FRONT_COUNTERS = ("pulls", "frames_pulled", "items_pulled", "frames_native")
+
+# a background unit of work longer than this lands in the flight recorder
+BACKGROUND_SLOW_NS = 100_000_000
+
+# what capture() hands jax.profiler: no Python tracer (it slowed the
+# daemon to a third of its launch rate while a capture lasted), host
+# tracer at the level TraceAnnotation records at
+CAPTURE_OPTIONS = {"python_tracer_level": 0, "host_tracer_level": 2}
 
 # log2-ns histogram: bucket i holds observations <= 2^(i+_SHIFT) ns.
 # _SHIFT=10 puts bucket 0 at ~1 us (finer resolution is clock noise on
 # these seams); 28 buckets reach ~137 s.
 _SHIFT = 10
 _NBUCKETS = 28
+# pls_profile's layout: per front phase its bucket counts, then n,
+# total_ns, max_ns; then the counters
+_FRONT_HIST_LEN = _NBUCKETS + 3
+FRONT_PROFILE_LEN = len(FRONT_PHASES) * _FRONT_HIST_LEN + len(FRONT_COUNTERS)
+
+
+def _bucket_quantile(counts, n: int, q: float) -> int:
+    """Upper bucket bound holding quantile `q` of a log2-ns histogram
+    (0 when empty)."""
+    if n == 0:
+        return 0
+    want = q * n
+    seen = 0
+    for i, c in enumerate(counts):
+        seen += c
+        if seen >= want:
+            return 1 << (i + _SHIFT)
+    return 1 << (_NBUCKETS - 1 + _SHIFT)
+
+
+def _hist_snapshot(counts, n: int, total_ns: int, max_ns: int) -> dict:
+    return {
+        "n": n,
+        "total_ns": total_ns,
+        "max_ns": max_ns,
+        "p50_ns": _bucket_quantile(counts, n, 0.50),
+        "p99_ns": _bucket_quantile(counts, n, 0.99),
+    }
+
+
+def _annotation(name: str):
+    """An entered jax.profiler.TraceAnnotation: a host span in the running
+    capture. (Entered outside a capture it records nothing.)"""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+class _Seams:
+    """One call's chain of host spans in a capture: `seams(name)` closes
+    the open span and opens the next, `seams(None)` closes the last. Lives
+    as long as the call that asked for it, so nothing is left open."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self):
+        self._ann = None
+
+    def __call__(self, name: Optional[str]) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._ann = None if name is None else _annotation(name)
+
+
+def _no_seams(name: Optional[str]) -> None:
+    """What Profiler.seams() hands out while no capture runs."""
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def background_of(holder, site: str):
+    """`with background_of(x, site):` — Profiler.background on
+    `x.profiler`; nothing when x carries no profiler (stubs, None)."""
+    prof = getattr(holder, "profiler", None)
+    return _NO_SPAN if prof is None else prof.background(site)
 
 
 def profile_enabled_default() -> bool:
@@ -103,27 +205,10 @@ class PhaseHist:
         with self._lock:
             return self.n, self.total_ns
 
-    def _quantile_locked(self, q: float) -> int:
-        """Upper bucket bound holding quantile `q` (0 when empty)."""
-        if self.n == 0:
-            return 0
-        want = q * self.n
-        seen = 0
-        for i, c in enumerate(self.counts):
-            seen += c
-            if seen >= want:
-                return 1 << (i + _SHIFT)
-        return 1 << (_NBUCKETS - 1 + _SHIFT)
-
     def snapshot(self) -> dict:
         with self._lock:
-            return {
-                "n": self.n,
-                "total_ns": self.total_ns,
-                "max_ns": self.max_ns,
-                "p50_ns": self._quantile_locked(0.50),
-                "p99_ns": self._quantile_locked(0.99),
-            }
+            return _hist_snapshot(self.counts, self.n, self.total_ns,
+                                  self.max_ns)
 
 
 class Profiler:
@@ -138,7 +223,24 @@ class Profiler:
         self.capture_min_interval_s = float(capture_min_interval_s)
         self._phases: Dict[str, PhaseHist] = {p: PhaseHist() for p in PHASES}
         self._sites: Dict[str, PhaseHist] = {}
+        self._bg_sites: Dict[str, PhaseHist] = {}
         self._sites_lock = witness.make_lock("profiler.sites")
+        # the native front's reader (service/peerlink.py front_profile),
+        # called at scrape time only
+        self._front = None
+        # flight recorder for slow background units (Instance wires it)
+        self.recorder = None
+        # background units in progress: unit id -> [site, open capture
+        # span or None]. capture() opens a span for the units it finds
+        # running and closes the ones still running when it ends, so a
+        # unit longer than the capture is in it whole.
+        self._bg_lock = witness.make_lock("profiler.background")
+        self._bg_open: Dict[int, list] = {}
+        self._bg_next = 0
+        self._bg_local = threading.local()  # per thread: nested units' ns
+        # true while a jax.profiler capture runs: the seams then write
+        # their spans into it (one attribute test each when it is not)
+        self._capturing = False
         # windowed views (slow-request attachment, anomaly baselines that
         # predate the history ring): totals snapshots every ~2 s, taken
         # lazily from the observe path so idle engines cost nothing
@@ -152,6 +254,7 @@ class Profiler:
         self._captures = 0
         self._last_capture_path: Optional[str] = None
         self._last_capture_mode: Optional[str] = None
+        self._last_capture_rates: Optional[dict] = None
 
     # ------------------------------------------------------- observation
 
@@ -176,6 +279,89 @@ class Profiler:
                 h = self._sites.setdefault(site, PhaseHist())
         h.observe(ns)
 
+    @contextlib.contextmanager
+    def background(self, site: str):
+        """Time one unit of a background ticker's work (the anomaly
+        sweep, the ledger audit, a history sample, a keyspace harvest...)
+        into the per-site histograms `bg_sites`, as lock_wait feeds
+        `lock_sites`. These threads take the GIL and the engine lock from
+        the serving threads, so a long unit IS a serving stall: one over
+        100 ms also lands in the flight recorder, and while a capture runs
+        the unit is a `bg:<site>` span in it.
+
+        Units nest (the anomaly sweep runs the ledger audit, which
+        resolves slots): a site's histogram holds its OWN time, the unit
+        less the units nested in it on the same thread, so the sites add
+        up to the time the tickers took. The recorder event and the
+        capture span carry the whole unit."""
+        if not self.enabled:
+            yield
+            return
+        nested = getattr(self._bg_local, "nested_ns", None)
+        if nested is None:
+            nested = self._bg_local.nested_ns = []
+        nested.append(0)
+        with self._bg_lock:
+            unit = self._bg_next = self._bg_next + 1
+            self._bg_open[unit] = [
+                site, _annotation("bg:" + site) if self._capturing else None]
+        t0 = time.perf_counter_ns()
+        try:
+            yield
+        finally:
+            ns = time.perf_counter_ns() - t0
+            with self._bg_lock:
+                ann = self._bg_open.pop(unit)[1]
+                if ann is not None:
+                    ann.__exit__(None, None, None)
+            own_ns = ns - nested.pop()
+            if nested:
+                nested[-1] += ns
+            h = self._bg_sites.get(site)
+            if h is None:
+                with self._sites_lock:
+                    h = self._bg_sites.setdefault(site, PhaseHist())
+            h.observe(own_ns)
+            rec = self.recorder
+            if ns >= BACKGROUND_SLOW_NS and rec is not None:
+                rec.emit("profile.background_slow", site=site,
+                         ms=round(ns / 1e6, 1), own_ms=round(own_ns / 1e6, 1))
+
+    def seams(self):
+        """`seams = prof.seams()`, then `seams("prep")` ... `seams(None)`
+        at a call's stamps: a chain of host spans while a capture runs, a
+        function that does nothing while none does."""
+        return _Seams() if self._capturing else _no_seams
+
+    def span(self, name: str):
+        """`with prof.span(name):` — a host span in the running capture,
+        nothing outside one."""
+        if not self._capturing:
+            return _NO_SPAN
+        from jax.profiler import TraceAnnotation
+
+        return TraceAnnotation(name)
+
+    def attach_front(self, reader) -> None:
+        """`reader()` -> the FRONT_PROFILE_LEN numbers pls_profile wrote
+        (native/peerlink.cpp), or None when the front is gone; None
+        detaches."""
+        self._front = reader
+
+    def front_totals(self) -> Tuple[Dict[str, dict], Dict[str, int]]:
+        """({front phase: snapshot}, {front counter: value}); zeros when
+        no native front is attached (grpcio deployments, library use)."""
+        reader = self._front
+        vals = reader() if reader is not None else None
+        if vals is None:
+            vals = [0] * FRONT_PROFILE_LEN
+        phases = {}
+        for i, p in enumerate(FRONT_PHASES):
+            h = vals[i * _FRONT_HIST_LEN:(i + 1) * _FRONT_HIST_LEN]
+            phases[p] = _hist_snapshot(h[:_NBUCKETS], *h[_NBUCKETS:])
+        return phases, dict(zip(FRONT_COUNTERS,
+                                vals[-len(FRONT_COUNTERS):]))
+
     def _maybe_tick(self) -> None:
         self._obs_since_tick = 0
         now = time.monotonic()
@@ -196,10 +382,19 @@ class Profiler:
         return out
 
     def site_totals(self) -> Dict[str, dict]:
+        return self._totals_of(self._sites)
+
+    def background_totals(self) -> Dict[str, dict]:
+        return self._totals_of(self._bg_sites)
+
+    def _totals_of(self, hists: Dict[str, PhaseHist]) -> Dict[str, dict]:
         with self._sites_lock:
-            sites = dict(self._sites)
-        return {s: {"n": h.totals()[0], "total_ns": h.totals()[1]}
-                for s, h in sites.items()}
+            sites = dict(hists)
+        out = {}
+        for s, h in sites.items():
+            n, total = h.totals()
+            out[s] = {"n": n, "total_ns": total}
+        return out
 
     def recent(self, window_s: float = 60.0) -> dict:
         """Per-phase decomposition over roughly the last `window_s`
@@ -263,6 +458,7 @@ class Profiler:
             "shares": {p: (round(cur[p]["total_ns"] / serial, 4)
                            if serial else 0.0) for p in SERIAL_PHASES},
             "lock_sites": len(self._sites),
+            "bg_sites": len(self._bg_sites),
             "captures": self._captures,
         }
 
@@ -271,11 +467,18 @@ class Profiler:
         (tests/test_debug_schema.py)."""
         with self._sites_lock:
             sites = dict(self._sites)
+            bg = dict(self._bg_sites)
+        front_phases, front_counters = self.front_totals()
+        phases = {p: h.snapshot() for p, h in self._phases.items()}
+        phases.update(front_phases)
         return {
             "schema_version": PROFILE_SCHEMA_VERSION,
             "enabled": self.enabled,
-            "phases": {p: h.snapshot() for p, h in self._phases.items()},
+            "phases": phases,
+            "front": {"attached": self._front is not None,
+                      **front_counters},
             "lock_sites": {s: h.snapshot() for s, h in sorted(sites.items())},
+            "bg_sites": {s: h.snapshot() for s, h in sorted(bg.items())},
             "decomposition": self.decomposition(),
             "recent": self.recent(),
             "capture": {
@@ -283,6 +486,8 @@ class Profiler:
                 "min_interval_s": self.capture_min_interval_s,
                 "last_path": self._last_capture_path,
                 "last_mode": self._last_capture_mode,
+                "last_rates": self._last_capture_rates,
+                "options": dict(CAPTURE_OPTIONS),
             },
         }
 
@@ -292,10 +497,14 @@ class Profiler:
                 mode: str = "auto") -> dict:
         """On-demand deep capture, rate-limited to one per
         `capture_min_interval_s`. `mode` "auto" tries `jax.profiler`
-        (device timeline) and falls back to the wall-clock stack sampler
-        (always works, CPU rigs included); "wall" forces the sampler.
-        Writes under `out_dir` (the bundle dir) and returns
-        {"ok", "path"/"error", "mode"}; never raises."""
+        (device timeline, the program's own host spans beside it, no
+        Python tracer: CAPTURE_OPTIONS) and falls back to the wall-clock
+        stack sampler; "wall" forces the sampler. Writes under `out_dir`
+        (the bundle dir) and returns {"ok", "path"/"error", "mode"}, and
+        for a jax trace what the capture cost the daemon:
+        `launches_per_s_in` (device launches per second while it ran) and
+        `launches_per_s_out` (over the `seconds`, at most 2, before it);
+        never raises."""
         now = time.monotonic()
         with self._capture_lock:
             since = now - self._last_capture
@@ -313,15 +522,13 @@ class Profiler:
             return {"ok": False, "error": f"capture dir: {e}"}
         if mode == "auto":
             try:
-                import jax
-
                 path = os.path.join(out_dir, f"profile_trace_{stamp}")
-                jax.profiler.start_trace(path)
-                time.sleep(seconds)
-                jax.profiler.stop_trace()
+                rates = self._jax_trace(path, seconds)
                 self._last_capture_path = path
                 self._last_capture_mode = "jax_trace"
-                return {"ok": True, "path": path, "mode": "jax_trace"}
+                self._last_capture_rates = rates
+                return {"ok": True, "path": path, "mode": "jax_trace",
+                        **rates}
             except Exception:  # noqa: BLE001 — fall through to the sampler
                 pass
         try:
@@ -331,6 +538,44 @@ class Profiler:
         self._last_capture_path = path
         self._last_capture_mode = "wall_sampler"
         return {"ok": True, "path": path, "mode": "wall_sampler"}
+
+    def _launches(self) -> Tuple[int, int]:
+        """(device launches so far, now): every launch observes the
+        dispatch phase once."""
+        return self._phases["dispatch"].totals()[0], time.perf_counter_ns()
+
+    def _jax_trace(self, path: str, seconds: float) -> dict:
+        """One jax.profiler trace of `seconds`, with the seams' spans in
+        it; returns the launch rates before and inside it."""
+        import jax
+
+        options = jax.profiler.ProfileOptions()
+        for k, v in CAPTURE_OPTIONS.items():
+            setattr(options, k, v)
+        n0, t0 = self._launches()
+        time.sleep(min(seconds, 2.0))
+        n1, t1 = self._launches()
+        jax.profiler.start_trace(path, profiler_options=options)
+        try:
+            with self._bg_lock:
+                # units already running: their spans start here
+                for entry in self._bg_open.values():
+                    entry[1] = _annotation("bg:" + entry[0])
+                self._capturing = True
+            n2, t2 = self._launches()
+            time.sleep(seconds)
+            n3, t3 = self._launches()
+        finally:
+            with self._bg_lock:
+                self._capturing = False
+                # units still running: their spans end here
+                for entry in self._bg_open.values():
+                    if entry[1] is not None:
+                        entry[1].__exit__(None, None, None)
+                        entry[1] = None
+            jax.profiler.stop_trace()
+        return {"launches_per_s_out": (n1 - n0) / ((t1 - t0) / 1e9),
+                "launches_per_s_in": (n3 - n2) / ((t3 - t2) / 1e9)}
 
     @staticmethod
     def _wall_sample(out_dir: str, seconds: float, stamp: int) -> str:

@@ -45,6 +45,7 @@ import time
 from typing import Callable, Dict, Optional, Tuple
 
 from gubernator_tpu.obs import witness
+from gubernator_tpu.obs.profile import background_of
 
 log = logging.getLogger("gubernator_tpu.autopilot")
 
@@ -413,7 +414,7 @@ class Autopilot:
         """Unconditional sweep (the daemon ticker and tests)."""
         if not self.enabled:
             return
-        with self._lock:
+        with background_of(self.instance, "autopilot.tick"), self._lock:
             self._tick_locked(time.monotonic() if now is None else now)
 
     def _tick_locked(self, now: float) -> None:

@@ -78,6 +78,7 @@ from typing import Callable, Dict, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from gubernator_tpu.obs import witness
+from gubernator_tpu.obs.profile import background_of
 from gubernator_tpu.cluster.pickers import PickerEmptyError
 from gubernator_tpu.types import (
     Behavior,
@@ -406,7 +407,8 @@ class CollectiveGlobalSync:
         while not self._stop.is_set():
             next_tick += self.interval_s
             try:
-                self.tick()
+                with background_of(self.instance, "global.collective"):
+                    self.tick()
             except Exception as e:  # noqa: BLE001 — degrade, don't die
                 self._failed = repr(e)
                 log.exception(

@@ -24,6 +24,7 @@ import time
 from typing import Dict, Optional
 
 from gubernator_tpu.obs import witness
+from gubernator_tpu.obs.profile import background_of
 from gubernator_tpu.service.config import BehaviorConfig
 from gubernator_tpu.service.convert import resp_to_pb
 from gubernator_tpu.service.pb import peers_pb2 as peers_pb
@@ -164,15 +165,24 @@ class GlobalManager:
         # broadcasts are the FIRST work class to shed — see queue_update
         self.admission = admission
         recorder = getattr(instance, "recorder", None)
+
+        def timed(site, flush_fn):
+            """The flush as a unit of background work (obs/profile.py)."""
+            def flush(batch):
+                with background_of(instance, site):
+                    flush_fn(batch)
+            return flush
+
         self._hits = _Pipeline(
             "hits", behaviors.global_sync_wait_s, behaviors.global_batch_limit,
-            self._send_hits,
+            timed("global.send_hits", self._send_hits),
             observe=metrics.async_durations.observe if metrics else None,
             recorder=recorder,
         )
         self._broadcasts = _Pipeline(
             "broadcast", behaviors.global_sync_wait_s,
-            behaviors.global_batch_limit, self._broadcast,
+            behaviors.global_batch_limit,
+            timed("global.broadcast", self._broadcast),
             observe=metrics.broadcast_durations.observe if metrics else None,
             recorder=recorder,
         )

@@ -271,6 +271,8 @@ class Instance:
         # subsystem hook is one attribute test; GUBER_FLIGHT_RECORDER=0
         # turns each emit into a single bool read
         self.recorder = conf.recorder or FlightRecorder()
+        # a background unit of work over 100 ms is an event there
+        self.profiler.recorder = self.recorder
         # decision ledger (obs/ledger.py): every admitted hit attributed
         # at decision time to its source of authority; the conservation
         # auditor runs off the serving path (anomaly ticker / scenario

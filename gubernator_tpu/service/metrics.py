@@ -713,6 +713,13 @@ class Metrics:
             ["site"],
             registry=self.registry,
         )
+        self.background_seconds = Counter(
+            "background_seconds_total",
+            "Time the background tickers spent per site (each site's own "
+            "time: nested units are counted at their own site).",
+            ["site"],
+            registry=self.registry,
+        )
         self.engine_kernel_dispatch_seconds = Counter(
             "engine_kernel_dispatch_seconds_total",
             "Wall time inside jitted decide-kernel dispatch calls, per "
@@ -858,6 +865,10 @@ class Metrics:
                 self._set_counter(
                     self.engine_lock_waits.labels(site=site),
                     float(t["n"]))
+            for site, t in prof.background_totals().items():
+                self._set_counter(
+                    self.background_seconds.labels(site=site),
+                    t["total_ns"] / 1e9)
         # live key-table occupancy: the engine directory IS the cache here,
         # so cache_size (reference: cache.go:87-95) reports it
         from gubernator_tpu.obs.introspect import key_table_size

@@ -546,6 +546,17 @@ class PeerLinkClient:
                 fut.set_exception(PeerLinkError(str(exc)))
 
 
+def read_front_profile(lib, handle) -> Optional[List[int]]:
+    """The C++ front's histograms and counters as pls_profile writes them
+    (obs/profile.py Profiler.front_totals reads the layout)."""
+    from gubernator_tpu.obs.profile import FRONT_PROFILE_LEN
+
+    buf = (ctypes.c_longlong * FRONT_PROFILE_LEN)()
+    if lib.pls_profile(handle, buf, FRONT_PROFILE_LEN) != FRONT_PROFILE_LEN:
+        return None
+    return list(buf)
+
+
 class _PullCtx:
     """One pull's buffers + reply bookkeeping on the v2 wire path: rows
     post to the wire as their sub-windows finalize (pls_send_partial),
@@ -636,6 +647,14 @@ class PeerLinkService:
                     f"peerlink: cannot bind gRPC port {grpc_port}")
             self.grpc_port = gp
         self.instance = instance
+        # the cycle profiler (obs/profile.py) reads this front's own
+        # histograms at scrape time, and while a capture runs the pull
+        # loop writes its spans (front.pull_wait, post) into it
+        from gubernator_tpu.obs.profile import Profiler
+
+        self._prof = getattr(instance, "profiler", None) \
+            or Profiler(enabled=False)
+        self._prof.attach_front(self.front_profile)
         # flight recorder (obs/events.py): columnar pipeline cuts and
         # fill stalls become causal events alongside the stat counters
         self._recorder = getattr(instance, "recorder", None)
@@ -709,6 +728,12 @@ class PeerLinkService:
         """Live C++ reply-assembly entries across every conn — the leak
         probe the wire-v2 tests assert returns to zero."""
         return int(self._lib.pls_pending_count(self._handle))
+
+    def front_profile(self) -> Optional[List[int]]:
+        """What Profiler.attach_front reads; None once closed."""
+        if self._stop:
+            return None
+        return read_front_profile(self._lib, self._handle)
 
     def wire_debug(self) -> dict:
         """The /v1/debug/vars "wire" section: negotiated-contract state
@@ -835,6 +860,7 @@ class PeerLinkService:
 
     def close(self) -> None:
         self._stop = True
+        self._prof.attach_front(None)  # no scrape may touch the freed handle
         if getattr(self.instance, "peerlink_service", None) is self:
             self.instance.peerlink_service = None
         # a stale peer-change listener would poke the freed native handle
@@ -910,9 +936,11 @@ class PeerLinkService:
         the differential tests prove bit-identical)."""
         b = self._mk_pull_bufs()
         args, resp_ptrs, meta_ptr = b["args"], b["resp_ptrs"], b["meta_ptr"]
+        prof = self._prof
         while not self._stop:
-            got = self._lib.pls_next_batch(
-                self._handle, 200_000, *args)  # 200 ms idle tick
+            with prof.span("front.pull_wait"):
+                got = self._lib.pls_next_batch(
+                    self._handle, 200_000, *args)  # 200 ms idle tick
             if got <= 0:
                 if got < 0:
                     return  # stopping
@@ -930,9 +958,10 @@ class PeerLinkService:
                 b["meta_off"][:got + 1] = 0
             try:
                 t_send = time.perf_counter()
-                self._lib.pls_send_responses(
-                    self._handle, got, *resp_ptrs, err_buf, meta_ptr,
-                    meta_buf)
+                with prof.span("post"):
+                    self._lib.pls_send_responses(
+                        self._handle, got, *resp_ptrs, err_buf, meta_ptr,
+                        meta_buf)
                 if self._metrics is not None:
                     self._metrics.peerlink_stage_ms.labels(
                         stage="send").observe(
@@ -968,6 +997,7 @@ class PeerLinkService:
             "ctxs": [None] * nsets,  # the ctx last prepped into each set
             "cur": 0,
         }
+        prof = self._prof
         while not self._stop:
             cur = ws["cur"]
             old = ws["ctxs"][cur]
@@ -975,6 +1005,7 @@ class PeerLinkService:
                 self._drain_one_entry(ws)  # free this set's buffers
             b = sets[cur]
             if ws["inflight"]:
+                # a poll, not a wait: it gets no span
                 got = self._lib.pls_next_batch(self._handle, 0, *b["args"])
                 if got == 0:
                     # launches in flight, nothing new to pull: the v1
@@ -987,8 +1018,9 @@ class PeerLinkService:
                     self._drain_one_entry(ws)
                     continue
             else:
-                got = self._lib.pls_next_batch(
-                    self._handle, 200_000, *b["args"])  # 200 ms idle tick
+                with prof.span("front.pull_wait"):
+                    got = self._lib.pls_next_batch(
+                        self._handle, 200_000, *b["args"])  # 200 ms idle tick
             if got < 0:
                 try:
                     self._drain_all(ws)  # stopping: settle device work
@@ -1076,30 +1108,32 @@ class PeerLinkService:
         rids, conns, idxs = b["rid"], b["conn"], b["idx"]
         cast = ctypes.c_void_p
         i = lo
-        while i < hi:
-            e = i + 1
-            # a run must not cross a FRAME boundary: a client may reuse a
-            # rid back-to-back (duplicate-rid fuzz), which (conn, rid)
-            # equality alone would merge into one oversized span that the
-            # C++ bounds check rejects — and the rid then never completes.
-            # Within a frame the pull keeps items contiguous, so idx
-            # advances by exactly 1; anything else starts a new frame.
-            while (e < hi and rids[e] == rids[i] and conns[e] == conns[i]
-                   and idxs[e] == idxs[e - 1] + 1):
-                e += 1
-            eo, eb = self._run_sidecar(ctx.errs, i, e)
-            mo, mb = self._run_sidecar(ctx.metas, i, e)
-            self._lib.pls_send_partial(
-                self._handle, int(conns[i]), int(rids[i]),
-                int(b["idx"][i]), e - i,
-                b["status"][i:e].ctypes.data_as(cast),
-                b["r_limit"][i:e].ctypes.data_as(cast),
-                b["r_remaining"][i:e].ctypes.data_as(cast),
-                b["r_reset"][i:e].ctypes.data_as(cast),
-                eo.ctypes.data_as(cast), eb, mo.ctypes.data_as(cast), mb)
-            if self._mt_span is not None:
-                self._mt_span.observe(e - i)
-            i = e
+        with self._prof.span("post"):
+            while i < hi:
+                e = i + 1
+                # a run must not cross a FRAME boundary: a client may
+                # reuse a rid back-to-back (duplicate-rid fuzz), which
+                # (conn, rid) equality alone would merge into one oversized
+                # span that the C++ bounds check rejects — and the rid then
+                # never completes. Within a frame the pull keeps items
+                # contiguous, so idx advances by exactly 1; anything else
+                # starts a new frame.
+                while (e < hi and rids[e] == rids[i] and conns[e] == conns[i]
+                       and idxs[e] == idxs[e - 1] + 1):
+                    e += 1
+                eo, eb = self._run_sidecar(ctx.errs, i, e)
+                mo, mb = self._run_sidecar(ctx.metas, i, e)
+                self._lib.pls_send_partial(
+                    self._handle, int(conns[i]), int(rids[i]),
+                    int(b["idx"][i]), e - i,
+                    b["status"][i:e].ctypes.data_as(cast),
+                    b["r_limit"][i:e].ctypes.data_as(cast),
+                    b["r_remaining"][i:e].ctypes.data_as(cast),
+                    b["r_reset"][i:e].ctypes.data_as(cast),
+                    eo.ctypes.data_as(cast), eb, mo.ctypes.data_as(cast), mb)
+                if self._mt_span is not None:
+                    self._mt_span.observe(e - i)
+                i = e
         ctx.posted += hi - lo
 
     @staticmethod

@@ -5,9 +5,11 @@ persistent compile cache lives.
 
 from __future__ import annotations
 
+import collections
 import ctypes
 import functools
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +67,71 @@ def device_facts(backend, directory: str) -> dict:
         "donation": bool(backend.donate),
         "key_directory": directory,
     }
+
+
+_MEMORY_KEYS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+
+
+def device_memory(backend) -> list:
+    """The allocator's own account of each device the table lives on
+    (`device.memory_stats()`): bytes in use, their peak since the process
+    started, and the limit. A key the backend does not report is None; the
+    CPU backend reports none of them. The devices are found by the names
+    `device_facts` recorded at boot, not through `backend.state`: while a
+    window is being dispatched that is the donated, deleted table."""
+    names = set(backend.device["devices"])
+    out = []
+    for device in jax.local_devices():
+        if str(device) in names:
+            stats = device.memory_stats() or {}
+            out.append({"device": str(device),
+                        **{k: stats.get(k) for k in _MEMORY_KEYS}})
+    return out
+
+
+class CompileWatch:
+    """Counts the XLA backend compiles from its creation on (the daemon
+    creates it at `Ready`): a shape that serving has not warmed compiles
+    inside a request and reads as one slow call, so each one also lands in
+    the flight recorder as `profile.compile` with the program's name. (A
+    program loaded from the persistent cache counts too: it was not
+    compiled before `Ready` either.)
+
+    JAX calls the listener on the compiling thread, under whatever lock
+    that thread holds (the engine's, when a window compiles), so it only
+    appends to a deque; `facts()` — every read of /v1/debug/vars, every
+    bundle — counts them and hands the new ones to the recorder, each with
+    the seconds since it happened."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self, recorder=None):
+        self._recorder = recorder
+        self._seen = collections.deque()  # (monotonic, seconds, program)
+        self._published = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, event: str, duration: float, **kwargs) -> None:
+        if event == self.EVENT:
+            self._seen.append((time.monotonic(), duration,
+                               str(kwargs.get("fun_name", ""))))
+
+    def facts(self) -> dict:
+        seen = list(self._seen)
+        # two readers at once can publish an event twice; they cannot
+        # miscount (the count is the deque's length)
+        if self._recorder is not None:
+            new, self._published = seen[self._published:], len(seen)
+            for at, seconds, program in new:
+                self._recorder.emit(
+                    "profile.compile", program=program,
+                    seconds=round(seconds, 3),
+                    ago_s=round(time.monotonic() - at, 3))
+        return {"count": len(seen),
+                "seconds": round(sum(s for _, s, _ in seen), 3)}
+
+    def close(self) -> None:
+        jax.monitoring.unregister_event_duration_listener(self._on_event)
 
 
 def release_compile_memory() -> None:

@@ -96,8 +96,14 @@ def test_engine_section_names_the_device(instance):
     dev = debug_vars(instance)["engine"]["device"]
     assert {"platform", "device_kind", "device_count",
             "visible_device_count", "devices", "table_bytes_per_device",
-            "donation", "key_directory"} <= set(dev)
+            "donation", "key_directory", "memory",
+            "compiles"} <= set(dev)
     assert dev["platform"] == "cpu"
+    # the two live facts: allocator memory per device (nulls on the CPU)
+    # and the compiles since Ready (nulls outside a daemon)
+    assert [set(m) for m in dev["memory"]] == [
+        {"device", "bytes_in_use", "peak_bytes_in_use", "bytes_limit"}]
+    assert set(dev["compiles"]) == {"count", "seconds"}
     assert dev["table_bytes_per_device"] == [256 * 64]
 
 
@@ -210,22 +216,30 @@ def test_profile_var_shape(instance):
 
 def test_profile_endpoint_schema_pinned(instance):
     body = instance.profiler.endpoint_body()
-    assert body["schema_version"] == PROFILE_SCHEMA_VERSION == 1
-    assert set(body) == {"schema_version", "enabled", "phases",
-                         "lock_sites", "decomposition", "recent",
-                         "capture"}
+    assert body["schema_version"] == PROFILE_SCHEMA_VERSION == 2
+    assert set(body) == {"schema_version", "enabled", "phases", "front",
+                         "lock_sites", "bg_sites", "decomposition",
+                         "recent", "capture"}
     # the phase taxonomy dashboards key on; renaming a phase is a
     # schema_version bump, not a silent drift
     taxonomy = {"queue_wait", "lock_wait", "prep", "dispatch",
                 "readback", "demux"}
-    assert set(body["phases"]) == taxonomy
+    # v2: the native front's own histograms ride beside the cycle's
+    # phases, outside the decomposition (a frame waits while other
+    # windows run), with its counters in `front`
+    front = {"front_wait", "front_call", "front_parse", "front_write"}
+    assert set(body["phases"]) == taxonomy | front
     assert set(body["decomposition"]) == taxonomy
+    assert set(body["front"]) == {"attached", "pulls", "frames_pulled",
+                                  "items_pulled", "frames_native"}
+    for snap in body["bg_sites"].values():
+        assert {"n", "total_ns", "max_ns", "p50_ns", "p99_ns"} == set(snap)
     for snap in body["phases"].values():
         assert {"n", "total_ns", "max_ns", "p50_ns", "p99_ns"} == set(snap)
     for d in body["decomposition"].values():
         assert {"count", "total_s", "avg_us", "share"} == set(d)
-    assert {"count", "min_interval_s", "last_path",
-            "last_mode"} <= set(body["capture"])
+    assert {"count", "min_interval_s", "last_path", "last_mode",
+            "last_rates", "options"} == set(body["capture"])
 
 
 def test_kernels_endpoint_schema_pinned(instance):

@@ -21,6 +21,7 @@ import pytest
 from gubernator_tpu.models.engine import Engine
 from gubernator_tpu.obs.anomaly import AnomalyEngine
 from gubernator_tpu.obs.profile import (
+    FRONT_PHASES,
     PHASES,
     SERIAL_PHASES,
     PhaseHist,
@@ -118,7 +119,7 @@ class TestProfiler:
         p.observe("prep", 1_000)
         body = p.endpoint_body()
         assert body["enabled"] is True
-        assert set(body["phases"]) == set(PHASES)
+        assert set(body["phases"]) == set(PHASES) | set(FRONT_PHASES)
         dbg = p.debug()
         assert dbg["phases"]["prep"]["n"] == 1
         assert set(dbg["shares"]) == set(SERIAL_PHASES)
