@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from gubernator_tpu.obs import witness
-from gubernator_tpu.models.keyspace import KeyDirectory
+from gubernator_tpu.models.keyspace import KeyDirectory, resolve_slots
 from gubernator_tpu.models.prep import (
     bucket_pow2 as _bucket_pow2,
     bucket_width as _bucket_width,
@@ -1237,35 +1237,13 @@ class Engine:
     # ------------------------------------------------------ hot-key support
 
     def resolve_slots(self, slots) -> dict:
-        """Map a SMALL set of slots back to their hash-key strings.
-
-        The directory only maps key→slot; the reverse walk costs one
-        items_raw arena scan, so the hot-key tracker calls this once per
-        detection window and only for the few slots that crossed the rate
-        threshold — never on the serving path. Slots without a live
-        directory entry (recycled mid-window) are simply absent from the
-        result."""
-        want = set(int(s) for s in slots)
-        if not want:
-            return {}
-        out: dict = {}
-        if hasattr(self.directory, "items_raw"):
-            blob, off, slots32 = self.directory.items_raw()
-            sl = np.asarray(slots32, np.int64)
-            off = np.asarray(off, np.int64)
-            hit = np.nonzero(np.isin(
-                sl, np.fromiter(want, np.int64, len(want))))[0]
-            for i in hit:
-                lo, hi = int(off[i]), int(off[i + 1])
-                try:
-                    out[int(sl[i])] = bytes(blob[lo:hi]).decode("utf-8")
-                except UnicodeDecodeError:
-                    continue
-        else:  # python-twin directory
-            for key, s in self.directory.items():
-                if int(s) in want:
-                    out[int(s)] = key
-        return out
+        """Map slots back to their hash-key strings (models/keyspace.py
+        resolve_slots): by index in the native directory, so the cost is
+        the slots asked. The hot-key tracker, the cartographer and the
+        ledger audit call this from their tickers, never on the serving
+        path. Slots without a live directory entry (recycled mid-window)
+        are simply absent from the result."""
+        return resolve_slots(self.directory, slots)
 
     def device_hit_counts(self, keys) -> dict:
         """Per-key lifetime attempt counters from device row field 7

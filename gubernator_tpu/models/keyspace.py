@@ -15,7 +15,9 @@ at millions of lookups/sec.
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import List, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
+
+import numpy as np
 
 
 class KeyDirectory:
@@ -101,3 +103,36 @@ class KeyDirectory:
     def peek_slot(self, key: str) -> int:
         """Slot for key without recency effects; -1 if absent."""
         return self._map.get(key, -1)
+
+
+def resolve_slots(directory, slots) -> Dict[int, str]:
+    """slot -> key for the slots that hold a key in `directory` right now;
+    slots without a live entry (free, recycled away, out of range) are
+    simply absent from the result.
+
+    The native directory answers by index (`keys_for_slots`): the cost is
+    the slots asked, whatever the directory holds, and its mutex is held
+    for at most one window's worth of slots at a time, so the tickers that
+    call this (ledger audit, hot-key tracker, cartographer) never stall a
+    window's prep. The directory keeps serving meanwhile, so each slot is
+    answered with the key that holds it at that instant: a slot recycled
+    between the decision and this call names its new key, as it did under
+    the whole-directory dump this replaced. The Python twin has only the
+    key -> slot map and is walked."""
+    want = np.unique(np.asarray(
+        slots if isinstance(slots, np.ndarray) else list(slots), np.int64))
+    want = want[(want >= 0) & (want < directory.capacity)]
+    if not want.size:
+        return {}
+    if hasattr(directory, "keys_for_slots"):
+        blob, off = directory.keys_for_slots(want)
+        live = np.flatnonzero(off[1:] > off[:-1])
+        bounds = zip(off[live].tolist(), off[live + 1].tolist())
+        if blob.isascii():  # one decode, then slices: bytes are characters
+            text = blob.decode("ascii")
+            keys = [text[lo:hi] for lo, hi in bounds]
+        else:
+            keys = [blob[lo:hi].decode("utf-8") for lo, hi in bounds]
+        return dict(zip(want[live].tolist(), keys))
+    asked = set(want.tolist())
+    return {int(s): key for key, s in directory.items() if int(s) in asked}

@@ -155,6 +155,11 @@ def load_library() -> ctypes.CDLL:
         lib.keydir_dump.argtypes = [
             c.c_void_p, c.c_void_p, c.c_int64, c.c_void_p, c.c_void_p, c.c_int64,
         ]
+        lib.keydir_keys_for_slots.restype = c.c_int64
+        lib.keydir_keys_for_slots.argtypes = [
+            c.c_void_p, c.c_void_p, c.c_int64, c.c_void_p, c.c_int64,
+            c.c_void_p,
+        ]
         lib.keydir_size.restype = c.c_int64
         lib.keydir_size.argtypes = [c.c_void_p]
         lib.keydir_evictions.restype = c.c_int64
@@ -759,6 +764,33 @@ class NativeKeyDirectory:
         count = int(count)
         return (key_buf.raw[:int(offsets[count])], offsets[:count + 1],
                 slots[:count])
+
+    def keys_for_slots(self, slots) -> Tuple[bytes, np.ndarray]:
+        """(key_blob, offsets i64[n+1]) for an array of slots: key i is
+        `key_blob[offsets[i]:offsets[i + 1]]`, empty for a free, negative
+        or out-of-range slot. Reverse lookup by index (keydir.cpp
+        keys_for_slots): the cost is the slots asked, not the directory,
+        and the directory's mutex is held for at most 8,192 slots at a
+        time, so tickers resolve slots through this and never through
+        items_raw(). The directory keeps serving meanwhile: each slot is
+        answered with the key that holds it at that instant, so a slot
+        recycled since the caller saw it names its new key (the dump had
+        the same contract)."""
+        # any integer in, int32 out: what int32 cannot hold is no slot
+        slots = np.clip(np.asarray(slots, np.int64), -1,
+                        np.iinfo(np.int32).max).astype(np.int32)
+        n = len(slots)
+        offsets = np.empty(n + 1, np.int64)
+        buf_cap = 48 * n + (1 << 16)
+        while True:
+            key_buf = np.empty(buf_cap, np.uint8)
+            nbytes = self._lib.keydir_keys_for_slots(
+                self._kd, slots.ctypes.data, n, key_buf.ctypes.data,
+                buf_cap, offsets.ctypes.data)
+            if nbytes >= 0:
+                return key_buf[:nbytes].tobytes(), offsets
+            # -nbytes is what these slots' keys took at that instant
+            buf_cap = -nbytes + (-nbytes >> 3) + (1 << 16)
 
     def peek_slots_raw(self, key_blob: bytes, offsets: np.ndarray
                        ) -> np.ndarray:

@@ -17,6 +17,7 @@
 // prep_pack fast path at the bottom — the core KeyDir is plain C++.
 #include <Python.h>
 
+#include <atomic>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
@@ -24,6 +25,7 @@
 #include <ctime>
 #include <mutex>
 #include <string>
+#include <thread>
 #include <unordered_set>
 #include <vector>
 
@@ -135,7 +137,7 @@ class KeyDir {
                          int32_t* slots_out, uint8_t* fresh_out,
                          int64_t* inject = nullptr,
                          int32_t* n_inject = nullptr) {
-        std::lock_guard<std::mutex> g(mu_);
+        Hold g(*this);
         ++gen_;
         int32_t ninj = 0;
         // Hash pass + software prefetch: at 10M+ entries every probe is a
@@ -201,7 +203,7 @@ class KeyDir {
 
     // Forget a key, returning its slot to the free list.
     void drop(const char* key, int32_t len) {
-        std::lock_guard<std::mutex> g(mu_);
+        Hold g(*this);
         int32_t e = find(key, len);
         if (e < 0) return;
         // unlink from the LRU before touching buckets: remove_bucket may
@@ -216,7 +218,7 @@ class KeyDir {
 
     // Peek a key's slot without recency effects; -1 if absent.
     int32_t peek(const char* key, int32_t len) const {
-        std::lock_guard<std::mutex> g(mu_);
+        Hold g(*this);
         int32_t e = find(key, len);
         return e < 0 ? -1 : entries_[e].slot;
     }
@@ -225,7 +227,7 @@ class KeyDir {
     // max_rows reconciliation rows (slot + 7 values) and clears the flags.
     // Returns the count; callers loop until 0.
     int32_t mirror_flush(int64_t* inject, int32_t max_rows) {
-        std::lock_guard<std::mutex> g(mu_);
+        Hold g(*this);
         int32_t ninj = 0;
         for (int32_t e = lru_head_; e >= 0 && ninj < max_rows;
              e = entries_[e].lru_next) {
@@ -243,7 +245,7 @@ class KeyDir {
     // meaningful for a live row; the caller gathers under the engine lock
     // so the row is post-window-authoritative.
     void mirror_seed(const char* key, int32_t len, const int64_t* row7) {
-        std::lock_guard<std::mutex> g(mu_);
+        Hold g(*this);
         int32_t e = find(key, len);
         if (e < 0) return;
         std::memcpy(entries_[e].mirror.row, row7, 7 * sizeof(int64_t));
@@ -259,7 +261,7 @@ class KeyDir {
     int decide_one(const char* key, int32_t len, int64_t hits, int64_t limit,
                    int64_t duration, int32_t algorithm, int32_t behavior,
                    int64_t now, int64_t* out4) {
-        std::lock_guard<std::mutex> g(mu_);
+        Hold g(*this);
         int32_t e = find(key, len);
         if (e < 0 || !entries_[e].mirror.valid) return 0;
         Entry& ent = entries_[e];
@@ -333,7 +335,7 @@ class KeyDir {
     // -needed_bytes when key_buf is too small.
     int64_t dump(char* key_buf, int64_t buf_cap, int64_t* offsets,
                  int32_t* slots, int64_t max_items) const {
-        std::lock_guard<std::mutex> g(mu_);
+        Hold g(*this);
         int64_t nbytes = 0, count = 0;
         for (int32_t e = lru_head_; e >= 0; e = entries_[e].lru_next) {
             nbytes += static_cast<int64_t>(entries_[e].key.size());
@@ -352,8 +354,56 @@ class KeyDir {
         return count;
     }
 
+    // Reverse lookup by index: the keys that hold the given slots right
+    // now. entries_[i].slot == i for the directory's whole life (set once
+    // in the constructor), so slot -> key is entries_[slot].key and the
+    // cost is the slots asked, not the directory. Keys are written
+    // back-to-back into key_buf with offsets (n+1 entries); a free,
+    // negative or out-of-range slot gets a zero-length key. Returns the
+    // key bytes, or -needed_bytes when key_buf is too small (offsets are
+    // then still complete). mu_ is taken per chunk of one window's worth
+    // of slots and released between chunks, so a lookup_batch never waits
+    // behind more than one chunk; each slot is answered with the key that
+    // holds it at the instant its chunk is read.
+    int64_t keys_for_slots(const int32_t* slots, int64_t n, char* key_buf,
+                           int64_t buf_cap, int64_t* offsets) const {
+        constexpr int64_t CHUNK = 8192;
+        int64_t off = 0;
+        for (int64_t lo = 0; lo < n; lo += CHUNK) {
+            const int64_t hi = lo + CHUNK < n ? lo + CHUNK : n;
+            // Outside the mutex: let whoever waits for it go first (see
+            // waiting_), and pull the chunk's entries towards the cache
+            // meanwhile (entries_ never reallocates; a prefetch reads
+            // nothing), which shortens the hold to cache hits.
+            for (int64_t i = lo; i < hi; ++i) {
+                const int32_t s = slots[i];
+                if (s >= 0 && s < capacity_) {
+                    __builtin_prefetch(&entries_[s]);
+                    __builtin_prefetch(&entries_[s].used);
+                }
+            }
+            while (waiting_.load(std::memory_order_relaxed) > 0) {
+                std::this_thread::yield();
+            }
+            Hold g(*this);
+            for (int64_t i = lo; i < hi; ++i) {
+                offsets[i] = off;
+                const int32_t s = slots[i];
+                if (s < 0 || s >= capacity_ || !entries_[s].used) continue;
+                const std::string& k = entries_[s].key;
+                const int64_t len = static_cast<int64_t>(k.size());
+                if (off + len <= buf_cap) {
+                    std::memcpy(key_buf + off, k.data(), k.size());
+                }
+                off += len;
+            }
+        }
+        offsets[n] = off;
+        return off > buf_cap ? -off : off;
+    }
+
     int64_t size() const {
-        std::lock_guard<std::mutex> g(mu_);
+        Hold g(*this);
         return capacity_ - static_cast<int64_t>(free_.size());
     }
     int64_t evictions() const { return evictions_; }
@@ -489,6 +539,24 @@ class KeyDir {
     // lone-request fast path (decide_one, called from the peerlink IO
     // thread WITHOUT the GIL) is atomic against them.
     mutable std::mutex mu_;
+    // Threads blocked on mu_ right now. Every entry point takes the mutex
+    // through Hold, which counts itself while it waits, so the one
+    // low-priority caller that takes the mutex again and again
+    // (keys_for_slots) can stand back until those waiting have it: a bare
+    // unlock-then-lock wins the mutex back before a woken waiter runs,
+    // and a prep then waits out many chunks instead of one.
+    mutable std::atomic<int32_t> waiting_{0};
+    struct Hold {
+        explicit Hold(const KeyDir& d) : d_(d) {
+            d_.waiting_.fetch_add(1, std::memory_order_relaxed);
+            d_.mu_.lock();
+            d_.waiting_.fetch_sub(1, std::memory_order_relaxed);
+        }
+        ~Hold() { d_.mu_.unlock(); }
+        Hold(const Hold&) = delete;
+        Hold& operator=(const Hold&) = delete;
+        const KeyDir& d_;
+    };
     int64_t capacity_;
     uint64_t nbuckets_;
     std::vector<Entry> entries_;
@@ -569,6 +637,15 @@ int64_t keydir_dump(void* kd, char* key_buf, int64_t buf_cap, int64_t* offsets,
                     int32_t* slots, int64_t max_items) {
     return static_cast<KeyDir*>(kd)->dump(key_buf, buf_cap, offsets, slots,
                                           max_items);
+}
+
+// slot -> key by index (see KeyDir::keys_for_slots). Pure C: called
+// through the CDLL handle it drops the GIL for the whole pass.
+int64_t keydir_keys_for_slots(void* kd, const int32_t* slots, int64_t n,
+                              char* key_buf, int64_t buf_cap,
+                              int64_t* offsets) {
+    return static_cast<KeyDir*>(kd)->keys_for_slots(slots, n, key_buf,
+                                                    buf_cap, offsets);
 }
 
 int64_t keydir_size(void* kd) { return static_cast<KeyDir*>(kd)->size(); }

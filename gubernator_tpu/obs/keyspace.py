@@ -43,6 +43,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from gubernator_tpu.models.keyspace import resolve_slots
 from gubernator_tpu.obs import witness
 from gubernator_tpu.obs.profile import background_of
 from gubernator_tpu.obs.introspect import (
@@ -196,34 +197,6 @@ def headroom_forecast(history, backend, pressure_fraction: float = 0.9,
 # ------------------------------------------------------------ cartographer
 
 
-def _resolve_directory(directory, want) -> dict:
-    """slot -> key for a SMALL slot set against one key directory; the
-    generic twin of Engine.resolve_slots for the sharded backend's
-    per-owner directories (native items_raw arena scan when available,
-    python items() walk otherwise)."""
-    want = set(int(s) for s in want)
-    if not want:
-        return {}
-    out: dict = {}
-    if hasattr(directory, "items_raw"):
-        blob, off, slots32 = directory.items_raw()
-        sl = np.asarray(slots32, np.int64)
-        off = np.asarray(off, np.int64)
-        hit = np.nonzero(np.isin(
-            sl, np.fromiter(want, np.int64, len(want))))[0]
-        for i in hit:
-            lo, hi = int(off[i]), int(off[i + 1])
-            try:
-                out[int(sl[i])] = bytes(blob[lo:hi]).decode("utf-8")
-            except UnicodeDecodeError:
-                continue
-    else:
-        for key, s in directory.items():
-            if int(s) in want:
-                out[int(s)] = key
-    return out
-
-
 class KeyspaceCartographer:
     """Periodic off-path harvest of the device table's keyspace shape
     for one Instance, served at /v1/debug/keyspace."""
@@ -303,7 +276,7 @@ class KeyspaceCartographer:
             for o, local in by_owner.items():
                 if o >= len(dirs):
                     continue
-                for ls, key in _resolve_directory(dirs[o], local).items():
+                for ls, key in resolve_slots(dirs[o], local).items():
                     resolved[o * owner_capacity + ls] = key
         elif getattr(backend, "fps", None) is None:
             resolve = getattr(backend, "resolve_slots", None)

@@ -20,12 +20,14 @@ counter the `over_admission` anomaly detector gates on), not a hope.
 Hot-path contract (the PhaseHist rule from obs/profile.py): the engine's
 window paths pay O(1) per *window*, not per lane — each dispatch parks a
 handful of small numpy column copies (slot, hits, status, limit, reset)
-on a pending ring under a leaf lock. Key resolution (slot → hash-key via
-the directory arena walk), bucket folding, window rolling, and the
-conservation evaluation all run in `audit()`, off the serving path —
-riding the cartographer harvest / anomaly ticker cadence. Lone native
-decisions and the non-engine authorities (lease consume, GLOBAL cache,
-minted budget) record per key directly: they are already per-item paths.
+on a pending ring under a leaf lock. Key resolution (slot → hash-key, by
+index in the directory, once per distinct slot), bucket folding, window
+rolling, and the conservation evaluation all run in `audit()`, off the
+serving path — riding the cartographer harvest / anomaly ticker cadence —
+at a cost set by the lanes drained, not by what the directory holds.
+Lone native decisions and the non-engine authorities (lease consume,
+GLOBAL cache, minted budget) record per key directly: they are already
+per-item paths.
 
 Authorities:
 
@@ -65,7 +67,9 @@ import numpy as np
 from gubernator_tpu.obs import witness
 from gubernator_tpu.obs.profile import background_of
 
-LEDGER_SCHEMA_VERSION = 1
+# v2: totals carry what the audit's attribution pass was asked
+# (slots_asked, slots_resolved, lanes_folded — cumulative).
+LEDGER_SCHEMA_VERSION = 2
 
 # Attribution taxonomy (docs/observability.md "## Decision ledger" pins
 # it; renaming an authority is a schema_version bump, not a drift).
@@ -81,6 +85,10 @@ _SLACK_AUTHORITIES = ("degraded", "reshard", "global_cache")
 
 # log2 over-admission histogram: bucket i holds overshoots <= 2^i hits.
 _NBUCKETS = 28
+
+# _fold_locked's per-slot codes below the tracked keys' indices
+_UNRESOLVED = -1  # the directory names no key for the slot
+_UNTRACKED = -2  # a key with no bucket and no room for one
 
 _AUTHORITY: contextvars.ContextVar = contextvars.ContextVar(
     "guber_ledger_authority", default="owner")
@@ -166,6 +174,11 @@ class DecisionLedger:
         self._overflow = 0  # key-capacity evictions declined
         self._pending_dropped = 0  # windows dropped at the ring cap
         self._unattributed = 0  # hits on slots the directory lost
+        # what the audit's attribution pass was asked to do (cumulative;
+        # the diff between two reads is the audits in between)
+        self._slots_asked = 0  # distinct slots sent to resolve_slots
+        self._slots_resolved = 0  # of those, slots that named a key
+        self._lanes_folded = 0  # decision lanes drained from the ring
         self._audits = 0
         self._last_audit = 0.0
         self._ground_truth = {"keys_checked": 0, "ledger_hits": 0,
@@ -292,46 +305,66 @@ class DecisionLedger:
             self._admits_total[auth] = self._admits_total.get(auth, 0) + hits
 
     def _roll_locked(self, key: str, b: _Bucket) -> None:
-        """Finalize one key-window: evaluate conservation, fold the
-        overshoot into the distribution, and open a fresh window (the
-        lifetime attempted counter survives)."""
-        total_admits = sum(b.admits.values())
-        if total_admits or b.attempted:
-            bound = b.limit + b.minted
-            # each exercised slack authority declares one window of
-            # `limit` as its documented worst case; an authority that
-            # admitted nothing this window contributes no slack
-            slack = b.limit * sum(1 for a in _SLACK_AUTHORITIES
-                                  if b.admits.get(a, 0))
-            overshoot = max(0, total_admits - bound)
-            self._windows_rolled += 1
-            if overshoot:
-                self._overshoot_hits += overshoot
-                if overshoot > self._max_overshoot:
-                    self._max_overshoot = overshoot
-                idx = min(overshoot.bit_length(), _NBUCKETS - 1)
-                self._over_counts[idx] += 1
-                self._over_n += 1
-            if overshoot > slack:
-                self._violations += 1
-                ev = {"key": key, "window": b.window, "limit": b.limit,
-                      "admits": dict(b.admits), "minted": b.minted,
-                      "overshoot": overshoot, "slack": slack}
-                self._recent.append(ev)
-                del self._recent[:-16]
-                if self._emit is not None:
-                    try:
-                        self._emit("ledger.violation", key=key,
-                                   overshoot=overshoot, slack=slack,
-                                   limit=b.limit, minted=b.minted,
-                                   authorities=",".join(sorted(b.admits)))
-                    except Exception:  # noqa: BLE001 — audit never raises
-                        pass
+        """Finalize one key-window and open a fresh one (the lifetime
+        attempted counter survives)."""
+        self._publish_locked(self._close_locked(key, b))
+
+    def _close_locked(self, key: str, b: _Bucket) -> Optional[dict]:
+        """_roll_locked without the publishing: the violation record, if
+        the window was one, is the caller's to publish in its turn."""
+        ev = self._judge_locked(key, b.window, b.limit, b.minted, b.admits,
+                                b.attempted)
         b.window = 0
         b.admits = {}
         b.attempted = 0
         b.rejected = 0
         b.minted = 0
+        return ev
+
+    def _judge_locked(self, key: str, window: int, limit: int, minted: int,
+                      admits: Dict[str, int],
+                      attempted: int) -> Optional[dict]:
+        """Evaluate conservation for one closed key-window and fold its
+        overshoot into the distribution; returns the violation record when
+        the window admitted more than its bound and declared slack."""
+        total_admits = sum(admits.values())
+        if not (total_admits or attempted):
+            return None
+        bound = limit + minted
+        # each exercised slack authority declares one window of `limit`
+        # as its documented worst case; an authority that admitted
+        # nothing this window contributes no slack
+        slack = limit * sum(1 for a in _SLACK_AUTHORITIES
+                            if admits.get(a, 0))
+        overshoot = max(0, total_admits - bound)
+        self._windows_rolled += 1
+        if overshoot:
+            self._overshoot_hits += overshoot
+            if overshoot > self._max_overshoot:
+                self._max_overshoot = overshoot
+            idx = min(overshoot.bit_length(), _NBUCKETS - 1)
+            self._over_counts[idx] += 1
+            self._over_n += 1
+        if overshoot <= slack:
+            return None
+        self._violations += 1
+        return {"key": key, "window": window, "limit": limit,
+                "admits": dict(admits), "minted": minted,
+                "overshoot": overshoot, "slack": slack}
+
+    def _publish_locked(self, ev: Optional[dict]) -> None:
+        if ev is None:
+            return
+        self._recent.append(ev)
+        del self._recent[:-16]
+        if self._emit is not None:
+            try:
+                self._emit("ledger.violation", key=ev["key"],
+                           overshoot=ev["overshoot"], slack=ev["slack"],
+                           limit=ev["limit"], minted=ev["minted"],
+                           authorities=",".join(sorted(ev["admits"])))
+            except Exception:  # noqa: BLE001 — audit never raises
+                pass
 
     # ------------------------------------------------------------ auditing
 
@@ -362,33 +395,18 @@ class DecisionLedger:
             now_ms = int(time.time() * 1000)
         with self._pending_lock:
             pending, self._pending = self._pending, []
+        cols = self._pending_columns(pending)
         resolved: Dict[int, str] = {}
-        if pending and engine is not None:
-            want = set()
-            for sh, _resp, _auth in pending:
-                want.update(int(s) for s in sh[0].tolist())
-            want.discard(-1)
+        if cols is not None and engine is not None:
             try:
                 with background_of(engine, "ledger.resolve_slots"):
-                    resolved = engine.resolve_slots(want)
+                    resolved = engine.resolve_slots(cols[0])
             except Exception:  # noqa: BLE001 — audit never raises
                 resolved = {}
+            self._slots_asked += len(cols[0])
         with self._lock:
-            for sh, resp, auth in pending:
-                sl = sh[0].tolist()
-                hl = sh[1].tolist()
-                stl = resp[0].tolist()
-                ll = resp[1].tolist()
-                rl = resp[3].tolist()
-                for j, s in enumerate(sl):
-                    if s < 0:
-                        continue  # padding lane, not a lost key
-                    key = resolved.get(int(s))
-                    if key is None:
-                        self._unattributed += hl[j]
-                        continue
-                    self._record_locked(key, hl[j], stl[j], ll[j],
-                                        rl[j], auth)
+            if cols is not None:
+                self._fold_locked(cols, resolved)
             for key, b in list(self._buckets.items()):
                 if b.window and (force or b.window <= now_ms):
                     self._roll_locked(key, b)
@@ -399,6 +417,225 @@ class DecisionLedger:
             with self._lock:
                 report["ground_truth"] = dict(self._ground_truth)
         return report
+
+    @staticmethod
+    def _pending_columns(pending):
+        """The drained ring as one set of lane columns in arrival order,
+        padding lanes (slot -1) dropped: (unique slots sorted, each lane's
+        index into them, hits, status, limit, reset, index into auths,
+        auths) — or None when no lane is left."""
+        if not pending:
+            return None
+        slot_hits, resps, rec_auths = zip(*pending)
+        slots = np.concatenate([sh[0] for sh in slot_hits])
+        live = np.flatnonzero(slots >= 0)
+        if not live.size:
+            return None
+        hits = np.concatenate([sh[1] for sh in slot_hits])
+        resp = np.concatenate(resps, axis=1)
+        uniq, inverse = np.unique(slots[live], return_inverse=True)
+        auths = sorted(set(rec_auths))
+        auth_of = np.repeat([auths.index(a) for a in rec_auths],
+                            [sh.shape[1] for sh in slot_hits])
+        return (uniq, inverse, hits[live], resp[0, live], resp[1, live],
+                resp[3, live], auth_of[live], auths)
+
+    def _fold_locked(self, cols, resolved: Dict[int, str]) -> None:
+        """Fold the drained lanes into the key buckets, leaving what the
+        per-lane walk (every lane through _record_locked, in arrival
+        order; tests/test_ledger.py keeps it as the reference) would
+        leave. The work that scales with traffic is done per distinct slot
+        and in numpy: a slot is named once, its key tested against the
+        buckets once, and lanes of keys that are not tracked (most of
+        them, once key_capacity keys are) are counted, not visited. Lanes
+        of tracked keys, and of keys there is still room to admit, go to
+        _fold_tracked_locked."""
+        uniq, inverse, hits, status, limit, reset, auth_of, auths = cols
+        self._lanes_folded += len(inverse)
+        # per distinct slot: the index of its tracked key in `tracked`,
+        # _UNRESOLVED, or _UNTRACKED (a key met with no room left). The
+        # two passes over all of them are dict lookups mapped in C; Python
+        # walks only the slots of tracked keys.
+        keys = list(map(resolved.get, uniq.tolist()))
+        held = list(map(self._buckets.get, keys))
+        code = np.full(len(uniq), _UNTRACKED, np.int64)
+        unresolved = [i for i, key in enumerate(keys) if key is None]
+        code[unresolved] = _UNRESOLVED
+        self._slots_resolved += len(uniq) - len(unresolved)
+        tracked: List[tuple] = []
+        index_of: Dict[str, int] = {}
+        for i in [i for i, b in enumerate(held) if b is not None]:
+            at = index_of.get(keys[i])
+            if at is None:
+                at = index_of[keys[i]] = len(tracked)
+                tracked.append((keys[i], held[i]))
+            code[i] = at
+        if len(self._buckets) < self.key_capacity:
+            # buckets go to keys in the order their first lanes arrived
+            first = np.unique(inverse, return_index=True)[1]
+            newcomers = np.flatnonzero(code == _UNTRACKED)
+            for i in newcomers[np.argsort(first[newcomers])].tolist():
+                at = index_of.get(keys[i])
+                if at is None:
+                    if len(self._buckets) >= self.key_capacity:
+                        continue
+                    b = self._buckets[keys[i]] = _Bucket()
+                    at = index_of[keys[i]] = len(tracked)
+                    tracked.append((keys[i], b))
+                code[i] = at
+        lane_code = code[inverse]
+        self._unattributed += int(hits[lane_code == _UNRESOLVED].sum())
+        self._overflow += int(np.count_nonzero(lane_code == _UNTRACKED))
+        keep = np.flatnonzero(lane_code >= 0)
+        if keep.size:
+            self._fold_tracked_locked(
+                tracked, lane_code[keep], hits[keep], status[keep],
+                limit[keep], reset[keep], auth_of[keep], auths)
+
+    def _fold_tracked_locked(self, tracked, kid, hits, status, limit, reset,
+                             auth_of, auths) -> None:
+        """Apply the lanes of tracked keys (`kid` indexes `tracked`, lanes
+        in arrival order) as _record_locked would one by one, in work per
+        key and per window instead of per lane.
+
+        Sorted by key (arrival order kept inside a key), a key's lanes fall
+        into segments: a new one starts wherever a lane's reset is past the
+        window open before it, which is where _record_locked rolls. The
+        first segment continues the bucket's open window unless the key's
+        very first lane already rolls it; each later one is a window of
+        its own, closed by the next; the last stays open in the bucket.
+        Sums per segment are exact int64 reductions (the width of the
+        device's own counters). A window that
+        admitted no more than its limit can only count as rolled, so those
+        are counted in numpy and only the others are judged one by one;
+        violations are published in the arrival order of the lanes that
+        closed their windows, as the per-lane walk emits them."""
+        n = len(kid)
+        order = np.argsort(kid, kind="stable")
+        kid, hits, status, limit, reset, auth_of = (
+            a[order] for a in (kid, hits, status, limit, reset, auth_of))
+        lane = np.arange(n)
+        is_gstart = np.ones(n, bool)
+        is_gstart[1:] = kid[1:] != kid[:-1]
+        gstart = np.flatnonzero(is_gstart)
+        n_groups = len(gstart)
+        group = np.cumsum(is_gstart) - 1
+        buckets = [tracked[i] for i in kid[gstart].tolist()]
+        window0 = np.asarray([b.window for _key, b in buckets], np.int64)
+        limit0 = np.asarray([b.limit for _key, b in buckets], np.int64)
+
+        # the window open before each lane: the running maximum of the
+        # bucket's own and the resets so far (0 is "none"; stamps are not
+        # negative). Ranks stand in for the stamps so that group * n_ranks
+        # + rank, whose running maximum never crosses a group, cannot
+        # overflow.
+        stamps, rank = np.unique(np.concatenate([window0, reset]),
+                                 return_inverse=True)
+        n_ranks = len(stamps)
+        base = group * n_ranks
+        run = np.maximum.accumulate(base + rank[n_groups:])
+        before = base + rank[:n_groups][group]
+        inner = ~is_gstart
+        before[inner] = np.maximum(before[inner], run[:-1][inner[1:]])
+        open_before = stamps[before - base]
+        rolls = (reset != 0) & (open_before != 0) & (reset > open_before)
+
+        # segments, and what each adds up to
+        is_sstart = rolls | is_gstart
+        sstart = np.flatnonzero(is_sstart)
+        n_segs = len(sstart)
+        send = np.append(sstart[1:], n) - 1  # last lane of each
+        seg_group = group[sstart]
+        rolled_in = rolls[sstart].tolist()
+        admitted = status != 1
+        attempted = np.add.reduceat(hits, sstart)
+        rejected = np.add.reduceat(np.where(admitted, 0, hits), sstart)
+        admits, present = [], []
+        for a in range(len(auths)):
+            mine = admitted & (auth_of == a)
+            admits.append(np.add.reduceat(np.where(mine, hits, 0), sstart))
+            present.append(np.add.reduceat(mine.astype(np.int64), sstart) > 0)
+        total_admits = np.sum(admits, axis=0)
+        # the limit in force at a segment's end: the last non-zero one so
+        # far in the key's lanes, else the bucket's
+        last_set = np.maximum.accumulate(np.where(limit != 0, lane, -1))
+        at = last_set[send]
+        limit_end = np.where(at >= gstart[seg_group], limit[at],
+                             limit0[seg_group])
+        # the window a segment belongs to: the stamp that rolled it in,
+        # else the bucket's, else the first stamp it meets
+        first_set = np.minimum.reduceat(np.where(reset != 0, lane, n), sstart)
+        met = np.where(first_set <= send, reset[np.minimum(first_set, n - 1)],
+                       0)
+        seg_window = np.where(
+            rolls[sstart], reset[sstart],
+            np.where(window0[seg_group] != 0, window0[seg_group], met))
+
+        self._attempted_total += int(hits.sum())
+        self._rejected_total += int(rejected.sum())
+        for a, name in enumerate(auths):
+            if present[a].any():
+                self._admits_total[name] = (
+                    self._admits_total.get(name, 0) + int(admits[a].sum()))
+
+        # closed windows that are whole segments: every one but a key's
+        # last, if something rolled it in (else it is the bucket's own)
+        closed = rolls[sstart]
+        closed[:-1] &= seg_group[1:] == seg_group[:-1]
+        closed[-1] = False
+        counted = closed & ((total_admits != 0) | (attempted != 0))
+        judged = counted & (total_admits > limit_end)
+        self._windows_rolled += int(np.count_nonzero(counted & ~judged))
+
+        admits_l = [a.tolist() for a in admits]
+        present_l = [p.tolist() for p in present]
+
+        def admits_of(s):
+            return {name: adm[s] for name, adm, there in zip(
+                auths, admits_l, present_l) if there[s]}
+
+        attempted_l, rejected_l = attempted.tolist(), rejected.tolist()
+        limit_l, window_l = limit_end.tolist(), seg_window.tolist()
+        # arrival rank of each segment's first lane, the one that closed
+        # the window before it
+        closer = order[sstart].tolist()
+        events = []
+        for s in np.flatnonzero(judged).tolist():
+            key = buckets[seg_group[s]][0]
+            ev = self._judge_locked(key, window_l[s], limit_l[s], 0,
+                                    admits_of(s), attempted_l[s])
+            if ev is not None:
+                events.append((closer[s + 1], ev))
+
+        # the buckets: continue the open window, roll it where the lanes
+        # say, leave the key's last window open
+        seg_lo = np.searchsorted(seg_group, np.arange(n_groups)).tolist()
+        seg_hi = seg_lo[1:] + [n_segs]
+        lifetime = np.add.reduceat(hits, gstart).tolist()
+        for g, (key, b) in enumerate(buckets):
+            b.lifetime_attempted += lifetime[g]
+            s, last = seg_lo[g], seg_hi[g] - 1
+            if not rolled_in[s]:
+                if not b.window:
+                    b.window = window_l[s]
+                b.limit = limit_l[s]
+                b.attempted += attempted_l[s]
+                b.rejected += rejected_l[s]
+                for name, hits_in in admits_of(s).items():
+                    b.admits[name] = b.admits.get(name, 0) + hits_in
+                if s == last:
+                    continue
+                s += 1
+            ev = self._close_locked(key, b)
+            if ev is not None:
+                events.append((closer[s], ev))
+            b.window = window_l[last]
+            b.limit = limit_l[last]
+            b.attempted = attempted_l[last]
+            b.rejected = rejected_l[last]
+            b.admits = admits_of(last)
+        for _at, ev in sorted(events, key=lambda e: e[0]):
+            self._publish_locked(ev)
 
     def _ground_truth_check(self, engine, sample: int = 64) -> None:
         """Hold the ledger's per-key lifetime attempted totals against
@@ -457,6 +694,9 @@ class DecisionLedger:
                 "pending_dropped": self._pending_dropped,
                 "unattributed_hits": self._unattributed,
                 "audits": self._audits,
+                "slots_asked": self._slots_asked,
+                "slots_resolved": self._slots_resolved,
+                "lanes_folded": self._lanes_folded,
             }
 
     def _overshoot_locked(self) -> dict:

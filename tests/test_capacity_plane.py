@@ -215,6 +215,38 @@ class TestCartographer:
         finally:
             inst.close()
 
+    def test_top_keys_through_per_owner_directories(self):
+        """The sharded backend's shape: one directory per owner, global
+        slot = owner * stride + local slot; each owner's directory is
+        asked by index (native) or walked (Python twin), same answer."""
+        from gubernator_tpu.models.keyspace import KeyDirectory
+        from gubernator_tpu.native import make_key_directory
+
+        inst = Instance(InstanceConfig(backend=Engine(capacity=64)))
+        try:
+            stride = 16
+            dirs = [make_key_directory(stride), KeyDirectory(stride)]
+            counts = np.zeros(2 * stride, np.int64)
+            want = {}
+            for o, d in enumerate(dirs):
+                names = [f"owner{o}-key{i}" for i in range(5)]
+                slots, _ = d.lookup(names)
+                for i, (name, s) in enumerate(zip(names, slots)):
+                    counts[o * stride + s] = 100 * (o + 1) + i
+                    want[o * stride + s] = name
+
+            class _Sharded:
+                directories = dirs
+
+            top = inst.keyspace._top_keys(_Sharded(), counts, stride)
+            assert len(top) == 10
+            assert [e["hits"] for e in top] == sorted(
+                counts[counts > 0].tolist(), reverse=True)
+            assert {e["slot"]: e["key"] for e in top} == want
+            assert {e["owner"] for e in top} == {0, 1}
+        finally:
+            inst.close()
+
     def test_top_k_bound_and_disabled_hatch(self):
         inst = Instance(InstanceConfig(backend=Engine(capacity=256),
                                        keyspace_top_k=3,

@@ -239,6 +239,251 @@ class TestConservationRule:
 # ------------------------------------------------------------- differential
 
 
+class _PerLaneLedger(DecisionLedger):
+    """The reference fold: the audit as it was before the grouped fold —
+    every drained lane walked in Python, its slot named through a dict,
+    its key sent to _record_locked. The program keeps only the grouped
+    fold (obs/ledger.py _fold_locked); this one says what it has to
+    equal."""
+
+    def _audit(self, engine, now_ms, force):
+        self._last_audit = time.monotonic()
+        if now_ms is None:
+            now_ms = int(time.time() * 1000)
+        with self._pending_lock:
+            pending, self._pending = self._pending, []
+        resolved = {}
+        if pending and engine is not None:
+            want = set()
+            for sh, _resp, _auth in pending:
+                want.update(int(s) for s in sh[0].tolist())
+            want.discard(-1)
+            try:
+                resolved = engine.resolve_slots(want)
+            except Exception:  # noqa: BLE001 — audit never raises
+                resolved = {}
+        with self._lock:
+            for sh, resp, auth in pending:
+                sl = sh[0].tolist()
+                hl = sh[1].tolist()
+                stl = resp[0].tolist()
+                ll = resp[1].tolist()
+                rl = resp[3].tolist()
+                for j, s in enumerate(sl):
+                    if s < 0:
+                        continue  # padding lane, not a lost key
+                    key = resolved.get(int(s))
+                    if key is None:
+                        self._unattributed += hl[j]
+                        continue
+                    self._record_locked(key, hl[j], stl[j], ll[j],
+                                        rl[j], auth)
+            for key, b in list(self._buckets.items()):
+                if b.window and (force or b.window <= now_ms):
+                    self._roll_locked(key, b)
+            self._audits += 1
+            report = self._report_locked()
+        if engine is not None:
+            self._ground_truth_check(engine)
+            with self._lock:
+                report["ground_truth"] = dict(self._ground_truth)
+        return report
+
+
+class _FakeEngine:
+    """slot -> key from a fixed map (several slots may name one key, some
+    name none), and device counters a fixed amount above the ledger's."""
+
+    def __init__(self, names):
+        self.names = names
+
+    def resolve_slots(self, want):
+        return {int(s): self.names[int(s)] for s in want
+                if int(s) in self.names}
+
+    def device_hit_counts(self, keys):
+        return {k: 7 * len(k) for k in keys if not k.endswith("3")}
+
+
+_NEW_COUNTERS = ("slots_asked", "slots_resolved", "lanes_folded")
+
+
+def _ledger_state(led):
+    """Everything the ledger serves or keeps, in comparable form."""
+    body = led.endpoint_body()
+    for name in _NEW_COUNTERS:
+        body["totals"].pop(name)
+    with led._lock:
+        buckets = [(k, b.window, b.limit, dict(b.admits), b.attempted,
+                    b.rejected, b.minted, b.lifetime_attempted)
+                   for k, b in led._buckets.items()]
+        hist = list(led._over_counts)
+    return {"body": body, "debug": led.debug(), "buckets": buckets,
+            "overshoot_counts": hist}
+
+
+class TestGroupedFoldEqualsPerLane:
+    """The audit's fold works per distinct slot and counts the lanes of
+    untracked keys in numpy; what it leaves behind must be what the
+    per-lane walk leaves, to the order of the violation events."""
+
+    @pytest.mark.parametrize("seed", range(12))
+    def test_random_pending_sequences(self, seed):
+        import random
+
+        rng = random.Random(1000 + seed)
+        n_slots = rng.choice([40, 120])
+        names = {}
+        for s in range(n_slots):
+            r = rng.random()
+            if r < 0.12:
+                continue  # a slot the directory lost
+            # a few slots share a key (recycled between chunks)
+            names[s] = f"key{s // 2 if r < 0.25 else s}"
+        engine = _FakeEngine(names)
+        capacity = rng.choice([4, 16, 1000])
+        events = ([], [])
+        leds = (
+            DecisionLedger(enabled=True, key_capacity=capacity,
+                           emit=lambda kind, **kw: events[0].append(
+                               (kind, kw))),
+            _PerLaneLedger(enabled=True, key_capacity=capacity,
+                           emit=lambda kind, **kw: events[1].append(
+                               (kind, kw))),
+        )
+        base = 1_700_000_000_000
+        tick = 0
+        for audit_no in range(4):
+            for _ in range(rng.randrange(0, 7)):
+                n = rng.randrange(1, 60)
+                slots = [rng.choice([-1] + list(range(n_slots)) * 3)
+                         for _ in range(n)]
+                hits = [rng.randrange(0, 6) for _ in range(n)]
+                status = [int(rng.random() < 0.3) for _ in range(n)]
+                limit = [rng.choice([0, 3, 8, 50]) for _ in range(n)]
+                if rng.random() < 0.4:
+                    # a leaky bucket's reset moves with every decision:
+                    # each lane closes the window the one before opened
+                    tick += n
+                    reset = [base + 1000 * audit_no + tick - n + j
+                             for j in range(n)]
+                else:
+                    # resets step forward inside one audit (windows roll
+                    # in the middle of it), fall back (late lanes), are 0
+                    reset = [rng.choice([0, base + 1000 * rng.randrange(
+                        audit_no, audit_no + 4)]) for _ in range(n)]
+                auth = rng.choice(["owner", "owner", "degraded", "lease",
+                                   MINT_AUTHORITY])
+                for led in leds:
+                    with authority(auth):
+                        led.note_arrays(slots, hits, status, limit, reset)
+            if rng.random() < 0.5:  # the per-key recorders in between
+                direct = f"key{rng.randrange(n_slots)}"
+                for led in leds:
+                    led.record_key(direct, 2, 0, 8, base + 1000 * audit_no)
+                    led.record_minted("key1", 3)
+            now_ms = base + 1000 * audit_no + 500
+            force = audit_no == 3
+            reports = [led.audit(engine=engine, now_ms=now_ms, force=force)
+                       for led in leds]
+            assert reports[0] == reports[1]
+            assert _ledger_state(leds[0]) == _ledger_state(leds[1])
+            assert events[0] == events[1]
+        t = leds[0].totals()
+        assert t["audits"] == 4
+        assert t["lanes_folded"] >= t["slots_asked"] >= t["slots_resolved"]
+        if capacity == 4:
+            assert t["key_overflow"] > 0
+
+    def test_the_cases_the_random_ones_must_have_met(self):
+        """One sequence with every feature by construction: capacity
+        crossed mid-window, a roll and a violation inside one audit, two
+        authorities, padding, an unresolved slot, two slots of one key."""
+        seen = ([], [])
+        leds = (DecisionLedger(enabled=True, key_capacity=2,
+                               emit=lambda k, **kw: seen[0].append((k, kw))),
+                _PerLaneLedger(enabled=True, key_capacity=2,
+                               emit=lambda k, **kw: seen[1].append((k, kw))))
+        engine = _FakeEngine({1: "a", 2: "b", 3: "c", 4: "a"})
+        for led in leds:
+            led.note_arrays([2, -1, 1, 3, 9], [1, 9, 4, 2, 5],
+                            [0, 0, 0, 0, 0], [3, 3, 3, 3, 3],
+                            [5000, 5000, 5000, 5000, 5000])
+            with authority("degraded"):
+                led.note_arrays([4, 3, 1, 2], [4, 1, 1, 1], [0, 0, 1, 0],
+                                [3, 3, 3, 3], [5000, 5000, 9000, 9000])
+            led.audit(engine=engine, now_ms=6000)
+        assert _ledger_state(leds[0]) == _ledger_state(leds[1])
+        assert seen[0] == seen[1]
+        t = leds[0].totals()
+        assert t["keys_tracked"] == 2  # "b" then "a"; "c" found no room
+        assert t["key_overflow"] == 2  # both lanes of slot 3
+        assert t["unattributed_hits"] == 5  # slot 9
+        # "a": 4 owner + 4 degraded against limit 3 rolls at reset 9000
+        # with overshoot 5 > one window of slack 3
+        assert t["violations"] == 1 and t["windows_rolled"] == 2
+        assert [kw["key"] for _k, kw in seen[0]] == ["a"]
+        assert (t["slots_asked"], t["slots_resolved"],
+                t["lanes_folded"]) == (5, 4, 8)
+
+    def test_the_counters_say_what_an_audit_was_asked(self):
+        led = DecisionLedger(enabled=True)
+        led.note_arrays([3, 7, -1, 3], [1, 1, 1, 1], [0, 0, 0, 0],
+                        [9, 9, 9, 9], [5000, 5000, 5000, 5000])
+        led.audit()  # no engine: nothing is asked, every lane is folded
+        t = led.totals()
+        assert (t["slots_asked"], t["slots_resolved"],
+                t["lanes_folded"], t["unattributed_hits"]) == (0, 0, 3, 3)
+        led.note_arrays([3, 7], [1, 1], [0, 0], [9, 9], [5000, 5000])
+        led.audit(engine=_FakeEngine({3: "alpha"}))
+        t = led.totals()
+        assert (t["slots_asked"], t["slots_resolved"],
+                t["lanes_folded"], t["unattributed_hits"]) == (2, 1, 5, 4)
+
+        class _Broken:
+            def resolve_slots(self, want):
+                raise RuntimeError("directory gone")
+
+        led.note_arrays([3], [2], [0], [9], [5000])
+        led.audit(engine=_Broken())  # the audit never raises
+        t = led.totals()
+        assert (t["slots_asked"], t["slots_resolved"],
+                t["lanes_folded"], t["unattributed_hits"]) == (3, 1, 6, 6)
+
+
+class TestResolveSlots:
+    def test_native_directory_against_the_python_twin_on_one_engine(self):
+        """Engine.resolve_slots picks index lookup or the walk by what the
+        directory offers; both name the same keys for the same slots."""
+        import numpy as np
+
+        from gubernator_tpu.models.keyspace import KeyDirectory
+
+        eng = Engine(capacity=256, min_width=64, max_width=64)
+        if not hasattr(eng.directory, "keys_for_slots"):
+            pytest.skip("native directory unavailable")
+        for lo in range(0, 300, 50):  # 300 keys through 256 slots: evicts
+            eng.get_rate_limits([_rl(f"k{i}-é") for i in range(lo, lo + 50)])
+        eng.directory.drop("led_k299-é")
+        native_dir = eng.directory
+        live = dict((s, k) for k, s in native_dir.items())
+        assert 200 < len(live) < 256
+        asked = [-1, 0, 5, 5, 255, 256, 10_000] + list(range(0, 256, 3))
+        by_index = eng.resolve_slots(asked)
+        twin = KeyDirectory(256)
+        twin._map.update(native_dir.items())
+        eng.directory = twin
+        try:
+            by_walk = eng.resolve_slots(asked)
+            assert eng.resolve_slots(np.asarray(asked)) == by_walk
+        finally:
+            eng.directory = native_dir
+        assert by_index == by_walk
+        assert by_index == {s: live[s] for s in set(asked) if s in live}
+        assert eng.resolve_slots([]) == {} == eng.resolve_slots([-1, 256])
+        assert eng.resolve_slots(range(256)) == live
+
+
 class TestEscapeHatchDifferential:
     """GUBER_LEDGER=0 must remove the plane, not degrade the data path."""
 

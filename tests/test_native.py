@@ -137,7 +137,7 @@ def test_sustained_eviction_churn_terminates():
 
 class TestDumpWhileServing:
     """items_raw() against a directory that gains keys between the size
-    read and the dump (the ledger audit's resolve_slots during a load):
+    read and the dump (a snapshot save during a load):
     the retry must re-read the size, not double the key buffer forever."""
 
     def test_stale_size_retries_with_a_fresh_one(self, monkeypatch):
@@ -195,3 +195,189 @@ class TestDumpWhileServing:
         finally:
             stop.set()
             t.join()
+
+
+def _packed_keys(prefix: bytes, ids):
+    """(blob, offsets) of `prefix` + 8 hex digits per id, built in numpy."""
+    import numpy as np
+
+    ids = np.asarray(ids, np.uint64)
+    out = np.empty((len(ids), len(prefix) + 8), np.uint8)
+    out[:, :len(prefix)] = np.frombuffer(prefix, np.uint8)
+    hexd = np.frombuffer(b"0123456789abcdef", np.uint8)
+    shifts = np.arange(28, -4, -4).astype(np.uint64)
+    out[:, len(prefix):] = hexd[
+        ((ids[:, None] >> shifts) & np.uint64(15)).astype(np.int64)]
+    width = out.shape[1]
+    return out.tobytes(), np.arange(len(ids) + 1, dtype=np.int64) * width
+
+
+class TestKeysForSlots:
+    """keys_for_slots(): slot -> key by index, the tickers' reverse lookup
+    (ledger audit, hot-key tracker, cartographer) in place of the
+    whole-directory dump."""
+
+    @staticmethod
+    def _by_dump(d):
+        blob, off, slots = d.items_raw()
+        return {int(s): blob[int(off[i]):int(off[i + 1])]
+                for i, s in enumerate(slots)}
+
+    @staticmethod
+    def _by_index(d, slots):
+        blob, off = d.keys_for_slots(slots)
+        assert len(off) == len(slots) + 1 and int(off[-1]) == len(blob)
+        return [blob[int(off[i]):int(off[i + 1])]
+                for i in range(len(slots))]
+
+    def test_same_map_as_the_dump_after_inserts_drops_and_evictions(self):
+        import numpy as np
+
+        rng = random.Random(7)
+        cap = 512
+        d = NativeKeyDirectory(cap)
+        # short keys (inline in the entry), long ones (on the heap) and
+        # multi-byte UTF-8
+        names = [f"k{i}" if i % 3 == 0 else
+                 f"tenant-{i}:" + "x" * (i % 90) if i % 3 == 1 else
+                 f"clé-{i}-ключ" for i in range(400)]
+        for lo in range(0, len(names), 64):
+            d.lookup(names[lo:lo + 64])
+        for key in rng.sample(names, 120):
+            d.drop(key)
+        free_after_drops = cap - len(d)
+        assert free_after_drops == 112 + 120
+        want = self._by_dump(d)
+        got = self._by_index(d, np.arange(cap, dtype=np.int32))
+        assert {s: k for s, k in enumerate(got) if k} == want
+        assert sum(1 for k in got if not k) == free_after_drops
+        # churn through the LRU: every slot changes hands at least once
+        for b in range(20):
+            d.lookup([f"churn:{b}:{i}" for i in range(64)])
+        assert d.evictions > 0 and len(d) == cap
+        want = self._by_dump(d)
+        assert len(want) == cap
+        got = self._by_index(d, np.arange(cap, dtype=np.int32))
+        assert dict(enumerate(got)) == want
+        # any order, repeats allowed: one answer per slot asked
+        asked = [5, 500, 5, 0, 77, 77]
+        assert self._by_index(d, np.asarray(asked, np.int32)) == \
+            [want[s] for s in asked]
+
+    def test_free_negative_and_out_of_range_slots_get_an_empty_key(self):
+        import numpy as np
+
+        d = NativeKeyDirectory(64)
+        slots, _ = d.lookup(["only", "two"])
+        asked = np.asarray([-1, -(2 ** 31), 64, 65, 2 ** 31 - 1, slots[0],
+                            63 if 63 not in slots else 62, slots[1]],
+                           np.int32)
+        assert self._by_index(d, asked) == \
+            [b"", b"", b"", b"", b"", b"only", b"", b"two"]
+        # wider integers are not wrapped into int32's range
+        assert self._by_index(d, np.asarray(
+            [2 ** 32 + slots[0], slots[0], -(2 ** 40)], np.int64)) == \
+            [b"", b"only", b""]
+        blob, off = d.keys_for_slots(np.empty(0, np.int32))
+        assert blob == b"" and off.tolist() == [0]
+        blob, off = NativeKeyDirectory(8).keys_for_slots([0, 1, 2])
+        assert blob == b"" and off.tolist() == [0, 0, 0, 0]
+
+    def test_a_buffer_too_small_is_retried_with_the_size_needed(self):
+        import numpy as np
+
+        d = NativeKeyDirectory(4096)
+        names = [f"{i:05d}" + "y" * 400 for i in range(2000)]
+        slots, _ = d.lookup(names)
+        calls = []
+
+        class _Counting:
+            def __init__(self, lib):
+                self._lib = lib
+
+            def __getattr__(self, name):
+                fn = getattr(self._lib, name)
+                if name != "keydir_keys_for_slots":
+                    return fn
+
+                def counted(*args):
+                    got = fn(*args)
+                    calls.append((args[4], got))  # (buf_cap, returned)
+                    return got
+                return counted
+
+        d._lib = _Counting(d._lib)
+        got = self._by_index(d, np.asarray(slots, np.int32))
+        assert [k.decode() for k in got] == names
+        nbytes = 405 * 2000
+        assert len(calls) == 2
+        assert calls[0][0] < nbytes and calls[0][1] == -nbytes
+        assert calls[1][0] >= nbytes and calls[1][1] == nbytes
+
+    def test_lookups_keep_their_pace_while_a_million_slots_resolve(self):
+        """The directory's mutex is taken per 8,192-slot chunk, so a
+        window's lookup_batch waits behind one chunk at most, never behind
+        the whole pass (the dump held it across all of the directory)."""
+        import threading
+        import time
+
+        import numpy as np
+
+        n = 1_000_000
+        d = NativeKeyDirectory(n)
+        for lo in range(0, n, 250_000):
+            blob, off = _packed_keys(b"tenant:", range(lo, lo + 250_000))
+            d.lookup_raw(blob, off)
+        assert len(d) == n
+        hot_blob, hot_off = _packed_keys(b"tenant:", range(0, n, n // 64))
+        all_slots = np.arange(n, dtype=np.int32)
+        # the C call itself, into buffers made beforehand: the wrapper's
+        # own copy of a 15 MB blob holds the GIL for longer than a chunk
+        # holds the mutex, and this case is about the mutex
+        key_buf = np.empty(15 * n, np.uint8)
+        offsets = np.empty(n + 1, np.int64)
+        passes = []  # (start, end) of each keys_for_slots pass
+        done = threading.Event()
+
+        def resolve():
+            try:
+                for _ in range(6):
+                    t0 = time.perf_counter()
+                    nbytes = d._lib.keydir_keys_for_slots(
+                        d._kd, all_slots.ctypes.data, n,
+                        key_buf.ctypes.data, len(key_buf),
+                        offsets.ctypes.data)
+                    passes.append((t0, time.perf_counter()))
+                    assert nbytes == 15 * n
+            finally:
+                done.set()
+
+        t = threading.Thread(target=resolve)
+        lookups = []  # (start, end) of each lookup_batch
+        t.start()
+        try:
+            while not done.is_set():
+                t0 = time.perf_counter()
+                d.lookup_raw(hot_blob, hot_off)
+                lookups.append((t0, time.perf_counter()))
+        finally:
+            t.join(timeout=120)
+        assert not t.is_alive() and len(passes) == 6
+        # Scheduling noise only ever lengthens a wait, so the calmest pass
+        # is the one held to the bound. A hold across the whole pass (the
+        # dump's) lets no lookup finish inside it and makes the first one
+        # behind it wait about the pass itself; a hold per chunk is 1/123
+        # of it. The bound leaves room for a host that takes a timer tick
+        # (4 ms seen, a sixth of a pass) to wake a thread blocked on the
+        # mutex, whoever held it and for however short a time.
+        best = None
+        for p0, p1 in passes:
+            inside = [e - s for s, e in lookups if s >= p0 and e <= p1]
+            if inside:
+                share = max(inside) / (p1 - p0)
+                if best is None or share < best[0]:
+                    best = (share, len(inside))
+        assert best is not None, "no lookup_batch completed inside a pass"
+        share, completed = best
+        assert share < 1 / 2, f"a lookup waited {share:.2f} of a pass"
+        assert completed >= 16

@@ -17,7 +17,9 @@ preloaded:
   production; the internal KeyDir mutex is the only synchronization, and
   that race (mirror math vs batch lookups on the same keys) is the main
   thing this stress exists to check. Do NOT wrap native_decider in the
-  Python lock: that would silently destroy the coverage.
+  Python lock: that would silently destroy the coverage. The tickers'
+  reverse lookup (keys_for_slots) runs the same way, lock-free outside
+  and chunk by chunk inside.
 
 A data race makes TSan print "WARNING: ThreadSanitizer" and exit 66
 (TSAN_OPTIONS exitcode); the test asserts a clean run.
@@ -205,6 +207,9 @@ _KEYDIR_STRESS = textwrap.dedent("""
                                       c.c_void_p]
     lib.keydir_mirror_flush.restype = c.c_int32
     lib.keydir_mirror_flush.argtypes = [c.c_void_p, c.c_void_p, c.c_int32]
+    lib.keydir_keys_for_slots.restype = c.c_int64
+    lib.keydir_keys_for_slots.argtypes = [c.c_void_p, c.c_void_p, c.c_int64,
+                                          c.c_void_p, c.c_int64, c.c_void_p]
 
     kd = lib.keydir_new(512)
     lock = threading.Lock()  # batch callers keep the engine-lock discipline
@@ -249,9 +254,24 @@ _KEYDIR_STRESS = textwrap.dedent("""
             if i % 97 == 0:
                 lib.keydir_mirror_flush(kd, c.cast(inject, c.c_void_p), 64)
 
+    def resolver(tid):
+        # the tickers' reverse lookup: no engine lock, the KeyDir mutex
+        # taken chunk by chunk (three chunks a call here) with the batch
+        # callers and the deciders let in between
+        N = 20000
+        slots = (c.c_int32 * N)(*[(i * 7) % 600 - 40 for i in range(N)])
+        buf = (c.c_char * (N * 8))()
+        offs = (c.c_int64 * (N + 1))()
+        for i in range(40):
+            got = lib.keydir_keys_for_slots(kd, c.cast(slots, c.c_void_p),
+                                            N, c.cast(buf, c.c_void_p),
+                                            N * 8, c.cast(offs, c.c_void_p))
+            assert 0 <= got == offs[N], got
+
     ts = [threading.Thread(target=hammer, args=(t,)) for t in range(6)]
     ts += [threading.Thread(target=native_decider, args=(t,))
            for t in range(3)]
+    ts += [threading.Thread(target=resolver, args=(t,)) for t in range(2)]
     [t.start() for t in ts]
     [t.join(timeout=120) for t in ts]
     lib.keydir_free(kd)
