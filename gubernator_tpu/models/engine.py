@@ -5,7 +5,7 @@ This is the TPU-native analogue of the reference's core request path
 instead of 1000 goroutines contending on a lock, a request batch becomes one
 device program. The engine owns:
 
-- the device key table (ops/decide.py row-major i64[C, 8] rows in HBM);
+- the device key table (ops/decide.py row-major u32[C, 16] rows in HBM);
 - the host key directory (models/keyspace.py);
 - duplicate-key *rounds*: the reference's mutex serializes same-key requests
   inside a batch; we split a window so each kernel call touches each slot at
@@ -40,6 +40,7 @@ from gubernator_tpu.models.prep import (
 from gubernator_tpu.ops.decide import (
     I32,
     I64,
+    ROW_HITS,
     TableState,
     compact_window,
     decide_packed_lean,
@@ -52,9 +53,12 @@ from gubernator_tpu.ops.decide import (
     decide_scan_packed,
     decide_scan_packed_compact,
     kernel_telemetry,
+    fetch_rows,
+    host_rows,
+    load_rows,
     make_table,
     pack_window,
-    pad_to_drop,
+    store_rows,
     widen_compact_out,
 )
 from gubernator_tpu.native import PREP_OVERCOMMIT
@@ -75,20 +79,18 @@ _GREG_MASK = int(Behavior.DURATION_IS_GREGORIAN)
 def _inject_rows(state: TableState, slot, algo, limit, remaining, duration,
                  stamp, expire_at, status) -> TableState:
     """Scatter host-provided rows into the table (store read-through/loader)."""
-    slot = pad_to_drop(slot, state.shape[-2])
     rows = jnp.stack(
         [algo.astype(I64), limit, remaining, duration, stamp, expire_at,
          status.astype(I64), jnp.zeros_like(limit)],
         axis=1,
     )
-    return state.at[slot].set(rows, mode="drop")
+    return store_rows(state, slot, rows)
 
 
 def _gather_rows(state: TableState, slot):
     """Fetch rows for store write-through / snapshotting (7-column tuple,
     TableState row field order)."""
-    g = jnp.maximum(slot, 0)
-    rows = state[g]
+    rows = load_rows(state, jnp.maximum(slot, 0))
     return tuple(rows[:, i] for i in range(7))
 
 
@@ -1265,9 +1267,9 @@ class Engine:
                 return {}
             # direct fancy-index fetch: _gather serves the 7 snapshot
             # fields only, and this debug surface needn't be jitted
-            rows = np.asarray(
-                self.state[jnp.asarray([s for _, s in pairs], I32)])
-        return {key: int(rows[i, 7]) for i, (key, _) in enumerate(pairs)}
+            rows = fetch_rows(self.state, [s for _, s in pairs])
+        return {key: int(rows[i, ROW_HITS])
+                for i, (key, _) in enumerate(pairs)}
 
     def rows_for_keys(self, keys):
         """Point-read the named keys' live rows -> (found_keys,
@@ -1297,9 +1299,7 @@ class Engine:
                     pairs.append((key, int(slot)))
             if not pairs:
                 return [], np.zeros((0, 7), np.int64)
-            rows = np.asarray(
-                self.state[jnp.asarray([s for _, s in pairs], I32)],
-                np.int64)[:, :7]
+            rows = fetch_rows(self.state, [s for _, s in pairs])[:, :7]
         live = (rows[:, 0] >= 0) & (rows[:, 5] >= now)
         found = [key for (key, _), ok in zip(pairs, live) if ok]
         return found, np.ascontiguousarray(rows[live])
@@ -1474,7 +1474,7 @@ class Engine:
             # the clamped start (it still covers [a, capacity))
             cs = min(a, self.capacity - S)
             with self._lock:
-                slab = np.asarray(slab_fn(self.state, cs))
+                slab = host_rows(slab_fn(self.state, cs))
             idx = order[lo:hi]  # original entry index, slot order
             ent_slots = slots_sorted[lo:hi]
             rows = slab[ent_slots - cs]  # [n, 8] in slot order

@@ -51,6 +51,7 @@ from gubernator_tpu.obs.introspect import (
     key_table_size,
     table_capacity,
 )
+from gubernator_tpu.ops.decide import ROW_HITS, fetch_column
 
 log = logging.getLogger("gubernator_tpu.keyspace")
 
@@ -232,25 +233,25 @@ class KeyspaceCartographer:
         # serving path donates the state buffer to each dispatch and
         # rebinds the attribute, so a reference captured outside the
         # lock can point at a deleted donated array by readback time
-        if plan is not None:  # sharded mesh table i64[R, S, C, 8]
+        if plan is not None:  # sharded mesh table u32[R, S, C, 16]
             if lock is not None:
                 with lock:
-                    arr = np.asarray(backend.state[..., 7])
+                    arr = fetch_column(backend.state, ROW_HITS)
             else:
                 # guberlint: disable=lock-discipline -- backend exposes no _lock (test stub): nothing donates, nothing to hold
-                arr = np.asarray(backend.state[..., 7])
+                arr = fetch_column(backend.state, ROW_HITS)
             C = int(plan.capacity_per_shard)
             flat = np.empty(int(plan.n_owners) * C, np.int64)
             for o in range(int(plan.n_owners)):
                 r_, s_ = plan.owner_coords(o)
                 flat[o * C:(o + 1) * C] = arr[r_, s_]
             return flat, C
-        if lock is not None:  # host/devdir engine table i64[C, 8]
+        if lock is not None:  # host/devdir engine table u32[C, 16]
             with lock:
-                counts = np.asarray(backend.state[:, 7])
+                counts = fetch_column(backend.state, ROW_HITS)
         else:
             # guberlint: disable=lock-discipline -- backend exposes no _lock (test stub): nothing donates, nothing to hold
-            counts = np.asarray(backend.state[:, 7])
+            counts = fetch_column(backend.state, ROW_HITS)
         return counts, None
 
     def _top_keys(self, backend, counts: np.ndarray,

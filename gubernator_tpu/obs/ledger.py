@@ -86,7 +86,7 @@ _SLACK_AUTHORITIES = ("degraded", "reshard", "global_cache")
 # log2 over-admission histogram: bucket i holds overshoots <= 2^i hits.
 _NBUCKETS = 28
 
-# _fold_locked's per-slot codes below the tracked keys' indices
+# _classify_locked's per-slot codes below the tracked keys' indices
 _UNRESOLVED = -1  # the directory names no key for the slot
 _UNTRACKED = -2  # a key with no bucket and no room for one
 
@@ -389,24 +389,50 @@ class DecisionLedger:
         with background_of(engine, "ledger.audit"):
             return self._audit(engine, now_ms, force)
 
+    # Lanes handled at a time. The lane columns of a fold and their
+    # transients (a dozen arrays a lane) are sized by this, not by what a
+    # tick drained: at 300k decisions/s a tick drains 1.5M lanes, and one
+    # set of columns over all of them held ~200 MB at once, in arrays glibc
+    # serves from the heap and keeps (the daemon's resident set +460 MB
+    # against the same daemon at 108k; PERF.md PR 29). Half a million lanes
+    # is a whole tick at 100k decisions/s. What is done per distinct slot
+    # (naming it, testing its key against the buckets) is still done once
+    # a tick.
+    _FOLD_LANES = 1 << 19
+
+    def _parts(self, pending):
+        """The drained ring in arrival order, _FOLD_LANES lanes at a time."""
+        part: List[tuple] = []
+        lanes = 0
+        for rec in pending:
+            part.append(rec)
+            lanes += rec[0].shape[1]
+            if lanes >= self._FOLD_LANES:
+                yield part
+                part, lanes = [], 0
+        if part:
+            yield part
+
     def _audit(self, engine, now_ms: Optional[int], force: bool) -> dict:
         self._last_audit = time.monotonic()
         if now_ms is None:
             now_ms = int(time.time() * 1000)
         with self._pending_lock:
             pending, self._pending = self._pending, []
-        cols = self._pending_columns(pending)
+        uniq, first = self._pending_slots(pending)
         resolved: Dict[int, str] = {}
-        if cols is not None and engine is not None:
+        if len(uniq) and engine is not None:
             try:
                 with background_of(engine, "ledger.resolve_slots"):
-                    resolved = engine.resolve_slots(cols[0])
+                    resolved = engine.resolve_slots(uniq)
             except Exception:  # noqa: BLE001 — audit never raises
                 resolved = {}
-            self._slots_asked += len(cols[0])
+            self._slots_asked += len(uniq)
         with self._lock:
-            if cols is not None:
-                self._fold_locked(cols, resolved)
+            if len(uniq):
+                code, tracked = self._classify_locked(uniq, first, resolved)
+                for part in self._parts(pending):
+                    self._fold_lanes_locked(part, uniq, code, tracked)
             for key, b in list(self._buckets.items()):
                 if b.window and (force or b.window <= now_ms):
                     self._roll_locked(key, b)
@@ -418,44 +444,33 @@ class DecisionLedger:
                 report["ground_truth"] = dict(self._ground_truth)
         return report
 
-    @staticmethod
-    def _pending_columns(pending):
-        """The drained ring as one set of lane columns in arrival order,
-        padding lanes (slot -1) dropped: (unique slots sorted, each lane's
-        index into them, hits, status, limit, reset, index into auths,
-        auths) — or None when no lane is left."""
-        if not pending:
-            return None
-        slot_hits, resps, rec_auths = zip(*pending)
-        slots = np.concatenate([sh[0] for sh in slot_hits])
-        live = np.flatnonzero(slots >= 0)
-        if not live.size:
-            return None
-        hits = np.concatenate([sh[1] for sh in slot_hits])
-        resp = np.concatenate(resps, axis=1)
-        uniq, inverse = np.unique(slots[live], return_inverse=True)
-        auths = sorted(set(rec_auths))
-        auth_of = np.repeat([auths.index(a) for a in rec_auths],
-                            [sh.shape[1] for sh in slot_hits])
-        return (uniq, inverse, hits[live], resp[0, live], resp[1, live],
-                resp[3, live], auth_of[live], auths)
+    def _pending_slots(self, pending):
+        """The drained ring's distinct slots, sorted, and for each the
+        arrival rank of its first lane among the lanes that carry a slot
+        (padding lanes, slot -1, dropped). Both empty when no lane does.
+        Found a part at a time and merged: np.unique with return_index
+        sorts stably, so a slot's first part is the one kept."""
+        uniqs, firsts, seen = [], [], 0
+        for part in self._parts(pending):
+            slots = np.concatenate([sh[0] for sh, _resp, _auth in part])
+            slots = slots[slots >= 0]
+            u, f = np.unique(slots, return_index=True)
+            uniqs.append(u)
+            firsts.append(f + seen)
+            seen += slots.size
+        if not seen:
+            return np.empty(0, np.int64), np.empty(0, np.int64)
+        uniq, at = np.unique(np.concatenate(uniqs), return_index=True)
+        return uniq, np.concatenate(firsts)[at]
 
-    def _fold_locked(self, cols, resolved: Dict[int, str]) -> None:
-        """Fold the drained lanes into the key buckets, leaving what the
-        per-lane walk (every lane through _record_locked, in arrival
-        order; tests/test_ledger.py keeps it as the reference) would
-        leave. The work that scales with traffic is done per distinct slot
-        and in numpy: a slot is named once, its key tested against the
-        buckets once, and lanes of keys that are not tracked (most of
-        them, once key_capacity keys are) are counted, not visited. Lanes
-        of tracked keys, and of keys there is still room to admit, go to
-        _fold_tracked_locked."""
-        uniq, inverse, hits, status, limit, reset, auth_of, auths = cols
-        self._lanes_folded += len(inverse)
-        # per distinct slot: the index of its tracked key in `tracked`,
-        # _UNRESOLVED, or _UNTRACKED (a key met with no room left). The
-        # two passes over all of them are dict lookups mapped in C; Python
-        # walks only the slots of tracked keys.
+    def _classify_locked(self, uniq, first, resolved: Dict[int, str]):
+        """Per distinct slot, once an audit: the index of its tracked key
+        in `tracked`, _UNRESOLVED, or _UNTRACKED (a key met with no room
+        left) -> (codes aligned with `uniq`, tracked). A slot is named
+        once and its key tested against the buckets once; the two passes
+        over all of them are dict lookups mapped in C, and Python walks
+        only the slots of tracked keys. While there is room, buckets go to
+        keys in the order their first lanes arrived."""
         keys = list(map(resolved.get, uniq.tolist()))
         held = list(map(self._buckets.get, keys))
         code = np.full(len(uniq), _UNTRACKED, np.int64)
@@ -471,8 +486,6 @@ class DecisionLedger:
                 tracked.append((keys[i], held[i]))
             code[i] = at
         if len(self._buckets) < self.key_capacity:
-            # buckets go to keys in the order their first lanes arrived
-            first = np.unique(inverse, return_index=True)[1]
             newcomers = np.flatnonzero(code == _UNTRACKED)
             for i in newcomers[np.argsort(first[newcomers])].tolist():
                 at = index_of.get(keys[i])
@@ -483,14 +496,36 @@ class DecisionLedger:
                     at = index_of[keys[i]] = len(tracked)
                     tracked.append((keys[i], b))
                 code[i] = at
-        lane_code = code[inverse]
+        return code, tracked
+
+    def _fold_lanes_locked(self, part, uniq, code, tracked) -> None:
+        """Fold one run of drained windows (arrival order) into the key
+        buckets, leaving what the per-lane walk (every lane through
+        _record_locked; tests/test_ledger.py keeps it as the reference)
+        would leave: lanes of keys that are not tracked (most of them,
+        once key_capacity keys are) are counted in numpy, not visited;
+        lanes of tracked keys go to _fold_tracked_locked."""
+        slot_hits, resps, rec_auths = zip(*part)
+        slots = np.concatenate([sh[0] for sh in slot_hits])
+        live = np.flatnonzero(slots >= 0)
+        if not live.size:
+            return
+        self._lanes_folded += live.size
+        lane_code = code[np.searchsorted(uniq, slots[live])]
+        hits = np.concatenate([sh[1] for sh in slot_hits])[live]
         self._unattributed += int(hits[lane_code == _UNRESOLVED].sum())
         self._overflow += int(np.count_nonzero(lane_code == _UNTRACKED))
         keep = np.flatnonzero(lane_code >= 0)
-        if keep.size:
-            self._fold_tracked_locked(
-                tracked, lane_code[keep], hits[keep], status[keep],
-                limit[keep], reset[keep], auth_of[keep], auths)
+        if not keep.size:
+            return
+        at = live[keep]
+        resp = np.concatenate(resps, axis=1)
+        auths = sorted(set(rec_auths))
+        auth_of = np.repeat([auths.index(a) for a in rec_auths],
+                            [sh.shape[1] for sh in slot_hits])
+        self._fold_tracked_locked(
+            tracked, lane_code[keep], hits[keep], resp[0, at], resp[1, at],
+            resp[3, at], auth_of[at], auths)
 
     def _fold_tracked_locked(self, tracked, kid, hits, status, limit, reset,
                              auth_of, auths) -> None:

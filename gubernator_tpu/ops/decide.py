@@ -9,14 +9,25 @@ branchless masked tensor program:
     gather state rows -> compute token & leaky paths as mask lattices
                       -> select -> scatter rows back
 
-State is ONE row-major i64[C, 8] array in HBM — 64 bytes per key slot, ~640 MB
-at 10M keys — resident on one chip, shardable across a mesh (parallel/).
+State is ONE row-major u32[C, 16] array in HBM — 64 bytes per key slot,
+~640 MB at 10M keys — resident on one chip, shardable across a mesh (parallel/).
 Row-major matters enormously on TPU: XLA executes random-index gather/scatter
 roughly element-at-a-time, so a struct-of-arrays layout (seven separate
 columns) costs 14 serialized random HBM touches per decision and capped the
 chip at ~1M decisions/s; one 64-byte row gather + one row scatter per
 decision runs the same workload ~5.6x faster (measured on v5e — see
 DESIGN.md "Row-major state").
+
+The row is eight 64-bit fields STORED AS THE CHIP HOLDS THEM: the TPU has no
+64-bit integers, and for an s64[C, 8] parameter its compiler splits the whole
+table into two u32 halves, scatters into those and recombines them — three
+table-sized passes and a table-sized temp per launch, whatever the launch
+decides (86% of device time at 10M rows, PERF.md PR 29). So words 2f and 2f+1
+of a row are the low and high half of field f (little-endian: the host's
+i64[n, 8].view("<u4") IS the device row), 64-bit values exist only on the
+gathered [B, 8] lanes, and a launch touches its own rows. The layout has one
+owner — make_table / load_rows / store_rows / load_column / host_rows below —
+and nothing else indexes the array.
 
 Semantics are bit-exact with the reference's integer math (the reference's
 leaky bucket is already integer: ``rate = duration/limit`` and
@@ -39,6 +50,7 @@ from typing import Dict, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from gubernator_tpu.obs import witness
 from gubernator_tpu.types import Algorithm, Behavior, Status
@@ -197,12 +209,14 @@ kernel_telemetry = KernelTelemetry()
 
 I32 = jnp.int32
 I64 = jnp.int64
+U32 = jnp.uint32
 
 # State-column algorithm codes: table slots hold -1 when vacant.
 _VACANT = -1
 
 
-# Row field indices of the i64[..., C, TABLE_ROW_FIELDS] bucket table.
+# Field indices of one bucket row: TABLE_ROW_FIELDS 64-bit fields, held on
+# the device as TABLE_ROW_WORDS u32 words (see load_rows / store_rows).
 # `stamp` is the token bucket's CreatedAt and the leaky bucket's UpdatedAt
 # (the reference keeps them in two different structs, store.go:11-24);
 # `status` persists the token bucket's sticky OVER_LIMIT
@@ -215,9 +229,11 @@ ROW_DURATION = 3  # ms
 ROW_STAMP = 4  # unix ms
 ROW_EXPIRE = 5  # unix ms (doubles as token ResetTime)
 ROW_STATUS = 6
+ROW_HITS = 7  # lifetime attempted hits (set in decide(); never in a snapshot)
 TABLE_ROW_FIELDS = 8
+TABLE_ROW_WORDS = 2 * TABLE_ROW_FIELDS
 
-# The device table type: plain jax.Array i64[..., C, TABLE_ROW_FIELDS].
+# The device table type: plain jax.Array u32[..., C, TABLE_ROW_WORDS].
 TableState = jax.Array
 
 
@@ -249,12 +265,77 @@ class RespBatch(NamedTuple):
     reset_time: jax.Array  # i64[B]
 
 
+def vacant_rows(shape: Tuple[int, ...]) -> TableState:
+    """u32[*shape, TABLE_ROW_WORDS] of vacant rows (algo = -1, the rest 0):
+    ONE broadcast of a 64-byte row, so building a table never holds a second
+    table-sized buffer (zeros().at[].set() would, and the allocator's peak
+    since boot is what hbm_peak_mb reads)."""
+    row = np.zeros(TABLE_ROW_FIELDS, "<i8")
+    row[ROW_ALGO] = _VACANT
+    return jnp.broadcast_to(jnp.asarray(row.view("<u4")),
+                            tuple(shape) + (TABLE_ROW_WORDS,))
+
+
 def make_table(capacity: int) -> TableState:
-    """Fresh vacant table: i64[capacity, 8] rows with algo = -1."""
-    return (
-        jnp.zeros((capacity, TABLE_ROW_FIELDS), I64)
-        .at[:, ROW_ALGO].set(_VACANT)
-    )
+    """Fresh vacant table: u32[capacity, 16] rows with algo = -1."""
+    return vacant_rows((capacity,))
+
+
+def _combine(words: jax.Array) -> jax.Array:
+    """u32[..., 2n] words -> i64[..., n]: word 2f is the low half."""
+    lo = words[..., 0::2].astype(I64)
+    hi = words[..., 1::2].astype(I64)
+    return lo | (hi << 32)
+
+
+def load_rows(state: TableState, idx: jax.Array) -> jax.Array:
+    """Rows `idx` of the table as i64[..., 8]: ONE 64-byte row gather per
+    lane, the 64-bit fields rebuilt on the lanes only."""
+    return _combine(state[idx])
+
+
+def store_rows(state: TableState, slot: jax.Array,
+               rows: jax.Array) -> TableState:
+    """Write i64[B, 8] `rows` at `slot` — ONE row scatter, split into words
+    on the lanes. Negative (padding) slots are dropped (pad_to_drop)."""
+    lo = (rows & 0xFFFFFFFF).astype(U32)
+    hi = ((rows >> 32) & 0xFFFFFFFF).astype(U32)
+    words = jnp.stack([lo, hi], axis=-1).reshape(
+        rows.shape[:-1] + (TABLE_ROW_WORDS,))
+    return state.at[pad_to_drop(slot, state.shape[-2])].set(
+        words, mode="drop")
+
+
+def load_column(state: TableState, field: int) -> jax.Array:
+    """Field `field` of every row, i64[..., C] — for the few whole-table
+    readers (the cartographer's hit column, the device directory's
+    refresh); two strided reads, no row is rebuilt."""
+    return _combine(state[..., 2 * field:2 * field + 2])[..., 0]
+
+
+def host_rows(words) -> np.ndarray:
+    """Fetched table words u32[..., 16] -> host i64[..., 8], no arithmetic:
+    the little-endian word order is the contract."""
+    return np.ascontiguousarray(np.asarray(words)).view("<i8")
+
+
+def fetch_column(state: TableState, field: int) -> np.ndarray:
+    """Field `field` of every row, to the host, i64[..., C]: its two word
+    columns are fetched as one u32[..., C, 2] and viewed there — no 64-bit
+    work on the chip, and no more host memory than the column itself."""
+    return host_rows(state[..., 2 * field:2 * field + 2])[..., 0]
+
+
+def host_words(rows) -> np.ndarray:
+    """Host i64[..., 8] rows -> the u32[..., 16] words the device holds
+    (the inverse view of host_rows)."""
+    return np.ascontiguousarray(rows, "<i8").view("<u4")
+
+
+def fetch_rows(state: TableState, slots) -> np.ndarray:
+    """Point-read rows `slots` to the host as i64[n, 8] (debug and settle
+    reads: an eager gather, the words viewed on the host)."""
+    return host_rows(state[jnp.asarray(slots, I32)])
 
 
 def _sel(default: jax.Array, *pairs) -> jax.Array:
@@ -288,7 +369,7 @@ def decide(state: TableState, reqs: ReqBatch, now_ms: jax.Array) -> Tuple[TableS
 
     # ONE 64-byte row gather per lane (the layout that keeps TPU
     # gather/scatter off the serialized random-element path)
-    rows = state[gslot]  # i64[B, 8]
+    rows = load_rows(state, gslot)  # i64[B, 8]
     st_algo = rows[:, ROW_ALGO]
     st_limit = rows[:, ROW_LIMIT]
     st_rem = rows[:, ROW_REMAINING]
@@ -407,7 +488,6 @@ def decide(state: TableState, reqs: ReqBatch, now_ms: jax.Array) -> Tuple[TableS
         (tok_miss | leak_miss, UNDER),
     )
 
-    sslot = pad_to_drop(slot, state.shape[-2])
     new_rows = jnp.stack(
         [
             n_algo.astype(I64),
@@ -422,12 +502,12 @@ def decide(state: TableState, reqs: ReqBatch, now_ms: jax.Array) -> Tuple[TableS
             # tier a device-resident hit count with zero extra dispatches
             # (service/leases.py). Responses and snapshots never read it,
             # so decision outputs are bit-identical with leases off.
-            rows[:, 7] + jnp.where(active, r_hits, 0),
+            rows[:, ROW_HITS] + jnp.where(active, r_hits, 0),
         ],
         axis=1,
     )
-    # ONE row scatter back (mode="drop" discards the remapped pad lanes)
-    new_state = state.at[sslot].set(new_rows, mode="drop")
+    # ONE row scatter back (the -1 pad lanes are dropped)
+    new_state = store_rows(state, slot, new_rows)
 
     # ---------------- select response --------------------------------------
     z64 = jnp.zeros_like(r_limit)
@@ -587,8 +667,6 @@ def decide_scan_packed_compact(
 def compact_window(packed):
     """Wide i64[9, W] (or [K, 9, W]) staging -> compact i32, or None when
     any lane is ineligible (gregorian, or a value outside [0, 2^31))."""
-    import numpy as np
-
     vals = packed[..., 1:4, :]
     if (vals < 0).any() or (vals > _I32_MAX).any():
         return None
@@ -609,8 +687,6 @@ def compact_window(packed):
 def widen_compact_out(out, now_ms: int):
     """Compact i32[..., 4, B] responses -> the wide i64 rows decide_packed
     returns (reset_delta -1 decodes to absolute 0)."""
-    import numpy as np
-
     wide = np.asarray(out).astype(np.int64)
     delta = wide[..., 3, :]
     wide[..., 3, :] = np.where(delta < 0, 0, now_ms + delta)
@@ -707,8 +783,6 @@ def _emit_interned(packed, inv):
     interned i32 rows. The bit layout has THREE writers (here, the two
     callers' id assignment aside: keydir.cpp keydir_prep_pack_interned)
     and one reader (decide_packed_interned) — keep them in sync."""
-    import numpy as np
-
     out = np.empty(packed.shape[:-2] + (INTERN_ROWS, packed.shape[-1]),
                    np.int32)
     out[..., 0, :] = packed[..., 0, :]
@@ -729,8 +803,6 @@ def intern_window(packed):
     INTERN_MAX_CFG distinct (limit, duration) pairs. Padding lanes
     (slot == -1) intern like any other (their zero config occupies one
     table row)."""
-    import numpy as np
-
     pair = _intern_pairs(packed)
     if pair is None:
         return None
@@ -753,8 +825,6 @@ class InternCache:
     wide/compact staging), leaving the cache intact."""
 
     def __init__(self):
-        import numpy as np
-
         self._sorted_pairs = np.empty(0, np.int64)  # sorted for searchsorted
         self._sorted_ids = np.empty(0, np.int64)  # pair -> stable config id
         self.cfg = np.zeros((INTERN_MAX_CFG, 2), np.int64)
@@ -763,8 +833,6 @@ class InternCache:
     def intern(self, packed):
         """Wide i64[..., 9, W] staging -> interned i32 rows (the shared
         self.cfg table ships alongside), or None when ineligible."""
-        import numpy as np
-
         pair = _intern_pairs(packed)
         if pair is None:
             return None
@@ -897,8 +965,6 @@ def lean_window(packed, capacity: int):
     roughly break-even against the host budget on a locally-attached
     single chip; the C serving emitter (keydir_prep_pack_lean) writes
     lean directly and pays none of this."""
-    import numpy as np
-
     if not lean_capacity_ok(capacity):
         return None
     slot = packed[..., 0, :]
@@ -962,8 +1028,6 @@ def pack_window(items, slots, fresh, width: int, out=None):
     (e.g. one window's slice of a scan group's staging buffer) and is
     filled in place instead of allocating.
     """
-    import numpy as np
-
     n = len(items)
     packed = np.zeros((9, width), np.int64) if out is None else out
     packed[0, :n] = slots

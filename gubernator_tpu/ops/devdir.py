@@ -43,7 +43,14 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from gubernator_tpu.ops.decide import I32, I64, ROW_ALGO, pad_to_drop
+from gubernator_tpu.ops.decide import (
+    I32,
+    I64,
+    ROW_ALGO,
+    ROW_EXPIRE,
+    load_column,
+    pad_to_drop,
+)
 from gubernator_tpu.utils.fnv import fnv1a_64_str
 
 PROBE_DEPTH = 16  # candidate positions per key; full = retry lane
@@ -198,8 +205,6 @@ def refresh_vacancies(fps: jax.Array, table: jax.Array,
     """Clear fingerprints whose bucket row is vacant or expired — the lazy
     recycling pass (host directory handles this with its LRU; here one
     full-column sweep, amortized across many windows)."""
-    from gubernator_tpu.ops.decide import ROW_EXPIRE
-
-    dead = (table[:, ROW_ALGO] < 0) | (
-        jnp.asarray(now_ms, I64) > table[:, ROW_EXPIRE])
+    dead = (load_column(table, ROW_ALGO) < 0) | (
+        jnp.asarray(now_ms, I64) > load_column(table, ROW_EXPIRE))
     return jnp.where(dead, jnp.zeros_like(fps), fps)

@@ -77,7 +77,7 @@ def make_global_sync(plan: MeshPlan, donate: bool = False):
     def _step(
         state: TableState, delta: jax.Array, cfg: GlobalConfig, now: jax.Array
     ) -> Tuple[TableState, GlobalMirror, jax.Array]:
-        local_state = state.reshape(state.shape[-2:])  # i64[C, 8]
+        local_state = state.reshape(state.shape[-2:])  # u32[C, 16]
         local_delta = delta.reshape(delta.shape[-1:])  # i64[G]
 
         total = jax.lax.psum(local_delta, (REGION_AXIS, SHARD_AXIS))

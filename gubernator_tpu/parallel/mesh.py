@@ -25,17 +25,10 @@ from functools import partial
 from typing import Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from gubernator_tpu.ops.decide import (
-    I64,
-    ROW_ALGO,
-    TABLE_ROW_FIELDS,
-    TableState,
-    _VACANT,
-)
+from gubernator_tpu.ops.decide import TableState, vacant_rows
 from gubernator_tpu.utils.fnv import fnv1a_64_str
 
 REGION_AXIS = "region"
@@ -105,14 +98,7 @@ def shard_of_key(key: str, n_owners: int) -> int:
 
 
 def make_sharded_table(plan: MeshPlan) -> TableState:
-    """Fresh vacant row table i64[R, S, C, 8] sharded over the mesh."""
-    R, S, C = plan.n_regions, plan.n_shards, plan.capacity_per_shard
-
-    @partial(jax.jit, out_shardings=plan.state_sharding())
-    def _make() -> TableState:
-        return (
-            jnp.zeros((R, S, C, TABLE_ROW_FIELDS), I64)
-            .at[..., ROW_ALGO].set(_VACANT)
-        )
-
-    return _make()
+    """Fresh vacant row table u32[R, S, C, 16] sharded over the mesh."""
+    shape = (plan.n_regions, plan.n_shards, plan.capacity_per_shard)
+    return jax.jit(partial(vacant_rows, shape),
+                   out_shardings=plan.state_sharding())()

@@ -56,9 +56,13 @@ from gubernator_tpu.ops.decide import (
     decide_packed_lean,
     decide_scan_packed,
     decide_scan_packed_lean,
+    host_rows,
+    host_words,
     lean_capacity_ok,
     lean_window,
+    load_rows,
     staging_policy,
+    store_rows,
     widen_compact_out,
     pack_window,
 )
@@ -230,7 +234,7 @@ def make_gather_sharded(plan: MeshPlan):
         local = state.reshape(state.shape[-2:])
         g = jnp.maximum(slot.reshape(slot.shape[-1:]), 0)
         # row fields 0..6 ARE the output row order (pad field dropped)
-        rows = local[g][:, :7].T
+        rows = load_rows(local, g)[:, :7].T
         return rows.reshape(1, 1, *rows.shape)
 
     mapped = jax.shard_map(
@@ -246,19 +250,16 @@ def make_inject_sharded(plan: MeshPlan, donate: bool = False):
     fn(state [R,S,C], slot i32[R,S,W], rows i64[R,S,7,W]) -> state; lanes
     with slot -1 are dropped. Mirrors models/engine.py _inject_rows for the
     single-table engine (reference: algorithms.go:26-33 read-through)."""
-    from gubernator_tpu.ops.decide import pad_to_drop
-
     spec_state = P(REGION_AXIS, SHARD_AXIS, None, None)
     spec_slot = P(REGION_AXIS, SHARD_AXIS, None)
     spec_rows = P(REGION_AXIS, SHARD_AXIS, None, None)
 
     def _step(state: TableState, slot: jax.Array, rows: jax.Array):
         local = state.reshape(state.shape[-2:])
-        s = pad_to_drop(slot.reshape(slot.shape[-1:]), local.shape[0])
         r = rows.reshape(rows.shape[-2:])  # [7, W], row field order
         w8 = jnp.concatenate(
             [r.T, jnp.zeros((r.shape[1], 1), r.dtype)], axis=1)
-        new = local.at[s].set(w8, mode="drop")
+        new = store_rows(local, slot.reshape(slot.shape[-1:]), w8)
         return new.reshape((1, 1) + new.shape)
 
     mapped = jax.shard_map(
@@ -486,7 +487,7 @@ class ShardedEngine:
         out = []
         now = millisecond_now()
         with self._lock:
-            tbl = np.asarray(self.state)  # [R, S, C, 8]
+            tbl = host_rows(self.state)  # [R, S, C, 8]
             for owner, directory in enumerate(self.directories):
                 r_, s_ = self.plan.owner_coords(owner)
                 for key, slot in directory.items():
@@ -514,7 +515,8 @@ class ShardedEngine:
         if not items:
             return 0
         with self._lock:
-            tbl = np.array(self.state)  # writable host copy [R, S, C, 8]
+            # writable host copy [R, S, C, 8]
+            tbl = host_rows(self.state).copy()
             n = 0
             by_owner: Dict[int, list] = {}
             for it in items:
@@ -534,7 +536,8 @@ class ShardedEngine:
                             it.algo, it.limit, it.remaining, it.duration,
                             it.stamp, it.expire_at, it.status)
                         n += 1
-            self.state = jax.device_put(tbl, self.plan.state_sharding())
+            self.state = jax.device_put(
+                host_words(tbl), self.plan.state_sharding())
         return n
 
     def close(self) -> None:

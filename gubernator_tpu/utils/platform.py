@@ -55,7 +55,8 @@ def device_facts(backend, directory: str) -> dict:
     (not off jax.devices(): a table that was meant to be sharded and sits
     whole on the first device shows here). `directory` names the key
     directory in use: native | python | device."""
-    shards = backend.state.addressable_shards
+    state = backend.state
+    shards = state.addressable_shards
     first = shards[0].device
     return {
         "platform": first.platform,
@@ -64,6 +65,10 @@ def device_facts(backend, directory: str) -> dict:
         "visible_device_count": jax.local_device_count(),
         "devices": [str(s.device) for s in shards],
         "table_bytes_per_device": [int(s.data.nbytes) for s in shards],
+        # as stored: "u32[C,16]" — a row's 64-bit fields as word pairs,
+        # because the chip has no 64-bit integers (ops/decide.py)
+        "table_layout": "%s%d[C,%d]" % (
+            state.dtype.kind, 8 * state.dtype.itemsize, state.shape[-1]),
         "donation": bool(backend.donate),
         "key_directory": directory,
     }

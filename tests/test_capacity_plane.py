@@ -267,14 +267,31 @@ class TestCartographer:
         finally:
             inst.close()
 
-    def test_maybe_harvest_interval_gate(self):
+    def test_maybe_harvest_interval_gate(self, monkeypatch):
+        """The gate reads time.monotonic() against a `_last_harvest` that
+        starts at 0.0: on a host up for less than the interval the first
+        call is not due, which made this test fail on a freshly booted
+        machine. The test owns the clock."""
+        import gubernator_tpu.obs.keyspace as keyspace_mod
+
+        class Clock:
+            now = 10_000.0
+            monotonic = staticmethod(lambda: Clock.now)
+            perf_counter = staticmethod(time.perf_counter)
+            time = staticmethod(time.time)
+
+        monkeypatch.setattr(keyspace_mod, "time", Clock)
         inst = Instance(InstanceConfig(backend=Engine(capacity=256),
                                        keyspace_interval_s=3600.0))
         try:
             inst.keyspace.maybe_harvest()
             assert inst.keyspace.harvests == 1
+            Clock.now += 3599.0
             inst.keyspace.maybe_harvest()  # within the interval: no scan
             assert inst.keyspace.harvests == 1
+            Clock.now += 1.0
+            inst.keyspace.maybe_harvest()  # one interval later: due
+            assert inst.keyspace.harvests == 2
         finally:
             inst.close()
 

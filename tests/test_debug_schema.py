@@ -96,7 +96,7 @@ def test_engine_section_names_the_device(instance):
     dev = debug_vars(instance)["engine"]["device"]
     assert {"platform", "device_kind", "device_count",
             "visible_device_count", "devices", "table_bytes_per_device",
-            "donation", "key_directory", "memory",
+            "table_layout", "donation", "key_directory", "memory",
             "compiles"} <= set(dev)
     assert dev["platform"] == "cpu"
     # the two live facts: allocator memory per device (nulls on the CPU)
@@ -105,6 +105,7 @@ def test_engine_section_names_the_device(instance):
         {"device", "bytes_in_use", "peak_bytes_in_use", "bytes_limit"}]
     assert set(dev["compiles"]) == {"count", "seconds"}
     assert dev["table_bytes_per_device"] == [256 * 64]
+    assert dev["table_layout"] == "u32[C,16]"
 
 
 def test_flight_recorder_and_anomaly_shapes(instance):

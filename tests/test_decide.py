@@ -14,7 +14,11 @@ import numpy as np
 import pytest
 
 from gubernator_tpu.ops import decide, make_table
-from gubernator_tpu.ops.decide import batch_from_columns
+from gubernator_tpu.ops.decide import (
+    batch_from_columns,
+    fetch_rows,
+    host_rows,
+)
 from gubernator_tpu.ops.oracle import oracle_decide
 from gubernator_tpu.types import Algorithm, Behavior, Status
 
@@ -313,10 +317,11 @@ class TestKernelMatchesOracle:
             row = oracle_table.get(key)
             if row is None or row.algo == -1:
                 continue
-            assert int(state[slot_idx, 0]) == row.algo, key
-            assert int(state[slot_idx, 2]) == row.remaining, key
-            assert int(state[slot_idx, 1]) == row.limit, key
-            assert int(state[slot_idx, 5]) == row.expire_at, key
+            got = fetch_rows(state, [slot_idx])[0]
+            assert int(got[0]) == row.algo, key
+            assert int(got[2]) == row.remaining, key
+            assert int(got[1]) == row.limit, key
+            assert int(got[5]) == row.expire_at, key
 
 
 class TestBatchMechanics:
@@ -329,8 +334,8 @@ class TestBatchMechanics:
             fresh=[True, False, False]))
         state, resp = _DECIDE(state, reqs, 1_000)
         assert int(resp.status[1]) == 0 and int(resp.remaining[1]) == 0
-        assert int(state[1, 0]) == -1  # untouched
-        assert int(state[0, 2]) == 9
+        assert int(host_rows(state)[1, 0]) == -1  # untouched
+        assert int(host_rows(state)[0, 2]) == 9
 
     def test_padding_never_clobbers_last_slot(self):
         """-1 lanes must not wrap to slot capacity-1: jnp's mode="drop" only
@@ -343,7 +348,7 @@ class TestBatchMechanics:
             algorithm=[0], behavior=[0], greg_expire=[0], greg_interval=[0],
             fresh=[True]))
         state, _ = _DECIDE(state, occupy, 1_000)
-        assert int(state[7, 2]) == 8
+        assert int(host_rows(state)[7, 2]) == 8
         # padded window touching a different slot; lanes 1-2 are padding
         win = padded_batch(dict(
             slot=[0, -1, -1], hits=[1, 0, 0], limit=[10, 0, 0],
@@ -351,8 +356,8 @@ class TestBatchMechanics:
             greg_expire=[0, 0, 0], greg_interval=[0, 0, 0],
             fresh=[True, False, False]))
         state, _ = _DECIDE(state, win, 1_001)
-        assert int(state[7, 0]) == 0
-        assert int(state[7, 2]) == 8  # last slot survived
+        assert int(host_rows(state)[7, 0]) == 0
+        assert int(host_rows(state)[7, 2]) == 8  # last slot survived
 
     def test_distinct_slots_parallel(self):
         state = make_table(64)
@@ -363,7 +368,7 @@ class TestBatchMechanics:
             greg_expire=[0] * n, greg_interval=[0] * n, fresh=[True] * n))
         state, resp = _DECIDE(state, reqs, 1_000)
         assert np.all(np.asarray(resp.remaining[:n]) == 7)
-        assert np.all(np.asarray(state[:n, 2]) == 7)
+        assert np.all(host_rows(state)[:n, 2] == 7)
 
 
 class TestScanPacked:
@@ -425,7 +430,7 @@ class TestDocumentedReferenceBugFixes:
               algorithm=Algorithm.LEAKY_BUCKET, now=now)
         h.hit("k", hits=1, limit=10, duration=60_000,
               algorithm=Algorithm.LEAKY_BUCKET, now=now + 5)
-        exp = int(h.state[h.dir["k"], 5])
+        exp = int(fetch_rows(h.state, [h.dir["k"]])[0, 5])
         assert exp == (now + 5) + 60_000  # not (now+5)*60_000
 
     def test_leaky_create_reset_time_is_now_plus_rate(self):

@@ -243,7 +243,7 @@ class _PerLaneLedger(DecisionLedger):
     """The reference fold: the audit as it was before the grouped fold —
     every drained lane walked in Python, its slot named through a dict,
     its key sent to _record_locked. The program keeps only the grouped
-    fold (obs/ledger.py _fold_locked); this one says what it has to
+    fold (obs/ledger.py _fold_lanes_locked); this one says what it has to
     equal."""
 
     def _audit(self, engine, now_ms, force):
@@ -327,10 +327,17 @@ class TestGroupedFoldEqualsPerLane:
     untracked keys in numpy; what it leaves behind must be what the
     per-lane walk leaves, to the order of the violation events."""
 
+    @pytest.mark.parametrize("fold_lanes", [None, 20],
+                             ids=["one_part", "parts_of_20_lanes"])
     @pytest.mark.parametrize("seed", range(12))
-    def test_random_pending_sequences(self, seed):
+    def test_random_pending_sequences(self, seed, fold_lanes, monkeypatch):
         import random
 
+        if fold_lanes:
+            # an audit folds its lanes a bounded run of windows at a time
+            # (a tick at 300k decisions/s is three parts): many parts an
+            # audit must leave what one pass leaves
+            monkeypatch.setattr(DecisionLedger, "_FOLD_LANES", fold_lanes)
         rng = random.Random(1000 + seed)
         n_slots = rng.choice([40, 120])
         names = {}

@@ -56,6 +56,7 @@ def test_device_facts_on_the_engine():
     dev = Engine(capacity=256).device
     assert dev["platform"] == "cpu" and dev["device_count"] == 1
     assert dev["table_bytes_per_device"] == [256 * 64]
+    assert dev["table_layout"] == "u32[C,16]"
     assert dev["donation"] is True
     assert dev["key_directory"] == "native"
     assert dev["visible_device_count"] == jax.local_device_count()
@@ -67,6 +68,7 @@ def test_device_facts_on_the_mesh():
     dev = ShardedEngine(n_shards=4, capacity_per_shard=128).device
     assert dev["device_count"] == 4 and len(set(dev["devices"])) == 4
     assert dev["table_bytes_per_device"] == [128 * 64] * 4
+    assert dev["table_layout"] == "u32[C,16]"
 
 
 class TestNativeFallbackIsLoud:

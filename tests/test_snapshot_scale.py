@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from gubernator_tpu.models.engine import Engine
+from gubernator_tpu.ops.decide import fetch_rows
 from gubernator_tpu.store import (
     BinarySnapshotLoader,
     BucketSnapshot,
@@ -79,7 +80,7 @@ class TestSnapshotScale:
         probes.update((0, N - 1))
         keys = [f"ss_{i}" for i in sorted(probes)]
         slots, _ = eng2.directory.lookup(keys)
-        rows = np.asarray(eng2.state)[np.asarray(slots)]
+        rows = fetch_rows(eng2.state, slots)
         for j, i in enumerate(sorted(probes)):
             r = rows[j]
             assert (int(r[0]), int(r[1]), int(r[2]), int(r[3]),
@@ -177,7 +178,8 @@ class TestJsonlCompat:
         eng2 = Engine(capacity=self.N_SMALL, min_width=64, max_width=8192)
         assert eng2.load_snapshot_slabs(loader.load_slabs()) == self.N_SMALL
         probe = eng2.directory.lookup(["ss_777"])[0][0]
-        assert int(np.asarray(eng2.state)[probe][2]) == 1_000 - (777 % 997)
+        assert int(fetch_rows(eng2.state, [probe])[0, 2]) == \
+            1_000 - (777 % 997)
         loader.save_slabs(eng2.snapshot_slabs())  # migrated
         with open(path, "rb") as f:
             assert f.read(8) == b"GTSLAB1\n"
@@ -203,4 +205,4 @@ class TestJsonlCompat:
         eng2 = Engine(capacity=self.N_SMALL, min_width=64, max_width=8192,
                       loader=loader)  # ctor restore path
         probe = eng2.directory.lookup(["ss_42"])[0][0]
-        assert int(np.asarray(eng2.state)[probe][2]) == 1_000 - 42
+        assert int(fetch_rows(eng2.state, [probe])[0, 2]) == 1_000 - 42

@@ -2,10 +2,13 @@
 for a v5e that is described, not attached (no chip time, nothing runs).
 
 What this guards: every program `Engine.warmup()` needs at the README's
-table size (10M rows, i64[10_000_000, 8] = 640 MB) is accepted by the
-chip's compiler, updates the donated table in place, and — for the mesh
-tier — splits the table four ways and reduces GLOBAL hits with a real
-all-reduce. A pass here is a compile, never a chip run.
+table size (10M rows, u32[10_000_000, 16] = 640 MB) is accepted by the
+chip's compiler, updates the donated table in place WITHOUT a table-sized
+temp or a 64-bit split/combine of the table (the chip has no 64-bit
+integers: an s64 table costs three table-sized passes a launch, PERF.md
+PR 29), and — for the mesh tier — splits the table four ways and reduces
+GLOBAL hits with a real all-reduce. A pass here is a compile, never a
+chip run.
 
 The topology is described inside a module-scoped fixture (never at
 import: only one process may load libtpu, and every xdist worker imports
@@ -30,8 +33,19 @@ D = sys.modules["gubernator_tpu.ops.decide"]
 
 CAPACITY = 10_000_000  # README: resident keys of one chip
 TABLE_BYTES = CAPACITY * 8 * 8
+TEMP_LIMIT = 16 << 20  # a launch's scratch: lanes, never the table
 WIDTH = 64  # widths 1024/8192 take 34-43 s each: compiled by hand, CHANGES.md
-I32, I64 = jnp.int32, jnp.int64
+I32, I64, U32 = jnp.int32, jnp.int64, jnp.uint32
+
+
+def _touches_only_its_rows(compiled):
+    """No table-sized temp, and no 64-bit emulation op (X64SplitLow/High,
+    X64Combine) over a table-sized operand."""
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < TEMP_LIMIT, mem
+    whole = [line.strip()[:200] for line in compiled.as_text().splitlines()
+             if "X64" in line and f"[{CAPACITY}," in line]
+    assert not whole, whole
 
 
 @pytest.fixture(scope="module")
@@ -96,30 +110,32 @@ class TestOneChip:
         "decide_packed", "decide_packed_compact", "decide_packed_lean",
         "decide_scan_packed"])
     def test_decide_compiles_and_aliases_the_table(self, one_chip, name):
-        table = one_chip((CAPACITY, 8), I64)
+        table = one_chip((CAPACITY, D.TABLE_ROW_WORDS), U32)
         compiled = jax.jit(getattr(D, name), donate_argnums=(0,)).lower(
             table, *_window_shapes(name, one_chip)).compile()
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= TABLE_BYTES, mem
         assert mem.argument_size_in_bytes < TABLE_BYTES * 1.01, mem
+        _touches_only_its_rows(compiled)
 
     def test_gather_compiles(self, one_chip):
         """The lone-miss mirror seed's 1-slot gather reads the table and
-        hands back 7 one-element columns (tile-padded). The compiler
-        gives it a temp of half the table (320 MB at 10M rows) — recorded
-        in ROADMAP.md for the first perf_opt, only bounded here."""
+        hands back 7 one-element columns (tile-padded): one row's words,
+        no temp of half the table (320 MB for an s64 table)."""
         compiled = jax.jit(engine_mod._gather_rows).lower(
-            one_chip((CAPACITY, 8), I64), one_chip((1,), I32)).compile()
+            one_chip((CAPACITY, D.TABLE_ROW_WORDS), U32),
+            one_chip((1,), I32)).compile()
         mem = compiled.memory_analysis()
         assert mem.output_size_in_bytes < 65536, mem
-        assert mem.temp_size_in_bytes <= TABLE_BYTES, mem
+        _touches_only_its_rows(compiled)
 
     def test_inject_compiles_and_aliases_the_table(self, one_chip):
         col64, col32 = one_chip((WIDTH,), I64), one_chip((WIDTH,), I32)
         compiled = jax.jit(engine_mod._inject_rows, donate_argnums=(0,)).lower(
-            one_chip((CAPACITY, 8), I64), col32, col32, col64, col64, col64,
-            col64, col64, col32).compile()
+            one_chip((CAPACITY, D.TABLE_ROW_WORDS), U32), col32, col32,
+            col64, col64, col64, col64, col64, col32).compile()
         assert compiled.memory_analysis().alias_size_in_bytes >= TABLE_BYTES
+        _touches_only_its_rows(compiled)
 
 
 class TestFourChips:
@@ -128,7 +144,8 @@ class TestFourChips:
 
         step = make_decide_sharded(plan, donate=True)
         state = jax.ShapeDtypeStruct(
-            (1, 4, CAPACITY, 8), I64, sharding=plan.state_sharding())
+            (1, 4, CAPACITY, D.TABLE_ROW_WORDS), U32,
+            sharding=plan.state_sharding())
         packed = jax.ShapeDtypeStruct(
             (1, 4, 9, WIDTH), I64, sharding=plan.state_sharding())
         now = jax.ShapeDtypeStruct((), I64, sharding=plan.replicated())
@@ -137,8 +154,33 @@ class TestFourChips:
         # per-device bytes: a quarter of the 4 x 640 MB table plus one window
         assert TABLE_BYTES <= mem.argument_size_in_bytes < TABLE_BYTES * 1.01
         assert mem.alias_size_in_bytes >= TABLE_BYTES, mem
+        _touches_only_its_rows(compiled)
+        _touches_only_its_rows(compiled)  # memory_analysis is per device
         # owner-local mutation: the normal path moves nothing between chips
         assert "all-reduce" not in compiled.as_text()
+
+    @pytest.mark.parametrize("name", ["gather", "inject"])
+    def test_sharded_row_steps_touch_only_their_rows(self, plan, name):
+        """The Store hooks' mesh gather and inject (restore, read-through):
+        each chip reads or writes its own lanes' rows, in place."""
+        from gubernator_tpu.parallel import sharded
+
+        sh = plan.state_sharding()
+        state = jax.ShapeDtypeStruct(
+            (1, 4, CAPACITY, D.TABLE_ROW_WORDS), U32, sharding=sh)
+        slot = jax.ShapeDtypeStruct(
+            (1, 4, WIDTH), I32, sharding=jax.sharding.NamedSharding(
+                plan.mesh, jax.sharding.PartitionSpec(*sh.spec[:3])))
+        if name == "gather":
+            compiled = sharded.make_gather_sharded(plan).lower(
+                state, slot).compile()
+        else:
+            rows = jax.ShapeDtypeStruct((1, 4, 7, WIDTH), I64, sharding=sh)
+            compiled = sharded.make_inject_sharded(plan, donate=True).lower(
+                state, slot, rows).compile()
+            assert compiled.memory_analysis().alias_size_in_bytes \
+                >= TABLE_BYTES
+        _touches_only_its_rows(compiled)
 
     def test_global_sync_psum_is_an_all_reduce(self, plan):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -154,7 +196,8 @@ class TestFourChips:
             return jax.ShapeDtypeStruct((G,), dtype, sharding=rep)
 
         state = jax.ShapeDtypeStruct(
-            (1, 4, CAPACITY, 8), I64, sharding=plan.state_sharding())
+            (1, 4, CAPACITY, D.TABLE_ROW_WORDS), U32,
+            sharding=plan.state_sharding())
         delta = jax.ShapeDtypeStruct(
             (1, 4, G), I64,
             sharding=NamedSharding(plan.mesh, P(REGION_AXIS, SHARD_AXIS, None)))
@@ -169,3 +212,4 @@ class TestFourChips:
         mem = compiled.memory_analysis()
         assert TABLE_BYTES <= mem.argument_size_in_bytes < TABLE_BYTES * 1.01
         assert mem.alias_size_in_bytes >= TABLE_BYTES, mem
+        _touches_only_its_rows(compiled)
