@@ -1,7 +1,8 @@
 # Parity with the reference's Makefile (Makefile:1-18): `test` runs the
-# whole suite with concurrency hygiene, plus this repo's bench/proto targets.
+# whole suite with concurrency hygiene, plus this repo's report/proto
+# targets. The benchmark is `python3 benchmarks/run.py` (BENCHMARK.json).
 
-.PHONY: test test-fast lint lockmap sanitize bench bench-skew bench-wire bench-reshard bench-suite bench-check scenarios capacity-report profile-report ledger-report soak chaos proto docker clean native
+.PHONY: test test-fast lint lockmap sanitize scenarios capacity-report profile-report ledger-report soak chaos proto docker clean native
 
 # the suite runs on a virtual 8-device CPU mesh (tests/conftest.py)
 test:
@@ -29,32 +30,6 @@ lockmap:
 # TSAN_OPTIONS=suppressions=native/tsan.supp (tests/test_tsan.py)
 sanitize:
 	python scripts/build_native.py --sanitize
-
-bench:
-	python bench.py
-
-# Zipf-1.1 skew through a 2-node loopback cluster: uniform vs leases-off
-# vs leases-on rows (client p99 + hot-owner work share, BENCH_r09)
-bench-skew:
-	python bench.py --skew
-
-# wire contract v1 vs v2 over a loopback peerlink, bare CPU rig plus a
-# link-emulated (slow host<->device link) regime
-bench-wire:
-	python bench.py --wire
-
-# live resharding at scale: 1M-row evacuate() handoff duration plus the
-# importer's foreground p50/p99 quiet vs mid-handoff (BENCH_r13)
-bench-reshard:
-	python bench.py --reshard
-
-bench-suite:
-	python scripts/bench_suite.py
-
-# diff the two newest BENCH_r*.json rounds; fails on a >25% cliff in a
-# throughput/latency key both rounds measured (see scripts/bench_check.py)
-bench-check:
-	python scripts/bench_check.py
 
 # scenario atlas: seeded workload drills against live 1-2 node clusters,
 # SLO verdicts written to the round's SCEN_r<NN>.json; exits 1 on any

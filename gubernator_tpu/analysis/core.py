@@ -7,8 +7,7 @@ tests, metric/event/fault registries in sync with their docs — existed
 only as convention and review memory. This package turns each one into a
 machine-checked invariant: every rule is grounded in a real historical
 bug (docs/static-analysis.md catalogues them), `make lint` runs the set,
-and tests/test_lint.py makes zero-findings-on-HEAD a tier-1 gate the same
-way `make bench-check` gates perf.
+and tests/test_lint.py makes zero-findings-on-HEAD a tier-1 gate.
 
 Waiver syntax (inline, justification REQUIRED after ``--``)::
 
@@ -129,7 +128,6 @@ class RepoIndex:
 
     # python trees the AST rules walk (repo-relative)
     CODE_DIRS = ("gubernator_tpu", "scripts")
-    CODE_FILES = ("bench.py",)
 
     def __init__(self, root: str):
         self.root = os.path.abspath(root)
@@ -175,9 +173,6 @@ class RepoIndex:
         for d in self.CODE_DIRS:
             if self.exists(d):
                 out.extend(self.walk(d, ".py"))
-        for f in self.CODE_FILES:
-            if self.exists(f):
-                out.append(f)
         return out
 
 

@@ -21,8 +21,6 @@ from gubernator_tpu.analysis.core import Finding, RepoIndex, Rule, register
 
 # (env knob, source-level aliases a test may use instead of the env name)
 HATCHES: Sequence[Tuple[str, Tuple[str, ...]]] = (
-    ("GUBER_WIRE_V2", ("wire_v2",)),
-    ("GUBER_COLUMNAR_PIPELINE", ("columnar_pipeline",)),
     ("GUBER_HOT_LEASES", ("hot_leases",)),
     ("GUBER_RESHARD", ("reshard",)),
     ("GUBER_PIPELINE_DEPTH", ("pipeline_depth",)),

@@ -126,11 +126,11 @@ class LinkClient:
     with transparent per-call fallback to the wire-compatible gRPC tier.
 
     The public gRPC surface stays untouched for reference-ecosystem
-    clients; this client exists because Python gRPC caps unbatched public
-    RPC at ~1-2k/s while the link's columnar frames (and, for lone
+    clients; this client exists because Python gRPC pays per-RPC
+    GIL-held machinery while the link's columnar frames (and, for lone
     requests on a standalone node, the server's C++ IO-thread decision
-    path) serve the same contract without that cap (scripts/bench_suite.py
-    'public_link_*' scenarios). Negotiation mirrors the peer tier:
+    path) serve the same contract without it. Negotiation mirrors the
+    peer tier:
     the link listens at grpc_port + GUBER_PEER_LINK_OFFSET (default
     1000); servers that don't answer it get gRPC."""
 

@@ -71,12 +71,14 @@ class ClusterInstance:
         close_channels(self.address)
 
 
-def wire_peerlink(cluster: "LocalCluster"):
+def wire_peerlink(cluster: "LocalCluster", old_nodes: Sequence[int] = ()):
     """Attach a peerlink service to every instance at grpc port + one
     shared offset (the daemon's production convention) and point the
     instances' peer clients at it. Returns the service list (callers own
     closing them), or [] when no offset binds cleanly — gRPC then carries
-    every peer call, exactly like a fleet with the link disabled."""
+    every peer call, exactly like a fleet with the link disabled. The
+    instances indexed by `old_nodes` get a server that never greets (an
+    old binary on the wire, for the mixed-version interop tests)."""
     from gubernator_tpu.service.peerlink import PeerLinkError, PeerLinkService
 
     ports = [int(ci.address.rsplit(":", 1)[1]) for ci in cluster.instances]
@@ -85,10 +87,8 @@ def wire_peerlink(cluster: "LocalCluster"):
         try:
             for i, ci in enumerate(cluster.instances):
                 attempt.append(
-                    PeerLinkService(
-                        ci.instance, port=ports[i] + offset,
-                        wire_v2=getattr(
-                            ci.instance.conf.behaviors, "wire_v2", None)))
+                    PeerLinkService(ci.instance, port=ports[i] + offset,
+                                    wire_v2=i not in old_nodes))
         except PeerLinkError:
             for svc in attempt:
                 svc.close()

@@ -399,7 +399,7 @@ def main(argv=None) -> int:
         log.warning("lock-order witness ARMED (GUBER_LOCK_WITNESS=1) — "
                     "test-rig instrument; every lock carries order "
                     "bookkeeping, do not run production traffic this way")
-    columnar_pipe = (conf.columnar_pipeline and conf.pipeline_depth != 1
+    columnar_pipe = (conf.pipeline_depth != 1
                      and getattr(backend, "supports_columnar",
                                  lambda: False)())
     if instance.combiner.pipelined or columnar_pipe:
@@ -415,8 +415,7 @@ def main(argv=None) -> int:
         log.info("pipelined serving loop on: depth=%d scan<=%d",
                  depth, conf.pipeline_scan)
     # the columnar wire path rides the combiner's RESOLVED depth (the
-    # autotune winner), so both protocols share one pipelining decision;
-    # GUBER_COLUMNAR_PIPELINE=0 pins just the wire path lock-step
+    # autotune winner), so both protocols share one pipelining decision
     columnar_depth = instance.combiner.depth if columnar_pipe else 1
     # autopilot ticker AFTER autotune so the pipeline controller's
     # baseline is the probed depth, not the pre-probe placeholder
@@ -481,9 +480,7 @@ def main(argv=None) -> int:
                 port=conf_grpc_port + conf.behaviors.peer_link_offset,
                 grpc_port=conf_grpc_port, grpc_host=conf_grpc_host,
                 metrics=metrics, pipeline_depth=columnar_depth,
-                pipeline_scan=conf.pipeline_scan,
-                columnar_pipeline=conf.columnar_pipeline,
-                wire_v2=conf.behaviors.wire_v2)
+                pipeline_scan=conf.pipeline_scan)
             port = conf_grpc_port
             metrics.set_native_front(peerlink.native_hits)
             log.info("native gRPC front on :%d (peerlink on %d, "
@@ -514,9 +511,7 @@ def main(argv=None) -> int:
                 peerlink = PeerLinkService(
                     instance, port=link_port, metrics=metrics,
                     pipeline_depth=columnar_depth,
-                    pipeline_scan=conf.pipeline_scan,
-                    columnar_pipeline=conf.columnar_pipeline,
-                    wire_v2=conf.behaviors.wire_v2)
+                    pipeline_scan=conf.pipeline_scan)
                 log.info("peerlink serving on port %d", peerlink.port)
             except (PeerLinkError, RuntimeError) as e:
                 log.warning("peerlink disabled: %s (peer calls ride gRPC)",

@@ -167,11 +167,6 @@ class DaemonConfig:
     # pipeline_scan caps the windows coalesced into one scan-group launch.
     pipeline_depth: int = 0
     pipeline_scan: int = 8
-    # depth-N pipelined COLUMNAR wire path (service/peerlink.py): the
-    # zero-object owner path shares GUBER_PIPELINE_DEPTH/SCAN with the
-    # combiner; this flag is its own escape hatch back to lock-step
-    # submit/complete (the object path keeps pipelining)
-    columnar_pipeline: bool = True
     # durable bucket snapshot: load at boot, save at shutdown (FileLoader;
     # the reference leaves persistence to the user, README.md:159-175)
     snapshot_path: str = ""
@@ -279,9 +274,6 @@ def config_from_env(args: Optional[List[str]] = None) -> DaemonConfig:
         "GUBER_MULTI_REGION_SYNC_WAIT", b.multi_region_sync_wait_s)
     b.peer_link_offset = _env_int("GUBER_PEER_LINK_OFFSET", b.peer_link_offset)
     b.link_retry_s = _env_float("GUBER_LINK_RETRY_S", b.link_retry_s)
-    # wire contract v2 (docs/wire.md): resolved here so the daemon and
-    # every PeerClient see one consistent answer for the process
-    b.wire_v2 = os.environ.get("GUBER_WIRE_V2", "1") != "0"
 
     # peer-failure resilience (service/peer_client.py CircuitBreaker)
     b.circuit_threshold = _env_int("GUBER_CIRCUIT_THRESHOLD",
@@ -370,7 +362,6 @@ def config_from_env(args: Optional[List[str]] = None) -> DaemonConfig:
         max_batch_width=_env_int("GUBER_MAX_BATCH_WIDTH", 8192),
         pipeline_depth=_env_pipeline_depth(),
         pipeline_scan=_env_int("GUBER_PIPELINE_SCAN", 8),
-        columnar_pipeline=_env_str("GUBER_COLUMNAR_PIPELINE", "1") != "0",
         snapshot_path=_env_str("GUBER_SNAPSHOT_PATH"),
         snapshot_format=_env_str("GUBER_SNAPSHOT_FORMAT", "binary"),
         profile_port=_env_int("GUBER_PROFILE_PORT", 0),

@@ -1595,8 +1595,9 @@ extern "C" {
 // GUBER_PEER_LINK_OFFSET=0 to disable and keep every peer call on gRPC.
 // Returns an opaque handle, or 0 on failure; *bound_port gets the port.
 // wire_v2_max caps the negotiable wire contract: >= 2 turns on the
-// GREETING/HELLO upgrade (GUBER_WIRE_V2), 1 keeps the server byte-exact
-// v1 — it never greets and ignores HELLOs.
+// GREETING/HELLO upgrade (the daemon's value), 1 keeps the server
+// byte-exact v1 — it never greets and ignores HELLOs (the interop tests'
+// old binary).
 void* pls_start2(int port, int* bound_port, int wire_v2_max) {
   auto s = std::make_unique<Server>();
   s->wire_v2_max = wire_v2_max;

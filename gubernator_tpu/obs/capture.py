@@ -6,8 +6,7 @@ maintains — the history ring (decision-rate curves), the keyspace
 cartographer (popularity concentration + Zipf fit), and the flight
 recorder (recent operational events) — assembled into one JSON
 document. No new instrumentation runs on the serving path: the only
-cost of a capture is the assembly itself, measured in bench.py
-(`capture.*`) against the standing 2% observability budget.
+cost of a capture is the assembly itself.
 
 A trace is replayable because its `derived` section reduces the raw
 curves to exactly what a `ScenarioSpec` needs: piecewise rate segments

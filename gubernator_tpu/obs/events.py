@@ -12,9 +12,7 @@ diagnostic bundle can interleave them with spans into one timeline.
 Cost discipline: emissions sit on serving-adjacent paths, so the
 recorder must be near-free. The ring is a ``deque(maxlen=...)`` (O(1)
 append with eviction), the only lock guards the per-kind counters, and
-``GUBER_FLIGHT_RECORDER=0`` turns ``emit`` into a single attribute test
-(bench.py "observability" section proves the on/off delta ≤ 2% on the
-serving path).
+``GUBER_FLIGHT_RECORDER=0`` turns ``emit`` into a single attribute test.
 """
 
 from __future__ import annotations

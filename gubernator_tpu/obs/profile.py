@@ -1,14 +1,11 @@
 """Continuous profiling plane: live serving-cycle decomposition.
 
-ROADMAP item 3 says the decision cycle is the ceiling, but until this
-module the only evidence was `serving_decomposition` in a bench artifact
-— computed offline, once per round, on an idle rig. The profiler makes
-every node measure its own cycle continuously: monotonic stamps at the
-serving path's real seams — combiner queue wait, engine-lock acquire
-wait, host prep, device dispatch, readback wait, response demux — feed
-streaming log2 histograms per phase, cheap enough to stay on in
-production (bench.py "profiler" section holds the on/off delta ≤ 2%,
-target 0.5%).
+The profiler makes every node measure its own serving cycle
+continuously: monotonic stamps at the serving path's real seams —
+combiner queue wait, engine-lock acquire wait, host prep, device
+dispatch, readback wait, response demux — feed streaming log2
+histograms per phase, cheap enough to stay on in production (PERF.md
+§6: tracing moved no end-to-end metric out of its spread on the chip).
 
 Consumers:
 
@@ -21,9 +18,8 @@ Consumers:
   decomposition drift is visible over the retention window and the
   anomaly engine's `profile_shift` detector can compare fast/slow
   windows;
-- bench.py's offline `serving_decomposition`, re-derived from the same
-  totals through `serving_decomposition()` below — one source of truth
-  (tests/test_profile_plane.py pins live-vs-offline agreement).
+- the benchmark's per-layer readers (benchmarks/layer_metrics/), which
+  diff the same phase totals over a measured window.
 
 Beside the serving cycle it meters what surrounds it, on the same
 clock (CLOCK_MONOTONIC): the native front's own histograms (frame
@@ -607,45 +603,6 @@ class Profiler:
                        "interval_s": interval, "samples": samples,
                        "stacks": dict(top)}, fh, indent=1)
         return path
-
-
-# ------------------------------------------------------- shared derivations
-
-def serving_decomposition(totals_before: Dict[str, dict],
-                          totals_after: Dict[str, dict],
-                          cycles: int, elapsed_s: float,
-                          upload_bytes: int = 0, download_bytes: int = 0,
-                          decisions: int = 0) -> dict:
-    """Derive the offline serving_decomposition keys from two Profiler
-    totals() snapshots — the ONE derivation bench.py emits and the live
-    endpoint agrees with (tests/test_profile_plane.py pins them within
-    10% per phase)."""
-    cycles = max(int(cycles), 1)
-
-    def delta(p):
-        a = totals_after.get(p, {}).get("total_ns", 0)
-        b = totals_before.get(p, {}).get("total_ns", 0)
-        return max(a - b, 0)
-
-    cycle_s = elapsed_s / cycles
-    host_prep_s = delta("prep") / 1e9 / cycles
-    device_s = (delta("dispatch") + delta("readback")) / 1e9 / cycles
-    demux_s = delta("demux") / 1e9 / cycles
-    lock_s = delta("lock_wait") / 1e9 / cycles
-    accounted = host_prep_s + device_s + demux_s + lock_s
-    return {
-        "cycle_s": cycle_s,
-        "host_prep_s": host_prep_s,
-        "device_s_est": device_s,
-        "demux_s": demux_s,
-        "lock_wait_s": lock_s,
-        "link_s_est": max(cycle_s - accounted, 0.0),
-        "host_prep_share": host_prep_s / cycle_s if cycle_s else 0.0,
-        "device_share": device_s / cycle_s if cycle_s else 0.0,
-        "upload_bytes_per_cycle": upload_bytes / cycles,
-        "download_bytes_per_cycle": download_bytes / cycles,
-        "decisions_per_cycle": decisions / cycles,
-    }
 
 
 def check_recompile(fingerprints: Dict[str, str], state_path: str,

@@ -104,9 +104,9 @@ _OWN_FILE = _grab_stack.__code__.co_filename
 
 def _acq_site() -> List[Tuple[str, int, str]]:
     """Single-frame acquisition site, stamped on EVERY acquisition (the
-    hot path — bench.py `lock_witness` gates its cost). One frame is
-    what lockdep itself keeps per held lock; the full report-side stack
-    (`_grab_stack`) is only captured when an edge actually misbehaves."""
+    hot path). One frame is what lockdep itself keeps per held lock; the
+    full report-side stack (`_grab_stack`) is only captured when an edge
+    actually misbehaves."""
     f = sys._getframe(1)
     while f is not None and f.f_code.co_filename == _OWN_FILE:
         f = f.f_back

@@ -11,8 +11,7 @@ is the TPU-first inversion of the reference's request micro-batching
 itself — a lone caller dispatches immediately, a thundering herd
 aggregates into dispatch-sized windows automatically.
 
-Depth-N pipelining (the bench.py serving-loop structure, productized):
-when the backend exposes the launch/collect split (models/engine.py
+Depth-N pipelining: when the backend exposes the launch/collect split (models/engine.py
 launch_windows — native prep, no Store), the combiner runs THREE
 overlapped stages instead of one lock-step loop:
 
@@ -21,8 +20,8 @@ overlapped stages instead of one lock-step loop:
   to GUBER_PIPELINE_SCAN windows per device call WITHOUT waiting for any
   earlier window's readback;
 - in flight: up to `depth` launches ride the link/device concurrently
-  (GUBER_PIPELINE_DEPTH; 'auto' defaults to 3 — bench.py's probe winner —
-  and autotune() re-probes it); a bounded queue applies backpressure, so
+  (GUBER_PIPELINE_DEPTH; 'auto' defaults to 3 and autotune() re-probes
+  it); a bounded queue applies backpressure, so
   a stalled link degrades to today's lock-step behavior instead of
   unbounded memory growth;
 - drain (the drainer thread): completes launches in order and resolves
@@ -59,9 +58,9 @@ from gubernator_tpu.types import RateLimitReq, RateLimitResp
 
 log = logging.getLogger("gubernator_tpu.combiner")
 
-# 'auto' pipeline depth resolves here until autotune() (the productized
-# bench.py {1, 3, 6} probe) refines it against the live link — depth 1
-# winning degrades the combiner to the serial lock-step path.
+# 'auto' pipeline depth resolves here until autotune() (the {1, 3, 6}
+# probe) refines it against the live link — depth 1 winning degrades the
+# combiner to the serial lock-step path.
 DEFAULT_PIPELINE_DEPTH = 3
 DEFAULT_PIPELINE_SCAN = 8
 
@@ -200,8 +199,7 @@ class BackendCombiner:
 
     def autotune(self, depths=(1, 3, 6), probe_windows: int = 12) -> int:
         """Resolve an 'auto' depth by timing no-op pipelined windows at
-        each candidate (bench.py's depth probe, productized — depth 1 IS
-        a candidate, so a host where overlap loses outright — a single
+        each candidate (depth 1 IS a candidate, so a host where overlap loses outright — a single
         shared core, a stalled link — auto-degrades to the serial
         lock-step path instead of staying pinned pipelined). Call BEFORE
         serving traffic (daemon boot, after warmup): the probe dispatches

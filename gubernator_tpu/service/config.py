@@ -41,11 +41,6 @@ class BehaviorConfig:
     # (GUBER_LINK_RETRY_S; jittered ±50% per attempt so a fleet doesn't
     # re-dial a revived link port in one synchronized wave)
     link_retry_s: float = 30.0
-    # wire contract v2 (GUBER_WIRE_V2, docs/wire.md): sequence-numbered
-    # partial responses + cross-pull pipelining on the link. None defers
-    # to the env knob at connect/listen time; False pins byte-exact v1
-    # on both the client (never HELLOs) and the server (never greets).
-    wire_v2: Optional[bool] = None
 
     # peer-failure resilience (service/peer_client.py CircuitBreaker,
     # docs/OPERATIONS.md "Failure modes"): a peer circuit opens after

@@ -182,7 +182,7 @@ class Metrics:
         }
         self.peerlink_stage_ms = Histogram(
             "peerlink_stage_milliseconds",
-            "Peerlink worker phases per pull: decode+handle, send.",
+            "Peerlink worker time per pull: decode, handle and post.",
             ["stage"], registry=self.registry,
             buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1, 2.5, 5, 10, 25, 100),
         )
@@ -225,11 +225,10 @@ class Metrics:
             "keys, gregorian, GLOBAL lanes force a pipeline drain).",
             registry=self.registry,
         )
-        # wire contract v2 (docs/wire.md; service/peerlink.py _worker_v2).
-        # pull_boundary_stalls counts the moments the worker had nothing to
-        # launch and fell back to draining inflight readbacks: on v1 that is
-        # the per-pull barrier the v2 contract removes, on v2 it only fires
-        # when the link itself runs dry.
+        # wire contract v2 (docs/wire.md; service/peerlink.py _worker).
+        # pull_boundary_stalls counts the moments the worker had launches
+        # in flight and nothing new to pull, and fell back to draining the
+        # oldest readback: it fires when the link itself runs dry.
         self.peerlink_pull_boundary_stalls = Counter(
             "peerlink_pull_boundary_stalls_total",
             "Worker iterations stalled at a pull boundary waiting on "

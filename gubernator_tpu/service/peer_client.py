@@ -231,7 +231,6 @@ class PeerClient:
         try:
             link = PeerLinkClient(f"{host}:{int(port) + offset}",
                                   fault_key=self.info.address,
-                                  wire_v2=getattr(self.conf, "wire_v2", None),
                                   recorder=self.circuit.recorder)
         except (OSError, ValueError, PeerLinkError):
             self._link_retry_at = time.monotonic() + self._link_retry_delay()

@@ -7,9 +7,11 @@ GIL-held machinery. peerlink moves everything per-RPC into C++
 aggregation) and enters Python once per BATCH:
 
     worker loop:  pls_next_batch (blocks in C, GIL released)
-                  -> decode arrays into RateLimitReqs
-                  -> Instance handler (one batched call)
-                  -> pls_send_responses (C++ serializes + writes)
+                  -> wire columns straight to the engine (columnar), or
+                     RateLimitReqs through the Instance handler
+                  -> pls_send_partial per finished row-span (C++
+                     serializes + writes: partial frames to a v2 peer,
+                     one whole frame per request to anyone else)
 
 Two methods ride the same frames: GetPeerRateLimits (method 1, the peer
 hop — owner-apply semantics) and GetRateLimits (method 0, the lean public
@@ -25,7 +27,6 @@ from __future__ import annotations
 import collections
 import ctypes
 import logging
-import os
 import socket
 import struct
 import threading
@@ -189,13 +190,6 @@ WIRE_PARTIAL = 0xF2   # server -> client: seq-numbered partial reply
 _PARTIAL_HDR = struct.Struct("<QBHHHB")  # rid, 0xF2, count, seq, base, final
 
 
-def _wire_v2_enabled() -> bool:
-    """GUBER_WIRE_V2=0 pins this process to the v1 whole-frame contract
-    on both ends (escape hatch — proven bit-identical by differential
-    test): the server never greets, the client never answers one."""
-    return os.environ.get("GUBER_WIRE_V2", "1") != "0"
-
-
 def encode_request_frame(rid: int, method: int,
                          reqs: Sequence[RateLimitReq]) -> bytes:
     """Columnar encode. Raises PeerLinkError for anything the wire format
@@ -348,7 +342,7 @@ class PeerLinkClient:
     a reader thread demuxes responses by rid into futures."""
 
     def __init__(self, address: str, connect_timeout_s: float = 1.0,
-                 fault_key: str = "", wire_v2: Optional[bool] = None,
+                 fault_key: str = "", wire_v2: bool = True,
                  recorder=None):
         host, _, port = address.rpartition(":")
         self.address = address
@@ -371,9 +365,10 @@ class PeerLinkClient:
         # it streams partial replies; the HELLO upgrade goes out from the
         # reader thread. Reassembly state (guarded by _flock) must never
         # outlive its future — call(), _fail and whole-frame arrival all
-        # clear it, so a dead rid cannot leak rows.
-        self._want_v2 = (_wire_v2_enabled() if wire_v2 is None
-                         else bool(wire_v2))
+        # clear it, so a dead rid cannot leak rows. wire_v2=False is the
+        # interop tests' old binary (a client that ignores the greeting);
+        # no production caller passes it.
+        self._want_v2 = bool(wire_v2)
         self.wire_version = 1
         self._expected: Dict[int, int] = {}  # rid -> response count due
         self._partial: Dict[int, list] = {}  # rid -> [rows, next_seq]
@@ -558,11 +553,11 @@ def read_front_profile(lib, handle) -> Optional[List[int]]:
 
 
 class _PullCtx:
-    """One pull's buffers + reply bookkeeping on the v2 wire path: rows
-    post to the wire as their sub-windows finalize (pls_send_partial),
-    and in-flight launches may outlive _handle_batch, so the pull's
-    buffer set and its error/metadata sidecars must live until every
-    launch referencing them drains (live == 0)."""
+    """One pull's buffers + reply bookkeeping: rows post to the wire as
+    their sub-windows finalize (pls_send_partial), and in-flight launches
+    may outlive _handle_batch, so the pull's buffer set and its
+    error/metadata sidecars must live until every launch referencing
+    them drains (live == 0)."""
 
     __slots__ = ("b", "got", "errs", "metas", "live", "posted")
 
@@ -584,8 +579,7 @@ class PeerLinkService:
     def __init__(self, instance, port: int = 0, workers: int = 2,
                  grpc_port: Optional[int] = None, grpc_host: str = "",
                  metrics=None, pipeline_depth=None, pipeline_scan=None,
-                 columnar_pipeline: Optional[bool] = None,
-                 wire_v2: Optional[bool] = None):
+                 wire_v2: bool = True):
         from gubernator_tpu import native
         from gubernator_tpu.native import load_peerlink
         from gubernator_tpu.service.combiner import (
@@ -598,31 +592,21 @@ class PeerLinkService:
         # scan knobs are SHARED with the object-path combiner
         # (GUBER_PIPELINE_DEPTH / GUBER_PIPELINE_SCAN — the daemon passes
         # the combiner's autotuned winner through pipeline_depth, so both
-        # wire protocols ride one resolved setting); GUBER_COLUMNAR_PIPELINE=0
-        # is the columnar-only escape hatch back to lock-step
-        # submit/complete. Depth 1 (pinned or auto-degraded) also pins
-        # lock-step.
+        # wire protocols ride one resolved setting). Depth 1 (pinned or
+        # auto-degraded) is lock-step submit/complete.
         self._col_depth = _env_depth(pipeline_depth) or DEFAULT_PIPELINE_DEPTH
         self._col_scan = _env_scan(pipeline_scan)
-        if columnar_pipeline is None:
-            columnar_pipeline = os.environ.get(
-                "GUBER_COLUMNAR_PIPELINE", "1") != "0"
-        self._col_pipe = bool(columnar_pipeline) and self._col_depth > 1
 
-        # wire contract v2 (docs/wire.md): the server greets v2-capable
-        # clients on accept and streams seq-numbered partial replies to
-        # them, which is what lets the worker pipeline ride ACROSS pull
-        # boundaries (_worker_v2). GUBER_WIRE_V2=0 pins the v1 whole-frame
-        # contract end to end — server never greets, worker keeps the
-        # per-pull barrier verbatim.
-        if wire_v2 is None:
-            wire_v2 = _wire_v2_enabled()
-        self._wire_v2 = bool(wire_v2)
-
+        # wire contract v2 (docs/wire.md): the server greets on accept and
+        # streams seq-numbered partial replies to the connections that
+        # answer HELLO; every other connection gets whole v1 frames,
+        # accumulated in C++. wire_v2=False is the interop tests' old
+        # binary (a server that never greets): it goes to pls_start2 and
+        # selects nothing in Python; no production caller passes it.
         self._lib = load_peerlink()
         bound = ctypes.c_int(0)
         self._handle = self._lib.pls_start2(port, ctypes.byref(bound),
-                                            2 if self._wire_v2 else 1)
+                                            2 if wire_v2 else 1)
         if not self._handle:
             raise PeerLinkError(f"peerlink: cannot bind port {port}")
         self.port = bound.value
@@ -631,8 +615,8 @@ class PeerLinkService:
         # decided in C, the rest punts to the Python servicers below
         self.grpc_port: Optional[int] = None
         self._metrics = metrics
-        # new wire-v2 families, resolved once (older/minimal Metrics
-        # objects in tests may not carry them)
+        # resolved once (minimal Metrics objects in tests may not carry
+        # them)
         self._mt_stall = getattr(metrics, "peerlink_pull_boundary_stalls",
                                  None)
         self._mt_span = getattr(metrics, "peerlink_partial_span_items",
@@ -665,17 +649,15 @@ class PeerLinkService:
                       # pipelined columnar serving (_columnar_chunk)
                       "columnar_windows": 0, "columnar_groups": 0,
                       "columnar_cuts": 0, "columnar_fill_stalls": 0,
-                      # wire v2: times the worker had launches in flight
-                      # but nothing new to pull (v1 pays this EVERY pull;
-                      # ~0 under sustained v2 load = the win's receipt)
+                      # times the worker had launches in flight but
+                      # nothing new to pull
                       "pull_boundary_stalls": 0}
         if metrics is not None and hasattr(metrics, "set_peerlink_stats"):
             # exports batches/requests/errors as peerlink_* families
             metrics.set_peerlink_stats(lambda: self.stats)
         if metrics is not None and hasattr(metrics,
                                            "peerlink_columnar_depth"):
-            metrics.peerlink_columnar_depth.set(
-                self._col_depth if self._col_pipe else 1)
+            metrics.peerlink_columnar_depth.set(self._col_depth)
         self._public_fast = False  # method-0 owner paths (standalone only)
         # native lone-request fast path: 1-item peer-hop frames decide in
         # the C++ IO thread against the engine's directory row mirrors
@@ -739,7 +721,6 @@ class PeerLinkService:
         """The /v1/debug/vars "wire" section: negotiated-contract state
         and the partial-streaming counters."""
         return {
-            "v2_enabled": self._wire_v2,
             "v2_conns": int(self._lib.pls_v2_conns(self._handle)),
             "partial_posts": self.wire_partial_posts(),
             "pending_replies": self.wire_pending_count(),
@@ -881,9 +862,9 @@ class PeerLinkService:
         """One pull-buffer set: request columns in, response rows out,
         plus the pre-built ctypes argument tuples pls_next_batch and
         pls_send_responses consume (pointers are stable — the arrays
-        never reallocate). The v1 worker owns one set; the v2 worker
-        rotates a ring so the next pull preps while launches against
-        earlier sets are still in flight."""
+        never reallocate). The worker rotates a ring of sets so the next
+        pull preps while launches against earlier sets are still in
+        flight."""
         n = self.MAX_N
         b = {
             "keys": ctypes.create_string_buffer(self.KEY_CAP),
@@ -924,65 +905,16 @@ class PeerLinkService:
         return b
 
     def _worker(self) -> None:
-        if self._wire_v2:
-            self._worker_v2()
-        else:
-            self._worker_v1()
-
-    def _worker_v1(self) -> None:
-        """The v1 whole-frame loop, kept verbatim: every pull is handled,
-        answered with ONE pls_send_responses, and only then is the next
-        pull taken — the per-pull barrier GUBER_WIRE_V2=0 promises (and
-        the differential tests prove bit-identical)."""
-        b = self._mk_pull_bufs()
-        args, resp_ptrs, meta_ptr = b["args"], b["resp_ptrs"], b["meta_ptr"]
-        prof = self._prof
-        while not self._stop:
-            with prof.span("front.pull_wait"):
-                got = self._lib.pls_next_batch(
-                    self._handle, 200_000, *args)  # 200 ms idle tick
-            if got <= 0:
-                if got < 0:
-                    return  # stopping
-                continue
-            try:
-                err_buf, meta_buf = self._handle_batch(got, b)
-            except Exception:  # noqa: BLE001 — a worker must never die
-                log.exception("peerlink batch failed")
-                self.stats["errors"] += 1
-                # Respond ANYWAY: an unanswered pull strands every
-                # co-batched frame (other connections included) in
-                # PeerLinkTimeout and leaks the C++ Conn::pending entries.
-                err_buf = self._fail_batch(got, b)
-                meta_buf = b""
-                b["meta_off"][:got + 1] = 0
-            try:
-                t_send = time.perf_counter()
-                with prof.span("post"):
-                    self._lib.pls_send_responses(
-                        self._handle, got, *resp_ptrs, err_buf, meta_ptr,
-                        meta_buf)
-                if self._metrics is not None:
-                    self._metrics.peerlink_stage_ms.labels(
-                        stage="send").observe(
-                            (time.perf_counter() - t_send) * 1e3)
-            except Exception:  # noqa: BLE001
-                log.exception("peerlink send_responses failed")
-                self.stats["errors"] += 1
-
-    def _worker_v2(self) -> None:
-        """The cross-pull pipelined loop (wire contract v2): columnar
-        launches stay in flight ACROSS pull boundaries — while a group
-        rides the device its earlier rows are already on the wire as
-        partial frames (_post_span), and the next pull preps into a
-        DIFFERENT buffer set of the ring. A set is reused only once no
-        in-flight launch references it, so with more sets than pipeline
-        depth the ring blocks only when the device is the bottleneck
-        anyway. This removes the v1 contract's per-pull barrier: the
-        worker polls for new frames while work is in flight and counts a
-        pull_boundary_stall each time the poll comes back empty (v1 paid
-        that stall at EVERY pull)."""
-        depth = self._col_depth if self._col_pipe else 1
+        """The serving loop: pull, handle, post. Columnar launches may
+        stay in flight ACROSS pull boundaries — while a group rides the
+        device its earlier rows are already on the wire (_post_span), and
+        the next pull preps into a DIFFERENT buffer set of the ring. A
+        set is reused only once no in-flight launch references it, so
+        with more sets than pipeline depth the ring blocks only when the
+        device is the bottleneck anyway. The worker polls for new frames
+        while work is in flight and counts a pull_boundary_stall each
+        time the poll comes back empty."""
+        depth = self._col_depth
         nsets = min(depth, 4) + 1
         sets = [self._mk_pull_bufs() for _ in range(nsets)]
         ws = {
@@ -1008,10 +940,8 @@ class PeerLinkService:
                 # a poll, not a wait: it gets no span
                 got = self._lib.pls_next_batch(self._handle, 0, *b["args"])
                 if got == 0:
-                    # launches in flight, nothing new to pull: the v1
-                    # contract drained the WHOLE pipe here every pull —
-                    # count the boundary stall the v2 contract removes,
-                    # retire the oldest launch, poll again
+                    # launches in flight, nothing new to pull: count the
+                    # boundary stall, retire the oldest launch, poll again
                     self.stats["pull_boundary_stalls"] += 1
                     if self._mt_stall is not None:
                         self._mt_stall.inc()
@@ -1040,8 +970,8 @@ class PeerLinkService:
                 self._recover_batch(ws, ctx)
 
     def _recover_batch(self, ws: dict, ctx: _PullCtx) -> None:
-        """Exception recovery on the v2 path: settle the shared pipeline,
-        then answer EVERY row of the failed pull with an error reply via
+        """Exception recovery: settle the shared pipeline, then answer
+        EVERY row of the failed pull with an error reply via
         pls_send_responses — rids already streamed to completion are
         skipped by C++ (their pending entries are gone), partially
         streamed rids complete as an authoritative whole error frame,
@@ -1178,24 +1108,24 @@ class PeerLinkService:
         b["err_off"][:got + 1] = np.arange(got + 1, dtype=np.int32) * len(msg)
         return msg * got
 
-    def _handle_batch(self, got: int, b: dict, ctx: "_PullCtx" = None,
-                      ws: dict = None) -> tuple:
-        """Decode -> handler calls -> fill the reusable response buffers.
-        v1 (ctx None): returns the (error, metadata) sidecar buffers for
-        the caller's single pls_send_responses. v2 (ctx set): every row
-        posts to the wire THROUGH this call via _post_span — per chunk
-        for carrier/object chunks, per drained group for columnar chunks,
-        which may leave clean groups in flight in ws when it returns.
+    def _handle_batch(self, got: int, b: dict, ctx: _PullCtx,
+                      ws: dict) -> None:
+        """Decode -> handler calls -> fill the pull's response buffers.
+        Every row posts to the wire THROUGH this call via _post_span —
+        per chunk for carrier/object chunks, per drained group for
+        columnar chunks, which may leave clean groups in flight in ws
+        when it returns.
 
         Peer-hop chunks ride the COLUMNAR path when the backend offers it
         (Engine.launch_columnar_windows / submit_columnar): the wire
         columns go through the GIL-free C prep straight to the device —
-        scan-grouped and depth-pipelined for wide pulls (_columnar_chunk)
-        — and the response rows scatter back into these buffers; no
-        RateLimitReq/RateLimitResp objects at all on the hot path. Items
-        the columnar prep can't take (invalid, gregorian,
-        GLOBAL/MULTI_REGION, duplicate occurrences) run through the
-        request-object path AFTER the packed round."""
+        scan-grouped and depth-pipelined where a chunk is wider than the
+        engine's widest window (_columnar_chunk) — and the response rows
+        scatter back into these buffers; no RateLimitReq/RateLimitResp
+        objects at all on the hot path. Items the columnar prep can't
+        take (invalid, gregorian, GLOBAL/MULTI_REGION, duplicate
+        occurrences) run through the request-object path AFTER the
+        packed round."""
         self.stats["batches"] += 1
         self.stats["requests"] += got
         t_batch0 = time.perf_counter()
@@ -1218,11 +1148,7 @@ class PeerLinkService:
             self._count_rpc("GetPeerRateLimits", True, n1)
             self._frames_in_batch = (n0, n1)
         method = b["method"]
-        if ctx is not None:  # v2: sidecars live with the pull's buffers
-            errs, metas = ctx.errs, ctx.metas
-        else:
-            errs = []   # (item index, error bytes), ascending
-            metas = []  # (item index, encoded pb metadata)
+        errs, metas = ctx.errs, ctx.metas  # live with the pull's buffers
         cb = getattr(self.instance, "columnar_backend", None)
         eng = cb() if callable(cb) else None
 
@@ -1230,8 +1156,7 @@ class PeerLinkService:
         # snapshots the key's device row, so it must install BEFORE the
         # reply reaches the wire: once the client can send the key's next
         # request, a late seed would overwrite natively-applied hits with
-        # the stale snapshot (the v1 loop got this ordering for free —
-        # it sent the whole frame after _handle_batch returned)
+        # the stale snapshot
         lone_seed = (
             got == 1 and self._seed_engine is not None
             and (int(method[0]) == METHOD_GET_PEER_RATE_LIMITS
@@ -1259,28 +1184,22 @@ class PeerLinkService:
                 # (a traced window's wait is part of the phase picture; a
                 # budgeted window's wait is where its budget dies)
                 self._carrier_chunk(m, j, k, b, errs, metas)
-                if ctx is not None:
-                    # post AFTER the whole carrier frame handling — the
-                    # lease grant overwrites its lane last
-                    self._post_span(ctx, j, k)
-            elif ctx is not None:
-                if lone_seed:
-                    # seed-ordering: decide lock-step WITHOUT posting;
-                    # the seed block below runs first, then the post
-                    if not (columnar_ok and self._columnar_chunk(
-                            m, eng, j, k, b, errs, metas)):
-                        self._object_chunk(m, j, k, b, errs, metas)
-                # v2: the columnar path posts its own spans as groups
-                # drain (and may leave clean groups in flight); object
-                # chunks post whole here
-                elif not (columnar_ok and self._columnar_chunk_v2(
-                        m, eng, j, k, ctx, ws)):
+                # post AFTER the whole carrier frame handling — the
+                # lease grant overwrites its lane last
+                self._post_span(ctx, j, k)
+            elif lone_seed:
+                # seed-ordering: decide lock-step WITHOUT posting; the
+                # seed block below runs first, then the post
+                if not (columnar_ok and not self._saturated()
+                        and self._columnar_chunk_lockstep(
+                            m, eng, [(j, k)], k, b, errs, metas)):
                     self._object_chunk(m, j, k, b, errs, metas)
-                    self._post_span(ctx, j, k)
-            elif not (columnar_ok
-                      and self._columnar_chunk(m, eng, j, k, b, errs,
-                                               metas)):
+            # the columnar path posts its own spans as groups drain (and
+            # may leave clean groups in flight); object chunks post whole
+            elif not (columnar_ok and self._columnar_chunk(
+                    m, eng, j, k, ctx, ws)):
                 self._object_chunk(m, j, k, b, errs, metas)
+                self._post_span(ctx, j, k)
             j = k
 
         if lone_seed:
@@ -1295,8 +1214,7 @@ class PeerLinkService:
                     + b["keys"][split:hi].decode())
             except Exception:  # noqa: BLE001 — seeding is best-effort
                 pass
-            if ctx is not None:
-                self._post_span(ctx, 0, got)  # mirror installed: post now
+            self._post_span(ctx, 0, got)  # mirror installed: post now
 
         if self._metrics is not None and got:
             # every frame in the pull experienced ~this service time (the
@@ -1318,33 +1236,6 @@ class PeerLinkService:
                         method="GetPeerRateLimits").observe(ms)
             except Exception:  # noqa: BLE001
                 pass
-        if ctx is not None:
-            return None, None  # every row already posted (or in flight)
-        return (self._sparse(errs, b["err_off"], got),
-                self._sparse(metas, b["meta_off"], got))
-
-    @staticmethod
-    def _sparse(pairs, off_col, got: int) -> bytes:
-        """Offset fill for the sparse error/metadata columns: one prefix
-        sum. Every producer emits pairs in ascending item order (chunks
-        advance monotonically, leftovers retire per sub-window in index
-        order, pipelined groups drain in dispatch order), so the common
-        path verifies order with one O(n) scan and skips the per-pull
-        O(n log n) sort."""
-        if not pairs:
-            off_col[1:got + 1] = 0
-            return b""
-        prev = -1
-        for i, _ in pairs:
-            if i < prev:
-                pairs.sort(key=lambda t: t[0])
-                break
-            prev = i
-        lens = np.zeros(got, np.int64)
-        for i, e in pairs:
-            lens[i] = len(e)
-        off_col[1:got + 1] = np.cumsum(lens)
-        return b"".join(e for _, e in pairs)
 
     def _chunk_spans(self, eng, j: int, k: int) -> List[tuple]:
         """Split [j, k) into engine sub-windows along the pow2 bucket
@@ -1386,187 +1277,44 @@ class PeerLinkService:
         b["r_reset"][s0:k] = 0
         errs.extend((i, msg) for i in range(s0, k))
 
-    def _columnar_chunk(self, m: int, eng, j: int, k: int, b: dict,
-                        errs: list, metas: list) -> bool:
-        """Serve one peer-hop chunk columnar-end-to-end, PIPELINED: the
-        chunk's sub-windows launch in scan groups of <= pipeline_scan
-        windows (one device call each, models/engine.py
-        launch_columnar_windows) with up to pipeline_depth group launches
-        in flight, and readbacks drain in dispatch order — host prep of
-        group g+1 overlaps device time of group g within the pull. A
-        sub-window that yields leftovers (duplicates, gregorian,
-        GLOBAL/MULTI_REGION, invalid) cuts its group AND barriers the
-        pipeline: every in-flight launch drains and the leftovers retire
-        through the request-object path before any later sub-window
-        preps — per-key wire order is the contract (the same argument the
-        object-path pipeline proved in tests/test_pipeline.py; the
-        columnar twin is tests/test_columnar_pipeline.py). Single-window
-        chunks and GUBER_COLUMNAR_PIPELINE=0 (or depth 1) keep the
-        lock-step path.
-
-        Overlap here is INTRA-pull: the v1 response contract posts one
-        whole frame set per pull (C++ Conn::pending retires whole), so a
-        window's rows cannot post early and launches cannot ride across
-        pull boundaries — the pull's own width (up to MAX_N items = many
-        sub-windows) is what this path overlaps. The v2 wire contract
-        removes exactly that barrier (_columnar_chunk_v2 + _worker_v2:
-        partial posting via pls_send_partial); this path is kept verbatim
-        for v1 peers and GUBER_WIRE_V2=0. False = the engine can't take
-        the shape at all (nothing mutated)."""
+    def _saturated(self) -> bool:
+        """Admission saturated: a columnar chunk is demoted to the object
+        path, whose admission gate answers RESOURCE_EXHAUSTED error rows
+        in microseconds — the zero-object fast path must not become the
+        hole overload pours through (one int compare when off)."""
         adm = getattr(self.instance, "admission", None)
-        if adm is not None and adm.enabled and adm.level() >= adm.SATURATED:
-            # saturated: demote the chunk to the object path, whose
-            # admission gate answers RESOURCE_EXHAUSTED error rows in
-            # microseconds — the zero-object fast path must not become
-            # the hole overload pours through (one int compare when off)
-            return False
-        launch = getattr(eng, "launch_columnar_windows", None)
-        spans = self._chunk_spans(eng, j, k)
-        if not self._col_pipe or launch is None or len(spans) <= 1:
-            return self._columnar_chunk_lockstep(m, eng, spans, k, b,
-                                                 errs, metas)
-        mt = self._metrics
-        # an over-eager GUBER_PIPELINE_SCAN must not push a group past the
-        # engine's compiled scan depth (launch would refuse it whole)
-        scan = min(self._col_scan, int(getattr(eng, "_MAX_SCAN", 0) or 1))
-        staging = b.get("_col_staging")
-        if staging is None:  # per-worker ring: one dict per pipeline slot
-            staging = b["_col_staging"] = [
-                dict() for _ in range(self._col_depth + 2)]
-        inflight: "collections.deque" = collections.deque()
-        seq = 0
-        wi = 0
-        n_spans = len(spans)
-        launched_any = False
+        return (adm is not None and adm.enabled
+                and adm.level() >= adm.SATURATED)
 
-        def drain_one():
-            """Collect the oldest launch; retire its leftovers through the
-            object path (in dispatch order, so per-key order holds).
-            Returns the handle's over-commit message (or None)."""
-            handle, gspans = inflight.popleft()
-            outs = [self._col_outs(b, s0, s1) for s0, s1 in gspans]
-            leftovers = eng.collect_columnar_windows(handle, outs)
-            for (s0, _s1), left in zip(gspans, leftovers):
-                if left is not None and len(left):
-                    self._leftover_items(m, s0, left.tolist(), b, errs,
-                                         metas)
-            return handle[1]
-
-        while wi < n_spans or inflight:
-            barrier = False
-            while wi < n_spans and len(inflight) < self._col_depth:
-                gspans = spans[wi:wi + scan]
-                wins = [self._col_window(b, s0, s1) for s0, s1 in gspans]
-                h = launch(wins, _COLUMNAR_SLOW_MASK,
-                           staging=staging[seq % len(staging)])
-                if h is None:
-                    if not launched_any and not inflight:
-                        return False  # nothing mutated: object fallback
-                    # mid-chunk refusal (defensive): earlier spans already
-                    # applied — drain them, then retire the rest lock-step
-                    while inflight:
-                        drain_one()
-                    rest = spans[wi:]
-                    if not self._columnar_chunk_lockstep(
-                            m, eng, rest, k, b, errs, metas):
-                        self._object_chunk(m, rest[0][0], k, b, errs,
-                                           metas)
-                    return True
-                launched_any = True
-                seq += 1
-                win_metas, failed = h[0], h[1]
-                consumed = len(win_metas)
-                wi += consumed
-                inflight.append((h, gspans[:consumed]))
-                self.stats["columnar_windows"] += consumed
-                self.stats["columnar_groups"] += 1
-                if mt is not None:
-                    mt.peerlink_columnar_windows.inc(consumed)
-                    mt.peerlink_columnar_group_windows.observe(consumed)
-                    mt.peerlink_columnar_occupancy.observe(len(inflight))
-                cut = (consumed < len(gspans)
-                       or (consumed and win_metas[-1][-1] is not None
-                           and len(win_metas[-1][-1])))
-                if failed is not None or cut:
-                    # barrier: drain everything in order, retire the cut
-                    # window's leftovers (inside drain_one), THEN resume
-                    barrier = True
-                    if cut and failed is None:
-                        self.stats["columnar_cuts"] += 1
-                        if mt is not None:
-                            mt.peerlink_columnar_cuts.inc()
-                        if self._recorder is not None:
-                            self._recorder.emit("peerlink.columnar_cut",
-                                                windows=consumed)
-                    break
-            if not inflight:
-                continue
-            if barrier or wi >= n_spans:
-                if not barrier:
-                    # the v1 response contract forces this full drain at
-                    # the chunk/pull boundary — the stall wire v2 removes
-                    # (counted on both paths so BENCH_r10 can attribute
-                    # the win to its absence)
-                    self.stats["pull_boundary_stalls"] += 1
-                    if self._mt_stall is not None:
-                        self._mt_stall.inc()
-                failed_msg = None
-                while inflight:
-                    failed_msg = drain_one() or failed_msg
-                if failed_msg is not None:
-                    # over-commit: the unconsumed remainder of the chunk
-                    # gets error replies (matching the lock-step contract)
-                    s_fail = spans[wi][0] if wi < n_spans else k
-                    self._col_error_fill(failed_msg.encode(), s_fail, k,
-                                         b, errs)
-                    return True
-            else:
-                # pipe full but the pull has more work: this drain IS the
-                # fill stall (the readback gates the next launch) — its
-                # duration is the wire path's queue residency, so it also
-                # feeds the profiler's queue_wait phase (obs/profile.py)
-                stalled = len(inflight) >= self._col_depth
-                if stalled:
-                    self.stats["columnar_fill_stalls"] += 1
-                    if mt is not None:
-                        mt.peerlink_columnar_fill_stalls.inc()
-                    if self._recorder is not None:
-                        self._recorder.emit("peerlink.fill_stall",
-                                            depth=self._col_depth)
-                tq = time.perf_counter_ns()
-                drain_one()
-                if stalled:
-                    prof = getattr(eng, "profiler", None)
-                    if prof is not None:
-                        prof.observe("queue_wait",
-                                     time.perf_counter_ns() - tq)
-        return True
-
-    def _columnar_chunk_v2(self, m: int, eng, j: int, k: int,
-                           ctx: _PullCtx, ws: dict) -> bool:
-        """_columnar_chunk's cross-pull twin (wire contract v2): groups
-        launch into the WORKER-level pipeline (ws["inflight"]) and clean
-        groups may still be in flight when this chunk — and this whole
-        pull — returns; each drained group's rows post immediately as
-        partial frames, so early rows ride the wire while later
-        sub-windows (or the next pull's prep) ride the device.
+    def _columnar_chunk(self, m: int, eng, j: int, k: int,
+                        ctx: _PullCtx, ws: dict) -> bool:
+        """Serve one peer-hop chunk columnar-end-to-end. A chunk that
+        fits the engine's widest window (every chunk at the shipped
+        widths: 1000 items against 8192 lanes) is one span and is served
+        lock-step, posted before return. A wider chunk is PIPELINED: its
+        sub-windows launch in scan groups of <= pipeline_scan windows
+        (one device call each, models/engine.py launch_columnar_windows)
+        into the WORKER-level pipeline (ws["inflight"], up to
+        pipeline_depth launches), and clean groups may still be in flight
+        when this chunk — and this whole pull — returns; each drained
+        group's rows post immediately, so early rows ride the wire while
+        later sub-windows (or the next pull's prep) ride the device.
 
         Per-key order still holds: deductions apply at LAUNCH time (the C
         prep packs and submits synchronously; only the readback defers),
         so dispatch order is application order across chunks and pulls —
         and a cut (leftovers: duplicates, gregorian, GLOBAL/MULTI_REGION,
         invalid) or an over-commit barriers the WHOLE shared pipeline
-        before anything later dispatches, exactly as the v1 path barriers
-        within its pull. Only leftover-free groups ever stay in flight.
+        before anything later dispatches. Only leftover-free groups ever
+        stay in flight (tests/test_columnar_pipeline.py).
         False = the engine can't take the shape (nothing mutated; the
         caller retires the chunk via the object path and posts it)."""
         b = ctx.b
-        adm = getattr(self.instance, "admission", None)
-        if adm is not None and adm.enabled and adm.level() >= adm.SATURATED:
-            return False  # demote to the object path's admission gate
+        if self._saturated():
+            return False
         launch = getattr(eng, "launch_columnar_windows", None)
         spans = self._chunk_spans(eng, j, k)
-        if not self._col_pipe or launch is None or len(spans) <= 1:
+        if self._col_depth <= 1 or launch is None or len(spans) <= 1:
             # lock-step serve: complete before return, post per chunk
             ok = self._columnar_chunk_lockstep(m, eng, spans, k, b,
                                                ctx.errs, ctx.metas)
@@ -1647,13 +1395,13 @@ class PeerLinkService:
 
     def _columnar_chunk_lockstep(self, m: int, eng, spans, k: int,
                                  b: dict, errs: list, metas: list) -> bool:
-        """The serial columnar path (GUBER_COLUMNAR_PIPELINE=0, depth 1,
-        single-window chunks, or engines without the launch/collect
-        split): complete sub-window i before submitting i+1 — the C
-        prep's duplicate tracking is per-submit, so a key demoted to the
-        leftover tail of sub-window i must finish before a later
-        sub-window packs its next occurrence. False = the engine can't
-        take the shape at all (nothing mutated)."""
+        """The serial columnar path (single-window chunks, depth 1, or
+        engines without the launch/collect split): complete sub-window i
+        before submitting i+1 — the C prep's duplicate tracking is
+        per-submit, so a key demoted to the leftover tail of sub-window i
+        must finish before a later sub-window packs its next occurrence.
+        False = the engine can't take the shape at all (nothing
+        mutated)."""
         for si, (s0, s1) in enumerate(spans):
             try:
                 h = eng.submit_columnar(

@@ -12,8 +12,8 @@ ceiling. Variants:
                    (locality probe: does HBM row locality buy anything?)
   decide           the real kernel (ops/decide.py) for comparison
 
-All completion-forced (data-dependent fetch), scan-coalesced K-deep like
-bench.py's headline, donated state. Prints one JSON line per variant.
+All completion-forced (data-dependent fetch), scan-coalesced K-deep,
+donated state. Prints one JSON line per variant.
 """
 
 from __future__ import annotations

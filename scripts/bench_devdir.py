@@ -15,7 +15,7 @@ Every line names the platform it ran on.
 
 Usage: python scripts/bench_devdir.py [--keys 1000000] [--width 4096]
        [--rounds 8]
-Emits one JSON line per scenario (bench_suite.py conventions).
+Emits one JSON line per scenario.
 """
 
 from __future__ import annotations

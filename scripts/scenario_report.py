@@ -3,10 +3,9 @@
 Each scenario boots its own in-process LocalCluster (1-2 nodes per the
 spec), paces the seeded schedule onto it, fires the spec's timeline
 events, and records the SLO verdict the anomaly engine + envelope
-render. The artifact is the scenario counterpart of BENCH_r<NN>.json:
-machine-readable, diffable across rounds, and gated — exit status 1
-when any scenario FAILs, so `make scenarios` is red exactly when an
-operator would have been paged.
+render. The artifact is machine-readable, diffable across rounds, and
+gated — exit status 1 when any scenario FAILs, so `make scenarios` is
+red exactly when an operator would have been paged.
 
 Usage:
     python scripts/scenario_report.py                  # short atlas
@@ -19,8 +18,7 @@ Usage:
 
 With --autopilot both, each shape runs twice on the same seed — static
 knobs, then GUBER_AUTOPILOT-armed via the spec overlay — and the armed
-run is keyed "<name>@autopilot", which bench_check gates at the same
-zero tolerance as the plain verdicts.
+run is keyed "<name>@autopilot".
 """
 
 import argparse

@@ -350,6 +350,43 @@ class TestShardedColumnarPipeline:
                     (it, i, "mesh pipelined")
 
 
+def pin_engine_clock(monkeypatch, now_ms=NOW):
+    """Pin the clock every Engine entry reads when no now_ms is passed
+    (the served path passes none), so a served answer can be held to an
+    engine-level reference at the same instant — reset_time included.
+    Returns the holder; set holder["now"] to move the clock."""
+    import gubernator_tpu.models.engine as engine_mod
+
+    holder = {"now": now_ms}
+    monkeypatch.setattr(engine_mod, "millisecond_now",
+                        lambda: holder["now"])
+    return holder
+
+
+def _rows(resps):
+    return [(int(r.status), r.limit, r.remaining, r.reset_time)
+            for r in resps]
+
+
+def reference_rows(eng, reqs, now_ms):
+    """run_lockstep's answers as (status, limit, remaining, reset_time)
+    rows: submit_/complete_columnar on a twin engine, leftovers through
+    the object path."""
+    st, li, re, rs = run_lockstep(eng, reqs, now_ms)
+    return list(zip(st.tolist(), li.tolist(), re.tolist(), rs.tolist()))
+
+
+def assert_served_rows(got, want, reqs, tag):
+    """Served RateLimitResps against reference_rows, column for column.
+    An invalid item is a zero lane in the reference and a zero lane plus
+    a message on the wire."""
+    assert len(got) == len(want)
+    for i, (g, w) in enumerate(zip(got, want)):
+        if g.error:
+            assert w == (0, 0, 0, 0), (tag, i, reqs[i], g, w)
+        assert _rows([g])[0] == w, (tag, i, reqs[i], g, w)
+
+
 def _serve(eng, **kw):
     from gubernator_tpu.service.config import InstanceConfig
     from gubernator_tpu.service.instance import Instance
@@ -365,23 +402,23 @@ def _serve(eng, **kw):
 
 
 class TestWireLevelDifferential:
-    def test_wire_hammer_pipelined_vs_lockstep(self):
+    def test_wire_hammer_pipelined_vs_lockstep(self, monkeypatch):
         """Wide peer-hop frames (duplicates, gregorian, GLOBAL, invalid
-        keys) through a PIPELINED service and a LOCK-STEP service must
-        produce identical wire replies (reset_time excluded: each
-        service stamps its own clock — the engine-level differentials
-        above pin now_ms and prove reset too)."""
+        keys) through a PIPELINED service must come back bit-identical to
+        the lock-step engine-level reference (run_lockstep on a twin
+        engine) — every column, reset_time included: the clock is
+        pinned."""
         from gubernator_tpu.service.peerlink import (
             METHOD_GET_PEER_RATE_LIMITS,
         )
 
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
-                            columnar_pipeline=True)
-        il, sl, cl = _serve(_engine(), columnar_pipeline=False)
-        assert sp._col_pipe and not sl._col_pipe
+        clock = pin_engine_clock(monkeypatch)
+        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
+        twin = _engine()
         rng = np.random.default_rng(41)
         try:
             for it in range(6):
+                clock["now"] = NOW + it * 500
                 reqs = _random_reqs(rng, int(rng.integers(40, 150)),
                                     n_keys=20)
                 # a GLOBAL lane demotes to the leftover path on both
@@ -389,20 +426,14 @@ class TestWireLevelDifferential:
                     name="cp", unique_key=f"gl{it}", hits=1, limit=9,
                     duration=60_000, behavior=int(Behavior.GLOBAL))
                 got = cp.call(METHOD_GET_PEER_RATE_LIMITS, reqs, 30.0)
-                want = cl.call(METHOD_GET_PEER_RATE_LIMITS, reqs, 30.0)
-                for i, (g, w) in enumerate(zip(got, want)):
-                    assert (g.status, g.limit, g.remaining, g.error) == \
-                        (w.status, w.limit, w.remaining, w.error), \
-                        (it, i, reqs[i], g, w)
+                assert_served_rows(
+                    got, reference_rows(twin, reqs, clock["now"]), reqs, it)
             assert sp.stats["columnar_windows"] > 0
             assert sp.stats["columnar_groups"] > 0
         finally:
             cp.close()
-            cl.close()
             sp.close()
-            sl.close()
             ip.close()
-            il.close()
 
     def test_wire_over_commit_error_fill(self):
         """Over-commit mid-chunk on the wire: the unconsumed remainder
@@ -413,8 +444,7 @@ class TestWireLevelDifferential:
         )
 
         eng = _engine()
-        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=2,
-                            columnar_pipeline=True)
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=2)
         real = native.prep_pack_columnar
         calls = {"n": 0}
 
@@ -451,8 +481,7 @@ class TestWireLevelDifferential:
         )
 
         eng = _engine()
-        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4,
-                            columnar_pipeline=True)
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4)
         errs = []
         done = []
 
@@ -484,6 +513,46 @@ class TestWireLevelDifferential:
             [RateLimitReq(name="dr", unique_key="post", hits=1, limit=5,
                           duration=60_000)], now_ms=NOW)
         assert out[0].remaining == 4
+
+
+class TestSaturationDemotion:
+    @pytest.mark.parametrize("n", [1, 40])
+    def test_saturated_chunk_is_shed_by_the_object_path(self, n):
+        """Admission saturated: a columnar chunk must not reach the
+        device. The lone request (the seed-before-post branch of
+        _handle_batch) and the wide chunk (_columnar_chunk) are both
+        demoted to the object path, whose admission gate answers
+        RESOURCE_EXHAUSTED rows; nothing is deducted, and when the
+        pressure clears the same frame decides from a full bucket."""
+        from gubernator_tpu.service.peerlink import (
+            METHOD_GET_PEER_RATE_LIMITS,
+        )
+
+        eng = _engine()
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4)
+        if n == 1 and sp._seed_engine is None:
+            cp.close()
+            sp.close()
+            ip.close()
+            pytest.skip("no native lone-request mirror on this build")
+        reqs = [RateLimitReq(name="sat", unique_key=f"s{i}", hits=1,
+                             limit=10, duration=60_000) for i in range(n)]
+        try:
+            ip.conf.behaviors.max_pending = 8
+            ip._forward_inflight = 16  # 2x saturation
+            windows = eng.stats.batches
+            out = cp.call(METHOD_GET_PEER_RATE_LIMITS, reqs, 30.0)
+            assert len(out) == n
+            assert all("RESOURCE_EXHAUSTED" in r.error for r in out), out
+            assert eng.stats.batches == windows  # no window launched
+            assert sp.stats["columnar_windows"] == 0
+            ip._forward_inflight = 0
+            out = cp.call(METHOD_GET_PEER_RATE_LIMITS, reqs, 30.0)
+            assert [(r.error, r.remaining) for r in out] == [("", 9)] * n
+        finally:
+            cp.close()
+            sp.close()
+            ip.close()
 
 
 class TestAutotuneDepthOne:
@@ -519,30 +588,37 @@ class TestAutotuneDepthOne:
             c.close()
 
 
-class TestSparseOffsets:
-    def test_in_order_pairs_skip_sort(self):
+class TestRunSidecar:
+    """_run_sidecar turns a span's (item index, bytes) pairs into the
+    offset column + blob pls_send_partial takes, and removes them from
+    the pull's list (each row posts once)."""
+
+    def test_in_order_pairs(self):
         from gubernator_tpu.service.peerlink import PeerLinkService
 
-        off = np.zeros(6, np.int32)
-        buf = PeerLinkService._sparse(
-            [(0, b"aa"), (2, b"b"), (4, b"ccc")], off, 5)
-        assert buf == b"aabccc"
+        pairs = [(0, b"aa"), (2, b"b"), (4, b"ccc"), (7, b"later")]
+        off, blob = PeerLinkService._run_sidecar(pairs, 0, 5)
+        assert blob == b"aabccc"
         assert off.tolist() == [0, 2, 2, 3, 3, 6]
+        assert pairs == [(7, b"later")]  # the next span's entry stays
 
     def test_out_of_order_pairs_still_correct(self):
-        """The scan is a guard, not an assumption: unordered producers
-        (future callers) still serialize correctly."""
+        """Inline object retirement interleaves with group drains, so
+        entries may sit out of index order; offsets are span-relative."""
         from gubernator_tpu.service.peerlink import PeerLinkService
 
-        off = np.zeros(6, np.int32)
-        buf = PeerLinkService._sparse(
-            [(4, b"ccc"), (0, b"aa"), (2, b"b")], off, 5)
-        assert buf == b"aabccc"
+        pairs = [(14, b"ccc"), (1, b"early"), (10, b"aa"), (12, b"b")]
+        off, blob = PeerLinkService._run_sidecar(pairs, 10, 15)
+        assert blob == b"aabccc"
         assert off.tolist() == [0, 2, 2, 3, 3, 6]
+        assert pairs == [(1, b"early")]
 
     def test_empty_pairs_zero_offsets(self):
         from gubernator_tpu.service.peerlink import PeerLinkService
 
-        off = np.ones(6, np.int32)
-        assert PeerLinkService._sparse([], off, 5) == b""
-        assert off.tolist() == [1, 0, 0, 0, 0, 0]
+        for pairs in ([], [(9, b"elsewhere")]):
+            kept = list(pairs)
+            off, blob = PeerLinkService._run_sidecar(pairs, 0, 5)
+            assert blob == b""
+            assert off.tolist() == [0, 0, 0, 0, 0, 0]
+            assert pairs == kept

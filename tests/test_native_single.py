@@ -191,8 +191,8 @@ class TestPeerlinkNativeHop:
     def test_lone_hop_latency_budget(self):
         """Loopback lone-hop latency through the native path. The <100 µs
         target assumes a deployment-shaped host; this rig is 1 CPU core
-        shared by client and server, so assert a loose bound; the number
-        itself is scripts/bench_suite.py's to measure."""
+        shared by client and server, so assert a loose bound; no cell of
+        the benchmark measures the lone hop between peers yet."""
         import time as _t
 
         from gubernator_tpu.service.config import InstanceConfig

@@ -5,10 +5,6 @@ escape hatch.
 - differential: ``profile_enabled=False`` (GUBER_PROFILE=0) is
   bit-identical to the profiling path — the profiler only reads clocks,
   so turning it off cannot change a single decision;
-- one source of truth: the live /v1/debug/profile decomposition and
-  bench.py's offline `serving_decomposition` derive from the SAME
-  Profiler totals through the same arithmetic (agreement pinned ≤ 10%
-  per phase here);
 - the `profile_shift` detector reads only history-ring columns and
   stays quiet without traffic.
 """
@@ -28,7 +24,6 @@ from gubernator_tpu.obs.profile import (
     Profiler,
     check_recompile,
     hlo_fingerprint,
-    serving_decomposition,
 )
 from gubernator_tpu.service.config import InstanceConfig
 from gubernator_tpu.service.instance import Instance
@@ -211,55 +206,6 @@ class TestDifferential:
         monkeypatch.setenv("GUBER_PROFILE_CAPTURE_S", "0s")
         with pytest.raises(ValueError, match="GUBER_PROFILE_CAPTURE_S"):
             config_from_env()
-
-
-# ------------------------------------------- live vs offline agreement
-
-
-class TestOneDerivation:
-    def test_live_and_offline_decomposition_agree(self):
-        """bench.py's offline serving_decomposition and the live
-        endpoint's decomposition come from the same totals: per serial
-        phase, the offline per-cycle seconds times cycle count must
-        match the live cumulative seconds within 10%."""
-        eng = Engine(capacity=256, min_width=8, max_width=16)
-        try:
-            eng.profiler.enabled = True
-            import time as _time
-
-            before = eng.profiler.totals()
-            reqs = [_rl(f"a{i}") for i in range(8)]
-            cycles = 6
-            t0 = _time.perf_counter()
-            for c in range(cycles):
-                eng.get_rate_limits(reqs, now_ms=1_000_000 + c)
-            elapsed = _time.perf_counter() - t0
-            after = eng.profiler.totals()
-
-            offline = serving_decomposition(before, after, cycles, elapsed)
-            live = eng.profiler.decomposition()
-            pairs = {
-                "prep": ("host_prep_s", live["prep"]["total_s"]),
-                "demux": ("demux_s", live["demux"]["total_s"]),
-                "lock_wait": ("lock_wait_s", live["lock_wait"]["total_s"]),
-                "dispatch+readback": (
-                    "device_s_est",
-                    live["dispatch"]["total_s"] + live["readback"]["total_s"]),
-            }
-            for label, (off_key, live_total_s) in pairs.items():
-                off_total_s = offline[off_key] * cycles
-                # abs floor: the live view rounds total_s to the
-                # microsecond, so sub-10us phases carry quantization
-                assert off_total_s == pytest.approx(
-                    live_total_s, rel=0.10, abs=1e-6), \
-                    (label, offline, live)
-            # the residual never goes negative and the per-cycle terms
-            # sum inside the measured cycle
-            assert offline["link_s_est"] >= 0.0
-            assert offline["cycle_s"] == pytest.approx(
-                elapsed / cycles, rel=1e-6)
-        finally:
-            eng.close()
 
 
 # ------------------------------------------------------- profile_shift

@@ -383,7 +383,7 @@ class TestLinkRetryKnob:
         dead = FakeLink()
         dead._closed = True
 
-        def fake_ctor(addr, fault_key="", wire_v2=None, recorder=None):
+        def fake_ctor(addr, fault_key="", recorder=None):
             # interleave: another thread wins the install race with a link
             # that dies immediately after
             pc._link = dead

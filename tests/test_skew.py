@@ -22,8 +22,8 @@ NOW = 1_700_000_000_000
 
 
 def zipf_keys(n, n_keys, seed=7, a=1.1):
-    """Zipf-1.1 key indices, folded into n_keys distinct keys — the
-    benchmark's skew shape (bench.py --skew), pinned-seed."""
+    """Zipf-1.1 key indices, folded into n_keys distinct keys,
+    pinned-seed."""
     rng = np.random.RandomState(seed)
     return [int(k) % n_keys for k in rng.zipf(a, size=n)]
 

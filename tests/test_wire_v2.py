@@ -2,12 +2,17 @@
 responses + cross-pull pipelining on the peerlink.
 
 The bar, in the issue's words: v2 responses are BIT-IDENTICAL in content
-to the lock-step v1 path (per-key order preserved across partial posts);
-`GUBER_WIRE_V2=0` / `wire_v2=False` pins byte-exact v1 framing on the
-wire (no greeting, no partial frames); negotiation survives reconnects;
-mixed v1/v2 fleets interop across forwards, GLOBAL drains, lease
-carriers, and deadline/trace carrier flags; and a mid-stream disconnect
-drops partial reassembly on both ends without leaking pending entries.
+to the lock-step engine-level reference (per-key order preserved across
+partial posts); a client that never says HELLO is served byte-exact v1
+whole frames by the one worker; negotiation survives reconnects; mixed
+v1/v2 fleets interop across forwards, GLOBAL drains, lease carriers, and
+deadline/trace carrier flags; and a mid-stream disconnect drops partial
+reassembly on both ends without leaking pending entries.
+
+An old binary on the wire is `wire_v2=False` on the constructors: a
+server that never greets (handed to pls_start2), a client that ignores
+the greeting. It selects no serving code and nothing reads it from the
+environment or from BehaviorConfig.
 """
 
 import socket
@@ -28,9 +33,18 @@ from gubernator_tpu.service.peerlink import (
     WIRE_PARTIAL,
     encode_request_frame,
 )
-from gubernator_tpu.types import Algorithm, Behavior, PeerInfo, RateLimitReq, Status
+from gubernator_tpu.types import Behavior, PeerInfo, RateLimitReq, Status
 
-from test_columnar_pipeline import _engine, _random_reqs, _serve
+from test_columnar_pipeline import (
+    NOW,
+    _engine,
+    _random_reqs,
+    _rows,
+    _serve,
+    assert_served_rows,
+    pin_engine_clock,
+    reference_rows,
+)
 
 
 def _req(key, hits=1, limit=10, behavior=0, name="w2"):
@@ -50,9 +64,8 @@ class TestNegotiation:
     def test_v2_negotiates_and_streams_partials(self):
         """Default build: client upgrades to v2 and wide pulls leave as
         partial frames; nothing pends once the wire is quiet."""
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
-                            columnar_pipeline=True, wire_v2=True)
-        cli = PeerLinkClient(f"127.0.0.1:{sp.port}", wire_v2=True)
+        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
+        cli = PeerLinkClient(f"127.0.0.1:{sp.port}")
         try:
             for it in range(8):
                 reqs = [_req(f"neg{it}_{i}", limit=1000) for i in range(96)]
@@ -72,7 +85,7 @@ class TestNegotiation:
     def test_v1_pinned_client_never_upgrades(self):
         """wire_v2=False on the client: it ignores the greeting, never
         HELLOs, and the server answers it whole-frame only."""
-        ip, sp, cp = _serve(_engine(), columnar_pipeline=True, wire_v2=True)
+        ip, sp, cp = _serve(_engine())
         cli = PeerLinkClient(f"127.0.0.1:{sp.port}", wire_v2=False)
         try:
             before = sp.wire_partial_posts()
@@ -97,8 +110,7 @@ class TestNegotiation:
         streamed only the post-upgrade spans of a half-accumulated rid —
         the client's reassembly ended with holes and the link died
         (caught live by the wire bench)."""
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
-                            columnar_pipeline=True, wire_v2=True)
+        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
         try:
             with socket.create_connection(
                     ("127.0.0.1", sp.port), 5.0) as s:
@@ -150,10 +162,10 @@ class TestNegotiation:
     def test_negotiation_survives_reconnect(self):
         """Close + reconnect re-runs the handshake from scratch — the
         upgrade is per-connection state, not per-peer memory."""
-        ip, sp, cp = _serve(_engine(), columnar_pipeline=True, wire_v2=True)
+        ip, sp, cp = _serve(_engine())
         try:
             for _ in range(3):
-                cli = PeerLinkClient(f"127.0.0.1:{sp.port}", wire_v2=True)
+                cli = PeerLinkClient(f"127.0.0.1:{sp.port}")
                 out = cli.call(METHOD_GET_PEER_RATE_LIMITS,
                                [_req("rc", limit=10_000)], 30.0)
                 assert out[0].error == ""
@@ -164,9 +176,22 @@ class TestNegotiation:
             _close_all(cp, sp, ip)
 
 
-class TestEscapeHatch:
-    """wire_v2=False (the GUBER_WIRE_V2=0 process knob resolves to the same
-    constructor argument) must keep the server byte-exact v1."""
+def _resp_frame(rid, method, rows):
+    """A v1 whole reply frame as docs/wire.md lays it out, error-free:
+    length, rid, method, count, then the status/limit/remaining/reset
+    columns and a zero error-length column."""
+    n = len(rows)
+    st, li, re, rs = zip(*rows)
+    body = (struct.pack("<QBH", rid, method, n)
+            + struct.pack(f"<{n}i", *st) + struct.pack(f"<{n}q", *li)
+            + struct.pack(f"<{n}q", *re) + struct.pack(f"<{n}q", *rs)
+            + struct.pack(f"<{n}H", *([0] * n)))
+    return struct.pack("<I", len(body)) + body
+
+
+class TestOldClientWholeFrames:
+    """A peer that never says HELLO is served whole v1 frames by the one
+    worker (C++ accumulates the posted spans per rid), byte for byte."""
 
     def _collect_frames(self, port, reqs_rounds, settle_s=0.3):
         """Send each round as one v1 frame; return every frame received
@@ -204,116 +229,108 @@ class TestEscapeHatch:
                 pass
         return frames
 
-    @staticmethod
-    def _zero_reset(frame):
-        """A reply frame split into (rid, method, count, status, limit,
-        remaining, tail) — every byte except the reset_time column (the
-        one legitimately clock-dependent column), by name so a failure
-        says which column moved."""
-        rid, method, count = struct.unpack_from("<QBH", frame, 4)
-        off = 4 + 11
-        cols = {}
-        for name, width in (("status", 4), ("limit", 8), ("remaining", 8),
-                            ("reset_time", 8)):
-            cols[name] = frame[off:off + width * count]
-            off += width * count
-        del cols["reset_time"]
-        return dict(rid=rid, method=method, count=count, head=frame[:4],
-                    tail=frame[off:], **cols)
+    def test_silent_client_gets_byte_exact_v1_frames(self, monkeypatch):
+        """Three frames pipelined on one connection that never answers
+        the greeting: the stream is the greeting, then exactly one whole
+        frame per request, each byte for byte the encoding of what the
+        engine-level reference (run_lockstep on a twin engine, the clock
+        pinned) says — no partial frame, no other control frame. Frames
+        two and three revisit keys of the first and the third is wider
+        than the engine's widest window (16), so its rows reach C++ in
+        several posts and still leave as one frame.
 
-    def test_pinned_server_is_byte_exact_v1(self):
-        """Identical engines + identical request bytes: the wire_v2=False
-        server's byte stream equals the v2 server's stream as seen by a
-        non-upgrading client, minus the greeting — and the pinned server
-        emits NO control frames at all.
-
-        One batch worker per server: the three frames are pipelined on
-        one connection and two of them revisit keys of the first, so with
-        the default two workers whichever pulls first decides first and
-        the `remaining` column (not the clock) moves between runs — seen
-        whenever the first frame's worker is held up by a cold compile."""
+        One batch worker: with two, whichever pulls first decides first
+        and the `remaining` column moves between runs."""
         rounds = [[_req(f"bx{i}", limit=100) for i in range(24)],
                   [_req("bx0", hits=2, limit=100)],
                   [_req(f"bx{i % 5}", limit=100) for i in range(40)]]
-
-        ip1, sp1, cp1 = _serve(_engine(), columnar_pipeline=True,
-                               wire_v2=False, workers=1)
-        ip2, sp2, cp2 = _serve(_engine(), columnar_pipeline=True,
-                               wire_v2=True, workers=1)
+        pin_engine_clock(monkeypatch)
+        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
+                            workers=1)
+        twin = _engine()
         try:
-            got1 = self._collect_frames(sp1.port, rounds)
-            got2 = self._collect_frames(sp2.port, rounds)
-            # pinned server: no greeting, no partials — count matches the
-            # request count exactly, every method byte is a real echo
-            assert len(got1) == len(rounds)
-            for f in got1:
-                _rid, method, _c = struct.unpack_from("<QBH", f, 4)
-                assert method < 0xF0 and method != WIRE_PARTIAL
-            # v2 server to a silent client: greeting first, then the SAME
-            # whole-frame bytes (reset column excepted — it is wall-clock)
-            _rid0, m0, _c0 = struct.unpack_from("<QBH", got2[0], 4)
+            got = self._collect_frames(sp.port, rounds)
+            _rid0, m0, _c0 = struct.unpack_from("<QBH", got[0], 4)
             assert m0 == 0xF0  # the greeting
-            replies2 = got2[1:]
-            assert len(replies2) == len(got1)
-            for f1, f2 in zip(got1, replies2):
-                assert self._zero_reset(f1) == self._zero_reset(f2)
+            replies = got[1:]
+            assert len(replies) == len(rounds)
+            for rid, (frame, reqs) in enumerate(zip(replies, rounds),
+                                                start=1):
+                want = _resp_frame(rid, METHOD_GET_PEER_RATE_LIMITS,
+                                   reference_rows(twin, reqs, NOW))
+                assert frame == want, (rid, frame.hex(), want.hex())
+            assert sp.wire_partial_posts() == 0
         finally:
-            _close_all(cp1, sp1, ip1, cp2, sp2, ip2)
+            _close_all(cp, sp, ip)
+
+    def test_old_and_new_client_receive_the_same_rows(self, monkeypatch):
+        """An old client (ignores the greeting) and a new one (upgrades)
+        send the same requests to twin engines behind the one worker:
+        the rows are the same, column for column, although one side got
+        them as whole frames and the other as partial frames."""
+        clock = pin_engine_clock(monkeypatch)
+        io, so, co = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
+        inw, sn, cn = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
+        old = PeerLinkClient(f"127.0.0.1:{so.port}", wire_v2=False)
+        new = PeerLinkClient(f"127.0.0.1:{sn.port}")
+        rng = np.random.default_rng(30)
+        try:
+            new.call(METHOD_GET_PEER_RATE_LIMITS, [_req("warm")], 30.0)
+            for it in range(4):
+                clock["now"] = NOW + it * 700
+                reqs = _random_reqs(rng, int(rng.integers(40, 150)),
+                                    n_keys=18)
+                a = old.call(METHOD_GET_PEER_RATE_LIMITS, reqs, 30.0)
+                b = new.call(METHOD_GET_PEER_RATE_LIMITS, reqs, 30.0)
+                assert [r.error for r in a] == [r.error for r in b]
+                assert _rows(a) == _rows(b), it
+            assert old.wire_version == 1 and new.wire_version == 2
+            assert so.wire_partial_posts() == 0
+            assert sn.wire_partial_posts() > 0
+        finally:
+            _close_all(old, new, co, cn, so, sn, io, inw)
 
 
 # ------------------------------------------------------------ differential
 
 
 class TestDifferentialV2:
-    def test_v2_contents_bit_identical_to_lockstep(self):
+    def test_v2_contents_bit_identical_to_lockstep(self, monkeypatch):
         """The acceptance hammer: duplicate keys, gregorian, invalid and
         GLOBAL leftover cuts through a full v2 link (partial posts +
-        cross-pull pipelining) against the lock-step v1 service — contents
-        must match item-for-item (reset excluded: separate clocks)."""
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
-                            columnar_pipeline=True, wire_v2=True)
-        il, sl, cl = _serve(_engine(), columnar_pipeline=False,
-                            wire_v2=False)
-        c2 = PeerLinkClient(f"127.0.0.1:{sp.port}", wire_v2=True)
-        c1 = PeerLinkClient(f"127.0.0.1:{sl.port}", wire_v2=False)
+        cross-pull pipelining) against the lock-step engine-level
+        reference (run_lockstep on a twin engine) — every column must
+        match item-for-item, reset_time and leaky buckets included: the
+        clock is pinned, so there is no second service whose wall clock
+        could land a leak tick away."""
+        clock = pin_engine_clock(monkeypatch)
+        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
+        twin = _engine()
+        c2 = PeerLinkClient(f"127.0.0.1:{sp.port}")
         rng = np.random.default_rng(88)
         try:
             c2.call(METHOD_GET_PEER_RATE_LIMITS, [_req("warm")], 30.0)
             for it in range(6):
+                clock["now"] = NOW + it * 500
                 reqs = _random_reqs(rng, int(rng.integers(40, 150)),
                                     n_keys=18)
                 reqs[int(rng.integers(0, len(reqs)))] = RateLimitReq(
                     name="cp", unique_key=f"gl{it}", hits=1, limit=9,
                     duration=60_000, behavior=int(Behavior.GLOBAL))
                 got = c2.call(METHOD_GET_PEER_RATE_LIMITS, reqs, 30.0)
-                want = c1.call(METHOD_GET_PEER_RATE_LIMITS, reqs, 30.0)
-                for i, (g, w) in enumerate(zip(got, want)):
-                    assert (g.status, g.limit, g.error) == \
-                        (w.status, w.limit, w.error), \
-                        (it, i, reqs[i], g, w)
-                    if reqs[i].algorithm == Algorithm.LEAKY_BUCKET:
-                        # leaky remaining refills with WALL-CLOCK time and
-                        # the two services stamp separate clocks, so the
-                        # calls may land one leak tick apart; exact leaky
-                        # equality is proven engine-level with pinned
-                        # now_ms (test_columnar_pipeline differentials)
-                        assert abs(g.remaining - w.remaining) <= 1, \
-                            (it, i, reqs[i], g, w)
-                    else:
-                        assert g.remaining == w.remaining, \
-                            (it, i, reqs[i], g, w)
+                assert_served_rows(
+                    got, reference_rows(twin, reqs, clock["now"]), reqs, it)
             assert c2.wire_version == 2
             assert sp.wire_partial_posts() > 0  # v2 actually streamed
         finally:
-            _close_all(c2, c1, cp, cl, sp, sl, ip, il)
+            _close_all(c2, cp, sp, ip)
 
     def test_duplicate_key_order_across_partial_posts(self):
         """One frame hammering ONE key: hits must apply in item order no
         matter how the rows leave as partial frames — the remaining
         column must be the exact arithmetic sequence."""
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
-                            columnar_pipeline=True, wire_v2=True)
-        cli = PeerLinkClient(f"127.0.0.1:{sp.port}", wire_v2=True)
+        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
+        cli = PeerLinkClient(f"127.0.0.1:{sp.port}")
         try:
             n = 120
             out = cli.call(METHOD_GET_PEER_RATE_LIMITS,
@@ -334,9 +351,8 @@ class TestDrainsAndLeaks:
         PeerLinkError — never a hang — and neither side leaks partial
         state."""
         eng = _engine()
-        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4,
-                            columnar_pipeline=True, wire_v2=True)
-        cli = PeerLinkClient(f"127.0.0.1:{sp.port}", wire_v2=True)
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4)
+        cli = PeerLinkClient(f"127.0.0.1:{sp.port}")
         errs, done = [], []
 
         def caller(i):
@@ -365,9 +381,8 @@ class TestDrainsAndLeaks:
         """The server dies between partial frames: in-flight futures fail
         with PeerLinkError (never hang) and the client's reassembly map
         is empty afterwards — the leak probe of the issue's acceptance."""
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
-                            columnar_pipeline=True, wire_v2=True)
-        cli = PeerLinkClient(f"127.0.0.1:{sp.port}", wire_v2=True)
+        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
+        cli = PeerLinkClient(f"127.0.0.1:{sp.port}")
         futs = []
         try:
             for i in range(8):
@@ -387,8 +402,7 @@ class TestDrainsAndLeaks:
     def test_client_vanish_reaps_server_pending(self):
         """A client that disconnects mid-pull must not leave pending
         reply entries behind on the server (conn teardown reaps them)."""
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
-                            columnar_pipeline=True, wire_v2=True)
+        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
         try:
             s = socket.create_connection(("127.0.0.1", sp.port), 5.0)
             s.sendall(encode_request_frame(
@@ -408,17 +422,31 @@ class TestDrainsAndLeaks:
 
 @pytest.mark.chaos
 class TestMixedVersionCluster:
-    """A rolling upgrade in miniature: node 0 speaks v2, node 1 is pinned
-    to v1 (`wire_v2=False`, the GUBER_WIRE_V2=0 posture). Everything that
-    rides the link must interop in BOTH directions."""
+    """A rolling upgrade in miniature: node 0 speaks v2, node 1 is an
+    old binary on the wire, built through the constructors' `wire_v2=False`
+    (its server never greets, its outbound links ignore the greeting).
+    Everything that rides the link must interop in BOTH directions."""
+
+    @staticmethod
+    def _old_link(pc):
+        """Give a PeerClient of the old node the link an old binary would
+        dial: a PeerLinkClient that never answers the greeting."""
+        host, _, port = pc.info.address.rpartition(":")
+        pc._link = PeerLinkClient(
+            f"{host}:{int(port) + pc.conf.peer_link_offset}",
+            fault_key=pc.info.address, wire_v2=False)
+        return pc
 
     def _mixed(self):
         c = LocalCluster().start(2)
-        c.instances[1].instance.conf.behaviors.wire_v2 = False
-        links = wire_peerlink(c)
+        links = wire_peerlink(c, old_nodes=(1,))
         if not links:
             c.stop()
             pytest.skip("no free peerlink port offset on this host")
+        old = c.instances[1]
+        for p in old.instance.all_peer_clients():
+            if p.info.address != old.address:
+                self._old_link(p)
         return c, links
 
     def _key_owned_by(self, sender, owner_ci, prefix, name="w2"):
@@ -481,6 +509,8 @@ class TestMixedVersionCluster:
             for src, dst in ((v2node, v1node), (v1node, v2node)):
                 pc = PeerClient(src.instance.conf.behaviors,
                                 PeerInfo(address=dst.address))
+                if src is v1node:
+                    self._old_link(pc)
                 try:
                     dst.instance.last_budget_ms.pop("peer", None)
                     dl = deadline_mod.capture(800)
