@@ -295,7 +295,7 @@ class Engine:
                     if self._lean_ok:
                         ln = lean_window(packed, self.capacity)
                         self.state, resp = self._decide_packed_lean(
-                            self.state, ln[0], jnp.asarray(ln[1]), 0)
+                            self.state, ln[0], ln[1], 0)
                 release_compile_memory()
             # every scan-path shape: depths 2..=_MAX_SCAN at min_width (the
             # fast path dispatches nothing else — see _split_scannable)
@@ -310,7 +310,7 @@ class Engine:
                     if self._lean_ok:
                         ln = lean_window(stacked, self.capacity)
                         self.state, resp = self._decide_scan_lean(
-                            self.state, ln[0], jnp.asarray(ln[1]), 0)
+                            self.state, ln[0], ln[1], 0)
                 release_compile_memory()
                 k *= 2
             # serving-path auxiliary jits: the lone-miss mirror seed's
@@ -348,14 +348,16 @@ class Engine:
             if self._lean_ok:
                 ln = lean_window(packed, self.capacity)
                 if ln is not None:
-                    lanes = jnp.asarray(ln[1])
+                    # host arrays go to the program as they are: its call
+                    # path places them, an explicit jnp.asarray first is
+                    # ~0.3 ms of Python a window under the GIL
                     if kernel_telemetry.needs_probe("packed_lean", w):
                         kernel_telemetry.offer_probe(
                             "packed_lean", w, self._decide_packed_lean,
-                            (self.state, ln[0], lanes, now_ms))
+                            (self.state, ln[0], ln[1], now_ms))
                     t = time.perf_counter_ns()
                     self.state, out = self._decide_packed_lean(
-                        self.state, ln[0], lanes, now_ms)
+                        self.state, ln[0], ln[1], now_ms)
                     kernel_telemetry.note(
                         "packed_lean", w,
                         dur_ns=time.perf_counter_ns() - t)
@@ -395,14 +397,13 @@ class Engine:
             if self._lean_ok:
                 ln = lean_window(stacked, self.capacity)
                 if ln is not None:
-                    lanes = jnp.asarray(ln[1])
                     if kernel_telemetry.needs_probe("scan_lean", w):
                         kernel_telemetry.offer_probe(
                             "scan_lean", w, self._decide_scan_lean,
-                            (self.state, ln[0], lanes, now_ms))
+                            (self.state, ln[0], ln[1], now_ms))
                     t = time.perf_counter_ns()
                     self.state, out = self._decide_scan_lean(
-                        self.state, ln[0], lanes, now_ms)
+                        self.state, ln[0], ln[1], now_ms)
                     kernel_telemetry.note(
                         "scan_lean", w, depth=k,
                         dur_ns=time.perf_counter_ns() - t)
@@ -861,7 +862,7 @@ class Engine:
                     if self._lean_ok:
                         ln = lean_window(stacked, self.capacity)
                         self.state, resp = self._decide_scan_lean(
-                            self.state, ln[0], jnp.asarray(ln[1]), 0)
+                            self.state, ln[0], ln[1], 0)
                 release_compile_memory()
                 k *= 2
             if resp is not None:

@@ -10,6 +10,7 @@ clean drain on service close.
 """
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -277,6 +278,30 @@ class TestPipelinedColumnarDifferential:
         assert len(lefts[0]) == 0
         assert outs[0][2].tolist() == [49] * 10  # prefix really decided
 
+    def test_reused_staging_holds_nothing_of_the_launch_before(self):
+        """A staging buffer of the ring is reused by the next launch of
+        its shape: after a full window, a short one on the same buffer
+        must ship padding lanes that carry no slot and no column of the
+        launch before."""
+        eng = _engine()
+        staging = {}
+        wide = [RateLimitReq(name="sg", unique_key=f"w{i}", hits=2,
+                             limit=77, duration=90_000,
+                             algorithm=Algorithm.LEAKY_BUCKET)
+                for i in range(16)]
+        short = [RateLimitReq(name="sg", unique_key=f"s{i}", hits=1,
+                              limit=10, duration=60_000) for i in range(9)]
+        for reqs, want in ((wide, 75), (short, 9), (wide, 73)):
+            h = eng.launch_columnar_windows([cols_from(reqs)], SLOW,
+                                            now_ms=NOW, staging=staging)
+            outs = [_outs(len(reqs))]
+            eng.collect_columnar_windows(h, outs)
+            assert outs[0][2].tolist() == [want] * len(reqs)
+            buf, = staging.values()
+            n = len(reqs)
+            assert (buf[0, 0, n:] == -1).all()
+            assert not buf[0, 1:, n:].any()
+
     def test_mixed_width_group_after_bucket_splits(self):
         """A chunk one item over a window boundary: the tail sub-window
         rides the same scan group at the group's max bucket width."""
@@ -401,20 +426,108 @@ def _serve(eng, **kw):
     return inst, svc, cli
 
 
+def chunk_cap(monkeypatch, n):
+    """Cut pulls into chunks of <= n items, as MAX_BATCH_SIZE (1000) cuts a
+    pull of 1000-request calls: with frames of n items every chunk is one
+    frame, and at an engine whose widest window holds n it is ONE window —
+    the shape of every chunk at the shipped widths."""
+    import gubernator_tpu.service.peerlink as peerlink_mod
+
+    monkeypatch.setattr(peerlink_mod, "MAX_BATCH_SIZE", n)
+
+
+def send_as_one_pull(svc, cli, frames, methods=None, pulls=None):
+    """Send `frames` on one connection so that they reach the (single)
+    worker in at most two pulls, the last of them holding several frames:
+    the worker is held inside its first pull until the IO thread has
+    parsed every frame, so the rest queue up and are pulled together.
+    `methods` gives each frame's method (peer hops by default); `pulls`
+    collects (ctx, got, the pull's method column) as the worker handles
+    them. Returns each frame's answers, in frame order."""
+    from gubernator_tpu.service.peerlink import METHOD_GET_PEER_RATE_LIMITS
+
+    gate = threading.Event()
+    real = svc._handle_batch
+
+    def gated(got, b, ctx, ws):
+        gate.wait(10.0)
+        if pulls is not None:
+            pulls.append((ctx, got, b["method"][:got].tolist()))
+        return real(got, b, ctx=ctx, ws=ws)
+
+    svc._handle_batch = gated
+    try:
+        futs = [cli.call_async(m, f)[0] for m, f in zip(
+            methods or [METHOD_GET_PEER_RATE_LIMITS] * len(frames), frames)]
+        deadline = time.time() + 10
+        while (svc.wire_pending_count() < len(frames)
+               and time.time() < deadline):
+            time.sleep(0.002)
+        assert svc.wire_pending_count() == len(frames)
+        gate.set()
+        return [f.result(30.0) for f in futs]
+    finally:
+        gate.set()
+        svc._handle_batch = real
+
+
+def oracle_rows(table, reqs, now_ms):
+    """ops/oracle.py's answers for `reqs`, one after another against
+    `table`, as (status, limit, remaining, reset_time) rows."""
+    from gubernator_tpu.ops.oracle import oracle_answer
+
+    return _rows([oracle_answer(table, r, now_ms) for r in reqs])
+
+
+def _shared_key_frames(rng, n_frames, n, leftovers):
+    """`n_frames` frames of `n` requests: keys distinct inside a frame,
+    a hot set shared by all of them (the hot keys of several calls), both
+    algorithms. With `leftovers`, every other frame also repeats one of
+    its own keys, carries a gregorian and a GLOBAL request: lanes the C
+    prep demotes to the object path."""
+    frames = []
+    for f in range(n_frames):
+        keys = [f"hot{i}" for i in rng.permutation(6)[:4]]
+        keys += [f"f{f}_{i}" for i in range(n - len(keys))]
+        reqs = [RateLimitReq(
+            name="mf", unique_key=k, hits=int(rng.integers(0, 3)), limit=30,
+            duration=60_000,
+            algorithm=(Algorithm.LEAKY_BUCKET if k.startswith("hot")
+                       and int(k[3:]) % 2 else Algorithm.TOKEN_BUCKET))
+            for k in keys]
+        if leftovers and f % 2 == 0:
+            reqs[-1] = RateLimitReq(name="mf", unique_key=keys[0], hits=1,
+                                    limit=30, duration=60_000,
+                                    algorithm=reqs[0].algorithm)
+            reqs[-2] = RateLimitReq(
+                name="mf", unique_key=f"greg{f}", hits=1, limit=30,
+                duration=1, behavior=int(Behavior.DURATION_IS_GREGORIAN))
+            reqs[-3] = RateLimitReq(
+                name="mf", unique_key=f"glob{f}", hits=1, limit=30,
+                duration=60_000, behavior=int(Behavior.GLOBAL))
+        frames.append(reqs)
+    return frames
+
+
 class TestWireLevelDifferential:
-    def test_wire_hammer_pipelined_vs_lockstep(self, monkeypatch):
-        """Wide peer-hop frames (duplicates, gregorian, GLOBAL, invalid
-        keys) through a PIPELINED service must come back bit-identical to
-        the lock-step engine-level reference (run_lockstep on a twin
-        engine) — every column, reset_time included: the clock is
-        pinned."""
+    @pytest.mark.parametrize("max_width", [16, 256])
+    def test_wire_hammer_pipelined_vs_lockstep(self, monkeypatch,
+                                               max_width):
+        """Peer-hop frames (duplicates, gregorian, GLOBAL, invalid keys)
+        through a PIPELINED service must come back bit-identical to the
+        lock-step engine-level reference (run_lockstep on a twin engine)
+        — every column, reset_time included: the clock is pinned. At
+        width 16 a frame is many windows, launched in scan groups; at 256
+        it is ONE window and is served lock-step (submit_/
+        complete_columnar), its leftovers retired before the next."""
         from gubernator_tpu.service.peerlink import (
             METHOD_GET_PEER_RATE_LIMITS,
         )
 
         clock = pin_engine_clock(monkeypatch)
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
-        twin = _engine()
+        ip, sp, cp = _serve(_engine(max_width), pipeline_depth=3,
+                            pipeline_scan=4)
+        twin = _engine(max_width)
         rng = np.random.default_rng(41)
         try:
             for it in range(6):
@@ -428,21 +541,74 @@ class TestWireLevelDifferential:
                 got = cp.call(METHOD_GET_PEER_RATE_LIMITS, reqs, 30.0)
                 assert_served_rows(
                     got, reference_rows(twin, reqs, clock["now"]), reqs, it)
-            assert sp.stats["columnar_windows"] > 0
-            assert sp.stats["columnar_groups"] > 0
+            if max_width == 256:  # one window a frame: nothing launched
+                assert sp.stats["columnar_windows"] == 0
+            else:
+                assert sp.stats["columnar_windows"] > 0
+                assert sp.stats["columnar_groups"] > 0
         finally:
             cp.close()
             sp.close()
             ip.close()
 
-    def test_wire_over_commit_error_fill(self):
-        """Over-commit mid-chunk on the wire: the unconsumed remainder
-        gets per-item error replies, the prefix still decides, and the
-        pull is answered (no stranded frames)."""
+    @pytest.mark.parametrize("leftovers", [False, True],
+                             ids=["clean", "leftovers"])
+    def test_multi_frame_pull_of_one_window_chunks(self, monkeypatch,
+                                                   leftovers):
+        """The shape of a batch1000 pull: several frames pulled together,
+        each a chunk that fits ONE window, keys shared between frames.
+        Every chunk is served lock-step inside the pull (submit_/
+        complete_columnar: nothing is launched into the worker pipeline)
+        and every column of every answer is that of run_lockstep on a
+        twin engine and of ops/oracle.py, in frame order — leaky buckets
+        and reset_time included. With leftovers (a key repeated inside
+        its frame, gregorian, GLOBAL) the chunk's tail retires through
+        the object path before the next frame, which asks for the same
+        hot keys, is prepped."""
+        clock = pin_engine_clock(monkeypatch)
+        chunk_cap(monkeypatch, 16)
+        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
+                            workers=1)
+        lockstep = []
+        real = sp._columnar_chunk_lockstep
+        sp._columnar_chunk_lockstep = lambda *a, **k: (
+            lockstep.append(a[2]), real(*a, **k))[1]
+        twin = _engine()
+        table = {}
+        rng = np.random.default_rng(53)
+        try:
+            for it in range(4):
+                clock["now"] = NOW + it * 900
+                frames = _shared_key_frames(rng, 5, 16, leftovers)
+                got = send_as_one_pull(sp, cp, frames)
+                for f, (reqs, out) in enumerate(zip(frames, got)):
+                    want = reference_rows(twin, reqs, clock["now"])
+                    assert_served_rows(out, want, reqs, (it, f))
+                    assert oracle_rows(table, reqs, clock["now"]) == want
+            assert len(lockstep) == 20  # every chunk, one span each
+            assert all(len(spans) == 1 for spans in lockstep)
+            assert sp.stats["columnar_windows"] == 0
+            assert sp.stats["pull_boundary_stalls"] == 0
+            assert sp.stats["errors"] == 0
+        finally:
+            cp.close()
+            sp.close()
+            ip.close()
+
+    @pytest.mark.parametrize("cap", [None, 16], ids=["one_chunk", "chunks"])
+    def test_wire_over_commit_error_fill(self, monkeypatch, cap):
+        """Over-commit on the wire: the unconsumed remainder of the chunk
+        gets per-item error replies, what was prepped before still
+        decides, and the pull is answered (no stranded frames). As one
+        chunk of three windows the failing window and everything after
+        error-fills; as three one-window chunks the failing chunk
+        error-fills whole and the chunk after it decides."""
         from gubernator_tpu.service.peerlink import (
             METHOD_GET_PEER_RATE_LIMITS,
         )
 
+        if cap is not None:
+            chunk_cap(monkeypatch, cap)
         eng = _engine()
         ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=2)
         real = native.prep_pack_columnar
@@ -468,19 +634,171 @@ class TestWireLevelDifferential:
         assert len(out) == 48
         # first sub-window (16 items at max_width 16) decided
         assert all(r.error == "" and r.remaining == 49 for r in out[:16])
-        # the failing window and everything after error-fills
-        assert all("over-committed" in r.error for r in out[16:])
+        # the failing window error-fills; so does everything after it in
+        # the same chunk
+        assert all("over-committed" in r.error for r in out[16:32])
+        if cap is None:
+            assert all("over-committed" in r.error for r in out[32:])
+        else:
+            assert all(r.error == "" and r.remaining == 49
+                       for r in out[32:])
 
-    def test_clean_drain_on_service_close(self):
+    def test_recover_batch_with_earlier_launches_in_flight(self,
+                                                           monkeypatch):
+        """A launch that raises while launches of earlier chunks (and of
+        an earlier pull) are still in flight: _recover_batch settles the
+        pipeline first, so the rows already launched are answered with
+        their decisions, and every row of the failed pull that was not is
+        answered with an error — each row once, no frame stranded, and
+        the engine keeps exactly the hits it answered. A chunk here is
+        two windows, so it is launched."""
+        chunk_cap(monkeypatch, 32)
+        eng = _engine()
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4,
+                            workers=1)
+        real = eng.launch_columnar_windows
+        calls = {"n": 0}
+
+        def third_raises(*a, **k):
+            calls["n"] += 1
+            if calls["n"] == 3:
+                raise RuntimeError("injected launch failure")
+            return real(*a, **k)
+
+        frames = [[RateLimitReq(name="rb", unique_key=f"f{f}k{i}", hits=1,
+                                limit=20, duration=60_000)
+                   for i in range(32)] for f in range(5)]
+        try:
+            eng.launch_columnar_windows = third_raises
+            got = send_as_one_pull(sp, cp, frames)
+            del eng.launch_columnar_windows
+            decided = []
+            for f, out in enumerate(got):
+                assert len(out) == 32
+                rows = {(r.error, r.remaining) for r in out}
+                decided.append(rows == {("", 19)})
+                if not decided[f]:
+                    assert rows == {("peerlink: internal batch failure",
+                                     0)}, (f, out)
+            # launched before the failure: decided; the failing chunk:
+            # errors; the frames behind it: errors where they shared its
+            # pull, decisions where the worker pulled them afterwards
+            assert decided[:3] == [True, True, False], decided
+            assert sp.stats["errors"] == 1
+            assert sp.wire_pending_count() == 0
+            # the same frames again: decided rows spend a second hit,
+            # error rows their first
+            again = send_as_one_pull(sp, cp, frames)
+            for f, out in enumerate(again):
+                assert [(r.error, r.remaining) for r in out] == \
+                    [("", 18 if decided[f] else 19)] * 32, f
+        finally:
+            cp.close()
+            sp.close()
+            ip.close()
+
+    @pytest.mark.parametrize("where", ["boundary", "inside_next_pull"])
+    def test_failed_collect_of_an_earlier_pull_is_answered(self,
+                                                           monkeypatch,
+                                                           where):
+        """The readback of a launch raises after the pull that launched
+        it has returned. `boundary`: the worker collects it between pulls
+        (an empty poll), where no pull's try is open. `inside_next_pull`:
+        the next pull is already queued, fills the pipe and drains the
+        earlier pull's launches inside its own try. Either way the worker
+        lives, the popped launch's rows are answered with the
+        internal-failure reply (through its OWN pull's buffers), every
+        other launch keeps its decisions, and the service serves on."""
+        from gubernator_tpu.service.peerlink import (
+            METHOD_GET_PEER_RATE_LIMITS,
+        )
+
+        chunk_cap(monkeypatch, 32)
+        eng = _engine()
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4,
+                            workers=1)
+        real_collect = eng.collect_columnar_windows
+        calls = {"n": 0}
+
+        def second_raises(handle, outs):
+            calls["n"] += 1
+            if calls["n"] == 2:
+                raise RuntimeError("injected readback failure")
+            return real_collect(handle, outs)
+
+        def frames_of(tag, n_frames):
+            return [[RateLimitReq(name="fc", unique_key=f"{tag}f{f}k{i}",
+                                  hits=1, limit=20, duration=60_000)
+                     for i in range(32)] for f in range(n_frames)]
+
+        def queued(n):
+            deadline = time.time() + 10
+            while sp.wire_pending_count() < n and time.time() < deadline:
+                time.sleep(0.002)
+            assert sp.wire_pending_count() == n
+
+        first, second = frames_of("a", 2), frames_of("b", 3)
+        futs = []
+        real_handle = sp._handle_batch
+        pulls = {"n": 0}
+
+        def handle(got, b, ctx, ws):
+            pulls["n"] += 1
+            if pulls["n"] == 1:
+                queued(len(first))  # both frames of the first pull
+            real_handle(got, b, ctx=ctx, ws=ws)
+            if pulls["n"] == 1 and where == "inside_next_pull":
+                # the first pull's launches are in flight; queue the
+                # second pull before the worker polls
+                futs.extend(cp.call_async(METHOD_GET_PEER_RATE_LIMITS, f)[0]
+                            for f in second)
+                queued(len(first) + len(second))
+
+        sp._handle_batch = handle
+        try:
+            eng.collect_columnar_windows = second_raises
+            futs[:0] = [cp.call_async(METHOD_GET_PEER_RATE_LIMITS, f)[0]
+                        for f in first]
+            if where == "boundary":
+                got = [f.result(30.0) for f in futs]
+                futs.extend(cp.call_async(METHOD_GET_PEER_RATE_LIMITS, f)[0]
+                            for f in second)
+            got = [f.result(30.0) for f in futs]
+            del eng.collect_columnar_windows
+            rows = [{(r.error, r.remaining) for r in out} for out in got]
+            failed = {("peerlink: internal batch failure", 0)}
+            # the second collect is the first pull's second launch
+            assert rows[0] == {("", 19)} and rows[1] == failed, rows
+            if where == "boundary":
+                assert rows[2:] == [{("", 19)}] * 3, rows
+            else:
+                # the second pull was interrupted: what it had launched
+                # keeps its decisions, what it had not is error-filled
+                assert rows[2] == {("", 19)}, rows
+                assert rows[4] == failed, rows
+            assert sp.stats["errors"] == 1
+            assert sp.wire_pending_count() == 0
+            # the worker is alive and the pipeline empty
+            out = cp.call(METHOD_GET_PEER_RATE_LIMITS, first[0], 30.0)
+            assert {(r.error, r.remaining) for r in out} == {("", 18)}
+        finally:
+            sp._handle_batch = real_handle
+            cp.close()
+            sp.close()
+            ip.close()
+
+    @pytest.mark.parametrize("max_width", [16, 64])
+    def test_clean_drain_on_service_close(self, max_width):
         """Frames in flight when the service closes either complete or
         fail loudly (PeerLinkError) — never hang; the engine stays
-        consistent afterwards."""
+        consistent afterwards. At width 64 every frame is one window,
+        served lock-step inside its pull."""
         from gubernator_tpu.service.peerlink import (
             METHOD_GET_PEER_RATE_LIMITS,
             PeerLinkError,
         )
 
-        eng = _engine()
+        eng = _engine(max_width)
         ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4)
         errs = []
         done = []
@@ -513,6 +831,153 @@ class TestWireLevelDifferential:
             [RateLimitReq(name="dr", unique_key="post", hits=1, limit=5,
                           duration=60_000)], now_ms=NOW)
         assert out[0].remaining == 4
+
+
+class TestOneWindowCounters:
+    """What the serving counters say: only a chunk wider than one window
+    is launched into the worker pipeline and counts columnar windows; a
+    chunk that fits one window (every chunk at the shipped widths) and a
+    lone request are served lock-step and count none."""
+
+    @pytest.mark.parametrize("windows", [1, 2])
+    def test_windows_count_launched_chunks_and_the_boundary_stalls(
+            self, monkeypatch, windows):
+        """Chunks of one window are served inside their pull: no columnar
+        window, no launch in flight when the worker goes back to the
+        queue, so no stall of either kind. Chunks of two windows are one
+        launch each: the windows count, four launches meet a pipe of
+        three (fill stall), and the last of a pull is still in flight
+        when the worker polls and finds nothing (boundary stall)."""
+        n = 16 * windows
+        chunk_cap(monkeypatch, n)
+        eng = _engine()
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4,
+                            workers=1)
+        try:
+            for it in range(3):
+                frames = [[RateLimitReq(name="ct", unique_key=f"p{it}f{f}k{i}",
+                                        hits=1, limit=9, duration=60_000)
+                           for i in range(n)] for f in range(4)]
+                for out in send_as_one_pull(sp, cp, frames):
+                    assert [(r.error, r.remaining) for r in out] == \
+                        [("", 8)] * n
+            assert sp.stats["columnar_cuts"] == 0
+            assert (sp.wire_debug()["pull_boundary_stalls"]
+                    == sp.stats["pull_boundary_stalls"])
+            if windows == 1:
+                assert sp.stats["columnar_windows"] == 0
+                assert sp.stats["columnar_groups"] == 0
+                assert sp.stats["pull_boundary_stalls"] == 0
+                assert sp.stats["columnar_fill_stalls"] == 0
+            else:
+                assert sp.stats["columnar_windows"] == 24  # two a chunk
+                assert sp.stats["columnar_groups"] == 12
+                assert sp.stats["pull_boundary_stalls"] >= 3
+                assert sp.stats["columnar_fill_stalls"] >= 3
+        finally:
+            cp.close()
+            sp.close()
+            ip.close()
+
+    def test_lone_request_is_lockstep_and_seeds_before_its_post(self):
+        """got == 1: the chunk is served submit_/complete_columnar inside
+        _handle_batch, the key's mirror is seeded, and only then is the
+        reply posted (a seed after the post could overwrite hits the IO
+        thread applied natively in between). It counts no columnar
+        window."""
+        from gubernator_tpu.service.peerlink import (
+            METHOD_GET_PEER_RATE_LIMITS,
+        )
+
+        eng = _engine()
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4,
+                            workers=1)
+        if sp._seed_engine is None:
+            cp.close()
+            sp.close()
+            ip.close()
+            pytest.skip("no native lone-request mirror on this build")
+        events = []
+        real_lock, real_seed, real_post = (
+            sp._columnar_chunk_lockstep, eng.seed_mirror, sp._post_span)
+        sp._columnar_chunk_lockstep = lambda *a, **k: (
+            events.append("lockstep"), real_lock(*a, **k))[1]
+        eng.seed_mirror = lambda key: (
+            events.append("seed"), real_seed(key))[1]
+        sp._post_span = lambda *a, **k: (
+            events.append("post"), real_post(*a, **k))[1]
+        try:
+            out = cp.call(METHOD_GET_PEER_RATE_LIMITS,
+                          [RateLimitReq(name="lone", unique_key="k", hits=1,
+                                        limit=7, duration=60_000)], 30.0)
+            assert (out[0].error, out[0].remaining) == ("", 6)
+            assert events == ["lockstep", "seed", "post"]
+            assert sp.stats["columnar_windows"] == 0
+            assert sp.stats["pull_boundary_stalls"] == 0
+        finally:
+            del eng.seed_mirror
+            cp.close()
+            sp.close()
+            ip.close()
+
+
+class TestChunkCut:
+    def test_chunks_end_at_a_method_change_or_at_the_cap(self, monkeypatch):
+        """_handle_batch cuts a pull into chunks with one pass over the
+        method column: every chunk is one method, at most the cap, and
+        ends only where the method changes, the cap is reached or the
+        pull ends — whatever frames the pull happened to hold. The
+        answers say every item was served once, in order."""
+        from gubernator_tpu.service.peerlink import (
+            METHOD_GET_PEER_RATE_LIMITS,
+            METHOD_GET_RATE_LIMITS,
+        )
+
+        cap = 16
+        chunk_cap(monkeypatch, cap)
+        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
+                            workers=1)
+        seen = []
+        real_col, real_obj = sp._columnar_chunk, sp._object_chunk
+
+        def spy_col(m, eng, j, k, ctx, ws):
+            seen.append((ctx, m, j, k, ctx.b["method"][j:k].tolist()))
+            return real_col(m, eng, j, k, ctx, ws)
+
+        def spy_obj(m, j, k, b, errs, metas, direct=True):
+            seen.append((b, m, j, k, b["method"][j:k].tolist()))
+            return real_obj(m, j, k, b, errs, metas, direct)
+
+        sp._columnar_chunk, sp._object_chunk = spy_col, spy_obj
+        sizes = [(METHOD_GET_PEER_RATE_LIMITS, 10),
+                 (METHOD_GET_PEER_RATE_LIMITS, 10),
+                 (METHOD_GET_RATE_LIMITS, 5),
+                 (METHOD_GET_PEER_RATE_LIMITS, 37),
+                 (METHOD_GET_RATE_LIMITS, 20)]
+        frames = [[RateLimitReq(name="cc", unique_key=f"f{f}k{i}", hits=1,
+                                limit=4, duration=60_000) for i in range(n)]
+                  for f, (_m, n) in enumerate(sizes)]
+        pulls = []
+        try:
+            got = send_as_one_pull(sp, cp, frames,
+                                   methods=[m for m, _n in sizes],
+                                   pulls=pulls)
+            for (_m, n), out in zip(sizes, got):
+                assert [(r.error, r.remaining) for r in out] == [("", 3)] * n
+            for ctx, n_pull, methods in pulls:
+                at = 0
+                for owner, m, j, k, inside in seen:
+                    if owner is not ctx and owner is not ctx.b:
+                        continue
+                    assert j == at and inside == [m] * (k - j)
+                    assert 0 < k - j <= cap
+                    assert (k == n_pull or methods[k] != m or k - j == cap)
+                    at = k
+                assert at == n_pull
+        finally:
+            cp.close()
+            sp.close()
+            ip.close()
 
 
 class TestSaturationDemotion:

@@ -229,15 +229,19 @@ class TestOldClientWholeFrames:
                 pass
         return frames
 
-    def test_silent_client_gets_byte_exact_v1_frames(self, monkeypatch):
+    @pytest.mark.parametrize("max_width", [16, 64])
+    def test_silent_client_gets_byte_exact_v1_frames(self, monkeypatch,
+                                                     max_width):
         """Three frames pipelined on one connection that never answers
         the greeting: the stream is the greeting, then exactly one whole
         frame per request, each byte for byte the encoding of what the
         engine-level reference (run_lockstep on a twin engine, the clock
         pinned) says — no partial frame, no other control frame. Frames
         two and three revisit keys of the first and the third is wider
-        than the engine's widest window (16), so its rows reach C++ in
-        several posts and still leave as one frame.
+        than the engine's widest window at 16, so its rows reach C++ in
+        several posts and still leave as one frame; at 64 each frame fits
+        one window and is served lock-step inside its pull (the one-item
+        frame, pulled alone, is the lone request).
 
         One batch worker: with two, whichever pulls first decides first
         and the `remaining` column moves between runs."""
@@ -245,9 +249,9 @@ class TestOldClientWholeFrames:
                   [_req("bx0", hits=2, limit=100)],
                   [_req(f"bx{i % 5}", limit=100) for i in range(40)]]
         pin_engine_clock(monkeypatch)
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
-                            workers=1)
-        twin = _engine()
+        ip, sp, cp = _serve(_engine(max_width), pipeline_depth=3,
+                            pipeline_scan=4, workers=1)
+        twin = _engine(max_width)
         try:
             got = self._collect_frames(sp.port, rounds)
             _rid0, m0, _c0 = struct.unpack_from("<QBH", got[0], 4)
@@ -295,17 +299,21 @@ class TestOldClientWholeFrames:
 
 
 class TestDifferentialV2:
-    def test_v2_contents_bit_identical_to_lockstep(self, monkeypatch):
+    @pytest.mark.parametrize("max_width", [16, 256])
+    def test_v2_contents_bit_identical_to_lockstep(self, monkeypatch,
+                                                   max_width):
         """The acceptance hammer: duplicate keys, gregorian, invalid and
         GLOBAL leftover cuts through a full v2 link (partial posts +
         cross-pull pipelining) against the lock-step engine-level
         reference (run_lockstep on a twin engine) — every column must
         match item-for-item, reset_time and leaky buckets included: the
         clock is pinned, so there is no second service whose wall clock
-        could land a leak tick away."""
+        could land a leak tick away. At width 256 every frame is one
+        window, served lock-step with its leftovers behind it."""
         clock = pin_engine_clock(monkeypatch)
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4)
-        twin = _engine()
+        ip, sp, cp = _serve(_engine(max_width), pipeline_depth=3,
+                            pipeline_scan=4)
+        twin = _engine(max_width)
         c2 = PeerLinkClient(f"127.0.0.1:{sp.port}")
         rng = np.random.default_rng(88)
         try:
@@ -346,11 +354,13 @@ class TestDifferentialV2:
 
 
 class TestDrainsAndLeaks:
-    def test_clean_drain_on_close_v2(self):
+    @pytest.mark.parametrize("max_width", [16, 64])
+    def test_clean_drain_on_close_v2(self, max_width):
         """Close racing live v2 traffic: every caller completes or gets
         PeerLinkError — never a hang — and neither side leaks partial
-        state."""
-        eng = _engine()
+        state. At width 64 every frame is one window, served lock-step
+        inside its pull."""
+        eng = _engine(max_width)
         ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4)
         cli = PeerLinkClient(f"127.0.0.1:{sp.port}")
         errs, done = [], []
