@@ -36,6 +36,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from gubernator_tpu.obs import witness
+from gubernator_tpu.obs.profile import Profiler
 from gubernator_tpu.models.keyspace import KeyDirectory
 from gubernator_tpu.models.prep import (
     WorkItem,
@@ -89,6 +90,7 @@ from gubernator_tpu.types import (
 from gubernator_tpu.utils.interval import millisecond_now
 from gubernator_tpu.utils.platform import release_compile_memory
 
+from gubernator_tpu import native
 from gubernator_tpu.native import PREP_OVERCOMMIT
 
 # lanes the sharded native fast path must hand to the python pipeline:
@@ -323,7 +325,6 @@ class ShardedEngine:
         if store is not None:
             self._gather = make_gather_sharded(self.plan)
             self._inject = make_inject_sharded(self.plan, donate=donate)
-        from gubernator_tpu import native
         from gubernator_tpu.native import make_key_directory
 
         self.directories = [
@@ -376,13 +377,23 @@ class ShardedEngine:
             "global_evictions": 0,
             "global_registry_fallbacks": 0,
             "lean_windows": 0,  # windows shipped on the 4 B/lane wire
+            # Σ over windows of the fullest shard's lanes: that shard sets
+            # the launch's padded width, so lanes_max x shards / requests
+            # is how unevenly the windows split (1.0 = evenly)
+            "lanes_max": 0,
         }
         # per-stage wall clocks, same contract as models/engine.py
-        # EngineStats (exposed as engine_stage_seconds_total in /metrics)
+        # EngineStats (exposed as engine_stage_seconds_total in /metrics).
+        # On the native paths prep_ns is the C route + lookup alone and
+        # pack_ns the Python pack; together they are the `prep` phase.
         from gubernator_tpu.models.engine import EngineStats
 
         for s in EngineStats.STAGES:
             self.stats[f"{s}_ns"] = 0
+        # the cycle profiler (obs/profile.py), stamped where Engine stamps
+        # its own and under the same phase names: the Instance, the
+        # combiner, /metrics and the capture's host spans take it from here
+        self.profiler = Profiler()
 
         if loader is not None:
             self.load_snapshot(loader.load())
@@ -570,29 +581,25 @@ class ShardedEngine:
         (native/keydir.cpp keydir_prep_route_sharded). Leftover lanes —
         invalid, gregorian, GLOBAL, duplicate occurrences — run through the
         python pipeline AFTER this round (same per-key order contract as
-        Engine._fast_window)."""
+        Engine._fast_window, and its stamps: lock_wait at site
+        `fast_window`, prep, dispatch, readback and demux, all under the
+        lock)."""
+        seams, tq = self._stamp_lock_wait()
         with self._lock:
-            t0 = time.perf_counter_ns()  # excludes the lock wait
+            t0 = self._stamp_lock_held("fast_window", tq, seams)
             n0, cols, lane_item, owner_count, leftover = self._prep_fast(
                 self.directories, requests, _SLOW_MASK)
-            if n0 == PREP_OVERCOMMIT:
-                self._raise_overcommit()
             if n0 < 0:
+                seams(None)
+                if n0 == PREP_OVERCOMMIT:
+                    self._raise_overcommit()
                 return None
-            t1 = time.perf_counter_ns()
-            self.stats["prep_ns"] += t1 - t0
-            self.stats["requests"] += n0
-            self.stats["batches"] += 1
+            out, placed = self._pack_and_decide(
+                n0, cols, lane_item, owner_count, now_ms, t0, seams)
             responses: List[Optional[RateLimitResp]] = [None] * len(requests)
             if n0:
-                out, placed = self._pack_and_decide(
-                    cols, lane_item, owner_count, now_ms, t1)
-                t3 = time.perf_counter_ns()
-                out = self._fetch_mesh(out)  # readback sync
-                t4 = time.perf_counter_ns()
-                self.stats["device_ns"] += t4 - t3
-                self._demux(out, placed, responses)
-                self.stats["demux_ns"] += time.perf_counter_ns() - t4
+                self._book_collect(
+                    *self._collect(out, placed, self._demux, responses))
         if len(leftover):
             idxs = leftover.tolist()
             tail = self._slow_window(
@@ -605,7 +612,7 @@ class ShardedEngine:
 
     def supports_columnar(self) -> bool:
         """True when the zero-object columnar serving path is available
-        (models/engine.py Engine.supports_columnar's mesh twin)."""
+        (Engine.supports_columnar's mesh twin; nothing is stamped here)."""
         return self._prep_fast is not None and self.store is None
 
     def submit_columnar(self, n: int, keys, key_off, name_len, hits, limit,
@@ -615,32 +622,28 @@ class ShardedEngine:
         to owner shards in one GIL-free C pass
         (native/keydir.cpp keydir_prep_route_columnar) and decide in one
         shard_map'ped launch. Same contract as Engine.submit_columnar —
-        the peerlink server drives either backend through it."""
+        the peerlink server drives either backend through it — and the
+        same stamps: lock_wait at site `submit_columnar`, prep (route +
+        pack here, lookup + pack there) and dispatch."""
         if not 0 < n <= self.max_width:
             return None
         if now_ms is None:
             now_ms = millisecond_now()
-        from gubernator_tpu import native
-
+        seams, tq = self._stamp_lock_wait()
         with self._lock:
-            t0 = time.perf_counter_ns()
+            t0 = self._stamp_lock_held("submit_columnar", tq, seams)
             n0, cols, lane_item, owner_count, leftover = \
                 native.prep_route_columnar(
                     self.directories, n, keys, key_off, name_len, hits,
                     limit, duration, algorithm, behavior,
                     slow_mask | _SLOW_MASK)
-            if n0 == PREP_OVERCOMMIT:
-                self._raise_overcommit()
             if n0 < 0:
+                seams(None)
+                if n0 == PREP_OVERCOMMIT:
+                    self._raise_overcommit()
                 return None
-            t1 = time.perf_counter_ns()
-            self.stats["prep_ns"] += t1 - t0
-            self.stats["requests"] += n0
-            self.stats["batches"] += 1
-            out, placed = None, []
-            if n0:
-                out, placed = self._pack_and_decide(
-                    cols, lane_item, owner_count, now_ms, t1)
+            out, placed = self._pack_and_decide(
+                n0, cols, lane_item, owner_count, now_ms, t0, seams)
         return (out, placed, leftover, n0)
 
     def _raise_overcommit(self):
@@ -649,17 +652,57 @@ class ShardedEngine:
             f">{self.plan.capacity_per_shard} distinct keys on one shard "
             "in one lookup")
 
-    def _pack_and_decide(self, cols, lane_item, owner_count, now_ms, t1):
+    # ---- one window's stamps, at Engine's seams and under its names
+    # (obs/profile.py PHASES). Every native entry goes _stamp_lock_wait ->
+    # _stamp_lock_held -> _pack_and_decide on the launching side and
+    # _collect -> _book_collect on the collecting side; each clock read
+    # feeds the profiler's phase and the stats["*_ns"] timer that share it.
+
+    def _stamp_lock_wait(self):
+        """(seams, tq) of a window about to take the engine lock: the
+        capture's span chain, opened on `lock_wait`, and the clock the
+        wait runs from (0 with the profiler off)."""
+        prof = self.profiler
+        seams = prof.seams()  # host spans, while a capture runs
+        tq = time.perf_counter_ns() if prof.enabled else 0
+        seams("lock_wait")
+        return seams, tq
+
+    def _stamp_lock_held(self, site: str, tq: int, seams) -> int:
+        """First thing under the engine lock: books the wait under `site`,
+        opens the `prep` span and returns the clock prep runs from (it
+        excludes the lock wait)."""
+        t0 = time.perf_counter_ns()
+        if tq:
+            self.profiler.lock_wait(site, t0 - tq)
+        seams("prep")
+        return t0
+
+    def _pack_and_decide(self, n0, cols, lane_item, owner_count, now_ms,
+                         t0, seams):
         """Pack owner-major staging cols into the [R,S,9,w] mesh buffer
         and dispatch one shard_map'ped window — the ONE copy of the mesh
         packing contract, shared by the object and columnar fast paths.
         Returns (_dispatch_mesh handle, placed) with placed rows
-        (r, s, None, lanes); readback via _fetch_mesh. Caller holds the
-        lock; `t1` is the pack-start clock; pack/rounds/dispatch stats
-        recorded here, readback+demux by the caller."""
+        (r, s, None, lanes), or (None, []) when no lane was routed;
+        readback via _collect. Caller holds the lock and has just routed
+        the window's `n0` lanes: `t0` is _stamp_lock_held's clock and
+        `seams` its span chain, closed here. Counted and stamped here:
+        requests, batches, rounds, lanes_max; prep_ns (the route), pack_ns
+        and the `prep` phase over both; device_ns and `dispatch`."""
+        t1 = time.perf_counter_ns()
+        prof = self.profiler
+        self.stats["prep_ns"] += t1 - t0
+        self.stats["requests"] += n0
+        self.stats["batches"] += 1
+        if not n0:
+            prof.observe("prep", t1 - t0)
+            seams(None)
+            return None, []
         R, S = self.plan.n_regions, self.plan.n_shards
         counts = owner_count.tolist()
-        w = bucket_width(max(counts), self.min_width, self.max_width)
+        fullest = max(counts)
+        w = bucket_width(fullest, self.min_width, self.max_width)
         packed = np.zeros((R, S, 9, w), np.int64)
         packed[:, :, 0, :] = -1
         placed = []
@@ -675,43 +718,84 @@ class ShardedEngine:
         t2 = time.perf_counter_ns()
         self.stats["pack_ns"] += t2 - t1
         self.stats["rounds"] += 1
+        self.stats["lanes_max"] += fullest
+        prof.observe("prep", t2 - t0)
+        seams("dispatch")
         handle = self._dispatch_mesh(packed, now_ms)
-        self.stats["device_ns"] += time.perf_counter_ns() - t2
+        td = time.perf_counter_ns()
+        seams(None)
+        self.stats["device_ns"] += td - t2
+        prof.observe("dispatch", td - t2)
         return handle, placed
+
+    def _collect(self, out, placed, demux, *into):
+        """Block on one dispatched mesh window (the device sync for THIS
+        window) and hand its rows to `demux(rows, placed, *into)`,
+        _scatter_columns or _demux, which returns its OVER_LIMIT count.
+        Stamps `readback` and `demux`; needs no lock. Returns what
+        _book_collect counts under it."""
+        prof = self.profiler
+        seams = prof.seams()
+        seams("readback")
+        t0 = time.perf_counter_ns()
+        rows = self._fetch_mesh(out)
+        t1 = time.perf_counter_ns()
+        seams("demux")
+        over = demux(rows, placed, *into)
+        t2 = time.perf_counter_ns()
+        seams(None)
+        prof.observe("readback", t1 - t0)
+        prof.observe("demux", t2 - t1)
+        return over, t1 - t0, t2 - t1
+
+    def _book_collect(self, over: int, readback_ns: int,
+                      demux_ns: int) -> None:
+        """Count one collected window. Caller holds the engine lock:
+        completers run concurrently and the counters stay exact."""
+        self.stats["over_limit"] += over
+        self.stats["device_ns"] += readback_ns
+        self.stats["demux_ns"] += demux_ns
+
+    @staticmethod
+    def _scatter_columns(rows, placed, o_st, o_li, o_re, o_rs) -> int:
+        """The columnar demux: each owner block's response rows to their
+        item positions in the caller's columns."""
+        over_status = int(Status.OVER_LIMIT)
+        over = 0
+        for r_, s_, _k, lanes in placed:
+            blk = rows[r_, s_]
+            cnt = len(lanes)
+            li = np.asarray(lanes, np.int64)
+            o_st[li] = blk[0, :cnt]
+            o_li[li] = blk[1, :cnt]
+            o_re[li] = blk[2, :cnt]
+            o_rs[li] = blk[3, :cnt]
+            over += int(np.count_nonzero(blk[0, :cnt] == over_status))
+        return over
 
     def complete_columnar(self, handle, out_status, out_limit,
                           out_remaining, out_reset) -> np.ndarray:
         """Read back a submitted mesh window and scatter the owner blocks'
         response rows to their item positions. Returns leftover indices
-        (run them through the request-object path AFTER this round)."""
+        (run them through the request-object path AFTER this round).
+        Stamps readback and demux outside the lock, as
+        Engine.complete_columnar."""
         out, placed, leftover, n0 = handle
         if n0:
-            t0 = time.perf_counter_ns()
-            rows = self._fetch_mesh(out)  # device sync for THIS window
-            t1 = time.perf_counter_ns()
-            over = 0
-            for r_, s_, _k, lanes in placed:
-                blk = rows[r_, s_]
-                cnt = len(lanes)
-                li = np.asarray(lanes, np.int64)
-                out_status[li] = blk[0, :cnt]
-                out_limit[li] = blk[1, :cnt]
-                out_remaining[li] = blk[2, :cnt]
-                out_reset[li] = blk[3, :cnt]
-                over += int(np.count_nonzero(
-                    blk[0, :cnt] == int(Status.OVER_LIMIT)))
-            t2 = time.perf_counter_ns()
-            with self._lock:  # concurrent completers: counters stay exact
-                self.stats["over_limit"] += over
-                self.stats["device_ns"] += t1 - t0
-                self.stats["demux_ns"] += t2 - t1
+            booked = self._collect(
+                out, placed, self._scatter_columns, out_status, out_limit,
+                out_remaining, out_reset)
+            with self._lock:
+                self._book_collect(*booked)
         return leftover
 
     # ------------------------------------------- pipelined columnar serving
     # Mesh twin of Engine.launch_columnar_windows (models/engine.py has
     # the full ordering argument): one shard_map launch per window, no
     # readback between launches, group cut on the first window that
-    # yields leftovers.
+    # yields leftovers. Shared stamps: per window lock_wait at site
+    # `launch_columnar_windows`, prep and dispatch on the launch; readback
+    # and demux on the collect.
 
     def launch_columnar_windows(self, windows, slow_mask: int,
                                 now_ms: Optional[int] = None, staging=None):
@@ -729,20 +813,22 @@ class ShardedEngine:
             return None
         if now_ms is None:
             now_ms = millisecond_now()
-        from gubernator_tpu import native
-
         metas = []
         failed = None
         for k, wc in enumerate(windows):
             (n, keys, key_off, name_len, hits, limit, duration,
              algorithm, behavior) = wc
+            seams, tq = self._stamp_lock_wait()
             with self._lock:
-                t0 = time.perf_counter_ns()
+                t0 = self._stamp_lock_held("launch_columnar_windows", tq,
+                                           seams)
                 n0, cols, lane_item, owner_count, leftover = \
                     native.prep_route_columnar(
                         self.directories, n, keys, key_off, name_len,
                         hits, limit, duration, algorithm, behavior,
                         slow_mask | _SLOW_MASK)
+                if n0 < 0:
+                    seams(None)
                 if n0 == PREP_OVERCOMMIT:
                     # earlier windows already dispatched; this one and the
                     # rest are not consumed (caller error-fills them)
@@ -758,14 +844,8 @@ class ShardedEngine:
                     metas.append((0, None, [],
                                   np.arange(n, dtype=np.int32)))
                     break
-                t1 = time.perf_counter_ns()
-                self.stats["prep_ns"] += t1 - t0
-                self.stats["requests"] += n0
-                self.stats["batches"] += 1
-                out, placed = None, []
-                if n0:
-                    out, placed = self._pack_and_decide(
-                        cols, lane_item, owner_count, now_ms, t1)
+                out, placed = self._pack_and_decide(
+                    n0, cols, lane_item, owner_count, now_ms, t0, seams)
                 metas.append((n0, out, placed, leftover))
             if len(leftover):
                 break  # group-cut barrier: leftovers retire first
@@ -776,38 +856,24 @@ class ShardedEngine:
         order) and scatter each window's owner blocks into the caller's
         column buffers. Same contract as Engine.collect_columnar_windows."""
         metas, _failed = handle
-        over_status = int(Status.OVER_LIMIT)
         leftovers = []
-        for (n0, out, placed, leftover), (o_st, o_li, o_re, o_rs) in zip(
-                metas, outs):
+        for (n0, out, placed, leftover), cols in zip(metas, outs):
             if n0:
-                t0 = time.perf_counter_ns()
-                rows = self._fetch_mesh(out)  # device sync, THIS window
-                t1 = time.perf_counter_ns()
-                over = 0
-                for r_, s_, _k, lanes in placed:
-                    blk = rows[r_, s_]
-                    cnt = len(lanes)
-                    li = np.asarray(lanes, np.int64)
-                    o_st[li] = blk[0, :cnt]
-                    o_li[li] = blk[1, :cnt]
-                    o_re[li] = blk[2, :cnt]
-                    o_rs[li] = blk[3, :cnt]
-                    over += int(np.count_nonzero(
-                        blk[0, :cnt] == over_status))
-                t2 = time.perf_counter_ns()
-                with self._lock:  # counters stay exact under concurrency
-                    self.stats["over_limit"] += over
-                    self.stats["device_ns"] += t1 - t0
-                    self.stats["demux_ns"] += t2 - t1
+                booked = self._collect(out, placed, self._scatter_columns,
+                                       *cols)
+                with self._lock:
+                    self._book_collect(*booked)
             leftovers.append(leftover)
         return leftovers
 
     # ----------------------------------------------------- pipelined serving
     # Launch/collect split for the combiner's depth-N pipeline
-    # (models/engine.py has the single-chip twin and the ordering
-    # argument). Mesh groups launch one shard_map window per member —
-    # still zero readbacks between launches, so depth cycles overlap.
+    # (models/engine.py has the single-chip twin, Engine.launch_windows /
+    # collect_windows, and the ordering argument). Mesh groups launch one
+    # shard_map window per member — still zero readbacks between
+    # launches, so depth cycles overlap. Shared stamps: per window
+    # lock_wait at site `launch_windows`, prep and dispatch on the launch;
+    # readback and demux on the collect.
 
     def supports_pipeline(self) -> bool:
         """True when the non-blocking launch/collect split is available
@@ -830,26 +896,22 @@ class ShardedEngine:
         meta = []
         tails = []
         for wk in windows:
+            seams, tq = self._stamp_lock_wait()
             with self._lock:
-                t0 = time.perf_counter_ns()
+                t0 = self._stamp_lock_held("launch_windows", tq, seams)
                 n0, cols, lane_item, owner_count, leftover = self._prep_fast(
                     self.directories, wk, _SLOW_MASK)
-                if n0 == PREP_OVERCOMMIT:
-                    self._raise_overcommit()
                 if n0 < 0:
+                    seams(None)
+                    if n0 == PREP_OVERCOMMIT:
+                        self._raise_overcommit()
                     # defensive: nothing committed for THIS window — it
                     # retires whole through the python tail below
                     n0, out, placed = 0, None, []
                     leftover = np.arange(len(wk), dtype=np.int32)
                 else:
-                    t1 = time.perf_counter_ns()
-                    self.stats["prep_ns"] += t1 - t0
-                    self.stats["requests"] += n0
-                    self.stats["batches"] += 1
-                    out, placed = (None, [])
-                    if n0:
-                        out, placed = self._pack_and_decide(
-                            cols, lane_item, owner_count, now_ms, t1)
+                    out, placed = self._pack_and_decide(
+                        n0, cols, lane_item, owner_count, now_ms, t0, seams)
                 meta.append((n0, out, placed, leftover))
             # Leftover tails retire NOW — after this window's dispatch,
             # BEFORE the next window preps — so a key pending in the tail
@@ -867,20 +929,16 @@ class ShardedEngine:
     def collect_windows(self, handle):
         """Block on a launched group's readbacks (in launch order) and
         demux: one response list per window. Runs outside the engine lock
-        except for the demux counter updates."""
+        except for the counter updates."""
         windows, meta, tails = handle
         results = []
         for k, wk in enumerate(windows):
             n0, out, placed, leftover = meta[k]
             responses: List[Optional[RateLimitResp]] = [None] * len(wk)
             if n0:
-                t0 = time.perf_counter_ns()
-                rows = self._fetch_mesh(out)  # device sync, THIS window
-                t1 = time.perf_counter_ns()
-                with self._lock:  # _demux mutates the stats counters
-                    self.stats["device_ns"] += t1 - t0
-                    self._demux(rows, placed, responses)
-                    self.stats["demux_ns"] += time.perf_counter_ns() - t1
+                booked = self._collect(out, placed, self._demux, responses)
+                with self._lock:
+                    self._book_collect(*booked)
             tail = tails[k]
             if tail is not None:
                 for i, resp in zip(leftover.tolist(), tail):
@@ -910,7 +968,12 @@ class ShardedEngine:
         t0 = time.perf_counter_ns()
         responses, rounds, n_errors = preprocess(requests, now_ms)
         prep_ns = time.perf_counter_ns() - t0  # excludes the lock wait below
+        prof = self.profiler
+        prof.observe("prep", prep_ns)
+        tq = time.perf_counter_ns() if prof.enabled else 0
         with self._lock:
+            if tq:
+                prof.lock_wait("slow_window", time.perf_counter_ns() - tq)
             self.stats["prep_ns"] += prep_ns
             self.stats["requests"] += len(requests)
             self.stats["batches"] += 1 if count_batch else 0
@@ -1121,6 +1184,7 @@ class ShardedEngine:
 
         `pre`, when given, maps owner -> (slots, fresh) already resolved by
         the caller (the Store path looks keys up before read-through)."""
+        self.stats["lanes_max"] += max(map(len, lanes))
         for owner, items in enumerate(lanes):
             if not items:
                 continue
@@ -1140,24 +1204,27 @@ class ShardedEngine:
             # order, so the group carries just the response indices
             placed.append((r_, s_, k, [item[0] for item in items]))
 
-    def _demux(self, out, placed, responses) -> None:
+    @staticmethod
+    def _demux(out, placed, responses) -> int:
         """Demux one readback buffer into responses.
 
         `placed` rows are (r, s, k, [resp indices]) — one group per owner
         lane-run, lanes 0..n-1 in index order; k is None outside the scan
         path. Response row order is decide_packed's output contract. One
-        C-level tolist per group beats four per-element int() casts."""
-        over = int(Status.OVER_LIMIT)
+        C-level tolist per group beats four per-element int() casts.
+        Returns the OVER_LIMIT answers, which the caller counts under the
+        engine lock."""
+        over_status = int(Status.OVER_LIMIT)
+        over = 0
         for r_, s_, k, idxs in placed:
             row = out[r_, s_] if k is None else out[r_, s_, k]
             status, limit, remaining, reset = row[:, :len(idxs)].tolist()
+            over += status.count(over_status)
             for j, i in enumerate(idxs):
-                st = status[j]
-                if st == over:
-                    self.stats["over_limit"] += 1
                 responses[i] = RateLimitResp(
-                    status=st, limit=limit[j], remaining=remaining[j],
-                    reset_time=reset[j])
+                    status=status[j], limit=limit[j],
+                    remaining=remaining[j], reset_time=reset[j])
+        return over
 
     @staticmethod
     def _row_snapshot(rows, r_: int, s_: int, j: int, key: str):
@@ -1230,6 +1297,7 @@ class ShardedEngine:
                 self._apply_round(group[0], now_ms, responses,
                                   pre=window_pre(lanes), lanes=lanes)
                 continue
+            t_prep = time.perf_counter_ns()
             k_pad = _bucket_pow2(len(group))
             packed = np.zeros((R, S, k_pad, 9, w), np.int64)
             packed[:, :, :, 0, :] = -1  # vacant lanes (incl. pad windows)
@@ -1239,12 +1307,8 @@ class ShardedEngine:
                 self._pack_lanes(lanes, w, packed, placed, k,
                                  pre=window_pre(lanes))
 
-            t = time.perf_counter_ns()
-            out = self._fetch_mesh(self._dispatch_mesh_scan(packed, now_ms))
-            t2 = time.perf_counter_ns()
-            self.stats["device_ns"] += t2 - t
-            self._demux(out, placed, responses)
-            self.stats["demux_ns"] += time.perf_counter_ns() - t2
+            self._decide_and_demux(self._dispatch_mesh_scan, packed, now_ms,
+                                   placed, responses, t_prep)
 
         if store_ctx is not None:
             per_owner, slotmat = store_ctx
@@ -1253,9 +1317,10 @@ class ShardedEngine:
     # -------------------------------------------------- staging dispatch
     # Every mesh window funnels through these helpers so the wide/lean
     # wire-format switch lives in one place (models/engine.py has the
-    # single-chip twin). The handle defers the device sync: the columnar
-    # path reads it back in complete_columnar, everyone else via
-    # _fetch_mesh immediately.
+    # single-chip twin, Engine._dispatch_staged / _fetch_staged; neither
+    # side stamps inside them, the callers do). The handle defers the
+    # device sync: the columnar path reads it back in complete_columnar,
+    # everyone else via _fetch_mesh immediately.
 
     def _dispatch_mesh(self, packed: np.ndarray, now_ms):
         """One wide i64[R,S,9,w] window, shipped on the 4 B/lane lean
@@ -1294,6 +1359,28 @@ class ShardedEngine:
             return widen_compact_out(np.asarray(out), lean_now)
         return np.asarray(out)
 
+    def _decide_and_demux(self, dispatch, packed, now_ms, placed, responses,
+                          t_prep: int) -> None:
+        """The python pipeline's launch of one packed buffer through
+        `dispatch` (_dispatch_mesh or _dispatch_mesh_scan), its readback
+        and its demux into `responses`, stamped as Engine._apply_round
+        stamps its own: routing, lookup and pack since `t_prep` are `prep`.
+        Caller holds the engine lock."""
+        prof = self.profiler
+        t = time.perf_counter_ns()
+        handle = dispatch(packed, now_ms)
+        td = time.perf_counter_ns()
+        out = self._fetch_mesh(handle)
+        t2 = time.perf_counter_ns()
+        self.stats["over_limit"] += self._demux(out, placed, responses)
+        t3 = time.perf_counter_ns()
+        self.stats["device_ns"] += t2 - t
+        self.stats["demux_ns"] += t3 - t2
+        prof.observe("prep", t - t_prep)
+        prof.observe("dispatch", td - t)
+        prof.observe("readback", t2 - td)
+        prof.observe("demux", t3 - t2)
+
     def _apply_round(self, round_work: List[WorkItem], now_ms, responses,
                      pre=None, lanes=None) -> None:
         """One window, one mesh dispatch. `pre` (owner -> (slots, fresh))
@@ -1303,6 +1390,7 @@ class ShardedEngine:
         if self.store is not None and pre is None:
             return self._apply_round_store(round_work, now_ms, responses)
         R, S = self.plan.n_regions, self.plan.n_shards
+        t_prep = time.perf_counter_ns()
         if lanes is None:
             lanes = self._route_lanes(round_work)
         w = bucket_width(
@@ -1314,13 +1402,8 @@ class ShardedEngine:
         packed[:, :, 0, :] = -1  # vacant lanes
         placed: List[Tuple[int, int, Optional[int], List[int]]] = []
         self._pack_lanes(lanes, w, packed, placed, None, pre=pre)
-
-        t = time.perf_counter_ns()
-        out = self._fetch_mesh(self._dispatch_mesh(packed, now_ms))
-        t2 = time.perf_counter_ns()
-        self.stats["device_ns"] += t2 - t
-        self._demux(out, placed, responses)
-        self.stats["demux_ns"] += time.perf_counter_ns() - t2
+        self._decide_and_demux(self._dispatch_mesh, packed, now_ms, placed,
+                               responses, t_prep)
 
     def _store_lookup_owners(self, work_items: List[WorkItem],
                              unbounded: bool = False):
@@ -1417,18 +1500,15 @@ class ShardedEngine:
         self._store_read_through_mesh(per_owner, slotmat, now_ms)
 
         # ---- decide ------------------------------------------------------
+        t_prep = time.perf_counter_ns()  # the store's own time is store_ns
         packed = np.zeros((R, S, 9, w), np.int64)
         packed[:, :, 0, :] = -1
         placed: List[Tuple[int, int, Optional[int], List[int]]] = []
         pre = {owner: (slots, fresh)
                for owner, _r, _s, _items, _keys, slots, fresh in per_owner}
         self._pack_lanes(lanes, w, packed, placed, None, pre=pre)
-        t2 = time.perf_counter_ns()
-        out = self._fetch_mesh(self._dispatch_mesh(packed, now_ms))
-        t3 = time.perf_counter_ns()
-        self.stats["device_ns"] += t3 - t2
-        self._demux(out, placed, responses)
-        self.stats["demux_ns"] += time.perf_counter_ns() - t3
+        self._decide_and_demux(self._dispatch_mesh, packed, now_ms, placed,
+                               responses, t_prep)
 
         self._store_write_through_mesh(per_owner, slotmat, now_ms)
 

@@ -248,10 +248,10 @@ class Instance:
 
             conf.backend = Engine()
         self.backend = conf.backend
-        # continuous profiling plane (obs/profile.py): the Engine carries
-        # its own profiler; backends without one (sharded, stubs) get an
-        # Instance-level fallback so the endpoints and debug sections are
-        # wired on every deployment shape. conf.profile_enabled None
+        # continuous profiling plane (obs/profile.py): Engine and
+        # ShardedEngine carry their own profiler; backends without one
+        # (stubs) get an Instance-level fallback so the endpoints and
+        # debug sections are wired on every deployment shape. conf.profile_enabled None
         # defers to GUBER_PROFILE; an explicit bool overrides the env.
         from gubernator_tpu.obs.profile import Profiler
 
