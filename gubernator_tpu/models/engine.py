@@ -31,7 +31,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from gubernator_tpu.obs import witness
-from gubernator_tpu.models.keyspace import KeyDirectory, resolve_slots
+from gubernator_tpu.models.keyspace import (
+    KeyDirectory,
+    peek_slots,
+    resolve_slots,
+)
 from gubernator_tpu.models.prep import (
     bucket_pow2 as _bucket_pow2,
     bucket_width as _bucket_width,
@@ -1247,6 +1251,21 @@ class Engine:
         path. Slots without a live directory entry (recycled mid-window)
         are simply absent from the result."""
         return resolve_slots(self.directory, slots)
+
+    def peek_slots(self, keys) -> np.ndarray:
+        """The other way round (models/keyspace.py peek_slots): int64 slots
+        for a list of hash-keys or a packed arena of them, -1 where the
+        directory holds no such key, recency untouched. The ledger audit
+        asks this for the keys it tracks, so its cost is theirs and not
+        the slots a tick drained."""
+        return peek_slots(self.directory, keys)
+
+    def slots_live(self, slots) -> np.ndarray:
+        """bool per slot: does the directory hold a key there right now.
+        What the ledger audit needs of a slot whose key it does not track:
+        lost (`unattributed_hits`) or merely untracked (`key_overflow`),
+        and no name."""
+        return self.directory.slots_live(slots)
 
     def device_hit_counts(self, keys) -> dict:
         """Per-key lifetime attempt counters from device row field 7

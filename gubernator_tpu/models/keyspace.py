@@ -19,6 +19,8 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from gubernator_tpu.native import pack_keys, unpack_keys
+
 
 class KeyDirectory:
     """LRU map key -> slot over a fixed slot capacity."""
@@ -104,6 +106,11 @@ class KeyDirectory:
         """Slot for key without recency effects; -1 if absent."""
         return self._map.get(key, -1)
 
+    def slots_live(self, slots) -> np.ndarray:
+        """Native-API twin: bool[n], which of `slots` hold a key."""
+        held = np.fromiter(self._map.values(), np.int64, count=len(self._map))
+        return np.isin(np.asarray(slots, np.int64), held)
+
 
 def resolve_slots(directory, slots) -> Dict[int, str]:
     """slot -> key for the slots that hold a key in `directory` right now;
@@ -127,12 +134,24 @@ def resolve_slots(directory, slots) -> Dict[int, str]:
     if hasattr(directory, "keys_for_slots"):
         blob, off = directory.keys_for_slots(want)
         live = np.flatnonzero(off[1:] > off[:-1])
-        bounds = zip(off[live].tolist(), off[live + 1].tolist())
-        if blob.isascii():  # one decode, then slices: bytes are characters
-            text = blob.decode("ascii")
-            keys = [text[lo:hi] for lo, hi in bounds]
-        else:
-            keys = [blob[lo:hi].decode("utf-8") for lo, hi in bounds]
-        return dict(zip(want[live].tolist(), keys))
+        return dict(zip(want[live].tolist(), unpack_keys(blob, off, live)))
     asked = set(want.tolist())
     return {int(s): key for key, s in directory.items() if int(s) in asked}
+
+
+def peek_slots(directory, keys) -> np.ndarray:
+    """key -> slot (int64, -1 where `directory` holds no such key), with no
+    effect on recency. `keys` is a sequence of str or a packed arena
+    `(blob, offsets)` as `native.pack_keys` makes: a caller that asks for
+    the same keys again and again (the ledger audit, for its tracked keys
+    every tick) packs them once. The native directory answers an arena in
+    one C pass with the GIL released; the Python twin is asked key by
+    key."""
+    packed = isinstance(keys, tuple)
+    if hasattr(directory, "peek_slots_raw"):
+        blob, offsets = keys if packed else pack_keys(keys)
+        return directory.peek_slots_raw(blob, offsets).astype(np.int64)
+    if packed:
+        keys = unpack_keys(*keys)
+    return np.fromiter(map(directory.peek_slot, keys), np.int64,
+                       count=len(keys))

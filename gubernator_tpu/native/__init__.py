@@ -160,6 +160,10 @@ def load_library() -> ctypes.CDLL:
             c.c_void_p, c.c_void_p, c.c_int64, c.c_void_p, c.c_int64,
             c.c_void_p,
         ]
+        lib.keydir_slots_live.restype = None
+        lib.keydir_slots_live.argtypes = [
+            c.c_void_p, c.c_void_p, c.c_int64, c.c_void_p,
+        ]
         lib.keydir_size.restype = c.c_int64
         lib.keydir_size.argtypes = [c.c_void_p]
         lib.keydir_evictions.restype = c.c_int64
@@ -597,7 +601,7 @@ def available() -> bool:
         return False
 
 
-def _pack_keys(keys: Sequence[str]) -> Tuple[bytes, np.ndarray]:
+def pack_keys(keys: Sequence[str]) -> Tuple[bytes, np.ndarray]:
     """Concatenate utf-8 keys; offsets[n+1] int64.
 
     Fast path: one join + one encode; when the result is pure ASCII,
@@ -616,11 +620,24 @@ def _pack_keys(keys: Sequence[str]) -> Tuple[bytes, np.ndarray]:
     return data, offsets
 
 
+def unpack_keys(data: bytes, offsets: np.ndarray, which=None) -> List[str]:
+    """pack_keys read back: the keys of a packed arena, or only those at
+    the indices `which`."""
+    lo, hi = offsets[:-1], offsets[1:]
+    if which is not None:
+        lo, hi = lo[which], hi[which]
+    bounds = zip(lo.tolist(), hi.tolist())
+    if data.isascii():  # one decode, then slices: bytes are characters
+        text = data.decode("ascii")
+        return [text[a:b] for a, b in bounds]
+    return [data[a:b].decode("utf-8") for a, b in bounds]
+
+
 def fingerprint_batch(keys: Sequence[str]) -> np.ndarray:
     """63-bit nonzero key fingerprints for the device directory
     (ops/devdir.py key_fingerprint, C fast path)."""
     lib = load_library()
-    data, offsets = _pack_keys(keys)
+    data, offsets = pack_keys(keys)
     out = np.empty(len(keys), np.int64)
     lib.fnv1a_fingerprint_batch(
         data, offsets.ctypes.data, len(keys), out.ctypes.data)
@@ -631,12 +648,18 @@ def owner_batch(keys: Sequence[str], n_owners: int) -> np.ndarray:
     """fnv1a64(key) % n_owners for a key batch (native fast path of
     parallel/mesh.py shard_of_key)."""
     lib = load_library()
-    data, offsets = _pack_keys(keys)
+    data, offsets = pack_keys(keys)
     out = np.empty(len(keys), np.int32)
     lib.fnv1a_owner_batch(
         data, offsets.ctypes.data, len(keys), n_owners, out.ctypes.data
     )
     return out
+
+
+def _as_slots32(slots) -> np.ndarray:
+    """Any integers in, int32 out: what int32 cannot hold is no slot."""
+    return np.clip(np.asarray(slots, np.int64), -1,
+                   np.iinfo(np.int32).max).astype(np.int32)
 
 
 class NativeKeyDirectory:
@@ -678,7 +701,7 @@ class NativeKeyDirectory:
         """lookup() + the dirty-mirror rows (i64[m, 8]: slot + 7 row
         values) that must be scattered into the device table BEFORE the
         window these slots feed (native lone-path reconciliation)."""
-        data, offsets = _pack_keys(keys)
+        data, offsets = pack_keys(keys)
         n = len(keys)
         slots = np.empty(n, np.int32)
         fresh = np.empty(n, np.uint8)
@@ -776,9 +799,7 @@ class NativeKeyDirectory:
         answered with the key that holds it at that instant, so a slot
         recycled since the caller saw it names its new key (the dump had
         the same contract)."""
-        # any integer in, int32 out: what int32 cannot hold is no slot
-        slots = np.clip(np.asarray(slots, np.int64), -1,
-                        np.iinfo(np.int32).max).astype(np.int32)
+        slots = _as_slots32(slots)
         n = len(slots)
         offsets = np.empty(n + 1, np.int64)
         buf_cap = 48 * n + (1 << 16)
@@ -791,6 +812,16 @@ class NativeKeyDirectory:
                 return key_buf[:nbytes].tobytes(), offsets
             # -nbytes is what these slots' keys took at that instant
             buf_cap = -nbytes + (-nbytes >> 3) + (1 << 16)
+
+    def slots_live(self, slots) -> np.ndarray:
+        """bool[n]: which of `slots` hold a key right now (keydir.cpp
+        slots_live: keys_for_slots' walk and instant-by-chunk contract,
+        with no key copied)."""
+        slots = _as_slots32(slots)
+        live = np.empty(len(slots), np.uint8)
+        self._lib.keydir_slots_live(
+            self._kd, slots.ctypes.data, len(slots), live.ctypes.data)
+        return live.view(np.bool_)
 
     def peek_slots_raw(self, key_blob: bytes, offsets: np.ndarray
                        ) -> np.ndarray:

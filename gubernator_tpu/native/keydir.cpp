@@ -361,14 +361,49 @@ class KeyDir {
     // back-to-back into key_buf with offsets (n+1 entries); a free,
     // negative or out-of-range slot gets a zero-length key. Returns the
     // key bytes, or -needed_bytes when key_buf is too small (offsets are
-    // then still complete). mu_ is taken per chunk of one window's worth
-    // of slots and released between chunks, so a lookup_batch never waits
-    // behind more than one chunk; each slot is answered with the key that
-    // holds it at the instant its chunk is read.
+    // then still complete). The walk is each_slot_chunked's.
     int64_t keys_for_slots(const int32_t* slots, int64_t n, char* key_buf,
                            int64_t buf_cap, int64_t* offsets) const {
-        constexpr int64_t CHUNK = 8192;
         int64_t off = 0;
+        each_slot_chunked(slots, n, [&](int64_t i, const Entry* e) {
+            offsets[i] = off;
+            if (e == nullptr) return;
+            const int64_t len = static_cast<int64_t>(e->key.size());
+            if (off + len <= buf_cap) {
+                std::memcpy(key_buf + off, e->key.data(), e->key.size());
+            }
+            off += len;
+        });
+        offsets[n] = off;
+        return off > buf_cap ? -off : off;
+    }
+
+    // Which of the given slots hold a key right now: live[i] = 1, or 0 for
+    // a free, negative or out-of-range slot. keys_for_slots without the
+    // keys: for a caller that needs no name (the ledger audit, for every
+    // slot whose key it does not track), nothing is copied.
+    void slots_live(const int32_t* slots, int64_t n, uint8_t* live) const {
+        each_slot_chunked(slots, n, [&](int64_t i, const Entry* e) {
+            live[i] = e != nullptr;
+        });
+    }
+
+    int64_t size() const {
+        Hold g(*this);
+        return capacity_ - static_cast<int64_t>(free_.size());
+    }
+    int64_t evictions() const { return evictions_; }
+    int64_t capacity() const { return capacity_; }
+
+  private:
+    // body(i, entry of slots[i] or nullptr when no key holds it), for every
+    // i in order. mu_ is taken per chunk of one window's worth of slots and
+    // released between chunks, so a lookup_batch never waits behind more
+    // than one chunk; each slot is answered as it is at the instant its
+    // chunk is read.
+    template <typename Body>
+    void each_slot_chunked(const int32_t* slots, int64_t n, Body body) const {
+        constexpr int64_t CHUNK = 8192;
         for (int64_t lo = 0; lo < n; lo += CHUNK) {
             const int64_t hi = lo + CHUNK < n ? lo + CHUNK : n;
             // Outside the mutex: let whoever waits for it go first (see
@@ -387,29 +422,13 @@ class KeyDir {
             }
             Hold g(*this);
             for (int64_t i = lo; i < hi; ++i) {
-                offsets[i] = off;
                 const int32_t s = slots[i];
-                if (s < 0 || s >= capacity_ || !entries_[s].used) continue;
-                const std::string& k = entries_[s].key;
-                const int64_t len = static_cast<int64_t>(k.size());
-                if (off + len <= buf_cap) {
-                    std::memcpy(key_buf + off, k.data(), k.size());
-                }
-                off += len;
+                const bool held = s >= 0 && s < capacity_ && entries_[s].used;
+                body(i, held ? &entries_[s] : nullptr);
             }
         }
-        offsets[n] = off;
-        return off > buf_cap ? -off : off;
     }
 
-    int64_t size() const {
-        Hold g(*this);
-        return capacity_ - static_cast<int64_t>(free_.size());
-    }
-    int64_t evictions() const { return evictions_; }
-    int64_t capacity() const { return capacity_; }
-
-  private:
     void diag_abort(const char* where) const {
         int64_t tomb = 0, occ = 0;
         for (uint64_t i = 0; i < nbuckets_; ++i) {
@@ -646,6 +665,12 @@ int64_t keydir_keys_for_slots(void* kd, const int32_t* slots, int64_t n,
                               int64_t* offsets) {
     return static_cast<KeyDir*>(kd)->keys_for_slots(slots, n, key_buf,
                                                     buf_cap, offsets);
+}
+
+// slot -> held or not (see KeyDir::slots_live). Pure C, GIL dropped.
+void keydir_slots_live(void* kd, const int32_t* slots, int64_t n,
+                       uint8_t* live) {
+    static_cast<KeyDir*>(kd)->slots_live(slots, n, live);
 }
 
 int64_t keydir_size(void* kd) { return static_cast<KeyDir*>(kd)->size(); }

@@ -20,11 +20,16 @@ counter the `over_admission` anomaly detector gates on), not a hope.
 Hot-path contract (the PhaseHist rule from obs/profile.py): the engine's
 window paths pay O(1) per *window*, not per lane — each dispatch parks a
 handful of small numpy column copies (slot, hits, status, limit, reset)
-on a pending ring under a leaf lock. Key resolution (slot → hash-key, by
-index in the directory, once per distinct slot), bucket folding, window
+on a pending ring under a leaf lock. Attribution, bucket folding, window
 rolling, and the conservation evaluation all run in `audit()`, off the
-serving path — riding the cartographer harvest / anomaly ticker cadence —
-at a cost set by the lanes drained, not by what the directory holds.
+serving path — riding the cartographer harvest / anomaly ticker cadence.
+An audit asks the directory where the keys it tracks live (key → slot,
+one batch peek) and matches the drained lanes against those slots; of
+every other drained slot it asks only whether a key holds it. What it
+does per key is bounded by `key_capacity`, whatever a tick drained and
+whatever the directory holds; what is per lane is numpy. A slot's name
+(slot → hash-key) is bought only for a newcomer that can still get a
+bucket.
 Lone native decisions and the non-engine authorities (lease consume,
 GLOBAL cache, minted budget) record per key directly: they are already
 per-item paths.
@@ -58,18 +63,21 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import os
 import time
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from gubernator_tpu.native import pack_keys
 from gubernator_tpu.obs import witness
 from gubernator_tpu.obs.profile import background_of
 
 # v2: totals carry what the audit's attribution pass was asked
 # (slots_asked, slots_resolved, lanes_folded — cumulative).
-LEDGER_SCHEMA_VERSION = 2
+# v3: slots_named beside them (names the audit bought from the directory).
+LEDGER_SCHEMA_VERSION = 3
 
 # Attribution taxonomy (docs/observability.md "## Decision ledger" pins
 # it; renaming an authority is a schema_version bump, not a drift).
@@ -86,9 +94,9 @@ _SLACK_AUTHORITIES = ("degraded", "reshard", "global_cache")
 # log2 over-admission histogram: bucket i holds overshoots <= 2^i hits.
 _NBUCKETS = 28
 
-# _classify_locked's per-slot codes below the tracked keys' indices
-_UNRESOLVED = -1  # the directory names no key for the slot
-_UNTRACKED = -2  # a key with no bucket and no room for one
+# a drained slot's code, below the tracked keys' indices
+_UNRESOLVED = -1  # the directory holds no key at the slot
+_UNTRACKED = -2  # a live slot whose key has no bucket and no room for one
 
 _AUTHORITY: contextvars.ContextVar = contextvars.ContextVar(
     "guber_ledger_authority", default="owner")
@@ -120,6 +128,14 @@ def authority(name: str):
 
 def current_authority() -> str:
     return _AUTHORITY.get()
+
+
+def _among(sorted_slots, slots):
+    """Where each of `slots` would sit in `sorted_slots` (not empty), and
+    whether it is there."""
+    at = np.minimum(np.searchsorted(sorted_slots, slots),
+                    len(sorted_slots) - 1)
+    return at, sorted_slots[at] == slots
 
 
 class _Bucket:
@@ -161,6 +177,12 @@ class DecisionLedger:
         # off-path state: key buckets, distribution, counters
         self._lock = witness.make_lock("ledger.buckets")
         self._buckets: Dict[str, _Bucket] = {}
+        # the buckets as the audit asks for them: (key, bucket) in the
+        # order they were made (buckets are never removed, so a key's
+        # index is for life), and their keys packed for the directory's
+        # batch peek. Both trail _buckets until _tracked_arena_locked.
+        self._tracked: List[tuple] = []
+        self._arena: Optional[tuple] = None
         self._admits_total: Dict[str, int] = {}
         self._attempted_total = 0
         self._rejected_total = 0
@@ -176,8 +198,9 @@ class DecisionLedger:
         self._unattributed = 0  # hits on slots the directory lost
         # what the audit's attribution pass was asked to do (cumulative;
         # the diff between two reads is the audits in between)
-        self._slots_asked = 0  # distinct slots sent to resolve_slots
-        self._slots_resolved = 0  # of those, slots that named a key
+        self._slots_asked = 0  # distinct slots drained beside an engine
+        self._slots_resolved = 0  # of those, slots that hold a key
+        self._slots_named = 0  # of those, slots whose key's name was bought
         self._lanes_folded = 0  # decision lanes drained from the ring
         self._audits = 0
         self._last_audit = 0.0
@@ -395,9 +418,9 @@ class DecisionLedger:
     # set of columns over all of them held ~200 MB at once, in arrays glibc
     # serves from the heap and keeps (the daemon's resident set +460 MB
     # against the same daemon at 108k; PERF.md PR 29). Half a million lanes
-    # is a whole tick at 100k decisions/s. What is done per distinct slot
-    # (naming it, testing its key against the buckets) is still done once
-    # a tick.
+    # is a whole tick at 100k decisions/s. What is asked of the directory
+    # (where the tracked keys live, which distinct slots hold a key) is
+    # still asked once a tick.
     _FOLD_LANES = 1 << 19
 
     def _parts(self, pending):
@@ -419,20 +442,22 @@ class DecisionLedger:
             now_ms = int(time.time() * 1000)
         with self._pending_lock:
             pending, self._pending = self._pending, []
-        uniq, first = self._pending_slots(pending)
-        resolved: Dict[int, str] = {}
+        # buckets are never removed: no room now is no room at the fold
+        has_room = len(self._buckets) < self.key_capacity
+        uniq, first = self._pending_slots(pending, ranked=has_room)
+        asked = None
         if len(uniq) and engine is not None:
             try:
                 with background_of(engine, "ledger.resolve_slots"):
-                    resolved = engine.resolve_slots(uniq)
+                    asked = self._ask_directory(engine, uniq, first)
             except Exception:  # noqa: BLE001 — audit never raises
-                resolved = {}
+                asked = None
             self._slots_asked += len(uniq)
         with self._lock:
             if len(uniq):
-                code, tracked = self._classify_locked(uniq, first, resolved)
+                where = self._attribute_locked(uniq, asked)
                 for part in self._parts(pending):
-                    self._fold_lanes_locked(part, uniq, code, tracked)
+                    self._fold_lanes_locked(part, *where)
             for key, b in list(self._buckets.items()):
                 if b.window and (force or b.window <= now_ms):
                     self._roll_locked(key, b)
@@ -444,74 +469,130 @@ class DecisionLedger:
                 report["ground_truth"] = dict(self._ground_truth)
         return report
 
-    def _pending_slots(self, pending):
-        """The drained ring's distinct slots, sorted, and for each the
-        arrival rank of its first lane among the lanes that carry a slot
-        (padding lanes, slot -1, dropped). Both empty when no lane does.
-        Found a part at a time and merged: np.unique with return_index
-        sorts stably, so a slot's first part is the one kept."""
+    def _pending_slots(self, pending, ranked: bool):
+        """The drained ring's distinct slots, sorted, and (`ranked`: only
+        while a newcomer can still get a bucket) for each the arrival rank
+        of its first lane among the lanes that carry a slot (padding lanes,
+        slot -1, dropped). Both empty when no lane does. Found a part at a
+        time and merged: np.unique with return_index sorts stably, so a
+        slot's first part is the one kept."""
         uniqs, firsts, seen = [], [], 0
         for part in self._parts(pending):
             slots = np.concatenate([sh[0] for sh, _resp, _auth in part])
             slots = slots[slots >= 0]
-            u, f = np.unique(slots, return_index=True)
+            if ranked:
+                u, f = np.unique(slots, return_index=True)
+                firsts.append(f + seen)
+            else:
+                u = np.unique(slots)
             uniqs.append(u)
-            firsts.append(f + seen)
             seen += slots.size
         if not seen:
             return np.empty(0, np.int64), np.empty(0, np.int64)
+        if not ranked:
+            return np.unique(np.concatenate(uniqs)), None
         uniq, at = np.unique(np.concatenate(uniqs), return_index=True)
         return uniq, np.concatenate(firsts)[at]
 
-    def _classify_locked(self, uniq, first, resolved: Dict[int, str]):
-        """Per distinct slot, once an audit: the index of its tracked key
-        in `tracked`, _UNRESOLVED, or _UNTRACKED (a key met with no room
-        left) -> (codes aligned with `uniq`, tracked). A slot is named
-        once and its key tested against the buckets once; the two passes
-        over all of them are dict lookups mapped in C, and Python walks
-        only the slots of tracked keys. While there is room, buckets go to
-        keys in the order their first lanes arrived."""
-        keys = list(map(resolved.get, uniq.tolist()))
-        held = list(map(self._buckets.get, keys))
-        code = np.full(len(uniq), _UNTRACKED, np.int64)
-        unresolved = [i for i, key in enumerate(keys) if key is None]
-        code[unresolved] = _UNRESOLVED
-        self._slots_resolved += len(uniq) - len(unresolved)
-        tracked: List[tuple] = []
-        index_of: Dict[str, int] = {}
-        for i in [i for i, b in enumerate(held) if b is not None]:
-            at = index_of.get(keys[i])
-            if at is None:
-                at = index_of[keys[i]] = len(tracked)
-                tracked.append((keys[i], held[i]))
-            code[i] = at
-        if len(self._buckets) < self.key_capacity:
-            newcomers = np.flatnonzero(code == _UNTRACKED)
-            for i in newcomers[np.argsort(first[newcomers])].tolist():
-                at = index_of.get(keys[i])
-                if at is None:
-                    if len(self._buckets) >= self.key_capacity:
-                        continue
-                    b = self._buckets[keys[i]] = _Bucket()
-                    at = index_of[keys[i]] = len(tracked)
-                    tracked.append((keys[i], b))
-                code[i] = at
-        return code, tracked
+    def _tracked_arena_locked(self):
+        """(packed keys of the buckets, how many) with `_tracked` caught up
+        to `_buckets`: packed anew only when a bucket was made since."""
+        n = len(self._buckets)
+        if len(self._tracked) < n:
+            self._tracked.extend(
+                itertools.islice(self._buckets.items(), len(self._tracked),
+                                 None))
+        if self._arena is None or len(self._arena[1]) - 1 != n:
+            self._arena = pack_keys([key for key, _b in self._tracked])
+        return self._arena, n
 
-    def _fold_lanes_locked(self, part, uniq, code, tracked) -> None:
+    def _ask_directory(self, engine, uniq, first):
+        """What an audit asks the directory, outside the buckets' lock: where
+        the tracked keys live now (one batch peek, no recency effect),
+        which of the drained slots hold a key at all, and, only while a
+        newcomer can still get a bucket, the names of the first `room`
+        live slots of untracked keys by arrival. The directory is a
+        bijection, so a slot holds key k iff peek(k) is that slot: a slot
+        recycled since its decision goes to whoever holds it now, a
+        tracked key that moved is met at its new slot, one the directory
+        dropped matches no lane (models/keyspace.py resolve_slots'
+        contract, read from the keys' side). -> (codes aligned with
+        `uniq`: a tracked key's index or _UNRESOLVED or _UNTRACKED;
+        the candidates' positions in `uniq` by arrival; their names by
+        slot)."""
+        with self._lock:
+            arena, n = self._tracked_arena_locked()
+        code = np.where(engine.slots_live(uniq), _UNTRACKED, _UNRESOLVED)
+        if n:
+            # two keys can both read one slot only if it changed hands
+            # between their peeks: the first bucket keeps it this tick
+            slots, index = np.unique(engine.peek_slots(arena),
+                                     return_index=True)
+            at, drained = _among(uniq, slots)
+            code[at[drained]] = index[drained]
+        room = self.key_capacity - n
+        if room <= 0:
+            return code, (), {}
+        newcomers = np.flatnonzero(code == _UNTRACKED)
+        newcomers = newcomers[np.argsort(first[newcomers])][:room]
+        names = engine.resolve_slots(uniq[newcomers]) if len(newcomers) \
+            else {}
+        return code, newcomers.tolist(), names
+
+    def _attribute_locked(self, uniq, asked):
+        """Give the newcomers their buckets, in the order their first lanes
+        arrived and while there is room, and -> what a lane is matched
+        against: (the slots of tracked keys, sorted; the index in `tracked`
+        beside each; the slots that hold no key, sorted; tracked). A lane
+        at neither kind of slot is a live key's with no bucket. A key that
+        record_key tracked since the directory was asked is met at the
+        next audit."""
+        if asked is None:  # no engine, or its directory failed
+            return (np.empty(0, np.int64), np.empty(0, np.int64), uniq,
+                    self._tracked)
+        code, newcomers, names = asked
+        self._slots_named += len(newcomers)
+        self._tracked_arena_locked()
+        for i in newcomers:
+            key = names.get(int(uniq[i]))
+            if key is None:  # dropped since the directory called it live
+                code[i] = _UNRESOLVED
+            elif key in self._buckets:  # record_key tracked it meanwhile
+                code[i] = next(at for at, (k, _b) in enumerate(self._tracked)
+                               if k == key)
+            elif len(self._buckets) < self.key_capacity:
+                b = self._buckets[key] = _Bucket()
+                code[i] = len(self._tracked)
+                self._tracked.append((key, b))
+        self._slots_resolved += int(np.count_nonzero(code != _UNRESOLVED))
+        held = code >= 0
+        return (uniq[held], code[held], uniq[code == _UNRESOLVED],
+                self._tracked)
+
+    def _fold_lanes_locked(self, part, held_slots, held_index, lost_slots,
+                           tracked) -> None:
         """Fold one run of drained windows (arrival order) into the key
         buckets, leaving what the per-lane walk (every lane through
         _record_locked; tests/test_ledger.py keeps it as the reference)
-        would leave: lanes of keys that are not tracked (most of them,
-        once key_capacity keys are) are counted in numpy, not visited;
-        lanes of tracked keys go to _fold_tracked_locked."""
+        would leave. A lane is matched against the slots of the tracked
+        keys (at most key_capacity of them, whatever the tick drained):
+        lanes of keys that are not tracked (most of them, once
+        key_capacity keys are) are counted in numpy, not visited; lanes of
+        tracked keys go to _fold_tracked_locked."""
         slot_hits, resps, rec_auths = zip(*part)
         slots = np.concatenate([sh[0] for sh in slot_hits])
         live = np.flatnonzero(slots >= 0)
         if not live.size:
             return
         self._lanes_folded += live.size
-        lane_code = code[np.searchsorted(uniq, slots[live])]
+        lane_slots = slots[live]
+        lane_code = np.full(live.size, _UNTRACKED, np.int64)
+        if len(lost_slots):
+            _at, hit = _among(lost_slots, lane_slots)
+            lane_code[hit] = _UNRESOLVED
+        if len(held_slots):
+            at, hit = _among(held_slots, lane_slots)
+            lane_code[hit] = held_index[at[hit]]
         hits = np.concatenate([sh[1] for sh in slot_hits])[live]
         self._unattributed += int(hits[lane_code == _UNRESOLVED].sum())
         self._overflow += int(np.count_nonzero(lane_code == _UNTRACKED))
@@ -731,6 +812,7 @@ class DecisionLedger:
                 "audits": self._audits,
                 "slots_asked": self._slots_asked,
                 "slots_resolved": self._slots_resolved,
+                "slots_named": self._slots_named,
                 "lanes_folded": self._lanes_folded,
             }
 

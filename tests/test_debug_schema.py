@@ -172,7 +172,7 @@ def test_ledger_var_shape(instance):
 
 def test_ledger_endpoint_schema_pinned(instance):
     body = instance.ledger.endpoint_body()
-    assert body["schema_version"] == LEDGER_SCHEMA_VERSION == 2
+    assert body["schema_version"] == LEDGER_SCHEMA_VERSION == 3
     assert set(body) == {"schema_version", "enabled", "authorities",
                          "totals", "overshoot", "recent_violations",
                          "ground_truth"}
@@ -181,7 +181,7 @@ def test_ledger_endpoint_schema_pinned(instance):
         "windows_rolled", "violations", "overshoot_hits", "max_overshoot",
         "keys_tracked", "key_overflow", "pending_windows",
         "pending_dropped", "unattributed_hits", "audits",
-        "slots_asked", "slots_resolved", "lanes_folded"}
+        "slots_asked", "slots_resolved", "slots_named", "lanes_folded"}
     assert set(body["overshoot"]) == {"n", "total_hits", "max_hits",
                                       "p50_hits", "p99_hits"}
     assert set(body["ground_truth"]) == {"keys_checked", "ledger_hits",

@@ -25,15 +25,18 @@ bodies offline — main() only adds the fetch.
 """
 
 import dataclasses
+import itertools
 import json
 import os
 import time
 
+import numpy as np
 import pytest
 
 from gubernator_tpu.cluster.harness import LocalCluster
 from gubernator_tpu.cluster.harness import test_behaviors as _behaviors
 from gubernator_tpu.models.engine import Engine
+from gubernator_tpu.native import pack_keys, unpack_keys
 from gubernator_tpu.obs.bundle import BundleWriter
 from gubernator_tpu.obs.ledger import (
     AUTHORITIES,
@@ -192,12 +195,12 @@ class TestConservationRule:
         led.note_arrays([3, 7, -1], [10, 4, 9], [0, 1, 0],
                         [100, 50, 1], [5000, 5000, 1])
 
-        class _Dir:
+        class _Dir(_FakeEngine):
             def resolve_slots(self, want):
                 assert -1 not in want
-                return {3: "alpha"}  # slot 7 fell out of the directory
+                return super().resolve_slots(want)
 
-        led.audit(engine=_Dir(), force=True)
+        led.audit(engine=_Dir({3: "alpha"}), force=True)  # 7 fell out
         t = led.totals()
         assert t["admits"]["owner"] == 10
         assert t["rejected"] == 0  # slot 7's rejection went unattributed too
@@ -291,21 +294,36 @@ class _PerLaneLedger(DecisionLedger):
 
 
 class _FakeEngine:
-    """slot -> key from a fixed map (several slots may name one key, some
-    name none), and device counters a fixed amount above the ledger's."""
+    """A directory as the ledger sees one: slot <-> key, one to one (some
+    slots hold no key), changed by hand between audits, and device
+    counters a fixed amount above the ledger's. `named` keeps every slot
+    whose name was asked for."""
 
     def __init__(self, names):
+        assert len(set(names.values())) == len(names)
         self.names = names
+        self.named = []
 
     def resolve_slots(self, want):
-        return {int(s): self.names[int(s)] for s in want
-                if int(s) in self.names}
+        want = [int(s) for s in want]
+        self.named.extend(want)
+        return {s: self.names[s] for s in want if s in self.names}
+
+    def peek_slots(self, keys):
+        if isinstance(keys, tuple):
+            keys = unpack_keys(*keys)
+        slot_of = {k: s for s, k in self.names.items()}
+        return np.asarray([slot_of.get(k, -1) for k in keys], np.int64)
+
+    def slots_live(self, slots):
+        return np.asarray([int(s) in self.names for s in slots], bool)
 
     def device_hit_counts(self, keys):
         return {k: 7 * len(k) for k in keys if not k.endswith("3")}
 
 
-_NEW_COUNTERS = ("slots_asked", "slots_resolved", "lanes_folded")
+_NEW_COUNTERS = ("slots_asked", "slots_resolved", "slots_named",
+                 "lanes_folded")
 
 
 def _ledger_state(led):
@@ -322,10 +340,32 @@ def _ledger_state(led):
             "overshoot_counts": hist}
 
 
+def _churn(rng, names, n_slots, fresh):
+    """The directory between a decision and its audit: one change to the
+    slot <-> key map, which stays one to one."""
+    held = sorted(names)
+    free = [s for s in range(n_slots) if s not in names]
+    kind = rng.choice(["move", "recycle_new", "recycle_held", "drop"])
+    if kind == "move" and held and free:
+        # a key evicted and looked up again: it lives at another slot
+        names[rng.choice(free)] = names.pop(rng.choice(held))
+    elif kind == "recycle_new" and held:
+        # a slot recycled to a key nobody has met
+        names[rng.choice(held)] = f"fresh{next(fresh)}"
+    elif kind == "recycle_held" and len(held) >= 2:
+        # a slot recycled to a key that lived elsewhere: the slot's old
+        # key is gone, the new one moved
+        to, frm = rng.sample(held, 2)
+        names[to] = names.pop(frm)
+    elif kind == "drop" and held:
+        del names[rng.choice(held)]  # a key the directory lost
+
+
 class TestGroupedFoldEqualsPerLane:
-    """The audit's fold works per distinct slot and counts the lanes of
-    untracked keys in numpy; what it leaves behind must be what the
-    per-lane walk leaves, to the order of the violation events."""
+    """The audit asks the directory where its tracked keys live and matches
+    lanes against those slots; lanes of untracked keys are counted in
+    numpy. What it leaves behind must be what the per-lane walk, which
+    names every slot, leaves, to the order of the violation events."""
 
     @pytest.mark.parametrize("fold_lanes", [None, 20],
                              ids=["one_part", "parts_of_20_lanes"])
@@ -340,14 +380,11 @@ class TestGroupedFoldEqualsPerLane:
             monkeypatch.setattr(DecisionLedger, "_FOLD_LANES", fold_lanes)
         rng = random.Random(1000 + seed)
         n_slots = rng.choice([40, 120])
-        names = {}
-        for s in range(n_slots):
-            r = rng.random()
-            if r < 0.12:
-                continue  # a slot the directory lost
-            # a few slots share a key (recycled between chunks)
-            names[s] = f"key{s // 2 if r < 0.25 else s}"
+        # one key a slot, as in a directory; some slots hold none
+        names = {s: f"key{s}" for s in range(n_slots)
+                 if rng.random() >= 0.12}
         engine = _FakeEngine(names)
+        fresh = itertools.count()
         capacity = rng.choice([4, 16, 1000])
         events = ([], [])
         leds = (
@@ -389,6 +426,11 @@ class TestGroupedFoldEqualsPerLane:
                 for led in leds:
                     led.record_key(direct, 2, 0, 8, base + 1000 * audit_no)
                     led.record_minted("key1", 3)
+            # the directory moves on between the decisions and the audit:
+            # tracked keys change slot, tracked slots change hands (to a
+            # new key, to another tracked key), tracked keys are lost
+            for _ in range(rng.randrange(0, 6) if audit_no else 0):
+                _churn(rng, names, n_slots, fresh)
             now_ms = base + 1000 * audit_no + 500
             force = audit_no == 3
             reports = [led.audit(engine=engine, now_ms=now_ms, force=force)
@@ -399,39 +441,77 @@ class TestGroupedFoldEqualsPerLane:
         t = leds[0].totals()
         assert t["audits"] == 4
         assert t["lanes_folded"] >= t["slots_asked"] >= t["slots_resolved"]
+        # names are bought for newcomers while there is room, no others
+        assert t["slots_named"] <= min(capacity, t["slots_resolved"])
         if capacity == 4:
             assert t["key_overflow"] > 0
 
     def test_the_cases_the_random_ones_must_have_met(self):
         """One sequence with every feature by construction: capacity
-        crossed mid-window, a roll and a violation inside one audit, two
-        authorities, padding, an unresolved slot, two slots of one key."""
+        crossed mid-window (room for some newcomers, not all), a roll and
+        a violation inside one audit, two authorities, padding, a slot
+        that holds no key; then, between audits, a tracked key moved to
+        another slot, its old slot recycled to an untracked key, a tracked
+        slot recycled to another tracked key, a tracked key gone."""
         seen = ([], [])
         leds = (DecisionLedger(enabled=True, key_capacity=2,
                                emit=lambda k, **kw: seen[0].append((k, kw))),
                 _PerLaneLedger(enabled=True, key_capacity=2,
                                emit=lambda k, **kw: seen[1].append((k, kw))))
-        engine = _FakeEngine({1: "a", 2: "b", 3: "c", 4: "a"})
+        # the reference names every slot it drains: it asks a twin
+        engine, twin = (_FakeEngine({1: "a", 2: "b", 3: "c"})
+                        for _ in range(2))
+
+        def audit(**kw):
+            twin.names = engine.names
+            leds[0].audit(engine=engine, **kw)
+            leds[1].audit(engine=twin, **kw)
+            assert _ledger_state(leds[0]) == _ledger_state(leds[1])
+            assert seen[0] == seen[1]
+            return leds[0].totals()
+
         for led in leds:
             led.note_arrays([2, -1, 1, 3, 9], [1, 9, 4, 2, 5],
                             [0, 0, 0, 0, 0], [3, 3, 3, 3, 3],
                             [5000, 5000, 5000, 5000, 5000])
             with authority("degraded"):
-                led.note_arrays([4, 3, 1, 2], [4, 1, 1, 1], [0, 0, 1, 0],
+                led.note_arrays([1, 3, 1, 2], [4, 1, 1, 1], [0, 0, 1, 0],
                                 [3, 3, 3, 3], [5000, 5000, 9000, 9000])
-            led.audit(engine=engine, now_ms=6000)
-        assert _ledger_state(leds[0]) == _ledger_state(leds[1])
-        assert seen[0] == seen[1]
-        t = leds[0].totals()
+        t = audit(now_ms=6000)
         assert t["keys_tracked"] == 2  # "b" then "a"; "c" found no room
+        assert engine.named == [2, 1]  # and its name was never asked for
         assert t["key_overflow"] == 2  # both lanes of slot 3
         assert t["unattributed_hits"] == 5  # slot 9
         # "a": 4 owner + 4 degraded against limit 3 rolls at reset 9000
         # with overshoot 5 > one window of slack 3
         assert t["violations"] == 1 and t["windows_rolled"] == 2
         assert [kw["key"] for _k, kw in seen[0]] == ["a"]
-        assert (t["slots_asked"], t["slots_resolved"],
-                t["lanes_folded"]) == (5, 4, 8)
+        assert (t["slots_asked"], t["slots_resolved"], t["slots_named"],
+                t["lanes_folded"]) == (4, 3, 2, 8)
+
+        # "a" was evicted and came back at slot 5; slot 1 went to "d"
+        engine.names = {5: "a", 1: "d", 2: "b", 3: "c"}
+        for led in leds:
+            led.note_arrays([1, 5, 2], [2, 1, 1], [0, 0, 0], [3, 3, 3],
+                            [9000, 9000, 9000])
+        t = audit(now_ms=9500)
+        assert t["key_overflow"] == 3  # slot 1 is "d" now: untracked
+        assert t["admits"]["owner"] == 1 + 4 + 1 + 1  # slot 5 is "a"
+        assert t["windows_rolled"] == 4 and t["violations"] == 1
+        assert (t["slots_asked"], t["slots_resolved"], t["slots_named"],
+                t["lanes_folded"]) == (7, 6, 2, 11)
+
+        # slot 5 went on to "b", which left slot 2 empty; "a" is gone
+        engine.names = {5: "b", 1: "d", 3: "c"}
+        for led in leds:
+            led.note_arrays([5, 2], [2, 7], [0, 0], [3, 3], [12000, 12000])
+        t = audit(force=True)
+        assert t["admits"]["owner"] == 7 + 2  # slot 5 is "b"
+        assert t["unattributed_hits"] == 5 + 7  # slot 2 holds no key
+        assert t["windows_rolled"] == 5 and t["keys_tracked"] == 2
+        assert (t["slots_asked"], t["slots_resolved"], t["slots_named"],
+                t["lanes_folded"]) == (9, 7, 2, 13)
+        assert engine.named == [2, 1]  # a full ledger buys no name
 
     def test_the_counters_say_what_an_audit_was_asked(self):
         led = DecisionLedger(enabled=True)
@@ -447,23 +527,101 @@ class TestGroupedFoldEqualsPerLane:
         assert (t["slots_asked"], t["slots_resolved"],
                 t["lanes_folded"], t["unattributed_hits"]) == (2, 1, 5, 4)
 
-        class _Broken:
-            def resolve_slots(self, want):
+        class _Broken(_FakeEngine):
+            def slots_live(self, slots):
                 raise RuntimeError("directory gone")
 
         led.note_arrays([3], [2], [0], [9], [5000])
-        led.audit(engine=_Broken())  # the audit never raises
+        led.audit(engine=_Broken({3: "alpha"}))  # the audit never raises
         t = led.totals()
         assert (t["slots_asked"], t["slots_resolved"],
                 t["lanes_folded"], t["unattributed_hits"]) == (3, 1, 6, 6)
 
 
+class TestNamesBoughtAreBoundedByTheRoomLeft:
+    def test_a_full_ledger_buys_no_name_whatever_it_drains(self):
+        """The bound, without a clock: 100,000 distinct live slots drained
+        into a ledger that has room for 10 more keys cost 10 names, those
+        of the first newcomers by arrival; drained into a full one, none."""
+        n = 100_000
+        engine = _FakeEngine({s: f"k{s}" for s in range(n)})
+        led = DecisionLedger(enabled=True)
+        cap = led.key_capacity
+        assert cap == 8192  # what service/instance.py runs with
+        for s in range(cap - 10):
+            led.record_key(f"k{s}", 1, 0, 100, 5000)
+        arrival = np.random.default_rng(7).permutation(n)
+
+        def drain():
+            for lo in range(0, n, 1000):
+                part = arrival[lo:lo + 1000]
+                led.note_arrays(part, np.ones(1000, int), np.zeros(1000, int),
+                                np.full(1000, 100), np.full(1000, 5000))
+            led.audit(engine=engine, now_ms=1000)
+            return led.totals()
+
+        t = drain()
+        first = [s for s in arrival.tolist() if s >= cap - 10][:10]
+        assert engine.named == first
+        assert set(led._buckets) >= {f"k{s}" for s in first}
+        assert (t["keys_tracked"], t["slots_named"], t["slots_asked"],
+                t["slots_resolved"], t["lanes_folded"]) == (cap, 10, n, n, n)
+        assert t["key_overflow"] == n - cap and t["unattributed_hits"] == 0
+
+        t = drain()  # full: every drained slot is live, none is named
+        assert engine.named == first
+        assert (t["keys_tracked"], t["slots_named"], t["slots_asked"],
+                t["slots_resolved"], t["lanes_folded"]) == (
+                    cap, 10, 2 * n, 2 * n, 2 * n)
+        assert t["key_overflow"] == 2 * (n - cap)
+        assert t["attempted"] == (cap - 10) + 2 * cap
+
+
+class TestAuditOnARealEngine:
+    def test_an_evicting_directory_is_audited_as_the_per_lane_walk_does(self):
+        """The differential on the shipped directory: 600 keys through 256
+        slots, so between a window and its audit slots are recycled,
+        tracked keys move and are lost; a ledger with room for 64 keys
+        fills up on the way. Both ledgers drain the same windows and ask
+        the same engine at the same instant."""
+        eng = Engine(capacity=256, min_width=64, max_width=64)
+        seen = ([], [])
+        led = DecisionLedger(enabled=True, key_capacity=64,
+                             emit=lambda k, **kw: seen[0].append((k, kw)))
+        ref = _PerLaneLedger(enabled=True, key_capacity=64,
+                             emit=lambda k, **kw: seen[1].append((k, kw)))
+        eng.ledger = led
+        import random
+
+        rng = random.Random(34)
+        for audit_no in range(5):
+            for _ in range(4):
+                picks = rng.sample(range(600), 40)
+                eng.get_rate_limits([_rl(f"k{i}", hits=rng.randrange(1, 4),
+                                         limit=rng.choice([2, 5, 1000]))
+                                     for i in picks])
+            with led._pending_lock:
+                ref._pending = list(led._pending)
+            now_ms = int(time.time() * 1000) + 3_600_000 * (audit_no == 4)
+            reports = [l.audit(engine=eng, now_ms=now_ms) for l in (led, ref)]
+            assert reports[0] == reports[1]
+            assert _ledger_state(led) == _ledger_state(ref)
+            assert seen[0] == seen[1]
+        t = led.totals()
+        assert t["keys_tracked"] == 64 == t["slots_named"]
+        assert t["key_overflow"] > 0 and t["windows_rolled"] > 0
+        assert t["unattributed_hits"] == 0  # a 40-key call evicts, then
+        # its window's own keys hold every slot it drained
+        assert eng.directory.evictions > 0
+
+
 class TestResolveSlots:
     def test_native_directory_against_the_python_twin_on_one_engine(self):
         """Engine.resolve_slots picks index lookup or the walk by what the
-        directory offers; both name the same keys for the same slots."""
-        import numpy as np
-
+        directory offers; both name the same keys for the same slots. The
+        same for the ledger audit's two questions: where keys live
+        (peek_slots, over a list or a packed arena) and which slots hold
+        a key at all (slots_live)."""
         from gubernator_tpu.models.keyspace import KeyDirectory
 
         eng = Engine(capacity=256, min_width=64, max_width=64)
@@ -477,14 +635,35 @@ class TestResolveSlots:
         assert 200 < len(live) < 256
         asked = [-1, 0, 5, 5, 255, 256, 10_000] + list(range(0, 256, 3))
         by_index = eng.resolve_slots(asked)
+        keys = ["led_k299-é", "never seen", "led_k0-é"] + sorted(live.values())
+        where = {k: s for s, k in live.items()}
+
+        def the_audits_questions():
+            peeked = eng.peek_slots(keys)
+            assert peeked.dtype == np.int64
+            assert peeked.tolist() == [where.get(k, -1) for k in keys]
+            assert eng.peek_slots(pack_keys(keys)).tolist() == peeked.tolist()
+            assert eng.peek_slots([]).tolist() == []
+            held = eng.slots_live(np.asarray(asked))
+            assert held.dtype == bool
+            assert held.tolist() == [s in live for s in asked]
+            assert eng.slots_live(asked).tolist() == held.tolist()
+            assert eng.slots_live([]).tolist() == []
+
+        evictions = native_dir.evictions
+        the_audits_questions()
         twin = KeyDirectory(256)
         twin._map.update(native_dir.items())
         eng.directory = twin
         try:
             by_walk = eng.resolve_slots(asked)
             assert eng.resolve_slots(np.asarray(asked)) == by_walk
+            the_audits_questions()
         finally:
             eng.directory = native_dir
+        # asking changed nothing: the same keys at the same slots
+        assert dict((s, k) for k, s in native_dir.items()) == live
+        assert native_dir.evictions == evictions
         assert by_index == by_walk
         assert by_index == {s: live[s] for s in set(asked) if s in live}
         assert eng.resolve_slots([]) == {} == eng.resolve_slots([-1, 256])

@@ -210,6 +210,12 @@ _KEYDIR_STRESS = textwrap.dedent("""
     lib.keydir_keys_for_slots.restype = c.c_int64
     lib.keydir_keys_for_slots.argtypes = [c.c_void_p, c.c_void_p, c.c_int64,
                                           c.c_void_p, c.c_int64, c.c_void_p]
+    lib.keydir_slots_live.restype = None
+    lib.keydir_slots_live.argtypes = [c.c_void_p, c.c_void_p, c.c_int64,
+                                      c.c_void_p]
+    lib.keydir_peek_batch.restype = c.c_int64
+    lib.keydir_peek_batch.argtypes = [c.c_void_p, c.c_char_p, c.c_void_p,
+                                      c.c_int64, c.c_void_p]
 
     kd = lib.keydir_new(512)
     lock = threading.Lock()  # batch callers keep the engine-lock discipline
@@ -262,11 +268,29 @@ _KEYDIR_STRESS = textwrap.dedent("""
         slots = (c.c_int32 * N)(*[(i * 7) % 600 - 40 for i in range(N)])
         buf = (c.c_char * (N * 8))()
         offs = (c.c_int64 * (N + 1))()
+        live = (c.c_uint8 * N)()
+        parts = [b"k%d_%d" % (tid, j) for j in range(64)]
+        names = b"".join(parts)
+        name_offs = (c.c_int64 * 65)()
+        for j, part in enumerate(parts):
+            name_offs[j + 1] = name_offs[j] + len(part)
+        where = (c.c_int32 * 64)()
         for i in range(40):
             got = lib.keydir_keys_for_slots(kd, c.cast(slots, c.c_void_p),
                                             N, c.cast(buf, c.c_void_p),
                                             N * 8, c.cast(offs, c.c_void_p))
             assert 0 <= got == offs[N], got
+            # the ledger audit's two questions, the same way: which slots
+            # hold a key (the chunked walk, nothing copied) and where
+            # keys live (a peek a key)
+            lib.keydir_slots_live(kd, c.cast(slots, c.c_void_p), N,
+                                  c.cast(live, c.c_void_p))
+            assert set(live) <= {0, 1}
+            assert not any(live[j] for j in range(N)
+                           if not 0 <= slots[j] < 512)
+            lib.keydir_peek_batch(kd, names, c.cast(name_offs, c.c_void_p),
+                                  64, c.cast(where, c.c_void_p))
+            assert all(-1 <= s < 512 for s in where)
 
     ts = [threading.Thread(target=hammer, args=(t,)) for t in range(6)]
     ts += [threading.Thread(target=native_decider, args=(t,))
