@@ -164,7 +164,16 @@ class EngineStats:
     Lock-acquisition waits are deliberately excluded (deltas are computed
     before entering the engine lock). Exposed as
     engine_stage_seconds_total{stage=...} in /metrics (the reference has no
-    tracing tier at all, SURVEY §5.1)."""
+    tracing tier at all, SURVEY §5.1).
+
+    The scan counters tell a repeated key's tail from plain traffic:
+    `rounds` counts the windows that held a lane, `scan_dispatches` the
+    device calls that carried more than one window (decide() under
+    lax.scan), `scan_rounds` the rounds those retired (the pads of a
+    power-of-two stack not counted), `scan_lanes_live` the lanes of those
+    rounds that decided a request and `scan_lanes` what the dispatches put
+    on the device (depth as launched x width). rounds - scan_rounds are
+    the rounds that rode a launch of their own."""
 
     STAGES = ("prep", "lookup", "store", "pack", "device", "demux")
 
@@ -175,12 +184,28 @@ class EngineStats:
         self.over_limit = 0
         self.errors = 0
         self.native_singles = 0  # lone requests decided in C (no dispatch)
+        self.scan_dispatches = 0
+        self.scan_rounds = 0
+        self.scan_lanes_live = 0
+        self.scan_lanes = 0
         self.stage_ns = {s: 0 for s in self.STAGES}
+
+    def note_scan(self, rounds: int, live: int, lanes: int) -> None:
+        """One scan dispatch that retires `rounds` rounds holding `live`
+        lanes, launched `lanes` wide in all. Caller holds the engine lock."""
+        self.scan_dispatches += 1
+        self.scan_rounds += rounds
+        self.scan_lanes_live += live
+        self.scan_lanes += lanes
 
     def as_dict(self) -> Dict[str, int]:
         d = dict(requests=self.requests, batches=self.batches,
                  rounds=self.rounds, over_limit=self.over_limit,
-                 errors=self.errors, native_singles=self.native_singles)
+                 errors=self.errors, native_singles=self.native_singles,
+                 scan_dispatches=self.scan_dispatches,
+                 scan_rounds=self.scan_rounds,
+                 scan_lanes_live=self.scan_lanes_live,
+                 scan_lanes=self.scan_lanes)
         for s, ns in self.stage_ns.items():
             d[f"{s}_ns"] = ns
         return d
@@ -745,6 +770,7 @@ class Engine:
                         stack = np.zeros((kb2, 9, w), np.int64)
                         stack[:m] = buf[seg_start:k]
                         stack[m:, 0, :] = -1
+                    self.stats.note_scan(rounds, total, len(stack) * w)
                     staged = self._dispatch_scan_staged(stack, now_ms)
                     scanned = True
                 td = time.perf_counter_ns()
@@ -1102,6 +1128,7 @@ class Engine:
                     stack = buf if kb2 == kb else buf[:kb2]
                     for kk in range(m, kb2):
                         stack[kk][0, :] = -1  # unprepped rows: all padding
+                    self.stats.note_scan(rounds, total, kb2 * w)
                     staged = self._dispatch_scan_staged(stack, now_ms)
                     scanned = True
                 td = time.perf_counter_ns()
@@ -1672,13 +1699,15 @@ class Engine:
                 host_ns += t3 - t
             prof = self.profiler
             prof.observe("prep", host_ns)
+            live = sum(len(wk) for wk in group)
+            self.stats.note_scan(len(group), live, k * width)
             t = time.perf_counter_ns()
             staged = self._dispatch_scan_staged(stacked, now_ms)
             td = time.perf_counter_ns()
             out = self._fetch_staged(staged)
             t2 = time.perf_counter_ns()
             stage["device"] += t2 - t
-            self._obs_device(t2 - t, sum(len(w) for w in group))
+            self._obs_device(t2 - t, live)
             prof.observe("dispatch", td - t)
             prof.observe("readback", t2 - td)
             led = self.ledger

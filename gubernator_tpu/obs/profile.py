@@ -55,7 +55,9 @@ from gubernator_tpu.obs import witness
 # v2: phases gains front_wait/front_call/front_parse/front_write (the
 # native front's histograms) and the body gains `front` (its counters),
 # `bg_sites` (background tickers per site) and capture.options.
-PROFILE_SCHEMA_VERSION = 2
+# v3: phases gains `leftover`; capture.last_rates gains the front's
+# counters across the capture (frames_pulled_in, items_pulled_in).
+PROFILE_SCHEMA_VERSION = 3
 KERNELS_SCHEMA_VERSION = 1
 
 # The serving-cycle phases, in cycle order. queue_wait overlaps the
@@ -218,6 +220,13 @@ class Profiler:
                         if enabled is None else bool(enabled))
         self.capture_min_interval_s = float(capture_min_interval_s)
         self._phases: Dict[str, PhaseHist] = {p: PhaseHist() for p in PHASES}
+        # a pull worker's stretch inside service/peerlink.py
+        # _leftover_items, one observation a columnar chunk that handed
+        # back leftovers (the request objects built, the router, the
+        # combiner, the engine's rounds, the fill of the answer rows). It
+        # holds whole serving cycles of other threads, so it stands
+        # outside PHASES and the decomposition, as the front's phases do
+        self._leftover = PhaseHist()
         self._sites: Dict[str, PhaseHist] = {}
         self._bg_sites: Dict[str, PhaseHist] = {}
         self._sites_lock = witness.make_lock("profiler.sites")
@@ -322,6 +331,12 @@ class Profiler:
             if ns >= BACKGROUND_SLOW_NS and rec is not None:
                 rec.emit("profile.background_slow", site=site,
                          ms=round(ns / 1e6, 1), own_ms=round(own_ns / 1e6, 1))
+
+    def observe_leftover(self, ns: int) -> None:
+        """Record one chunk's stretch inside _leftover_items (the
+        `leftover` phase of /v1/debug/profile)."""
+        if self.enabled:
+            self._leftover.observe(ns)
 
     def seams(self):
         """`seams = prof.seams()`, then `seams("prep")` ... `seams(None)`
@@ -467,6 +482,7 @@ class Profiler:
         front_phases, front_counters = self.front_totals()
         phases = {p: h.snapshot() for p, h in self._phases.items()}
         phases.update(front_phases)
+        phases["leftover"] = self._leftover.snapshot()
         return {
             "schema_version": PROFILE_SCHEMA_VERSION,
             "enabled": self.enabled,
@@ -499,7 +515,9 @@ class Profiler:
         (the bundle dir) and returns {"ok", "path"/"error", "mode"}, and
         for a jax trace what the capture cost the daemon:
         `launches_per_s_in` (device launches per second while it ran) and
-        `launches_per_s_out` (over the `seconds`, at most 2, before it);
+        `launches_per_s_out` (over the `seconds`, at most 2, before it),
+        and `frames_pulled_in` / `items_pulled_in` (calls and items the
+        front's pull loop took between the capture's two edges);
         never raises."""
         now = time.monotonic()
         with self._capture_lock:
@@ -535,22 +553,28 @@ class Profiler:
         self._last_capture_mode = "wall_sampler"
         return {"ok": True, "path": path, "mode": "wall_sampler"}
 
-    def _launches(self) -> Tuple[int, int]:
-        """(device launches so far, now): every launch observes the
-        dispatch phase once."""
-        return self._phases["dispatch"].totals()[0], time.perf_counter_ns()
+    def _edge(self) -> Tuple[int, int, int, int]:
+        """(device launches, frames pulled, items pulled so far, now):
+        every launch observes the dispatch phase once; the front counts
+        the calls and the items its pull loop took."""
+        front = self.front_totals()[1]
+        return (self._phases["dispatch"].totals()[0],
+                front["frames_pulled"], front["items_pulled"],
+                time.perf_counter_ns())
 
     def _jax_trace(self, path: str, seconds: float) -> dict:
         """One jax.profiler trace of `seconds`, with the seams' spans in
-        it; returns the launch rates before and inside it."""
+        it; returns the launch rates before and inside it, and what the
+        front's pull loop took between the capture's two edges (the work
+        its device time belongs to)."""
         import jax
 
         options = jax.profiler.ProfileOptions()
         for k, v in CAPTURE_OPTIONS.items():
             setattr(options, k, v)
-        n0, t0 = self._launches()
+        n0, _, _, t0 = self._edge()
         time.sleep(min(seconds, 2.0))
-        n1, t1 = self._launches()
+        n1, _, _, t1 = self._edge()
         jax.profiler.start_trace(path, profiler_options=options)
         try:
             with self._bg_lock:
@@ -558,9 +582,9 @@ class Profiler:
                 for entry in self._bg_open.values():
                     entry[1] = _annotation("bg:" + entry[0])
                 self._capturing = True
-            n2, t2 = self._launches()
+            n2, f2, i2, t2 = self._edge()
             time.sleep(seconds)
-            n3, t3 = self._launches()
+            n3, f3, i3, t3 = self._edge()
         finally:
             with self._bg_lock:
                 self._capturing = False
@@ -571,7 +595,8 @@ class Profiler:
                         entry[1] = None
             jax.profiler.stop_trace()
         return {"launches_per_s_out": (n1 - n0) / ((t1 - t0) / 1e9),
-                "launches_per_s_in": (n3 - n2) / ((t3 - t2) / 1e9)}
+                "launches_per_s_in": (n3 - n2) / ((t3 - t2) / 1e9),
+                "frames_pulled_in": f3 - f2, "items_pulled_in": i3 - i2}
 
     @staticmethod
     def _wall_sample(out_dir: str, seconds: float, stamp: int) -> str:

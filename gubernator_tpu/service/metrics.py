@@ -178,6 +178,9 @@ class Metrics:
                 ("batches", "Aggregated pulls served by the link workers."),
                 ("requests", "Requests carried by those pulls."),
                 ("errors", "Worker batch/send failures."),
+                ("leftover_items", "Items a columnar chunk handed back as "
+                 "leftovers for the request-object path (later occurrences "
+                 "of a key in the chunk, flagged or invalid lanes)."),
             )
         }
         self.peerlink_stage_ms = Histogram(

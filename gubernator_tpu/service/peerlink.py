@@ -649,6 +649,9 @@ class PeerLinkService:
                       # pipelined columnar serving (_columnar_chunk)
                       "columnar_windows": 0, "columnar_groups": 0,
                       "columnar_cuts": 0, "columnar_fill_stalls": 0,
+                      # what the columnar chunks handed back
+                      # (_leftover_items)
+                      "leftover_items": 0,
                       # times the worker had launches in flight but
                       # nothing new to pull
                       "pull_boundary_stalls": 0}
@@ -1465,39 +1468,49 @@ class PeerLinkService:
         Runs AFTER the packed round, preserving per-key order. Method 0
         (public) leftovers take the FULL router path — a GLOBAL-flagged
         request on the wire-compatible surface must reach the global
-        pipelines, not owner-apply semantics."""
-        idxs = [j + r for r in rel_idx]
-        reqs, good_idx = [], []
-        koff = b["key_off"]
-        nlen = b["name_len"]
-        raw_keys = b["keys"]
-        for i in idxs:
-            lo, hi = int(koff[i]), int(koff[i + 1])
-            split = lo + int(nlen[i])
-            try:
-                reqs.append(RateLimitReq(
-                    name=raw_keys[lo:split].decode(),
-                    unique_key=raw_keys[split:hi].decode(),
-                    hits=int(b["hits"][i]), limit=int(b["limit"][i]),
-                    duration=int(b["duration"][i]),
-                    algorithm=int(b["algorithm"][i]),
-                    behavior=int(b["behavior"][i])))
-                good_idx.append(i)
-            except UnicodeDecodeError:
-                self._fill_one(b, i, RateLimitResp(
-                    error="invalid utf-8 in key"), errs, metas)
-        if not reqs:
-            return
-        try:
-            if m == METHOD_GET_PEER_RATE_LIMITS:
-                resps = self.instance.apply_owner_batch_direct(
-                    reqs, from_peer_rpc=True)
-            else:
-                resps = self.instance.get_rate_limits(reqs)
-        except Exception as e:  # noqa: BLE001
-            resps = [RateLimitResp(error=str(e)) for _ in reqs]
-        for i, resp in zip(good_idx, resps):
-            self._fill_one(b, i, resp, errs, metas)
+        pipelines, not owner-apply semantics.
+
+        Metered as stats `leftover_items` (peerlink_leftover_items_total)
+        and the profiler's `leftover` phase, a host span of that name
+        while a capture runs (docs/observability.md). The C prep gives no
+        reason for a demotion, so the items are counted whole."""
+        n_left = len(rel_idx)  # a local: no call between read and store
+        self.stats["leftover_items"] += n_left
+        prof = self._prof
+        t0 = time.perf_counter_ns()
+        with prof.span("leftover"):
+            idxs = [j + r for r in rel_idx]
+            reqs, good_idx = [], []
+            koff = b["key_off"]
+            nlen = b["name_len"]
+            raw_keys = b["keys"]
+            for i in idxs:
+                lo, hi = int(koff[i]), int(koff[i + 1])
+                split = lo + int(nlen[i])
+                try:
+                    reqs.append(RateLimitReq(
+                        name=raw_keys[lo:split].decode(),
+                        unique_key=raw_keys[split:hi].decode(),
+                        hits=int(b["hits"][i]), limit=int(b["limit"][i]),
+                        duration=int(b["duration"][i]),
+                        algorithm=int(b["algorithm"][i]),
+                        behavior=int(b["behavior"][i])))
+                    good_idx.append(i)
+                except UnicodeDecodeError:
+                    self._fill_one(b, i, RateLimitResp(
+                        error="invalid utf-8 in key"), errs, metas)
+            if reqs:
+                try:
+                    if m == METHOD_GET_PEER_RATE_LIMITS:
+                        resps = self.instance.apply_owner_batch_direct(
+                            reqs, from_peer_rpc=True)
+                    else:
+                        resps = self.instance.get_rate_limits(reqs)
+                except Exception as e:  # noqa: BLE001
+                    resps = [RateLimitResp(error=str(e)) for _ in reqs]
+                for i, resp in zip(good_idx, resps):
+                    self._fill_one(b, i, resp, errs, metas)
+        prof.observe_leftover(time.perf_counter_ns() - t0)
 
     @staticmethod
     def _fill_one(b: dict, i: int, resp: RateLimitResp, errs: list,

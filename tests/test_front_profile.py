@@ -368,6 +368,8 @@ class TestCaptureSpans:
         assert out["launches_per_s_in"] > 0 and out["launches_per_s_out"] > 0
         rates = prof.endpoint_body()["capture"]["last_rates"]
         assert rates["launches_per_s_in"] == out["launches_per_s_in"]
+        # no native front attached: its counters stand still
+        assert (rates["frames_pulled_in"], rates["items_pulled_in"]) == (0, 0)
         spans = _host_spans(out["path"])
         for name in ("lock_wait", "prep", "dispatch", "readback", "demux"):
             assert spans.get(name, 0) >= 1, (name, sorted(spans))

@@ -114,7 +114,8 @@ class TestProfiler:
         p.observe("prep", 1_000)
         body = p.endpoint_body()
         assert body["enabled"] is True
-        assert set(body["phases"]) == set(PHASES) | set(FRONT_PHASES)
+        assert set(body["phases"]) == \
+            set(PHASES) | set(FRONT_PHASES) | {"leftover"}
         dbg = p.debug()
         assert dbg["phases"]["prep"]["n"] == 1
         assert set(dbg["shares"]) == set(SERIAL_PHASES)
