@@ -48,6 +48,9 @@ from gubernator_tpu.ops.decide import (
     TableState,
     compact_window,
     decide_packed_lean,
+    decide_scan_carried,
+    decide_scan_carried_compact,
+    decide_scan_carried_lean,
     decide_scan_packed_lean,
     lean_capacity_ok,
     lean_window,
@@ -107,19 +110,8 @@ def _jit_decide_packed(donate: bool):
 
 
 @_functools.lru_cache(maxsize=None)
-def _jit_decide_scan(donate: bool):
-    return jax.jit(decide_scan_packed, donate_argnums=(0,) if donate else ())
-
-
-@_functools.lru_cache(maxsize=None)
 def _jit_decide_packed_compact(donate: bool):
     return jax.jit(decide_packed_compact,
-                   donate_argnums=(0,) if donate else ())
-
-
-@_functools.lru_cache(maxsize=None)
-def _jit_decide_scan_compact(donate: bool):
-    return jax.jit(decide_scan_packed_compact,
                    donate_argnums=(0,) if donate else ())
 
 
@@ -130,9 +122,16 @@ def _jit_decide_packed_lean(donate: bool):
 
 
 @_functools.lru_cache(maxsize=None)
-def _jit_decide_scan_lean(donate: bool):
-    return jax.jit(decide_scan_packed_lean,
-                   donate_argnums=(0,) if donate else ())
+def _jit_scans(donate: bool, carried: bool):
+    """The scan programs as (wide, compact, lean): with the table as the
+    carry (any windows), or the rows (`carried`: a lane-aligned group's;
+    ops/decide.py _scan_carried)."""
+    fns = ((decide_scan_carried, decide_scan_carried_compact,
+            decide_scan_carried_lean) if carried else
+           (decide_scan_packed, decide_scan_packed_compact,
+            decide_scan_packed_lean))
+    return tuple(jax.jit(fn, donate_argnums=(0,) if donate else ())
+                 for fn in fns)
 
 
 @_functools.lru_cache(maxsize=None)
@@ -172,8 +171,11 @@ class EngineStats:
     lax.scan), `scan_rounds` the rounds those retired (the pads of a
     power-of-two stack not counted), `scan_lanes_live` the lanes of those
     rounds that decided a request and `scan_lanes` what the dispatches put
-    on the device (depth as launched x width). rounds - scan_rounds are
-    the rounds that rode a launch of their own."""
+    on the device (depth as launched x width), `scan_rounds_carried` the
+    scan rounds whose rows rode the scan's carry (one row gather and one
+    row scatter a dispatch, not one of each a round). rounds - scan_rounds
+    rode a launch of their own, scan_rounds - scan_rounds_carried rode the
+    table, the rest the carry."""
 
     STAGES = ("prep", "lookup", "store", "pack", "device", "demux")
 
@@ -186,15 +188,19 @@ class EngineStats:
         self.native_singles = 0  # lone requests decided in C (no dispatch)
         self.scan_dispatches = 0
         self.scan_rounds = 0
+        self.scan_rounds_carried = 0
         self.scan_lanes_live = 0
         self.scan_lanes = 0
         self.stage_ns = {s: 0 for s in self.STAGES}
 
-    def note_scan(self, rounds: int, live: int, lanes: int) -> None:
+    def note_scan(self, rounds: int, live: int, lanes: int,
+                  carried: bool = False) -> None:
         """One scan dispatch that retires `rounds` rounds holding `live`
-        lanes, launched `lanes` wide in all. Caller holds the engine lock."""
+        lanes, launched `lanes` wide in all, the rows `carried` through it
+        or not. Caller holds the engine lock."""
         self.scan_dispatches += 1
         self.scan_rounds += rounds
+        self.scan_rounds_carried += rounds if carried else 0
         self.scan_lanes_live += live
         self.scan_lanes += lanes
 
@@ -204,6 +210,7 @@ class EngineStats:
                  errors=self.errors, native_singles=self.native_singles,
                  scan_dispatches=self.scan_dispatches,
                  scan_rounds=self.scan_rounds,
+                 scan_rounds_carried=self.scan_rounds_carried,
                  scan_lanes_live=self.scan_lanes_live,
                  scan_lanes=self.scan_lanes)
         for s, ns in self.stage_ns.items():
@@ -268,11 +275,10 @@ class Engine:
             donate = donation_supported()
         self.donate = donate
         self._decide_packed = _jit_decide_packed(donate)
-        self._decide_scan = _jit_decide_scan(donate)
         self._decide_packed_compact = _jit_decide_packed_compact(donate)
-        self._decide_scan_compact = _jit_decide_scan_compact(donate)
         self._decide_packed_lean = _jit_decide_packed_lean(donate)
-        self._decide_scan_lean = _jit_decide_scan_lean(donate)
+        self._scans = _jit_scans(donate, False)
+        self._scans_carried = _jit_scans(donate, True)
         # lean staging needs every slot to fit the 24-bit lane field
         self._lean_ok = lean_capacity_ok(capacity)
         self._inject = _jit_inject(donate)
@@ -326,21 +332,17 @@ class Engine:
                         self.state, resp = self._decide_packed_lean(
                             self.state, ln[0], ln[1], 0)
                 release_compile_memory()
-            # every scan-path shape: depths 2..=_MAX_SCAN at min_width (the
-            # fast path dispatches nothing else — see _split_scannable)
+            # every scan shape _apply_windows_scanned dispatches: depths
+            # 2..=_MAX_SCAN, min_width wide; below max_width the row-carried
+            # programs (its groups are lane-aligned whenever the rounds
+            # come from one preprocess() whose round 0 fits a window: every
+            # serving path), at max_width the table-carried ones the group
+            # launches share (_carries)
+            scans = self._scans_carried if self._carries(self.min_width) \
+                else self._scans
             k = 2
             while k <= self._MAX_SCAN:
-                stacked = np.zeros((k, 9, self.min_width), np.int64)
-                stacked[:, 0, :] = -1
-                self.state, resp = self._decide_scan(self.state, stacked, 0)
-                if both:
-                    self.state, resp = self._decide_scan_compact(
-                        self.state, compact_window(stacked), 0)
-                    if self._lean_ok:
-                        ln = lean_window(stacked, self.capacity)
-                        self.state, resp = self._decide_scan_lean(
-                            self.state, ln[0], ln[1], 0)
-                release_compile_memory()
+                resp = self._warm_scan_depth(scans, k, self.min_width)
                 k *= 2
             # serving-path auxiliary jits: the lone-miss mirror seed's
             # 1-slot gather and the mirror-flush inject at its common
@@ -355,6 +357,33 @@ class Engine:
             self._apply_inject_rows(warm_inject)
             if resp is not None:
                 jax.block_until_ready(resp)
+
+    def _carries(self, width: int) -> bool:
+        """One scan program a shape: scans `max_width` wide belong to the
+        group launches (different callers' windows, keys at arbitrary
+        lanes: the table as the carry), so a repeated key's rounds ride
+        the rows' carry only below it. On a one-width ladder they share
+        the group launches' programs, as they always did: a loaded program
+        is ~50 MB of the daemon's resident set (9 more read +2.4-3.4% of
+        daemon_rss_mb at 8192..8192, PERF.md PR 39)."""
+        return width < self.max_width
+
+    def _warm_scan_depth(self, scans, k: int, width: int):
+        """Compile the depth-`k`, `width`-lane programs of `scans` (wide,
+        compact, lean) by one all-padding launch each: every lane drops,
+        the table is untouched. Caller holds the engine lock."""
+        wide, compact, lean = scans
+        stacked = np.zeros((k, 9, width), np.int64)
+        stacked[:, 0, :] = -1
+        self.state, resp = wide(self.state, stacked, 0)
+        if self._staging != "wide":  # auto serves any eligible wire format
+            self.state, resp = compact(
+                self.state, compact_window(stacked), 0)
+            if self._lean_ok:
+                ln = lean_window(stacked, self.capacity)
+                self.state, resp = lean(self.state, ln[0], ln[1], 0)
+        release_compile_memory()
+        return resp
 
     # -------------------------------------------------- staging dispatch
     # Every window dispatch funnels through these two helpers so the
@@ -414,51 +443,44 @@ class Engine:
                               dur_ns=time.perf_counter_ns() - t)
         return out, None
 
-    def _dispatch_scan_staged(self, stacked: np.ndarray, now_ms):
+    def _dispatch_scan_staged(self, stacked: np.ndarray, now_ms,
+                              carried: bool = False):
         """decide_scan dispatch of a wide i64[K, 9, W] stack, shipped
-        lean/compact when eligible. Handle contract matches
-        _dispatch_staged. Caller holds the engine lock."""
+        lean/compact when eligible. `carried` takes the row-carried
+        programs: the caller has aligned the stack's lanes (a lane holds
+        one slot through the stack; ops/decide.py _scan_carried). Handle
+        contract matches _dispatch_staged. Caller holds the engine lock."""
         ht = self.hot_tracker
         if ht is not None:
             ht.feed_slots(stacked[:, 0, :], stacked[:, 1, :])
-        k, w = stacked.shape[0], stacked.shape[2]
+        wide, compact, lean = self._scans_carried if carried else self._scans
+        tag = "carry" if carried else "scan"
         if self._staging != "wide":
             if self._lean_ok:
                 ln = lean_window(stacked, self.capacity)
                 if ln is not None:
-                    if kernel_telemetry.needs_probe("scan_lean", w):
-                        kernel_telemetry.offer_probe(
-                            "scan_lean", w, self._decide_scan_lean,
-                            (self.state, ln[0], ln[1], now_ms))
-                    t = time.perf_counter_ns()
-                    self.state, out = self._decide_scan_lean(
-                        self.state, ln[0], ln[1], now_ms)
-                    kernel_telemetry.note(
-                        "scan_lean", w, depth=k,
-                        dur_ns=time.perf_counter_ns() - t)
-                    return out, now_ms
+                    return self._launch_scan(
+                        tag + "_lean", lean, ln, stacked, now_ms), now_ms
             c = compact_window(stacked)
             if c is not None:
-                if kernel_telemetry.needs_probe("scan_compact", w):
-                    kernel_telemetry.offer_probe(
-                        "scan_compact", w, self._decide_scan_compact,
-                        (self.state, c, now_ms))
-                t = time.perf_counter_ns()
-                self.state, out = self._decide_scan_compact(
-                    self.state, c, now_ms)
-                kernel_telemetry.note(
-                    "scan_compact", w, depth=k,
-                    dur_ns=time.perf_counter_ns() - t)
-                return out, now_ms
-        if kernel_telemetry.needs_probe("scan_wide", w):
+                return self._launch_scan(
+                    tag + "_compact", compact, (c,), stacked, now_ms), now_ms
+        return self._launch_scan(
+            tag + "_wide", wide, (stacked,), stacked, now_ms), None
+
+    def _launch_scan(self, kernel: str, fn, staged, stacked, now_ms):
+        """One scan launch of `fn` over the `staged` form of `stacked`,
+        told to the kernel telemetry under `kernel`. Caller holds the
+        engine lock."""
+        k, w = stacked.shape[0], stacked.shape[2]
+        if kernel_telemetry.needs_probe(kernel, w):
             kernel_telemetry.offer_probe(
-                "scan_wide", w, self._decide_scan,
-                (self.state, stacked, now_ms))
+                kernel, w, fn, (self.state, *staged, now_ms))
         t = time.perf_counter_ns()
-        self.state, out = self._decide_scan(self.state, stacked, now_ms)
-        kernel_telemetry.note("scan_wide", w, depth=k,
+        self.state, out = fn(self.state, *staged, now_ms)
+        kernel_telemetry.note(kernel, w, depth=k,
                               dur_ns=time.perf_counter_ns() - t)
-        return out, None
+        return out
 
     def _obs_device(self, ns: int, lanes: int) -> None:
         """Feed one window's device dispatch+readback wall time and live
@@ -476,7 +498,8 @@ class Engine:
 
     def kernel_fingerprints(self) -> Dict[str, str]:
         """HLO fingerprints of the canonical decision programs: the wide
-        per-window kernel and the depth-2 scan at min_width. Every
+        per-window kernel and the two depth-2 scans (the table and the
+        rows as the carry) at min_width. Every
         staging variant lowers from the same decide body, so any kernel
         change — a jax/libtpu bump, a decide.py edit, an XLA flag drift
         — shows here. Boot-time introspection only (cmd/daemon.py
@@ -496,7 +519,10 @@ class Engine:
                     state_aval, packed, 0).as_text())
             stacked = jax.ShapeDtypeStruct((2, 9, w), I64)
             out[f"scan_wide@{w}"] = hlo_fingerprint(
-                self._decide_scan.lower(
+                self._scans[0].lower(
+                    state_aval, stacked, 0).as_text())
+            out[f"carry_wide@{w}"] = hlo_fingerprint(
+                self._scans_carried[0].lower(
                     state_aval, stacked, 0).as_text())
         except Exception:  # noqa: BLE001 — introspection must not break boot
             pass
@@ -878,22 +904,13 @@ class Engine:
         window would stall that window for the whole compile."""
         if not self.supports_pipeline():
             return
-        both = self._staging != "wide"
         resp = None
         with self._lock:
             k = 2
             while k <= min(max_group, self._MAX_SCAN):
-                stacked = np.zeros((k, 9, self.max_width), np.int64)
-                stacked[:, 0, :] = -1
-                self.state, resp = self._decide_scan(self.state, stacked, 0)
-                if both:
-                    self.state, resp = self._decide_scan_compact(
-                        self.state, compact_window(stacked), 0)
-                    if self._lean_ok:
-                        ln = lean_window(stacked, self.capacity)
-                        self.state, resp = self._decide_scan_lean(
-                            self.state, ln[0], ln[1], 0)
-                release_compile_memory()
+                # different callers' windows, keys at arbitrary lanes: the
+                # table-carried programs
+                resp = self._warm_scan_depth(self._scans, k, self.max_width)
                 k *= 2
             if resp is not None:
                 jax.block_until_ready(resp)
@@ -1625,7 +1642,15 @@ class Engine:
         The worst case this exists for is a hot-key thundering herd: d
         duplicates of one key = d rounds, which the per-round path pays d
         full dispatches for — launch overhead plus a host round trip per
-        dispatch, while the kernel body is cheap."""
+        dispatch, while the kernel body is cheap.
+
+        A group whose windows are nested (round k+1's keys among round
+        k's: every tail preprocess() makes out of rounds that fit one
+        window) is lane-aligned first (_lane_aligned), and its rows ride
+        the scan's carry: one row gather and one row scatter a group. Any
+        other group (round 0 chunked at max_width, so a later round's key
+        lives in a head chunk) keeps the table-carried program, and so
+        does every group of a one-width ladder (_carries)."""
         stage = self.stats.stage_ns
         width = self.min_width  # _split_scannable guarantees every window fits
         union = None  # per-key first occurrence across the WHOLE tail
@@ -1680,15 +1705,22 @@ class Engine:
             k = _bucket_pow2(len(group))
             stacked = np.zeros((k, 9, width), np.int64)
             stacked[:, 0, :] = -1  # pad windows are all padding lanes
-            host_ns = 0
-            for gi, wk in enumerate(group):
+            t = time.perf_counter_ns()
+            group_keys = [[item[1].hash_key() for item in wk]
+                          for wk in group]
+            aligned = self._lane_aligned(group, group_keys) \
+                if self._carries(width) else None
+            carried = aligned is not None
+            if carried:
+                group, group_keys = aligned
+            host_ns = time.perf_counter_ns() - t
+            stage["pack"] += host_ns
+            for gi, (wk, keys) in enumerate(zip(group, group_keys)):
                 t = time.perf_counter_ns()
                 if union is not None:
-                    keys = [item[1].hash_key() for item in wk]
                     slots = [slot_map[k] for k in keys]
                     fresh = [fresh_map.pop(k, False) for k in keys]
                 else:
-                    keys = [item[1].hash_key() for item in wk]
                     slots, fresh, inj = self.directory.lookup_inject(keys)
                     self._apply_inject_rows(inj)
                 t2 = time.perf_counter_ns()
@@ -1700,9 +1732,9 @@ class Engine:
             prof = self.profiler
             prof.observe("prep", host_ns)
             live = sum(len(wk) for wk in group)
-            self.stats.note_scan(len(group), live, k * width)
+            self.stats.note_scan(len(group), live, k * width, carried)
             t = time.perf_counter_ns()
-            staged = self._dispatch_scan_staged(stacked, now_ms)
+            staged = self._dispatch_scan_staged(stacked, now_ms, carried)
             td = time.perf_counter_ns()
             out = self._fetch_staged(staged)
             t2 = time.perf_counter_ns()
@@ -1732,6 +1764,34 @@ class Engine:
             t = time.perf_counter_ns()
             self._store_write_through(uwork, ukeys, uslots, now_ms)
             stage["store"] += time.perf_counter_ns() - t
+
+    @staticmethod
+    def _lane_aligned(group, group_keys):
+        """`group`'s windows and their keys reordered so that a key holds
+        the same lane in every window it stands in, or None where that
+        cannot be had with every window's live lanes a prefix (pack_window,
+        the demux and the ledger's note_slots read lanes [0, n), as
+        before). Lanes go to the first window's keys by how many windows
+        they stay in (a stable sort), which gives nested windows their
+        prefixes. A window's items carry their response index, so their
+        order inside it is free."""
+        stay: Dict[str, int] = {}
+        for keys in group_keys:
+            for k in keys:
+                stay[k] = stay.get(k, 0) + 1
+        first = group_keys[0]
+        if len(stay) != len(first):
+            return None  # a key of a later window has no lane
+        order = sorted(range(len(first)), key=lambda j: -stay[first[j]])
+        lane = {first[j]: n for n, j in enumerate(order)}
+        windows, window_keys = [], []
+        for wk, keys in zip(group, group_keys):
+            order = sorted(range(len(keys)), key=lambda j: lane[keys[j]])
+            if lane[keys[order[-1]]] != len(keys) - 1:
+                return None  # this window's lanes are no prefix
+            windows.append([wk[j] for j in order])
+            window_keys.append([keys[j] for j in order])
+        return windows, window_keys
 
     def _apply_round(self, round_work, now_ms, responses,
                      skip_store: bool = False, resolved=None) -> None:

@@ -362,14 +362,26 @@ def decide(state: TableState, reqs: ReqBatch, now_ms: jax.Array) -> Tuple[TableS
     All requests in the batch must target distinct slots (engine guarantees
     via rounds); padding lanes carry slot == -1.
     """
-    now = jnp.asarray(now_ms, I64)
-    slot = reqs.slot
-    active = slot >= 0
-    gslot = jnp.maximum(slot, 0)  # clipped gather index for padding lanes
-
     # ONE 64-byte row gather per lane (the layout that keeps TPU
-    # gather/scatter off the serialized random-element path)
-    rows = load_rows(state, gslot)  # i64[B, 8]
+    # gather/scatter off the serialized random-element path); padding
+    # lanes gather row 0 and are dropped on the way back
+    rows = load_rows(state, jnp.maximum(reqs.slot, 0))  # i64[B, 8]
+    new_rows, resp = decide_rows(rows, reqs, now_ms)
+    # ONE row scatter back (the -1 pad lanes are dropped)
+    return store_rows(state, reqs.slot, new_rows), resp
+
+
+def decide_rows(rows: jax.Array, reqs: ReqBatch,
+                now_ms: jax.Array) -> Tuple[jax.Array, RespBatch]:
+    """Everything of decide() between the gather and the scatter: the
+    lanes' rows i64[B, 8] and their requests -> the rows as the requests
+    leave them, and the responses. It reads no table, so a row may come
+    from the table (decide) or from the lane's previous round (the carried
+    scans below). A padding lane (slot == -1) gets its row back unchanged
+    and an all-zero response.
+    """
+    now = jnp.asarray(now_ms, I64)
+    active = reqs.slot >= 0
     st_algo = rows[:, ROW_ALGO]
     st_limit = rows[:, ROW_LIMIT]
     st_rem = rows[:, ROW_REMAINING]
@@ -506,8 +518,6 @@ def decide(state: TableState, reqs: ReqBatch, now_ms: jax.Array) -> Tuple[TableS
         ],
         axis=1,
     )
-    # ONE row scatter back (the -1 pad lanes are dropped)
-    new_state = store_rows(state, slot, new_rows)
 
     # ---------------- select response --------------------------------------
     z64 = jnp.zeros_like(r_limit)
@@ -538,7 +548,29 @@ def decide(state: TableState, reqs: ReqBatch, now_ms: jax.Array) -> Tuple[TableS
             (tok_reset, z64),
         ),
     )
-    return new_state, resp
+    return new_rows, resp
+
+
+def _wide_reqs(packed: jax.Array) -> ReqBatch:
+    """The ReqBatch of one wide i64[9, B] staging window (pack_window's
+    row order)."""
+    return ReqBatch(
+        slot=packed[0].astype(I32),
+        hits=packed[1],
+        limit=packed[2],
+        duration=packed[3],
+        algorithm=packed[4].astype(I32),
+        behavior=packed[5].astype(I32),
+        greg_expire=packed[6],
+        greg_interval=packed[7],
+        fresh=packed[8] != 0,
+    )
+
+
+def _wide_response(resp: RespBatch) -> jax.Array:
+    return jnp.stack(
+        [resp.status.astype(I64), resp.limit, resp.remaining, resp.reset_time]
+    )
 
 
 def decide_packed(
@@ -554,22 +586,8 @@ def decide_packed(
     exactly one buffer each way. The host-side packer is pack_window below
     — the row-order contract lives only in this file.
     """
-    reqs = ReqBatch(
-        slot=packed[0].astype(I32),
-        hits=packed[1],
-        limit=packed[2],
-        duration=packed[3],
-        algorithm=packed[4].astype(I32),
-        behavior=packed[5].astype(I32),
-        greg_expire=packed[6],
-        greg_interval=packed[7],
-        fresh=packed[8] != 0,
-    )
-    new_state, resp = decide(state, reqs, now_ms)
-    out = jnp.stack(
-        [resp.status.astype(I64), resp.limit, resp.remaining, resp.reset_time]
-    )
-    return new_state, out
+    new_state, resp = decide(state, _wide_reqs(packed), now_ms)
+    return new_state, _wide_response(resp)
 
 
 def decide_scan_packed(
@@ -582,9 +600,9 @@ def decide_scan_packed(
     calls would — `lax.scan` compiles the kernel body once and loops on
     device, so the per-window cost collapses from one full dispatch (launch
     overhead plus a host round trip) to the on-device loop carry. The
-    engine uses this to retire all duplicate-key *rounds* of a window — a
-    hot-key thundering herd is the worst case, d duplicates = d rounds —
-    in one launch instead of d.
+    table is the carry, so every window pays its own row gather and row
+    scatter and may hold any keys at any lanes: the group launches of
+    different callers' windows ride this one (Engine.launch_windows).
     """
 
     def body(st, pk):
@@ -592,6 +610,42 @@ def decide_scan_packed(
         return st2, out
 
     return jax.lax.scan(body, state, packed_k)
+
+
+def _scan_carried(state: TableState, packed_k: jax.Array, reqs_of, out_of,
+                  now_ms: jax.Array) -> Tuple[TableState, jax.Array]:
+    """K staged rounds of ONE lane-aligned group with the lanes' rows, not
+    the table, as the scan's carry: one row gather before the loop, K times
+    decide_rows on the lanes, one row scatter after it.
+
+    The contract the table-carried scans do not need: inside the stack a
+    lane holds one slot — every round either that slot or -1 (the key sat
+    the round out; decide_rows then hands its row back as it was). So the
+    row a lane would gather in round k+1 is the row it scattered in round
+    k, and the table is touched twice a group instead of twice a round.
+    Same arithmetic in the same order with the same `now`: bit-identical
+    to the table-carried scan of the same stack, table and responses
+    (tests/test_scan_carried.py). The engine aligns a repeated key's rounds
+    so (Engine._apply_windows_scanned); stacks it cannot align keep the
+    table-carried program."""
+    reqs_k = jax.vmap(reqs_of)(packed_k)  # ReqBatch of [K, B] columns
+    slot = reqs_k.slot.max(axis=0)  # the lane's slot; -1: never live
+    rows = load_rows(state, jnp.maximum(slot, 0))
+
+    def body(rows, reqs):
+        rows2, resp = decide_rows(rows, reqs, now_ms)
+        return rows2, out_of(resp)
+
+    rows, out = jax.lax.scan(body, rows, reqs_k)
+    return store_rows(state, slot, rows), out
+
+
+def decide_scan_carried(
+    state: TableState, packed_k: jax.Array, now_ms: jax.Array
+) -> Tuple[TableState, jax.Array]:
+    """decide_scan_packed's contract (i64[K, 9, B] -> i64[K, 4, B]) for a
+    lane-aligned stack, the rows carried (see _scan_carried)."""
+    return _scan_carried(state, packed_k, _wide_reqs, _wide_response, now_ms)
 
 
 # ---------------------------------------------------------------- compact
@@ -612,16 +666,11 @@ _META_FRESH = 1 << 7
 _I32_MAX = (1 << 31) - 1
 
 
-def decide_packed_compact(
-    state: TableState, packed: jax.Array, now_ms: jax.Array
-) -> Tuple[TableState, jax.Array]:
-    """decide() over one compact i32[5, B] staging buffer.
-
-    Bit-identical to decide_packed on any window compact_window() accepts —
-    held so by TestCompactStaging's differential. Returns i32[4, B]."""
+def _compact_reqs(packed: jax.Array) -> ReqBatch:
+    """The ReqBatch of one compact i32[5, B] staging window."""
     meta = packed[4]
     zero64 = jnp.zeros(packed.shape[-1], I64)
-    reqs = ReqBatch(
+    return ReqBatch(
         slot=packed[0],
         hits=packed[1].astype(I64),
         limit=packed[2].astype(I64),
@@ -632,7 +681,16 @@ def decide_packed_compact(
         greg_interval=zero64,
         fresh=(meta & _META_FRESH) != 0,
     )
-    new_state, resp = decide(state, reqs, now_ms)
+
+
+def decide_packed_compact(
+    state: TableState, packed: jax.Array, now_ms: jax.Array
+) -> Tuple[TableState, jax.Array]:
+    """decide() over one compact i32[5, B] staging buffer.
+
+    Bit-identical to decide_packed on any window compact_window() accepts —
+    held so by TestCompactStaging's differential. Returns i32[4, B]."""
+    new_state, resp = decide(state, _compact_reqs(packed), now_ms)
     return new_state, _compact_response(resp, now_ms)
 
 
@@ -662,6 +720,16 @@ def decide_scan_packed_compact(
         return st2, out
 
     return jax.lax.scan(body, state, packed_k)
+
+
+def decide_scan_carried_compact(
+    state: TableState, packed_k: jax.Array, now_ms: jax.Array
+) -> Tuple[TableState, jax.Array]:
+    """decide_scan_packed_compact's contract (i32[K, 5, B] -> i32[K, 4, B])
+    for a lane-aligned stack, the rows carried (see _scan_carried)."""
+    return _scan_carried(
+        state, packed_k, _compact_reqs,
+        lambda resp: _compact_response(resp, now_ms), now_ms)
 
 
 def compact_window(packed):
@@ -908,6 +976,37 @@ def lean_capacity_ok(capacity: int) -> bool:
     return capacity <= _LEAN_SLOT_MASK
 
 
+def _config_rows(cfg: jax.Array, cfgid: jax.Array) -> jax.Array:
+    """cfg[cfgid] as i64[..., 4], by a one-hot select over the table's few
+    rows and not by a gather: the chip gathers element by element whatever
+    the table's size (four column gathers were 4.2 ms of a 32 x 2048-lane
+    lean scan, 4.89 ms against the compact one's 0.64; the select is 0.15,
+    PERF.md PR 39), and exactly one row is hot, so the sum is that row."""
+    hot = cfgid[..., None] == jnp.arange(cfg.shape[0], dtype=cfgid.dtype)
+    return jnp.where(hot[..., None], cfg, 0).sum(axis=-2)
+
+
+def _lean_reqs(lane: jax.Array, cfg: jax.Array) -> ReqBatch:
+    """The ReqBatch of one lean i32[B] lane-word window and its config
+    table; hits = 1 implied."""
+    slot24 = lane & _LEAN_SLOT_MASK
+    slot = jnp.where(slot24 == _LEAN_PAD, jnp.asarray(-1, I32), slot24)
+    cfgid = (lane >> _LEAN_CFG_SHIFT) & (LEAN_MAX_CFG - 1)
+    zero64 = jnp.zeros(lane.shape[-1], I64)
+    config = _config_rows(cfg, cfgid)
+    return ReqBatch(
+        slot=slot,
+        hits=jnp.ones(lane.shape[-1], I64),
+        limit=config[..., 0],
+        duration=config[..., 1],
+        algorithm=config[..., 2].astype(I32),
+        behavior=config[..., 3].astype(I32),
+        greg_expire=zero64,
+        greg_interval=zero64,
+        fresh=((lane >> _LEAN_FRESH_SHIFT) & 1) != 0,
+    )
+
+
 def decide_packed_lean(
     state: TableState, packed: jax.Array, cfg: jax.Array, now_ms: jax.Array
 ) -> Tuple[TableState, jax.Array]:
@@ -916,23 +1015,7 @@ def decide_packed_lean(
     implied. Bit-identical to decide_packed on any window lean_window()
     accepts (TestLeanStaging differential). Returns the compact i32[4, B]
     response rows."""
-    lane = packed
-    slot24 = lane & _LEAN_SLOT_MASK
-    slot = jnp.where(slot24 == _LEAN_PAD, jnp.asarray(-1, I32), slot24)
-    cfgid = (lane >> _LEAN_CFG_SHIFT) & (LEAN_MAX_CFG - 1)
-    zero64 = jnp.zeros(lane.shape[-1], I64)
-    reqs = ReqBatch(
-        slot=slot,
-        hits=jnp.ones(lane.shape[-1], I64),
-        limit=cfg[cfgid, 0],
-        duration=cfg[cfgid, 1],
-        algorithm=cfg[cfgid, 2].astype(I32),
-        behavior=cfg[cfgid, 3].astype(I32),
-        greg_expire=zero64,
-        greg_interval=zero64,
-        fresh=((lane >> _LEAN_FRESH_SHIFT) & 1) != 0,
-    )
-    new_state, resp = decide(state, reqs, now_ms)
+    new_state, resp = decide(state, _lean_reqs(packed, cfg), now_ms)
     return new_state, _compact_response(resp, now_ms)
 
 
@@ -948,6 +1031,17 @@ def decide_scan_packed_lean(
         return st2, out
 
     return jax.lax.scan(body, state, packed_k)
+
+
+def decide_scan_carried_lean(
+    state: TableState, packed_k: jax.Array, cfg: jax.Array, now_ms: jax.Array
+) -> Tuple[TableState, jax.Array]:
+    """decide_scan_packed_lean's contract (i32[K, B] + config table ->
+    i32[K, 4, B]) for a lane-aligned stack, the rows carried (see
+    _scan_carried)."""
+    return _scan_carried(
+        state, packed_k, lambda lane: _lean_reqs(lane, cfg),
+        lambda resp: _compact_response(resp, now_ms), now_ms)
 
 
 def lean_window(packed, capacity: int):

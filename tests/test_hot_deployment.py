@@ -112,7 +112,8 @@ class _Node:
         self.widest_pull = 0  # items of the largest pull served
         # what the engine launched, counted beside its own counters
         self.launched = {"single": 0, "scan": 0, "scan_rounds": 0,
-                         "scan_live": 0, "scan_lanes": 0, "shapes": set()}
+                         "carried_rounds": 0, "scan_live": 0,
+                         "scan_lanes": 0, "shapes": set()}
         single, scan = (self.engine._dispatch_staged,
                         self.engine._dispatch_scan_staged)
 
@@ -121,14 +122,19 @@ class _Node:
             self.launched["shapes"].add(packed.shape)
             return single(packed, now_ms)
 
-        def spy_scan(stacked, now_ms):
+        def spy_scan(stacked, now_ms, carried=False):
             live = stacked[:, 0, :] >= 0
             self.launched["scan"] += 1
             self.launched["scan_rounds"] += int(live.any(axis=1).sum())
+            if carried:  # a lane holds one slot through the stack
+                self.launched["carried_rounds"] += int(
+                    live.any(axis=1).sum())
+                slots = np.where(live, stacked[:, 0, :], -1)
+                assert ((slots == slots.max(axis=0)) | ~live).all()
             self.launched["scan_live"] += int(live.sum())
             self.launched["scan_lanes"] += live.size
             self.launched["shapes"].add(stacked.shape)
-            return scan(stacked, now_ms)
+            return scan(stacked, now_ms, carried)
 
         self.engine._dispatch_staged = spy_single
         self.engine._dispatch_scan_staged = spy_scan
@@ -314,6 +320,10 @@ class TestTheLadderChangesShapesNeverAnswers:
         # rounds in scans + rounds on a launch of their own = rounds
         assert st.scan_dispatches == seen["scan"] > 0
         assert st.scan_rounds == seen["scan_rounds"]
+        # a repeated key's rounds are nested: below max_width every one
+        # rode the carry, at it (one program a shape) the table
+        assert st.scan_rounds_carried == seen["carried_rounds"] \
+            == (st.scan_rounds if which == "ladder" else 0)
         assert st.rounds - st.scan_rounds == seen["single"]
         assert st.scan_lanes_live == seen["scan_live"]
         assert st.scan_lanes == seen["scan_lanes"]
@@ -321,9 +331,10 @@ class TestTheLadderChangesShapesNeverAnswers:
         assert st.scan_rounds <= st.scan_dispatches * Engine._MAX_SCAN
         d = st.as_dict()
         assert [d[k] for k in ("scan_dispatches", "scan_rounds",
-                               "scan_lanes_live", "scan_lanes")] == \
-            [st.scan_dispatches, st.scan_rounds, st.scan_lanes_live,
-             st.scan_lanes]
+                               "scan_rounds_carried", "scan_lanes_live",
+                               "scan_lanes")] == \
+            [st.scan_dispatches, st.scan_rounds, st.scan_rounds_carried,
+             st.scan_lanes_live, st.scan_lanes]
         assert all(isinstance(v, int) for v in d.values())
 
     def test_the_phase_and_the_family_say_the_same(self, served):
@@ -413,15 +424,18 @@ def test_a_capture_gets_a_leftover_span_a_chunk(monkeypatch):
 
 
 def test_every_scan_shape_the_combiner_launches_is_one_the_warm_up_compiled():
-    """On a ladder `warmup()` compiles scan depths at the bottom width only
-    and `warmup_pipeline` at the top width only, and `launch_windows`
-    launches a group of K > 1 windows at the group's own bucket width. The
-    combiner cannot reach a width between the two: it opens a second window
-    only when the next submission would overflow the first, so one of the
-    two holds more than half of `max_width` and the group launches
-    `max_width` wide; two callers' leftovers merge into ONE window, whose
-    single launch is warmed at every width, and its repeats retire in
-    `min_width` scans."""
+    """On a ladder `warmup()` compiles the row-carried scan depths at the
+    bottom width only and `warmup_pipeline` the table-carried ones at the
+    top width only, and `launch_windows` launches a group of K > 1 windows
+    (table-carried: different callers' keys at arbitrary lanes) at the
+    group's own bucket width. The combiner cannot reach a width between the
+    two: it opens a second window only when the next submission would
+    overflow the first, so one of the two holds more than half of
+    `max_width` and the group launches `max_width` wide; two callers'
+    leftovers merge into ONE window, whose single launch is warmed at every
+    width, and its repeats retire in `min_width` scans on the carry (their
+    rounds are nested). So after the two warm-ups no scan program compiles,
+    carried or not."""
     import threading
 
     from gubernator_tpu.service.combiner import BackendCombiner
@@ -431,11 +445,16 @@ def test_every_scan_shape_the_combiner_launches_is_one_the_warm_up_compiled():
     eng = Engine(capacity=4096, min_width=lo, max_width=hi)
     if not eng.supports_pipeline():
         pytest.skip("native prep unavailable")
-    warmed = {(k, 9, lo) for k in (2, 4, 8, 16, 32)} \
-        | {(k, 9, hi) for k in (2, 4, 8)}
+    eng.warmup()
+    eng.warmup_pipeline(max_group=scan)
+    programs = eng._scans + eng._scans_carried
+    compiled = [fn._cache_size() for fn in programs]
+    warmed = {((k, 9, lo), True) for k in (2, 4, 8, 16, 32)} \
+        | {((k, 9, hi), False) for k in (2, 4, 8)}
     shapes, real = [], eng._dispatch_scan_staged
-    eng._dispatch_scan_staged = lambda stacked, now_ms: (
-        shapes.append(stacked.shape), real(stacked, now_ms))[1]
+    eng._dispatch_scan_staged = lambda stacked, now_ms, carried=False: (
+        shapes.append((stacked.shape, carried)),
+        real(stacked, now_ms, carried))[1]
     gate, launch = threading.Event(), eng.launch_windows
     eng.launch_windows = lambda *a, **kw: (gate.wait(10), launch(*a, **kw))[1]
     comb = BackendCombiner(eng, depth=3, scan=scan)
@@ -452,12 +471,53 @@ def test_every_scan_shape_the_combiner_launches_is_one_the_warm_up_compiled():
                 futs.append(comb.submit_async([RateLimitReq(
                     name="w", unique_key="hot" if trial % 2 and i < 4
                     else f"t{trial}s{n}k{i}",
-                    hits=1, limit=1000, duration=60_000)
+                    hits=1 + (i + n) % 3, limit=1000, duration=60_000)
                     for i in range(size)], now_ms=NOW))
             gate.set()
             assert [len(f.result(30)) for f in futs] == sizes
         assert set(shapes) <= warmed, set(shapes) - warmed
-        assert {s for s in shapes if s[2] == hi}  # groups of K > 1 ran
-        assert {s for s in shapes if s[2] == lo}  # and repeats' scans
+        assert {s for s, c in shapes if s[2] == hi and not c}  # groups ran
+        assert {s for s, c in shapes if s[2] == lo and c}  # repeats' scans
+        assert [fn._cache_size() for fn in programs] == compiled
     finally:
         comb.close()
+
+
+@pytest.mark.parametrize("lo, hi, programs, carried", [
+    # the hot ladder: 3 widths x 3 staging formats, 15 carried scans at the
+    # bottom, 9 group scans at the top (33 before the carry too)
+    (2048, 8192, 33, 15),
+    # one width: 3 + 15 table-carried scans, 9 of them the group shapes (18
+    # before the carry too: one program a shape)
+    (8192, 8192, 18, 0),
+])
+def test_the_programs_the_two_warm_ups_compile(lo, hi, programs, carried):
+    """Counted by the launches the warm-ups make, nothing compiled: each
+    distinct (program, argument shapes) is one XLA program."""
+    eng = Engine(capacity=2 * hi, min_width=lo, max_width=hi)
+    if not eng.supports_pipeline():
+        pytest.skip("native prep unavailable")
+    seen = set()
+
+    def spy(name):
+        def launch(state, *args):
+            seen.add((name,) + tuple(np.shape(a) for a in args[:-1]))
+            return state, None
+        return launch
+
+    eng._decide_packed = spy("packed_wide")
+    eng._decide_packed_compact = spy("packed_compact")
+    eng._decide_packed_lean = spy("packed_lean")
+    eng._scans = tuple(spy("scan_" + f) for f in ("wide", "compact", "lean"))
+    eng._scans_carried = tuple(
+        spy("carry_" + f) for f in ("wide", "compact", "lean"))
+    eng.warmup()
+    eng.warmup_pipeline(max_group=8)  # the daemon's GUBER_PIPELINE_SCAN
+    assert len(seen) == programs
+    on_rows = {s for s in seen if s[0].startswith("carry_")}
+    on_table = {s for s in seen if s[0].startswith("scan_")}
+    widths = {w for w in (lo, 2 * lo, 4 * lo) if w <= hi}
+    assert len(on_rows) == carried  # the rest: the single-window programs
+    assert len(seen) - len(on_table) - carried == 3 * len(widths)
+    assert {s[1][-1] for s in on_rows} <= {lo} - {hi}
+    assert {s[1][-1] for s in on_table} == {hi}
