@@ -175,7 +175,13 @@ class EngineStats:
     scan rounds whose rows rode the scan's carry (one row gather and one
     row scatter a dispatch, not one of each a round). rounds - scan_rounds
     rode a launch of their own, scan_rounds - scan_rounds_carried rode the
-    table, the rest the carry."""
+    table, the rest the carry.
+
+    The link counters are bumped in the funnels every launch and every
+    fetch goes through (Engine._launch, _fetch_staged): `staged_bytes` the
+    host arrays handed to the program (their nbytes, whichever wire format
+    carried the window), `fetched_bytes` what was copied back (the device
+    array's nbytes, before widening)."""
 
     STAGES = ("prep", "lookup", "store", "pack", "device", "demux")
 
@@ -191,6 +197,8 @@ class EngineStats:
         self.scan_rounds_carried = 0
         self.scan_lanes_live = 0
         self.scan_lanes = 0
+        self.staged_bytes = 0
+        self.fetched_bytes = 0
         self.stage_ns = {s: 0 for s in self.STAGES}
 
     def note_scan(self, rounds: int, live: int, lanes: int,
@@ -212,7 +220,9 @@ class EngineStats:
                  scan_rounds=self.scan_rounds,
                  scan_rounds_carried=self.scan_rounds_carried,
                  scan_lanes_live=self.scan_lanes_live,
-                 scan_lanes=self.scan_lanes)
+                 scan_lanes=self.scan_lanes,
+                 staged_bytes=self.staged_bytes,
+                 fetched_bytes=self.fetched_bytes)
         for s, ns in self.stage_ns.items():
             d[f"{s}_ns"] = ns
         return d
@@ -396,52 +406,33 @@ class Engine:
         compact (20 B/lane) otherwise, wide as the last resort. Returns an
         opaque handle for _fetch_staged. Caller holds the engine lock
         (self.state is donated and rebound here)."""
+        prof = self.profiler
+        t_in = time.perf_counter_ns() if prof.enabled else 0
+        sub = prof.seams()  # nested in the caller's `dispatch`
+        sub("stage")
         ht = self.hot_tracker
         if ht is not None:
             # the staged rows are already host numpy: two bulk adds per
             # window, no per-key cost (service/leases.py)
             ht.feed_slots(packed[0], packed[1])
         w = packed.shape[1]
+        # host arrays go to the program as they are: its call path places
+        # them, an explicit jnp.asarray first is ~0.3 ms of Python a
+        # window under the GIL
         if self._staging != "wide":
             if self._lean_ok:
                 ln = lean_window(packed, self.capacity)
                 if ln is not None:
-                    # host arrays go to the program as they are: its call
-                    # path places them, an explicit jnp.asarray first is
-                    # ~0.3 ms of Python a window under the GIL
-                    if kernel_telemetry.needs_probe("packed_lean", w):
-                        kernel_telemetry.offer_probe(
-                            "packed_lean", w, self._decide_packed_lean,
-                            (self.state, ln[0], ln[1], now_ms))
-                    t = time.perf_counter_ns()
-                    self.state, out = self._decide_packed_lean(
-                        self.state, ln[0], ln[1], now_ms)
-                    kernel_telemetry.note(
-                        "packed_lean", w,
-                        dur_ns=time.perf_counter_ns() - t)
-                    return out, now_ms
+                    return self._launch(
+                        "packed_lean", self._decide_packed_lean, ln, w, 1,
+                        now_ms, t_in, sub), now_ms
             c = compact_window(packed)
             if c is not None:
-                if kernel_telemetry.needs_probe("packed_compact", w):
-                    kernel_telemetry.offer_probe(
-                        "packed_compact", w, self._decide_packed_compact,
-                        (self.state, c, now_ms))
-                t = time.perf_counter_ns()
-                self.state, out = self._decide_packed_compact(
-                    self.state, c, now_ms)
-                kernel_telemetry.note(
-                    "packed_compact", w,
-                    dur_ns=time.perf_counter_ns() - t)
-                return out, now_ms
-        if kernel_telemetry.needs_probe("packed_wide", w):
-            kernel_telemetry.offer_probe(
-                "packed_wide", w, self._decide_packed,
-                (self.state, packed, now_ms))
-        t = time.perf_counter_ns()
-        self.state, out = self._decide_packed(self.state, packed, now_ms)
-        kernel_telemetry.note("packed_wide", w,
-                              dur_ns=time.perf_counter_ns() - t)
-        return out, None
+                return self._launch(
+                    "packed_compact", self._decide_packed_compact, (c,), w,
+                    1, now_ms, t_in, sub), now_ms
+        return self._launch("packed_wide", self._decide_packed, (packed,),
+                            w, 1, now_ms, t_in, sub), None
 
     def _dispatch_scan_staged(self, stacked: np.ndarray, now_ms,
                               carried: bool = False):
@@ -450,36 +441,54 @@ class Engine:
         programs: the caller has aligned the stack's lanes (a lane holds
         one slot through the stack; ops/decide.py _scan_carried). Handle
         contract matches _dispatch_staged. Caller holds the engine lock."""
+        prof = self.profiler
+        t_in = time.perf_counter_ns() if prof.enabled else 0
+        sub = prof.seams()
+        sub("stage")
         ht = self.hot_tracker
         if ht is not None:
             ht.feed_slots(stacked[:, 0, :], stacked[:, 1, :])
         wide, compact, lean = self._scans_carried if carried else self._scans
         tag = "carry" if carried else "scan"
+        k, w = stacked.shape[0], stacked.shape[2]
         if self._staging != "wide":
             if self._lean_ok:
                 ln = lean_window(stacked, self.capacity)
                 if ln is not None:
-                    return self._launch_scan(
-                        tag + "_lean", lean, ln, stacked, now_ms), now_ms
+                    return self._launch(tag + "_lean", lean, ln, w, k,
+                                        now_ms, t_in, sub), now_ms
             c = compact_window(stacked)
             if c is not None:
-                return self._launch_scan(
-                    tag + "_compact", compact, (c,), stacked, now_ms), now_ms
-        return self._launch_scan(
-            tag + "_wide", wide, (stacked,), stacked, now_ms), None
+                return self._launch(tag + "_compact", compact, (c,), w, k,
+                                    now_ms, t_in, sub), now_ms
+        return self._launch(tag + "_wide", wide, (stacked,), w, k, now_ms,
+                            t_in, sub), None
 
-    def _launch_scan(self, kernel: str, fn, staged, stacked, now_ms):
-        """One scan launch of `fn` over the `staged` form of `stacked`,
-        told to the kernel telemetry under `kernel`. Caller holds the
-        engine lock."""
-        k, w = stacked.shape[0], stacked.shape[2]
+    def _launch(self, kernel: str, fn, staged, w: int, depth: int, now_ms,
+                t_in: int, sub):
+        """The one launch: `fn` over `staged`, the host arrays of a window
+        (or of a stack `depth` windows deep) `w` lanes wide, told to the
+        kernel telemetry under `kernel`. Everything since the funnel's
+        entry at `t_in` (0: the profiler is off) was `stage`; the jitted
+        call (enqueue and host -> device placement) is `launch`, and one
+        pair of clock reads feeds that phase and the telemetry's
+        histogram. `sub` is the funnel's span chain, closed here. Caller
+        holds the engine lock."""
         if kernel_telemetry.needs_probe(kernel, w):
             kernel_telemetry.offer_probe(
                 kernel, w, fn, (self.state, *staged, now_ms))
+        for a in staged:
+            self.stats.staged_bytes += a.nbytes
+        sub("launch")
         t = time.perf_counter_ns()
         self.state, out = fn(self.state, *staged, now_ms)
-        kernel_telemetry.note(kernel, w, depth=k,
-                              dur_ns=time.perf_counter_ns() - t)
+        t2 = time.perf_counter_ns()
+        sub(None)
+        kernel_telemetry.note(kernel, w, depth=depth, dur_ns=t2 - t)
+        if t_in:
+            prof = self.profiler
+            prof.observe_sub("stage", t - t_in)
+            prof.observe_sub("launch", t2 - t)
         return out
 
     def _obs_device(self, ns: int, lanes: int) -> None:
@@ -528,14 +537,35 @@ class Engine:
             pass
         return out
 
-    @staticmethod
-    def _fetch_staged(handle) -> np.ndarray:
-        """Block on a dispatched window and return the wide i64 response
-        rows regardless of which wire format carried it."""
+    def _fetch_staged(self, handle):
+        """Block on a dispatched window and return (the wide i64 response
+        rows regardless of which wire format carried it, the bytes copied
+        back for them). Needs no lock; the caller adds the bytes to
+        `fetched_bytes` where it holds one.
+
+        The copy waits for the device by itself. While a capture runs the
+        wait is made apart from it (`device_wait`, then the copy and its
+        widening as `fetch`: both inside the caller's `readback`); that is
+        a second release of the GIL a window, ~1% of a busy daemon's rate
+        (PERF.md section 6, PR 40), so nobody pays it otherwise."""
         out, compact_now = handle
-        if compact_now is not None:
-            return widen_compact_out(out, compact_now)
-        return np.asarray(out)
+        prof = self.profiler
+        split = prof.capturing
+        if split:
+            sub = prof.seams()  # nested in the caller's `readback`
+            sub("device_wait")
+            t0 = time.perf_counter_ns()
+            out.block_until_ready()
+            t1 = time.perf_counter_ns()
+            sub("fetch")
+        rows = (np.asarray(out) if compact_now is None
+                else widen_compact_out(out, compact_now))
+        if split:
+            t2 = time.perf_counter_ns()
+            sub(None)
+            prof.observe_sub("device_wait", t1 - t0)
+            prof.observe_sub("fetch", t2 - t1)
+        return rows, out.nbytes
 
     def get_rate_limits(
         self, requests: Sequence[RateLimitReq], now_ms: Optional[int] = None
@@ -556,16 +586,20 @@ class Engine:
         duplicate-key round splitting (models/prep.py). `count_batch` is
         False when called as a fast window's leftover tail — the client
         batch was already counted there."""
+        prof = self.profiler
+        seams = prof.seams()  # host spans, while a capture runs
+        seams("prep")
         t0 = time.perf_counter_ns()
         responses, rounds, n_errors = preprocess(requests, now_ms)
         prep_ns = time.perf_counter_ns() - t0  # excludes the lock wait below
-        prof = self.profiler
         prof.observe("prep", prep_ns)
-
         tq = time.perf_counter_ns() if prof.enabled else 0
+        seams("lock_wait")
         with self._lock:
             if tq:
-                prof.lock_wait("slow_window", time.perf_counter_ns() - tq)
+                t0 = time.perf_counter_ns()
+                prof.lock_wait("slow_window", t0 - tq)
+            seams(None)  # each round writes its own chain from here
             self.stats.stage_ns["prep"] += prep_ns
             self.stats.requests += len(requests)
             self.stats.batches += 1 if count_batch else 0
@@ -580,6 +614,8 @@ class Engine:
                 self._apply_round(wk, now_ms, responses)
             if tail:
                 self._apply_windows_scanned(tail, now_ms, responses)
+            if tq:
+                prof.lock_hold("slow_window", time.perf_counter_ns() - t0)
         return responses  # type: ignore[return-value]
 
     def _fast_window(self, requests, now_ms) -> Optional[List[RateLimitResp]]:
@@ -596,9 +632,10 @@ class Engine:
         Returns None only for windows the native path can't start at all
         (nothing mutated)."""
         w = _bucket_width(len(requests), self.min_width, self.max_width)
-        packed = np.zeros((9, w), np.int64)
         prof = self.profiler
         seams = prof.seams()  # host spans, while a capture runs
+        seams("alloc")
+        packed = np.zeros((9, w), np.int64)
         tq = time.perf_counter_ns() if prof.enabled else 0
         seams("lock_wait")
         with self._lock:
@@ -617,6 +654,10 @@ class Engine:
                     f"key directory over-committed: >{self.capacity} "
                     "distinct keys in one lookup")
             if n0 < 0:
+                seams(None)
+                if tq:
+                    prof.lock_hold("fast_window",
+                                   time.perf_counter_ns() - t0)
                 return None
             stage = self.stats.stage_ns
             t1 = time.perf_counter_ns()
@@ -632,9 +673,10 @@ class Engine:
                 staged = self._dispatch_staged(packed, now_ms)
                 td = time.perf_counter_ns()
                 seams("readback")
-                out = self._fetch_staged(staged)
+                out, nbytes = self._fetch_staged(staged)
                 t2 = time.perf_counter_ns()
                 seams("demux")
+                self.stats.fetched_bytes += nbytes
                 stage["device"] += t2 - t1
                 self._obs_device(t2 - t1, n0)
                 prof.observe("dispatch", td - t1)
@@ -656,6 +698,8 @@ class Engine:
                 if led is not None and led.enabled:
                     led.note_slots(packed, out, n0)
             seams(None)
+            if tq:
+                prof.lock_hold("fast_window", time.perf_counter_ns() - t0)
         if len(leftover):
             idxs = leftover.tolist()
             tail = self._slow_window(
@@ -685,6 +729,21 @@ class Engine:
         host calls around every window)."""
         return self._prep_fast is not None and self.store is None
 
+    @staticmethod
+    def _staging_buffer(shape, staging, seams) -> np.ndarray:
+        """The zeroed i64 staging stack of a group launch: the one parked
+        in `staging` under its shape (a pipeline slot's own dict), zeroed
+        again, or a new one parked there; an `alloc` span on `seams`."""
+        seams("alloc")
+        buf = None if staging is None else staging.get(shape)
+        if buf is None:
+            buf = np.zeros(shape, np.int64)
+            if staging is not None:
+                staging[shape] = buf
+        else:
+            buf.fill(0)  # the prep contract: zeroed staging rows
+        return buf
+
     def launch_windows(self, windows, now_ms: Optional[int] = None,
                        staging=None):
         """Dispatch 1..K request-object windows as ONE device launch
@@ -709,14 +768,9 @@ class Engine:
         w = max(_bucket_width(len(wk), self.min_width, self.max_width)
                 for wk in windows)
         kb = _bucket_pow2(k_req) if k_req > 1 else 1
-        shape = (kb, 9, w)
-        buf = None if staging is None else staging.get(shape)
-        if buf is None:
-            buf = np.zeros(shape, np.int64)
-            if staging is not None:
-                staging[shape] = buf
-        else:
-            buf.fill(0)  # the prep contract: zeroed staging rows
+        prof = self.profiler
+        seams = prof.seams()  # host spans, while a capture runs
+        buf = self._staging_buffer((kb, 9, w), staging, seams)
         # Segmented group launch. A window whose prep yields LEFTOVERS
         # (duplicate occurrences, gregorian, invalid) CUTS the group: the
         # segment so far dispatches and its tails retire before any later
@@ -729,7 +783,6 @@ class Engine:
         meta: List[Optional[tuple]] = [None] * k_req
         tails: List[Optional[list]] = [None] * k_req
         segments = []  # (staged, k_start, m, scanned) in launch order
-        prof = self.profiler
         led = self.ledger
         if led is not None and not led.enabled:
             led = None
@@ -738,10 +791,12 @@ class Engine:
         while k < k_req:
             seg_start = k
             tq = time.perf_counter_ns() if prof.enabled else 0
+            seams("lock_wait")
             with self._lock:
                 t0 = time.perf_counter_ns()  # excludes the lock wait
                 if tq:
                     prof.lock_wait("launch_windows", t0 - tq)
+                seams("prep")
                 total = 0
                 rounds = 0
                 cut = False
@@ -779,6 +834,7 @@ class Engine:
                 self.stats.requests += total
                 self.stats.batches += m
                 self.stats.rounds += rounds
+                seams("dispatch")
                 if m == 1:
                     staged = self._dispatch_staged(buf[seg_start], now_ms)
                     scanned = False
@@ -793,9 +849,10 @@ class Engine:
                     elif kb2 == m:
                         stack = buf[seg_start:k]  # contiguous prefix run
                     else:  # rare (a cut left a non-pow2 run): copy-pad
-                        stack = np.zeros((kb2, 9, w), np.int64)
-                        stack[:m] = buf[seg_start:k]
-                        stack[m:, 0, :] = -1
+                        with prof.span("alloc"):
+                            stack = np.zeros((kb2, 9, w), np.int64)
+                            stack[:m] = buf[seg_start:k]
+                            stack[m:, 0, :] = -1
                     self.stats.note_scan(rounds, total, len(stack) * w)
                     staged = self._dispatch_scan_staged(stack, now_ms)
                     scanned = True
@@ -808,6 +865,10 @@ class Engine:
                     for kk in range(seg_start, k):
                         stashes[kk] = led.stash_columns(
                             buf[kk], meta[kk][0])
+                seams(None)
+                if tq:
+                    prof.lock_hold("launch_windows",
+                                   time.perf_counter_ns() - t0)
             segments.append((staged, seg_start, m, scanned))
             # Leftover tails retire NOW — after this segment's dispatch,
             # before any later window preps — preserving per-key
@@ -835,11 +896,18 @@ class Engine:
         over = 0
         lanes = 0
         t_fetch = 0
+        fetched = 0
+        prof = self.profiler
+        seams = prof.seams()  # host spans, while a capture runs
         t0 = time.perf_counter_ns()
         for staged, seg_start, m, scanned in segments:
+            seams("readback")
             tf = time.perf_counter_ns()
-            out = self._fetch_staged(staged)  # device sync, this segment
+            # device sync, this segment
+            out, nbytes = self._fetch_staged(staged)
             t_fetch += time.perf_counter_ns() - tf
+            seams("demux")
+            fetched += nbytes
             for k in range(seg_start, seg_start + m):
                 wk = windows[k]
                 n0, lane_item, leftover = meta[k]
@@ -871,12 +939,13 @@ class Engine:
                         responses[i] = resp
                 results[k] = responses
         t2 = time.perf_counter_ns()
+        seams(None)
         self._obs_device(t_fetch, lanes)
-        prof = self.profiler
         prof.observe("readback", t_fetch)
         prof.observe("demux", t2 - t0 - t_fetch)
         with self._lock:  # concurrent completers: counters stay exact
             self.stats.over_limit += over
+            self.stats.fetched_bytes += fetched
             self.stats.stage_ns["device"] += t_fetch
             self.stats.stage_ns["demux"] += t2 - t0 - t_fetch
         return results
@@ -893,7 +962,8 @@ class Engine:
             return self._dispatch_staged(packed, 0)
 
     def collect_noop(self, handle) -> None:
-        """Block on a launch_noop readback."""
+        """Block on a launch_noop readback (its bytes are not the link
+        counters': no request rode it)."""
         self._fetch_staged(handle)
 
     def warmup_pipeline(self, max_group: int = 8) -> None:
@@ -944,9 +1014,10 @@ class Engine:
         from gubernator_tpu import native
 
         w = _bucket_width(n, self.min_width, self.max_width)
-        packed = np.zeros((9, w), np.int64)
         prof = self.profiler
         seams = prof.seams()  # host spans, while a capture runs
+        seams("alloc")
+        packed = np.zeros((9, w), np.int64)
         tq = time.perf_counter_ns() if prof.enabled else 0
         seams("lock_wait")
         with self._lock:
@@ -963,6 +1034,10 @@ class Engine:
                     f"key directory over-committed: >{self.capacity} "
                     "distinct keys in one lookup")
             if n0 < 0:
+                seams(None)
+                if tq:
+                    prof.lock_hold("submit_columnar",
+                                   time.perf_counter_ns() - t0)
                 return None
             t1 = time.perf_counter_ns()
             self.stats.stage_ns["prep"] += t1 - t0
@@ -983,6 +1058,9 @@ class Engine:
                 if led is not None and led.enabled:
                     stash = led.stash_columns(packed, n0)
             seams(None)
+            if tq:
+                prof.lock_hold("submit_columnar",
+                               time.perf_counter_ns() - t0)
         return (handle, lane_item, leftover, n0, stash)
 
     def complete_columnar(self, handle, out_status, out_limit,
@@ -996,7 +1074,8 @@ class Engine:
             seams = self.profiler.seams()
             seams("readback")
             t0 = time.perf_counter_ns()
-            rows = self._fetch_staged(staged)  # device sync for THIS window
+            # device sync for THIS window
+            rows, nbytes = self._fetch_staged(staged)
             t1 = time.perf_counter_ns()
             seams("demux")
             led = self.ledger
@@ -1015,6 +1094,7 @@ class Engine:
             prof.observe("demux", t2 - t1)
             with self._lock:  # concurrent completers: counters stay exact
                 self.stats.over_limit += over
+                self.stats.fetched_bytes += nbytes
                 self.stats.stage_ns["device"] += t1 - t0
                 self.stats.stage_ns["demux"] += t2 - t1
         return leftover
@@ -1067,22 +1147,15 @@ class Engine:
         w = max(_bucket_width(wc[0], self.min_width, self.max_width)
                 for wc in windows)
         kb = _bucket_pow2(k_req) if k_req > 1 else 1
-        shape = (kb, 9, w)
-        buf = None if staging is None else staging.get(shape)
-        if buf is None:
-            buf = np.zeros(shape, np.int64)
-            if staging is not None:
-                staging[shape] = buf
-        else:
-            buf.fill(0)  # the prep contract: zeroed staging rows
+        prof = self.profiler
+        seams = prof.seams()  # host spans, while a capture runs
+        buf = self._staging_buffer((kb, 9, w), staging, seams)
         metas: List[tuple] = []
         failed = None
-        prof = self.profiler
         led = self.ledger
         if led is not None and not led.enabled:
             led = None
         stashes: List[Optional[tuple]] = []
-        seams = prof.seams()  # host spans, while a capture runs
         tq = time.perf_counter_ns() if prof.enabled else 0
         seams("lock_wait")
         with self._lock:
@@ -1111,6 +1184,10 @@ class Engine:
                     break
                 if n0 < 0:
                     if k == 0:
+                        seams(None)
+                        if tq:
+                            prof.lock_hold("launch_columnar_windows",
+                                           time.perf_counter_ns() - t0)
                         return None  # nothing mutated: object-path fallback
                     # defensive — the size preconditions rule this out;
                     # nothing committed for THIS window, so it retires
@@ -1155,6 +1232,9 @@ class Engine:
                     stashes = [led.stash_columns(buf[kk], metas[kk][0])
                                for kk in range(m)]
                 seams(None)
+            if tq:
+                prof.lock_hold("launch_columnar_windows",
+                               time.perf_counter_ns() - t0)
         return (metas, failed, staged, scanned, stashes)
 
     def collect_columnar_windows(self, handle, outs):
@@ -1172,7 +1252,8 @@ class Engine:
         seams = self.profiler.seams()
         seams("readback")
         t0 = time.perf_counter_ns()
-        rows_all = self._fetch_staged(staged) if staged is not None else None
+        rows_all, nbytes = (self._fetch_staged(staged)
+                            if staged is not None else (None, 0))
         t1 = time.perf_counter_ns()
         seams("demux")
         over = 0
@@ -1201,6 +1282,7 @@ class Engine:
         prof.observe("demux", t2 - t1)
         with self._lock:  # concurrent completers: counters stay exact
             self.stats.over_limit += over
+            self.stats.fetched_bytes += nbytes
             self.stats.stage_ns["device"] += t1 - t0
             self.stats.stage_ns["demux"] += t2 - t1
         return leftovers
@@ -1703,9 +1785,13 @@ class Engine:
                                   resolved=resolved)
                 continue
             k = _bucket_pow2(len(group))
+            prof = self.profiler
+            seams = prof.seams()  # host spans, while a capture runs
+            seams("alloc")
             stacked = np.zeros((k, 9, width), np.int64)
             stacked[:, 0, :] = -1  # pad windows are all padding lanes
             t = time.perf_counter_ns()
+            seams("prep")
             group_keys = [[item[1].hash_key() for item in wk]
                           for wk in group]
             aligned = self._lane_aligned(group, group_keys) \
@@ -1729,15 +1815,18 @@ class Engine:
                 t3 = time.perf_counter_ns()
                 stage["pack"] += t3 - t2
                 host_ns += t3 - t
-            prof = self.profiler
             prof.observe("prep", host_ns)
             live = sum(len(wk) for wk in group)
             self.stats.note_scan(len(group), live, k * width, carried)
+            seams("dispatch")
             t = time.perf_counter_ns()
             staged = self._dispatch_scan_staged(stacked, now_ms, carried)
             td = time.perf_counter_ns()
-            out = self._fetch_staged(staged)
+            seams("readback")
+            out, nbytes = self._fetch_staged(staged)
             t2 = time.perf_counter_ns()
+            seams("demux")
+            self.stats.fetched_bytes += nbytes
             stage["device"] += t2 - t
             self._obs_device(t2 - t, live)
             prof.observe("dispatch", td - t)
@@ -1756,6 +1845,7 @@ class Engine:
                 if led is not None and led.enabled:
                     led.note_slots(stacked[gi], out[gi], n)
             demux_ns = time.perf_counter_ns() - t2
+            seams(None)
             stage["demux"] += demux_ns
             prof.observe("demux", demux_ns)
         if union is not None:
@@ -1802,6 +1892,8 @@ class Engine:
         the engine lock."""
         stage = self.stats.stage_ns
         prof = self.profiler
+        seams = prof.seams()  # host spans, while a capture runs
+        seams("prep")
         n = len(round_work)
         t = time.perf_counter_ns()
         keys = [item[1].hash_key() for item in round_work]
@@ -1828,10 +1920,14 @@ class Engine:
         stage["pack"] += t2 - t
         # lookup + pack are host prep in the profiler's cycle taxonomy
         prof.observe("prep", lookup_ns + (t2 - t))
+        seams("dispatch")
         staged = self._dispatch_staged(packed, now_ms)
         td = time.perf_counter_ns()
-        out = self._fetch_staged(staged)
+        seams("readback")
+        out, nbytes = self._fetch_staged(staged)
         t3 = time.perf_counter_ns()
+        seams("demux")
+        self.stats.fetched_bytes += nbytes
         stage["device"] += t3 - t2
         self._obs_device(t3 - t2, n)
         prof.observe("dispatch", td - t2)
@@ -1847,6 +1943,7 @@ class Engine:
                 status=st, limit=limit[j], remaining=remaining[j],
                 reset_time=reset[j])
         demux_ns = time.perf_counter_ns() - t3
+        seams(None)
         stage["demux"] += demux_ns
         prof.observe("demux", demux_ns)
         led = self.ledger

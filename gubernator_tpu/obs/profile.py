@@ -21,6 +21,15 @@ Consumers:
 - the benchmark's per-layer readers (benchmarks/layer_metrics/), which
   diff the same phase totals over a measured window.
 
+Inside the cycle, the engines' launch and fetch funnels split `dispatch`
+and `readback` where the bytes are staged and waited for (SUB_PHASES:
+`stage` and `launch` every launch; `device_wait` and `fetch` while a
+capture runs, because telling them apart takes a wait of its own), and
+every release of the engine lock on a serving path books how long it was
+HELD (`lock_hold(site, ns)`) beside how long it was waited for. Both
+stand outside PHASES and the decomposition, so nothing that reads those
+moves.
+
 Beside the serving cycle it meters what surrounds it, on the same
 clock (CLOCK_MONOTONIC): the native front's own histograms (frame
 residency before the pull, whole-call residency, parse and write time —
@@ -29,13 +38,15 @@ background tickers' units of work (`background(site)`: the anomaly
 sweep, the ledger audit, history samples, keyspace harvests), so "what
 stopped serving for a second" has an answer inside the daemon. While a
 deep capture runs the same seams write `jax.profiler.TraceAnnotation`
-spans into the capture, named as the phases are: host and device share
-one timeline there, so a device idle gap can be put down to what the
-host was doing.
+spans into the capture, named as the phases are (the sub-phases nested
+in theirs, the combiner's threads as `combiner.wait` / `combiner.form`,
+a pull's handling as `pull`): host and device share one timeline there,
+so a device idle gap can be put down to what the host was doing.
 
 `GUBER_PROFILE=0` turns every observation site into a single attribute
-test; the off path is bit-identical (differential-tested) because the
-profiler only ever *reads* clocks.
+test (a site that reads a clock for the profiler alone tests `enabled`
+first and skips the read); the off path is bit-identical
+(differential-tested) because the profiler only ever *reads* clocks.
 """
 
 from __future__ import annotations
@@ -57,8 +68,11 @@ from gubernator_tpu.obs import witness
 # `bg_sites` (background tickers per site) and capture.options.
 # v3: phases gains `leftover`; capture.last_rates gains the front's
 # counters across the capture (frames_pulled_in, items_pulled_in).
-PROFILE_SCHEMA_VERSION = 3
-KERNELS_SCHEMA_VERSION = 1
+# v4: phases gains the four SUB_PHASES and `lock_hold`; the body gains
+# `lock_hold_sites`, shaped as `lock_sites` is.
+PROFILE_SCHEMA_VERSION = 4
+# kernels v2: `lanes_total` is gone (no dispatch ever fed it)
+KERNELS_SCHEMA_VERSION = 2
 
 # The serving-cycle phases, in cycle order. queue_wait overlaps the
 # serial phases of OTHER windows, so decomposition shares are computed
@@ -76,6 +90,20 @@ SERIAL_PHASES = ("lock_wait", "prep", "dispatch", "readback", "demux")
 # reply serialise + send. Outside the serial cycle: a frame waits while
 # other windows run.
 FRONT_PHASES = ("front_wait", "front_call", "front_parse", "front_write")
+# What `dispatch` and `readback` are made of, stamped inside the funnels
+# every launch and every fetch goes through (models/engine.py _launch /
+# _fetch_staged, parallel/sharded.py _launch_mesh / _fetch_mesh). `stage`
+# (funnel entry -> the jitted call: the hot tracker's feed, lean_window /
+# compact_window and their refusals) and `launch` (the jitted call:
+# enqueue and host -> device placement) lie inside `dispatch`, one
+# observation a launch. `device_wait` (block_until_ready on the answer)
+# and `fetch` (the copy back and its widening) lie inside `readback`, one
+# observation a launch FETCHED WHILE A CAPTURE RUNS: the copy waits for
+# the device by itself, so telling the two apart takes an explicit wait,
+# a second release of the GIL a window, which a daemon nobody is looking
+# into does not pay. Outside PHASES, the decomposition and the history
+# ring's columns, as `leftover` is.
+SUB_PHASES = ("stage", "launch", "device_wait", "fetch")
 FRONT_COUNTERS = ("pulls", "frames_pulled", "items_pulled", "frames_native")
 
 # a background unit of work longer than this lands in the flight recorder
@@ -134,7 +162,10 @@ def _annotation(name: str):
 class _Seams:
     """One call's chain of host spans in a capture: `seams(name)` closes
     the open span and opens the next, `seams(None)` closes the last. Lives
-    as long as the call that asked for it, so nothing is left open."""
+    as long as the call that asked for it, so nothing is left open. A
+    funnel's own chain (`stage`, `launch`; `device_wait`, `fetch`) runs
+    while its caller's `dispatch` or `readback` span is open: the capture
+    nests spans by time."""
 
     __slots__ = ("_ann",)
 
@@ -159,6 +190,13 @@ def background_of(holder, site: str):
     `x.profiler`; nothing when x carries no profiler (stubs, None)."""
     prof = getattr(holder, "profiler", None)
     return _NO_SPAN if prof is None else prof.background(site)
+
+
+def seams_of(holder):
+    """`holder.profiler.seams()`; the chain that does nothing when
+    `holder` carries no profiler (stubs, None)."""
+    prof = getattr(holder, "profiler", None)
+    return _no_seams if prof is None else prof.seams()
 
 
 def profile_enabled_default() -> bool:
@@ -227,6 +265,11 @@ class Profiler:
         # holds whole serving cycles of other threads, so it stands
         # outside PHASES and the decomposition, as the front's phases do
         self._leftover = PhaseHist()
+        self._sub: Dict[str, PhaseHist] = {p: PhaseHist() for p in SUB_PHASES}
+        # how long the engine lock was HELD on a serving path, beside how
+        # long it was waited for: total and per site, as lock_wait is
+        self._lock_hold = PhaseHist()
+        self._hold_sites: Dict[str, PhaseHist] = {}
         self._sites: Dict[str, PhaseHist] = {}
         self._bg_sites: Dict[str, PhaseHist] = {}
         self._sites_lock = witness.make_lock("profiler.sites")
@@ -278,11 +321,28 @@ class Profiler:
         if not self.enabled:
             return
         self._phases["lock_wait"].observe(ns)
-        h = self._sites.get(site)
+        self._site(self._sites, site).observe(ns)
+
+    def lock_hold(self, site: str, ns: int) -> None:
+        """Record one hold of the engine lock at `site`, acquire to
+        release (the `lock_hold` phase and the per-site histogram)."""
+        if not self.enabled:
+            return
+        self._lock_hold.observe(ns)
+        self._site(self._hold_sites, site).observe(ns)
+
+    def _site(self, hists: Dict[str, PhaseHist], site: str) -> PhaseHist:
+        h = hists.get(site)
         if h is None:
             with self._sites_lock:
-                h = self._sites.setdefault(site, PhaseHist())
-        h.observe(ns)
+                h = hists.setdefault(site, PhaseHist())
+        return h
+
+    def observe_sub(self, phase: str, ns: int) -> None:
+        """Record `ns` nanoseconds of one launch (or of one fetch) in a
+        SUB_PHASES member."""
+        if self.enabled:
+            self._sub[phase].observe(ns)
 
     @contextlib.contextmanager
     def background(self, site: str):
@@ -322,11 +382,7 @@ class Profiler:
             own_ns = ns - nested.pop()
             if nested:
                 nested[-1] += ns
-            h = self._bg_sites.get(site)
-            if h is None:
-                with self._sites_lock:
-                    h = self._bg_sites.setdefault(site, PhaseHist())
-            h.observe(own_ns)
+            self._site(self._bg_sites, site).observe(own_ns)
             rec = self.recorder
             if ns >= BACKGROUND_SLOW_NS and rec is not None:
                 rec.emit("profile.background_slow", site=site,
@@ -337,6 +393,12 @@ class Profiler:
         `leftover` phase of /v1/debug/profile)."""
         if self.enabled:
             self._leftover.observe(ns)
+
+    @property
+    def capturing(self) -> bool:
+        """True while a jax.profiler capture runs: what the fetch funnels
+        test before they wait for the device apart from the copy."""
+        return self._capturing
 
     def seams(self):
         """`seams = prof.seams()`, then `seams("prep")` ... `seams(None)`
@@ -478,11 +540,14 @@ class Profiler:
         (tests/test_debug_schema.py)."""
         with self._sites_lock:
             sites = dict(self._sites)
+            holds = dict(self._hold_sites)
             bg = dict(self._bg_sites)
         front_phases, front_counters = self.front_totals()
         phases = {p: h.snapshot() for p, h in self._phases.items()}
         phases.update(front_phases)
         phases["leftover"] = self._leftover.snapshot()
+        phases.update((p, h.snapshot()) for p, h in self._sub.items())
+        phases["lock_hold"] = self._lock_hold.snapshot()
         return {
             "schema_version": PROFILE_SCHEMA_VERSION,
             "enabled": self.enabled,
@@ -490,6 +555,8 @@ class Profiler:
             "front": {"attached": self._front is not None,
                       **front_counters},
             "lock_sites": {s: h.snapshot() for s, h in sorted(sites.items())},
+            "lock_hold_sites": {s: h.snapshot()
+                                for s, h in sorted(holds.items())},
             "bg_sites": {s: h.snapshot() for s, h in sorted(bg.items())},
             "decomposition": self.decomposition(),
             "recent": self.recent(),

@@ -78,20 +78,19 @@ class KernelTelemetry:
     def __init__(self):
         self._lock = witness.make_lock("kernel.telemetry")
         self._counts: Dict[Tuple[str, int], int] = {}
-        self._lanes = 0
         self._hists: Dict[Tuple[str, int], "object"] = {}
         self._probes: Dict[Tuple[str, int], tuple] = {}
         self._costs: Dict[Tuple[str, int], dict] = {}
 
     def note(self, kernel: str, width: int, depth: int = 1,
-             lanes: int = 0, dur_ns: int = 0) -> None:
+             dur_ns: int = 0) -> None:
         """One dispatch of `kernel` at staging width `width` retiring
-        `depth` windows (scan kernels) and `lanes` live lanes; `dur_ns`,
-        when nonzero, is the dispatch-call wall time."""
+        `depth` windows (scan kernels); `dur_ns`, when nonzero, is the
+        dispatch-call wall time (the profiler's `launch` sub-phase is fed
+        from the same pair of clock reads)."""
         key = (kernel, width)
         with self._lock:
             self._counts[key] = self._counts.get(key, 0) + depth
-            self._lanes += lanes
             hist = self._hists.get(key) if dur_ns else None
             if dur_ns and hist is None:
                 from gubernator_tpu.obs.profile import PhaseHist
@@ -164,7 +163,6 @@ class KernelTelemetry:
         with self._lock:
             counts = dict(self._counts)
             hists = dict(self._hists)
-            lanes = self._lanes
         kernels = {}
         for (k, w), n in sorted(counts.items()):
             hist = hists.get((k, w))
@@ -175,7 +173,6 @@ class KernelTelemetry:
             }
         return {
             "schema_version": KERNELS_SCHEMA_VERSION,
-            "lanes_total": lanes,
             "kernels": kernels,
         }
 
@@ -190,7 +187,6 @@ class KernelTelemetry:
             return {
                 "windows": {f"{k}@{w}": n
                             for (k, w), n in sorted(self._counts.items())},
-                "lanes_total": self._lanes,
             }
 
     def counts(self) -> Dict[Tuple[str, int], int]:

@@ -381,6 +381,12 @@ class ShardedEngine:
             # the launch's padded width, so lanes_max x shards / requests
             # is how unevenly the windows split (1.0 = evenly)
             "lanes_max": 0,
+            # the link, counted in the funnels every launch and fetch go
+            # through (_launch_mesh, _fetch_mesh), as models/engine.py
+            # EngineStats counts its own: nbytes of the host arrays handed
+            # to the program, nbytes copied back
+            "staged_bytes": 0,
+            "fetched_bytes": 0,
         }
         # per-stage wall clocks, same contract as models/engine.py
         # EngineStats (exposed as engine_stage_seconds_total in /metrics).
@@ -593,6 +599,9 @@ class ShardedEngine:
                 seams(None)
                 if n0 == PREP_OVERCOMMIT:
                     self._raise_overcommit()
+                if tq:
+                    self.profiler.lock_hold(
+                        "fast_window", time.perf_counter_ns() - t0)
                 return None
             out, placed = self._pack_and_decide(
                 n0, cols, lane_item, owner_count, now_ms, t0, seams)
@@ -600,6 +609,9 @@ class ShardedEngine:
             if n0:
                 self._book_collect(
                     *self._collect(out, placed, self._demux, responses))
+            if tq:
+                self.profiler.lock_hold(
+                    "fast_window", time.perf_counter_ns() - t0)
         if len(leftover):
             idxs = leftover.tolist()
             tail = self._slow_window(
@@ -641,9 +653,15 @@ class ShardedEngine:
                 seams(None)
                 if n0 == PREP_OVERCOMMIT:
                     self._raise_overcommit()
+                if tq:
+                    self.profiler.lock_hold(
+                        "submit_columnar", time.perf_counter_ns() - t0)
                 return None
             out, placed = self._pack_and_decide(
                 n0, cols, lane_item, owner_count, now_ms, t0, seams)
+            if tq:
+                self.profiler.lock_hold(
+                    "submit_columnar", time.perf_counter_ns() - t0)
         return (out, placed, leftover, n0)
 
     def _raise_overcommit(self):
@@ -678,6 +696,15 @@ class ShardedEngine:
         seams("prep")
         return t0
 
+    def _vacant_buffer(self, *shape: int) -> np.ndarray:
+        """An i64 staging buffer [..., 9, w] with every lane vacant (slot
+        row -1); an `alloc` span in a capture, inside its caller's
+        `prep`."""
+        with self.profiler.span("alloc"):
+            packed = np.zeros(shape, np.int64)
+            packed[..., 0, :] = -1
+        return packed
+
     def _pack_and_decide(self, n0, cols, lane_item, owner_count, now_ms,
                          t0, seams):
         """Pack owner-major staging cols into the [R,S,9,w] mesh buffer
@@ -703,8 +730,7 @@ class ShardedEngine:
         counts = owner_count.tolist()
         fullest = max(counts)
         w = bucket_width(fullest, self.min_width, self.max_width)
-        packed = np.zeros((R, S, 9, w), np.int64)
-        packed[:, :, 0, :] = -1
+        packed = self._vacant_buffer(R, S, 9, w)
         placed = []
         lanes = lane_item.tolist()
         pos = 0
@@ -738,7 +764,7 @@ class ShardedEngine:
         seams = prof.seams()
         seams("readback")
         t0 = time.perf_counter_ns()
-        rows = self._fetch_mesh(out)
+        rows, nbytes = self._fetch_mesh(out)
         t1 = time.perf_counter_ns()
         seams("demux")
         over = demux(rows, placed, *into)
@@ -746,13 +772,14 @@ class ShardedEngine:
         seams(None)
         prof.observe("readback", t1 - t0)
         prof.observe("demux", t2 - t1)
-        return over, t1 - t0, t2 - t1
+        return over, nbytes, t1 - t0, t2 - t1
 
-    def _book_collect(self, over: int, readback_ns: int,
+    def _book_collect(self, over: int, fetched: int, readback_ns: int,
                       demux_ns: int) -> None:
         """Count one collected window. Caller holds the engine lock:
         completers run concurrently and the counters stay exact."""
         self.stats["over_limit"] += over
+        self.stats["fetched_bytes"] += fetched
         self.stats["device_ns"] += readback_ns
         self.stats["demux_ns"] += demux_ns
 
@@ -847,6 +874,9 @@ class ShardedEngine:
                 out, placed = self._pack_and_decide(
                     n0, cols, lane_item, owner_count, now_ms, t0, seams)
                 metas.append((n0, out, placed, leftover))
+                if tq:
+                    self.profiler.lock_hold(
+                        "launch_columnar_windows", time.perf_counter_ns() - t0)
             if len(leftover):
                 break  # group-cut barrier: leftovers retire first
         return (metas, failed)
@@ -913,6 +943,9 @@ class ShardedEngine:
                     out, placed = self._pack_and_decide(
                         n0, cols, lane_item, owner_count, now_ms, t0, seams)
                 meta.append((n0, out, placed, leftover))
+                if tq:
+                    self.profiler.lock_hold(
+                        "launch_windows", time.perf_counter_ns() - t0)
             # Leftover tails retire NOW — after this window's dispatch,
             # BEFORE the next window preps — so a key pending in the tail
             # is never overtaken by its next arrival (per-key submission
@@ -957,7 +990,8 @@ class ShardedEngine:
             return self._dispatch_mesh(packed, 0)
 
     def collect_noop(self, handle) -> None:
-        """Block on a launch_noop readback."""
+        """Block on a launch_noop readback (its bytes are not the link
+        counters': no request rode it)."""
         self._fetch_mesh(handle)
 
     def _slow_window(self, requests, now_ms,
@@ -965,15 +999,20 @@ class ShardedEngine:
         """The python pipeline (full validation, gregorian, GLOBAL mirror,
         duplicate rounds). `count_batch` is False for a fast window's
         leftover tail — the client batch was already counted there."""
+        prof = self.profiler
+        seams = prof.seams()  # host spans, while a capture runs
+        seams("prep")
         t0 = time.perf_counter_ns()
         responses, rounds, n_errors = preprocess(requests, now_ms)
         prep_ns = time.perf_counter_ns() - t0  # excludes the lock wait below
-        prof = self.profiler
         prof.observe("prep", prep_ns)
         tq = time.perf_counter_ns() if prof.enabled else 0
+        seams("lock_wait")
         with self._lock:
             if tq:
-                prof.lock_wait("slow_window", time.perf_counter_ns() - tq)
+                t0 = time.perf_counter_ns()
+                prof.lock_wait("slow_window", t0 - tq)
+            seams(None)  # each round writes its own chain from here
             self.stats["prep_ns"] += prep_ns
             self.stats["requests"] += len(requests)
             self.stats["batches"] += 1 if count_batch else 0
@@ -995,6 +1034,9 @@ class ShardedEngine:
                 self._apply_round(wk, now_ms, responses)
             if tail:
                 self._apply_rounds_scanned(tail, now_ms, responses)
+            if tq:
+                self.profiler.lock_hold(
+                    "slow_window", time.perf_counter_ns() - t0)
         return responses  # type: ignore[return-value]
 
     def global_sync(self, now_ms: Optional[int] = None) -> int:
@@ -1297,10 +1339,12 @@ class ShardedEngine:
                 self._apply_round(group[0], now_ms, responses,
                                   pre=window_pre(lanes), lanes=lanes)
                 continue
+            seams = self.profiler.seams()
+            seams("prep")
             t_prep = time.perf_counter_ns()
             k_pad = _bucket_pow2(len(group))
-            packed = np.zeros((R, S, k_pad, 9, w), np.int64)
-            packed[:, :, :, 0, :] = -1  # vacant lanes (incl. pad windows)
+            # vacant lanes, the pad windows' among them
+            packed = self._vacant_buffer(R, S, k_pad, 9, w)
             placed: List[Tuple[int, int, Optional[int], List[int]]] = []
             for k, wk in enumerate(group):
                 lanes = self._route_lanes(wk)
@@ -1308,7 +1352,7 @@ class ShardedEngine:
                                  pre=window_pre(lanes))
 
             self._decide_and_demux(self._dispatch_mesh_scan, packed, now_ms,
-                                   placed, responses, t_prep)
+                                   placed, responses, t_prep, seams)
 
         if store_ctx is not None:
             per_owner, slotmat = store_ctx
@@ -1317,63 +1361,120 @@ class ShardedEngine:
     # -------------------------------------------------- staging dispatch
     # Every mesh window funnels through these helpers so the wide/lean
     # wire-format switch lives in one place (models/engine.py has the
-    # single-chip twin, Engine._dispatch_staged / _fetch_staged; neither
-    # side stamps inside them, the callers do). The handle defers the
+    # single-chip twin, Engine._dispatch_staged / _fetch_staged; both
+    # stamp the sub-phases and count the link's bytes inside them, the
+    # callers stamp the phases around them). The handle defers the
     # device sync: the columnar path reads it back in complete_columnar,
     # everyone else via _fetch_mesh immediately.
 
     def _dispatch_mesh(self, packed: np.ndarray, now_ms):
         """One wide i64[R,S,9,w] window, shipped on the 4 B/lane lean
         wire when eligible. Returns an opaque handle for _fetch_mesh."""
+        prof = self.profiler
+        t_in = time.perf_counter_ns() if prof.enabled else 0
+        sub = prof.seams()  # nested in the caller's `dispatch`
+        sub("stage")
         if self._staging != "wide" and self._lean_ok:
             ln = lean_window(packed, self.plan.capacity_per_shard)
             if ln is not None:
-                self.stats["lean_windows"] += 1
-                self.state, out = self._decide_lean(
-                    self.state, jnp.asarray(ln[0]), jnp.asarray(ln[1]),
-                    now_ms)
-                return out, now_ms
-        self.state, out = self._decide(self.state, packed, now_ms)
-        return out, None
+                return self._launch_mesh(self._decide_lean, ln, packed,
+                                         now_ms, t_in, sub), now_ms
+        return self._launch_mesh(self._decide, None, packed, now_ms, t_in,
+                                 sub), None
 
     def _dispatch_mesh_scan(self, stacked: np.ndarray, now_ms):
         """decide_scan dispatch of a wide i64[R,S,K,9,w] stack, shipped
         lean when eligible. Handle contract matches _dispatch_mesh."""
+        prof = self.profiler
+        t_in = time.perf_counter_ns() if prof.enabled else 0
+        sub = prof.seams()
+        sub("stage")
         if self._staging != "wide" and self._lean_ok:
             ln = lean_window(stacked, self.plan.capacity_per_shard)
             if ln is not None:
-                self.stats["lean_windows"] += 1
-                self.state, out = self._decide_scan_lean(
-                    self.state, jnp.asarray(ln[0]), jnp.asarray(ln[1]),
-                    now_ms)
-                return out, now_ms
-        self.state, out = self._decide_scan(self.state, stacked, now_ms)
-        return out, None
+                return self._launch_mesh(self._decide_scan_lean, ln,
+                                         stacked, now_ms, t_in, sub), now_ms
+        return self._launch_mesh(self._decide_scan, None, stacked, now_ms,
+                                 t_in, sub), None
 
-    @staticmethod
-    def _fetch_mesh(handle) -> np.ndarray:
-        """Block on a dispatched mesh window and return the wide i64
-        response rows regardless of which wire format carried it."""
+    def _launch_mesh(self, fn, lean, wide, now_ms, t_in: int, sub):
+        """The one mesh launch (Engine._launch's twin): `fn` over the wide
+        buffer `wide`, or over its `lean` form (lanes, cfg) where
+        lean_window gave one. Everything since the funnel's entry at
+        `t_in` (the lean attempt; 0: the profiler is off) was `stage`; the
+        placement onto the chips and the jitted call are `launch`. `sub`
+        is the funnel's span chain, closed here. Caller holds the engine
+        lock."""
+        st = self.stats
+        if lean is None:
+            st["staged_bytes"] += wide.nbytes
+        else:
+            st["lean_windows"] += 1
+            st["staged_bytes"] += lean[0].nbytes + lean[1].nbytes
+        sub("launch")
+        t = time.perf_counter_ns() if t_in else 0
+        if lean is None:
+            self.state, out = fn(self.state, wide, now_ms)
+        else:
+            self.state, out = fn(self.state, jnp.asarray(lean[0]),
+                                 jnp.asarray(lean[1]), now_ms)
+        sub(None)
+        if t_in:
+            t2 = time.perf_counter_ns()
+            prof = self.profiler
+            prof.observe_sub("stage", t - t_in)
+            prof.observe_sub("launch", t2 - t)
+        return out
+
+    def _fetch_mesh(self, handle):
+        """Block on a dispatched mesh window and return (the wide i64
+        response rows regardless of which wire format carried it, the
+        bytes copied back for them). Needs no lock. While a capture runs
+        the wait for the chips is made apart from the copy (`device_wait`,
+        then the copy and its widening as `fetch`: both inside the
+        caller's `readback`), and only then: Engine._fetch_staged says
+        what that costs."""
         out, lean_now = handle
+        prof = self.profiler
+        split = prof.capturing
+        if split:
+            sub = prof.seams()  # nested in the caller's `readback`
+            sub("device_wait")
+            t0 = time.perf_counter_ns()
+            out.block_until_ready()
+            t1 = time.perf_counter_ns()
+            sub("fetch")
+        rows = np.asarray(out)
         if lean_now is not None:
-            return widen_compact_out(np.asarray(out), lean_now)
-        return np.asarray(out)
+            rows = widen_compact_out(rows, lean_now)
+        if split:
+            t2 = time.perf_counter_ns()
+            sub(None)
+            prof.observe_sub("device_wait", t1 - t0)
+            prof.observe_sub("fetch", t2 - t1)
+        return rows, out.nbytes
 
     def _decide_and_demux(self, dispatch, packed, now_ms, placed, responses,
-                          t_prep: int) -> None:
+                          t_prep: int, seams) -> None:
         """The python pipeline's launch of one packed buffer through
         `dispatch` (_dispatch_mesh or _dispatch_mesh_scan), its readback
         and its demux into `responses`, stamped as Engine._apply_round
-        stamps its own: routing, lookup and pack since `t_prep` are `prep`.
-        Caller holds the engine lock."""
+        stamps its own: routing, lookup and pack since `t_prep` are `prep`,
+        the span `seams` has open; the chain is closed here. Caller holds
+        the engine lock."""
         prof = self.profiler
+        seams("dispatch")
         t = time.perf_counter_ns()
         handle = dispatch(packed, now_ms)
         td = time.perf_counter_ns()
-        out = self._fetch_mesh(handle)
+        seams("readback")
+        out, nbytes = self._fetch_mesh(handle)
         t2 = time.perf_counter_ns()
+        seams("demux")
         self.stats["over_limit"] += self._demux(out, placed, responses)
         t3 = time.perf_counter_ns()
+        seams(None)
+        self.stats["fetched_bytes"] += nbytes
         self.stats["device_ns"] += t2 - t
         self.stats["demux_ns"] += t3 - t2
         prof.observe("prep", t - t_prep)
@@ -1390,6 +1491,8 @@ class ShardedEngine:
         if self.store is not None and pre is None:
             return self._apply_round_store(round_work, now_ms, responses)
         R, S = self.plan.n_regions, self.plan.n_shards
+        seams = self.profiler.seams()
+        seams("prep")
         t_prep = time.perf_counter_ns()
         if lanes is None:
             lanes = self._route_lanes(round_work)
@@ -1398,12 +1501,11 @@ class ShardedEngine:
 
         # one i64[R,S,9,w] staging buffer up, one i64[R,S,4,w] back
         # (row order must match make_decide_sharded's unpack)
-        packed = np.zeros((R, S, 9, w), np.int64)
-        packed[:, :, 0, :] = -1  # vacant lanes
+        packed = self._vacant_buffer(R, S, 9, w)
         placed: List[Tuple[int, int, Optional[int], List[int]]] = []
         self._pack_lanes(lanes, w, packed, placed, None, pre=pre)
         self._decide_and_demux(self._dispatch_mesh, packed, now_ms, placed,
-                               responses, t_prep)
+                               responses, t_prep, seams)
 
     def _store_lookup_owners(self, work_items: List[WorkItem],
                              unbounded: bool = False):
@@ -1500,15 +1602,16 @@ class ShardedEngine:
         self._store_read_through_mesh(per_owner, slotmat, now_ms)
 
         # ---- decide ------------------------------------------------------
+        seams = self.profiler.seams()
+        seams("prep")
         t_prep = time.perf_counter_ns()  # the store's own time is store_ns
-        packed = np.zeros((R, S, 9, w), np.int64)
-        packed[:, :, 0, :] = -1
+        packed = self._vacant_buffer(R, S, 9, w)
         placed: List[Tuple[int, int, Optional[int], List[int]]] = []
         pre = {owner: (slots, fresh)
                for owner, _r, _s, _items, _keys, slots, fresh in per_owner}
         self._pack_lanes(lanes, w, packed, placed, None, pre=pre)
         self._decide_and_demux(self._dispatch_mesh, packed, now_ms, placed,
-                               responses, t_prep)
+                               responses, t_prep, seams)
 
         self._store_write_through_mesh(per_owner, slotmat, now_ms)
 

@@ -53,6 +53,7 @@ from typing import List, Optional, Sequence
 
 from gubernator_tpu.obs import witness
 from gubernator_tpu.obs import trace
+from gubernator_tpu.obs.profile import seams_of
 from gubernator_tpu.service import deadline as deadline_mod
 from gubernator_tpu.types import RateLimitReq, RateLimitResp
 
@@ -346,14 +347,23 @@ class BackendCombiner:
     def _run(self) -> None:
         try:
             while True:
+                # this thread's host spans while a capture runs:
+                # `combiner.wait` where it blocks (here, and on a full
+                # pipeline in _launch_group), `combiner.form` over the
+                # shedding and the window forming; the backend's calls
+                # write their own (lock_wait, prep, dispatch...)
+                seams = seams_of(self.backend)
+                seams("combiner.wait")
                 with self._cond:
                     while not self._pending and not self._closed:
                         self._cond.wait()
                     if not self._pending:  # closed and drained
+                        seams(None)
                         return
                     batch, self._pending = self._pending, []
+                seams("combiner.form")
                 try:
-                    self._execute(batch)
+                    self._execute(batch, seams)
                 except BaseException as e:  # noqa: BLE001 — never die silently
                     log.exception("combiner window failed")
                     for entry in batch:
@@ -362,6 +372,7 @@ class BackendCombiner:
                             fut.set_exception(
                                 RuntimeError(f"combiner window failed: {e!r}")
                             )
+                seams(None)
         finally:
             if self._drainer is not None:
                 self._inflight.put(None)  # drain sentinel: finish in-flight
@@ -396,7 +407,10 @@ class BackendCombiner:
                     stage=deadline_mod.STAGE_QUEUE).inc()
         return live
 
-    def _execute(self, batch: List[tuple]) -> None:
+    def _execute(self, batch: List[tuple], seams) -> None:
+        """One drained batch, shed, grouped and dispatched. `seams` is the
+        worker's span chain, open on `combiner.form`: whoever calls the
+        backend closes it around the call."""
         batch = self._shed_expired(batch)
         # group by explicit timestamp: tests pin now_ms; production passes
         # None, which resolves at launch — exactly the reference's behavior
@@ -406,13 +420,13 @@ class BackendCombiner:
             groups.setdefault(entry[1], []).append(entry)
         for now_ms, entries in groups.items():
             if self._pipelined:
-                self._execute_pipelined(now_ms, entries)
+                self._execute_pipelined(now_ms, entries, seams)
             else:
-                self._execute_serial(now_ms, entries)
+                self._execute_serial(now_ms, entries, seams)
 
     # ------------------------------------------------- serial (lock-step)
 
-    def _execute_serial(self, now_ms, entries) -> None:
+    def _execute_serial(self, now_ms, entries, seams) -> None:
         m = self._metrics
         tracer = self._tracer
         prof = self._profiler
@@ -440,7 +454,9 @@ class BackendCombiner:
             if merged:
                 m.combiner_merged_windows.inc()
         try:
+            seams(None)  # the backend's own spans from here
             resps = self.backend.get_rate_limits(flat, now_ms=now_ms)
+            seams("combiner.form")
             self._record_dispatch(entries, t_launch, len(flat))
             if resps is None or len(resps) != len(flat):
                 raise RuntimeError(
@@ -457,7 +473,7 @@ class BackendCombiner:
 
     # --------------------------------------------------- pipelined stages
 
-    def _execute_pipelined(self, now_ms, entries) -> None:
+    def _execute_pipelined(self, now_ms, entries, seams) -> None:
         """Pack stage: partition one timestamp group submission-granular
         into windows of <= max_width lanes, then launch them in scan
         groups of <= GUBER_PIPELINE_SCAN without blocking on readbacks.
@@ -476,9 +492,9 @@ class BackendCombiner:
                 if cur:
                     windows.append(cur)
                     cur, cur_n = [], 0
-                self._flush_windows(windows, now_ms)
+                self._flush_windows(windows, now_ms, seams)
                 windows = []
-                self._execute_serial(now_ms, [entry])
+                self._execute_serial(now_ms, [entry], seams)
                 continue
             if cur_n + n > max_w:
                 windows.append(cur)
@@ -487,18 +503,18 @@ class BackendCombiner:
             cur_n += n
         if cur:
             windows.append(cur)
-        self._flush_windows(windows, now_ms)
+        self._flush_windows(windows, now_ms, seams)
 
-    def _flush_windows(self, windows, now_ms) -> None:
+    def _flush_windows(self, windows, now_ms, seams) -> None:
         if len(windows) > self._scan and self._recorder is not None:
             # the scan bound cut this timestamp group into several
             # launches — the pipeline is running at its coalescing limit
             self._recorder.emit("combiner.group_cut",
                                 windows=len(windows), scan=self._scan)
         for g0 in range(0, len(windows), self._scan):
-            self._launch_group(windows[g0:g0 + self._scan], now_ms)
+            self._launch_group(windows[g0:g0 + self._scan], now_ms, seams)
 
-    def _launch_group(self, group, now_ms) -> None:
+    def _launch_group(self, group, now_ms, seams) -> None:
         """Dispatch stage: one launch_windows call for <= scan windows;
         on queue-full (backpressure) this blocks — the pipeline degrades
         to lock-step instead of queueing unbounded launches."""
@@ -547,11 +563,14 @@ class BackendCombiner:
                 self._recorder.emit("combiner.fill_stall",
                                     depth=self._depth,
                                     windows=len(group))
+            seams("combiner.wait")
             slots.acquire()
         staging = self._staging[self._launch_seq % len(self._staging)]
         try:
+            seams(None)  # the backend's own spans from here
             handle = self.backend.launch_windows(
                 win_reqs, now_ms=now_ms, staging=staging)
+            seams("combiner.form")
         except Exception as e:  # noqa: BLE001 — fail THIS group's callers
             slots.release()
             for entries in group:
@@ -566,7 +585,7 @@ class BackendCombiner:
             # dispatch order — and per-key order — is preserved
             slots.release()
             for entries in group:
-                self._execute_serial(now_ms, entries)
+                self._execute_serial(now_ms, entries, seams)
             return
         self._launch_seq += 1
         self._pipelined_windows += len(group)
@@ -586,7 +605,10 @@ class BackendCombiner:
         caller's future. Backend errors fail the affected group's callers;
         the drainer itself never dies."""
         while True:
+            seams = seams_of(self.backend)
+            seams("combiner.wait")
             item = self._inflight.get()
+            seams(None)
             if item is None:
                 return
             handle, group, t_launch, t_launched, slots = item

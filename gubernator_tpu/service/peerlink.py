@@ -965,12 +965,16 @@ class PeerLinkService:
             ctx = _PullCtx(b, got)
             ws["ctxs"][cur] = ctx
             ws["cur"] = (cur + 1) % nsets
-            try:
-                self._handle_batch(got, b, ctx=ctx, ws=ws)
-            except Exception:  # noqa: BLE001 — a worker must never die
-                log.exception("peerlink batch failed")
-                self.stats["errors"] += 1
-                self._recover_batch(ws, ctx)
+            # the parent of every span this pull's handling writes on
+            # this thread: its self time (duration less its children) is
+            # the loop's own Python
+            with prof.span("pull"):
+                try:
+                    self._handle_batch(got, b, ctx=ctx, ws=ws)
+                except Exception:  # noqa: BLE001 — a worker must never die
+                    log.exception("peerlink batch failed")
+                    self.stats["errors"] += 1
+                    self._recover_batch(ws, ctx)
 
     def _drain_at_boundary(self, ws: dict) -> None:
         """_drain_one_entry between pulls, where no pull's try is open: a
@@ -1472,13 +1476,18 @@ class PeerLinkService:
 
         Metered as stats `leftover_items` (peerlink_leftover_items_total)
         and the profiler's `leftover` phase, a host span of that name
-        while a capture runs (docs/observability.md). The C prep gives no
-        reason for a demotion, so the items are counted whole."""
+        while a capture runs, with three children: `leftover.build` (the
+        request objects), `leftover.serve` (the instance's call: the wait
+        for the combiner and the engine's rounds) and `leftover.fill` (the
+        answers into the pull's rows; docs/observability.md). The C prep
+        gives no reason for a demotion, so the items are counted whole."""
         n_left = len(rel_idx)  # a local: no call between read and store
         self.stats["leftover_items"] += n_left
         prof = self._prof
         t0 = time.perf_counter_ns()
         with prof.span("leftover"):
+            sub = prof.seams()
+            sub("leftover.build")
             idxs = [j + r for r in rel_idx]
             reqs, good_idx = [], []
             koff = b["key_off"]
@@ -1500,6 +1509,7 @@ class PeerLinkService:
                     self._fill_one(b, i, RateLimitResp(
                         error="invalid utf-8 in key"), errs, metas)
             if reqs:
+                sub("leftover.serve")
                 try:
                     if m == METHOD_GET_PEER_RATE_LIMITS:
                         resps = self.instance.apply_owner_batch_direct(
@@ -1508,8 +1518,10 @@ class PeerLinkService:
                         resps = self.instance.get_rate_limits(reqs)
                 except Exception as e:  # noqa: BLE001
                     resps = [RateLimitResp(error=str(e)) for _ in reqs]
+                sub("leftover.fill")
                 for i, resp in zip(good_idx, resps):
                     self._fill_one(b, i, resp, errs, metas)
+            sub(None)
         prof.observe_leftover(time.perf_counter_ns() - t0)
 
     @staticmethod

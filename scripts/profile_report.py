@@ -82,19 +82,21 @@ def render_report(profile_body, kernels_body=None):
             rp)
         lines.append("")
 
-    sites = profile_body.get("lock_sites") or {}
-    lines.append("engine-lock wait by call site")
-    lines.append("-" * 58)
-    if sites:
-        for s, h in sorted(sites.items(),
-                           key=lambda kv: -(kv[1].get("total_ns") or 0)):
-            lines.append(f"{s:<24} {h.get('n'):>9} waits  "
-                         f"p50 {_fmt_ns(h.get('p50_ns')):>9}  "
-                         f"p99 {_fmt_ns(h.get('p99_ns')):>9}  "
-                         f"total {_fmt_ns(h.get('total_ns'))}")
-    else:
-        lines.append("(none recorded)")
-    lines.append("")
+    for block, title, unit in (("lock_sites", "wait", "waits"),
+                               ("lock_hold_sites", "hold", "holds")):
+        sites = profile_body.get(block) or {}
+        lines.append(f"engine-lock {title} by call site")
+        lines.append("-" * 58)
+        if sites:
+            for s, h in sorted(sites.items(),
+                               key=lambda kv: -(kv[1].get("total_ns") or 0)):
+                lines.append(f"{s:<24} {h.get('n'):>9} {unit}  "
+                             f"p50 {_fmt_ns(h.get('p50_ns')):>9}  "
+                             f"p99 {_fmt_ns(h.get('p99_ns')):>9}  "
+                             f"total {_fmt_ns(h.get('total_ns'))}")
+        else:
+            lines.append("(none recorded)")
+        lines.append("")
 
     cap = profile_body.get("capture") or {}
     lines.append(f"deep captures  {cap.get('count', 0)} taken "
@@ -121,7 +123,6 @@ def render_report(profile_body, kernels_body=None):
             lines.append(f"{name:<22} {rec.get('windows'):>9} windows  "
                          f"dispatch p99 {_fmt_ns(hist.get('p99_ns')):>9}  "
                          f"{cost_txt}")
-        lines.append(f"lanes total    {kernels_body.get('lanes_total')}")
     return "\n".join(lines) + "\n"
 
 

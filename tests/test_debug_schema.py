@@ -218,10 +218,10 @@ def test_profile_var_shape(instance):
 
 def test_profile_endpoint_schema_pinned(instance):
     body = instance.profiler.endpoint_body()
-    assert body["schema_version"] == PROFILE_SCHEMA_VERSION == 3
+    assert body["schema_version"] == PROFILE_SCHEMA_VERSION == 4
     assert set(body) == {"schema_version", "enabled", "phases", "front",
-                         "lock_sites", "bg_sites", "decomposition",
-                         "recent", "capture"}
+                         "lock_sites", "lock_hold_sites", "bg_sites",
+                         "decomposition", "recent", "capture"}
     # the phase taxonomy dashboards key on; renaming a phase is a
     # schema_version bump, not a silent drift
     taxonomy = {"queue_wait", "lock_wait", "prep", "dispatch",
@@ -234,7 +234,16 @@ def test_profile_endpoint_schema_pinned(instance):
     # holds whole cycles of other threads, so it too stands outside the
     # decomposition
     paths = {"leftover"}
-    assert set(body["phases"]) == taxonomy | front | paths
+    # v4: what `dispatch` and `readback` are made of, stamped in the
+    # engines' launch and fetch funnels, and the engine lock's holds (per
+    # site in `lock_hold_sites`); outside the decomposition too, so its
+    # shares read what they read in v3
+    parts = {"stage", "launch", "device_wait", "fetch", "lock_hold"}
+    assert set(body["phases"]) == taxonomy | front | paths | parts
+    for block in ("lock_sites", "lock_hold_sites"):
+        for snap in body[block].values():
+            assert {"n", "total_ns", "max_ns", "p50_ns",
+                    "p99_ns"} == set(snap)
     assert set(body["decomposition"]) == taxonomy
     assert set(body["front"]) == {"attached", "pulls", "frames_pulled",
                                   "items_pulled", "frames_native"}
@@ -252,8 +261,9 @@ def test_kernels_endpoint_schema_pinned(instance):
     from gubernator_tpu.ops.decide import kernel_telemetry
 
     body = kernel_telemetry.kernels_body()
-    assert body["schema_version"] == KERNELS_SCHEMA_VERSION == 1
-    assert set(body) == {"schema_version", "lanes_total", "kernels"}
+    # v2: `lanes_total` went (nothing ever fed it)
+    assert body["schema_version"] == KERNELS_SCHEMA_VERSION == 2
+    assert set(body) == {"schema_version", "kernels"}
     for rec in body["kernels"].values():
         assert {"windows", "dispatch_ns", "cost"} == set(rec)
 

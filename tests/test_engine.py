@@ -520,7 +520,8 @@ class TestStagingAutoSelect:
         packed[1:4, :3] = [[1] * 3, [10] * 3, [60_000] * 3]
         handle = eng._dispatch_staged(packed, NOW)
         assert handle[1] == NOW  # compact: handle carries now_ms
-        out = eng._fetch_staged(handle)
+        out, nbytes = eng._fetch_staged(handle)
+        assert nbytes == 4 * 8 * 4  # the compact i32[4, 8] as it came back
         assert out.dtype == np.int64 and out.shape == (4, 8)
         assert out[3, 0] == NOW + 60_000  # widened back to absolute
 

@@ -398,7 +398,7 @@ def test_a_capture_gets_a_leftover_span_a_chunk(monkeypatch):
     log = []
 
     class Span:
-        def __init__(self, name):
+        def __init__(self, name, **args):
             self.name = name
 
         def __enter__(self):

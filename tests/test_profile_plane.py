@@ -20,6 +20,7 @@ from gubernator_tpu.obs.profile import (
     FRONT_PHASES,
     PHASES,
     SERIAL_PHASES,
+    SUB_PHASES,
     PhaseHist,
     Profiler,
     check_recompile,
@@ -115,7 +116,8 @@ class TestProfiler:
         body = p.endpoint_body()
         assert body["enabled"] is True
         assert set(body["phases"]) == \
-            set(PHASES) | set(FRONT_PHASES) | {"leftover"}
+            set(PHASES) | set(FRONT_PHASES) | set(SUB_PHASES) \
+            | {"leftover", "lock_hold"}
         dbg = p.debug()
         assert dbg["phases"]["prep"]["n"] == 1
         assert set(dbg["shares"]) == set(SERIAL_PHASES)
@@ -177,6 +179,22 @@ class TestDifferential:
                        for t in eng_off.profiler.totals().values())
             assert any(t["n"] > 0
                        for t in eng_on.profiler.totals().values())
+            # nor a sub-phase, a lock hold or a site; and the link's
+            # counters are the engine's, not the profiler's: they count
+            # the same on both
+            body_off = eng_off.profiler.endpoint_body()
+            body_on = eng_on.profiler.endpoint_body()
+            assert all(h["n"] == 0 for h in body_off["phases"].values())
+            assert body_off["lock_hold_sites"] == {}
+            # (no capture ran: the wait is not told from the copy)
+            for p in ("stage", "launch", "lock_hold"):
+                assert body_on["phases"][p]["n"] > 0, p
+            for p in ("device_wait", "fetch"):
+                assert body_on["phases"][p]["n"] == 0, p
+            on_stats, off_stats = (e.stats.as_dict()
+                                   for e in (eng_on, eng_off))
+            for key in ("staged_bytes", "fetched_bytes"):
+                assert on_stats[key] == off_stats[key] > 0, key
         finally:
             eng_on.close()
             eng_off.close()
