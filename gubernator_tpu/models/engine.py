@@ -65,6 +65,7 @@ from gubernator_tpu.ops.decide import (
     load_rows,
     make_table,
     pack_window,
+    pad_window,
     store_rows,
     widen_compact_out,
 )
@@ -181,7 +182,11 @@ class EngineStats:
     fetch goes through (Engine._launch, _fetch_staged): `staged_bytes` the
     host arrays handed to the program (their nbytes, whichever wire format
     carried the window), `fetched_bytes` what was copied back (the device
-    array's nbytes, before widening)."""
+    array's nbytes, before widening), `staged_lanes` the lanes the host
+    walked to make the staged arrays (depth x the launch's live prefix; x
+    the launched width where the wide format ships whole): beside
+    `scan_lanes` and the kernel telemetry's widths, what the host touched
+    of what it launched."""
 
     STAGES = ("prep", "lookup", "store", "pack", "device", "demux")
 
@@ -198,6 +203,7 @@ class EngineStats:
         self.scan_lanes_live = 0
         self.scan_lanes = 0
         self.staged_bytes = 0
+        self.staged_lanes = 0
         self.fetched_bytes = 0
         self.stage_ns = {s: 0 for s in self.STAGES}
 
@@ -222,6 +228,7 @@ class EngineStats:
                  scan_lanes_live=self.scan_lanes_live,
                  scan_lanes=self.scan_lanes,
                  staged_bytes=self.staged_bytes,
+                 staged_lanes=self.staged_lanes,
                  fetched_bytes=self.fetched_bytes)
         for s, ns in self.stage_ns.items():
             d[f"{s}_ns"] = ns
@@ -396,89 +403,94 @@ class Engine:
         return resp
 
     # -------------------------------------------------- staging dispatch
-    # Every window dispatch funnels through these two helpers so the
-    # wide/compact wire-format switch lives in exactly one place
-    # (VERDICT r3 item 1: auto-selected by eligibility).
+    # Every window dispatch funnels through these two helpers into
+    # _launch, so the wide/compact/lean wire-format switch and the live
+    # prefix the host walks live in exactly one place (VERDICT r3 item 1:
+    # auto-selected by eligibility).
 
-    def _dispatch_staged(self, packed: np.ndarray, now_ms):
+    def _dispatch_staged(self, packed: np.ndarray, now_ms, live=None):
         """Dispatch one wide-format i64[9, W] window, shipping it lean
         (4 B/lane — the hits==1, few-configs serving shape) when eligible,
-        compact (20 B/lane) otherwise, wide as the last resort. Returns an
+        compact (20 B/lane) otherwise, wide as the last resort. `live`:
+        lanes [live, W) are padding (slot -1, zeros elsewhere), so the
+        host walks the prefix alone; None walks the buffer. Returns an
         opaque handle for _fetch_staged. Caller holds the engine lock
         (self.state is donated and rebound here)."""
+        return self._launch(
+            "packed", (self._decide_packed, self._decide_packed_compact,
+                       self._decide_packed_lean),
+            packed, 1, now_ms, live, None)
+
+    def _dispatch_scan_staged(self, stacked: np.ndarray, now_ms,
+                              carried: bool = False, live=None, width=None):
+        """decide_scan dispatch of a wide i64[K, 9, L] stack, shipped
+        lean/compact when eligible. `carried` takes the row-carried
+        programs: the caller has aligned the stack's lanes (a lane holds
+        one slot through the stack; ops/decide.py _scan_carried). `live`
+        as in _dispatch_staged; `width` (default L) is the launched width
+        of a stack that holds its live lanes alone. Handle contract
+        matches _dispatch_staged. Caller holds the engine lock."""
+        return self._launch(
+            "carry" if carried else "scan",
+            self._scans_carried if carried else self._scans,
+            stacked, stacked.shape[0], now_ms, live, width)
+
+    def _launch(self, tag: str, fns, packed: np.ndarray, depth: int, now_ms,
+                live, width):
+        """The one launch: stage `packed`, a wide window (or a stack `depth`
+        windows deep), then call the program of `fns` (wide, compact, lean;
+        `tag` names them to the kernel telemetry) that takes what it was
+        staged as.
+
+        Every numpy pass the host makes over a launch (the hot tracker's
+        feed, the wire-format converters and, through the handle, the
+        widening of its answers) runs over its live prefix, whatever width
+        it launches at; the shipped array is the one a walk of the whole
+        buffer would make, and `staged_lanes` counts what was walked.
+        Everything from here to the jitted call is `stage` (`t_in` 0: the
+        profiler is off); the call (enqueue and host -> device placement)
+        is `launch`, and one pair of clock reads feeds that phase and the
+        telemetry's histogram. Caller holds the engine lock."""
         prof = self.profiler
         t_in = time.perf_counter_ns() if prof.enabled else 0
         sub = prof.seams()  # nested in the caller's `dispatch`
         sub("stage")
+        src = packed if live is None else packed[..., :live]
+        live = src.shape[-1]
+        w = width or packed.shape[-1]
         ht = self.hot_tracker
         if ht is not None:
             # the staged rows are already host numpy: two bulk adds per
             # window, no per-key cost (service/leases.py)
-            ht.feed_slots(packed[0], packed[1])
-        w = packed.shape[1]
+            ht.feed_slots(src[..., 0, :], src[..., 1, :])
         # host arrays go to the program as they are: its call path places
         # them, an explicit jnp.asarray first is ~0.3 ms of Python a
         # window under the GIL
+        wide, compact, lean = fns
+        kernel, fn, staged = tag + "_wide", wide, None
         if self._staging != "wide":
             if self._lean_ok:
-                ln = lean_window(packed, self.capacity)
-                if ln is not None:
-                    return self._launch(
-                        "packed_lean", self._decide_packed_lean, ln, w, 1,
-                        now_ms, t_in, sub), now_ms
-            c = compact_window(packed)
-            if c is not None:
-                return self._launch(
-                    "packed_compact", self._decide_packed_compact, (c,), w,
-                    1, now_ms, t_in, sub), now_ms
-        return self._launch("packed_wide", self._decide_packed, (packed,),
-                            w, 1, now_ms, t_in, sub), None
-
-    def _dispatch_scan_staged(self, stacked: np.ndarray, now_ms,
-                              carried: bool = False):
-        """decide_scan dispatch of a wide i64[K, 9, W] stack, shipped
-        lean/compact when eligible. `carried` takes the row-carried
-        programs: the caller has aligned the stack's lanes (a lane holds
-        one slot through the stack; ops/decide.py _scan_carried). Handle
-        contract matches _dispatch_staged. Caller holds the engine lock."""
-        prof = self.profiler
-        t_in = time.perf_counter_ns() if prof.enabled else 0
-        sub = prof.seams()
-        sub("stage")
-        ht = self.hot_tracker
-        if ht is not None:
-            ht.feed_slots(stacked[:, 0, :], stacked[:, 1, :])
-        wide, compact, lean = self._scans_carried if carried else self._scans
-        tag = "carry" if carried else "scan"
-        k, w = stacked.shape[0], stacked.shape[2]
-        if self._staging != "wide":
-            if self._lean_ok:
-                ln = lean_window(stacked, self.capacity)
-                if ln is not None:
-                    return self._launch(tag + "_lean", lean, ln, w, k,
-                                        now_ms, t_in, sub), now_ms
-            c = compact_window(stacked)
-            if c is not None:
-                return self._launch(tag + "_compact", compact, (c,), w, k,
-                                    now_ms, t_in, sub), now_ms
-        return self._launch(tag + "_wide", wide, (stacked,), w, k, now_ms,
-                            t_in, sub), None
-
-    def _launch(self, kernel: str, fn, staged, w: int, depth: int, now_ms,
-                t_in: int, sub):
-        """The one launch: `fn` over `staged`, the host arrays of a window
-        (or of a stack `depth` windows deep) `w` lanes wide, told to the
-        kernel telemetry under `kernel`. Everything since the funnel's
-        entry at `t_in` (0: the profiler is off) was `stage`; the jitted
-        call (enqueue and host -> device placement) is `launch`, and one
-        pair of clock reads feeds that phase and the telemetry's
-        histogram. `sub` is the funnel's span chain, closed here. Caller
-        holds the engine lock."""
+                staged = lean_window(src, self.capacity, w)
+            if staged is not None:
+                kernel, fn = tag + "_lean", lean
+            else:
+                c = compact_window(src, w)
+                if c is not None:
+                    kernel, fn, staged = tag + "_compact", compact, (c,)
+        if staged is None:
+            # the buffer itself where it is the launched width already (the
+            # C prep's, pack_window's); a stack of live lanes alone is
+            # padded out
+            staged = (pad_window(packed, w),)
+            lanes, compact_now = depth * w, None
+        else:
+            lanes, compact_now = depth * live, now_ms
         if kernel_telemetry.needs_probe(kernel, w):
             kernel_telemetry.offer_probe(
                 kernel, w, fn, (self.state, *staged, now_ms))
         for a in staged:
             self.stats.staged_bytes += a.nbytes
+        self.stats.staged_lanes += lanes
         sub("launch")
         t = time.perf_counter_ns()
         self.state, out = fn(self.state, *staged, now_ms)
@@ -486,10 +498,9 @@ class Engine:
         sub(None)
         kernel_telemetry.note(kernel, w, depth=depth, dur_ns=t2 - t)
         if t_in:
-            prof = self.profiler
             prof.observe_sub("stage", t - t_in)
             prof.observe_sub("launch", t2 - t)
-        return out
+        return out, compact_now, live
 
     def _obs_device(self, ns: int, lanes: int) -> None:
         """Feed one window's device dispatch+readback wall time and live
@@ -548,7 +559,7 @@ class Engine:
         widening as `fetch`: both inside the caller's `readback`); that is
         a second release of the GIL a window, ~1% of a busy daemon's rate
         (PERF.md section 6, PR 40), so nobody pays it otherwise."""
-        out, compact_now = handle
+        out, compact_now, live = handle
         prof = self.profiler
         split = prof.capturing
         if split:
@@ -559,7 +570,7 @@ class Engine:
             t1 = time.perf_counter_ns()
             sub("fetch")
         rows = (np.asarray(out) if compact_now is None
-                else widen_compact_out(out, compact_now))
+                else widen_compact_out(out, compact_now, live))
         if split:
             t2 = time.perf_counter_ns()
             sub(None)
@@ -670,7 +681,7 @@ class Engine:
             if n0:
                 self.stats.rounds += 1
                 seams("dispatch")
-                staged = self._dispatch_staged(packed, now_ms)
+                staged = self._dispatch_staged(packed, now_ms, n0)
                 td = time.perf_counter_ns()
                 seams("readback")
                 out, nbytes = self._fetch_staged(staged)
@@ -835,8 +846,10 @@ class Engine:
                 self.stats.batches += m
                 self.stats.rounds += rounds
                 seams("dispatch")
+                live = max(meta[kk][0] for kk in range(seg_start, k))
                 if m == 1:
-                    staged = self._dispatch_staged(buf[seg_start], now_ms)
+                    staged = self._dispatch_staged(buf[seg_start], now_ms,
+                                                   live)
                     scanned = False
                 else:
                     kb2 = _bucket_pow2(m)
@@ -854,7 +867,8 @@ class Engine:
                             stack[:m] = buf[seg_start:k]
                             stack[m:, 0, :] = -1
                     self.stats.note_scan(rounds, total, len(stack) * w)
-                    staged = self._dispatch_scan_staged(stack, now_ms)
+                    staged = self._dispatch_scan_staged(stack, now_ms,
+                                                        live=live)
                     scanned = True
                 td = time.perf_counter_ns()
                 self.stats.stage_ns["device"] += td - t1
@@ -959,7 +973,7 @@ class Engine:
         packed = np.zeros((9, w), np.int64)
         packed[0, :] = -1
         with self._lock:
-            return self._dispatch_staged(packed, 0)
+            return self._dispatch_staged(packed, 0, 0)
 
     def collect_noop(self, handle) -> None:
         """Block on a launch_noop readback (its bytes are not the link
@@ -1050,7 +1064,7 @@ class Engine:
             if n0:
                 self.stats.rounds += 1
                 seams("dispatch")
-                handle = self._dispatch_staged(packed, now_ms)
+                handle = self._dispatch_staged(packed, now_ms, n0)
                 td = time.perf_counter_ns()
                 self.stats.stage_ns["device"] += td - t1
                 prof.observe("dispatch", td - t1)
@@ -1215,15 +1229,17 @@ class Engine:
             scanned = False
             seams("dispatch" if total else None)
             if total:
+                live = max(n0 for n0, _lane_item, _leftover in metas)
                 if m == 1:
-                    staged = self._dispatch_staged(buf[0], now_ms)
+                    staged = self._dispatch_staged(buf[0], now_ms, live)
                 else:
                     kb2 = _bucket_pow2(m)
                     stack = buf if kb2 == kb else buf[:kb2]
                     for kk in range(m, kb2):
                         stack[kk][0, :] = -1  # unprepped rows: all padding
                     self.stats.note_scan(rounds, total, kb2 * w)
-                    staged = self._dispatch_scan_staged(stack, now_ms)
+                    staged = self._dispatch_scan_staged(stack, now_ms,
+                                                        live=live)
                     scanned = True
                 td = time.perf_counter_ns()
                 self.stats.stage_ns["device"] += td - t1
@@ -1788,7 +1804,11 @@ class Engine:
             prof = self.profiler
             seams = prof.seams()  # host spans, while a capture runs
             seams("alloc")
-            stacked = np.zeros((k, 9, width), np.int64)
+            # the stack holds the group's live lanes alone: pack_window,
+            # the converters, the ledger and the demux never see the
+            # `width` it launches at
+            live = max(len(wk) for wk in group)
+            stacked = np.zeros((k, 9, live), np.int64)
             stacked[:, 0, :] = -1  # pad windows are all padding lanes
             t = time.perf_counter_ns()
             seams("prep")
@@ -1811,16 +1831,17 @@ class Engine:
                     self._apply_inject_rows(inj)
                 t2 = time.perf_counter_ns()
                 stage["lookup"] += t2 - t
-                pack_window(wk, slots, fresh, width, out=stacked[gi])
+                pack_window(wk, slots, fresh, live, out=stacked[gi])
                 t3 = time.perf_counter_ns()
                 stage["pack"] += t3 - t2
                 host_ns += t3 - t
             prof.observe("prep", host_ns)
-            live = sum(len(wk) for wk in group)
-            self.stats.note_scan(len(group), live, k * width, carried)
+            decided = sum(len(wk) for wk in group)
+            self.stats.note_scan(len(group), decided, k * width, carried)
             seams("dispatch")
             t = time.perf_counter_ns()
-            staged = self._dispatch_scan_staged(stacked, now_ms, carried)
+            staged = self._dispatch_scan_staged(stacked, now_ms, carried,
+                                                width=width)
             td = time.perf_counter_ns()
             seams("readback")
             out, nbytes = self._fetch_staged(staged)
@@ -1828,7 +1849,7 @@ class Engine:
             seams("demux")
             self.stats.fetched_bytes += nbytes
             stage["device"] += t2 - t
-            self._obs_device(t2 - t, live)
+            self._obs_device(t2 - t, decided)
             prof.observe("dispatch", td - t)
             prof.observe("readback", t2 - td)
             led = self.ledger
@@ -1921,7 +1942,7 @@ class Engine:
         # lookup + pack are host prep in the profiler's cycle taxonomy
         prof.observe("prep", lookup_ns + (t2 - t))
         seams("dispatch")
-        staged = self._dispatch_staged(packed, now_ms)
+        staged = self._dispatch_staged(packed, now_ms, n)
         td = time.perf_counter_ns()
         seams("readback")
         out, nbytes = self._fetch_staged(staged)

@@ -728,19 +728,31 @@ def decide_scan_carried_compact(
         lambda resp: _compact_response(resp, now_ms), now_ms)
 
 
-def compact_window(packed):
-    """Wide i64[9, W] (or [K, 9, W]) staging -> compact i32, or None when
-    any lane is ineligible (gregorian, or a value outside [0, 2^31))."""
+def compact_window(packed, width=None):
+    """Wide i64[9, L] (or [K, 9, L]) staging -> compact i32[..., 5, width],
+    or None when any lane is ineligible (gregorian, or a value outside
+    [0, 2^31)).
+
+    `width` (default L) is the launched width: `packed` is then the
+    launch's live prefix and lanes [L, width) are padding (slot -1, zeros
+    elsewhere), written by one fill — the array the whole-buffer form
+    makes of the same window, for the price of its L live lanes."""
     vals = packed[..., 1:4, :]
     if (vals < 0).any() or (vals > _I32_MAX).any():
         return None
     if (packed[..., 5, :] & int(Behavior.DURATION_IS_GREGORIAN)).any():
         return None
-    out = np.empty(packed.shape[:-2] + (COMPACT_ROWS, packed.shape[-1]),
-                   np.int32)
-    out[..., 0, :] = packed[..., 0, :]
-    out[..., 1:4, :] = vals
-    out[..., 4, :] = (
+    live = packed.shape[-1]
+    if width is None or width == live:
+        out = np.empty(packed.shape[:-2] + (COMPACT_ROWS, live), np.int32)
+    else:
+        # np.zeros is the allocator's zero pages; np.full would fault
+        # every page in to write it
+        out = np.zeros(packed.shape[:-2] + (COMPACT_ROWS, width), np.int32)
+        out[..., 0, live:] = -1
+    out[..., 0, :live] = packed[..., 0, :]
+    out[..., 1:4, :live] = vals
+    out[..., 4, :live] = (
         (packed[..., 4, :] & 1)
         | ((packed[..., 5, :] & _META_BEHAVIOR_MASK) << _META_BEHAVIOR_SHIFT)
         | ((packed[..., 8, :] != 0) << 7)
@@ -748,13 +760,28 @@ def compact_window(packed):
     return out
 
 
-def widen_compact_out(out, now_ms: int):
+def widen_compact_out(out, now_ms: int, live=None):
     """Compact i32[..., 4, B] responses -> the wide i64 rows decide_packed
-    returns (reset_delta -1 decodes to absolute 0)."""
-    wide = np.asarray(out).astype(np.int64)
+    returns (reset_delta -1 decodes to absolute 0); with `live`, of lanes
+    [0, live) alone (nothing reads a window beyond its live lanes)."""
+    host = np.asarray(out)
+    wide = (host if live is None else host[..., :live]).astype(np.int64)
     delta = wide[..., 3, :]
     wide[..., 3, :] = np.where(delta < 0, 0, now_ms + delta)
     return wide
+
+
+def pad_window(packed, width: int):
+    """A live-prefix wide stack i64[..., 9, L] at its launched width:
+    lanes [L, width) padding (slot -1, zeros elsewhere). What a launch
+    ships when no narrower wire format takes its window."""
+    live = packed.shape[-1]
+    if live == width:
+        return packed
+    out = np.zeros(packed.shape[:-1] + (width,), np.int64)
+    out[..., 0, live:] = -1
+    out[..., :live] = packed
+    return out
 
 
 # ---------------------------------------------------------------- interned
@@ -1040,14 +1067,15 @@ def decide_scan_carried_lean(
         lambda resp: _compact_response(resp, now_ms), now_ms)
 
 
-def lean_window(packed, capacity: int):
+def lean_window(packed, capacity: int, width=None):
     """Wide i64[9, W] (or [K, 9, W]) staging -> (lean i32[W] / [K, W] lane
     words, i64[LEAN_MAX_CFG, 4] config table), or None when any non-padding
     lane is ineligible: hits != 1, gregorian, limit/duration outside
     [0, 2^31), behavior past 6 bits, algorithm past 1 bit, slot too wide
     for 24 bits, or > LEAN_MAX_CFG distinct (limit, duration, algorithm,
     behavior) tuples. Padding lanes emit the 0xFFFFFF sentinel and occupy
-    no config row.
+    no config row. `width` as in compact_window: `packed` is the launch's
+    live prefix, the lane words come back `width` wide.
 
     Host cost ~120 ns/item (masks + two 1-D uniques) to drop the wire
     from 72 to 4 B/lane — clearly worth it on link-bound paths
@@ -1104,7 +1132,14 @@ def lean_window(packed, capacity: int):
     )
     # bit 31 of the cfgid field lands in the i32 sign bit — wrap the bit
     # pattern through uint32 (every reader masks, so negatives are fine)
-    return lanes.astype(np.uint32).view(np.int32), cfg
+    lanes = lanes.astype(np.uint32).view(np.int32)
+    n = lanes.shape[-1]
+    if width is None or width == n:
+        return lanes, cfg
+    out = np.empty(lanes.shape[:-1] + (width,), np.int32)
+    out[..., n:] = _LEAN_PAD
+    out[..., :n] = lanes
+    return out, cfg
 
 
 def pack_window(items, slots, fresh, width: int, out=None):

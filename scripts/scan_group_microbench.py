@@ -8,6 +8,15 @@ Each line: the program, the first call (trace + compile, compile cache off),
 a call as the engine makes it (host arrays in, the answers fetched) and the
 program alone (device-resident arguments, launches chained), in ms. Every
 pair must read `same: true` (answers and the touched rows bit-identical).
+
+Then (alone with `--staged-only`) the 32 x 2048 compact group as
+Engine._apply_windows_scanned retires it, the host's parts and the chip's
+apart: the stack zeroed (`alloc_ms`), lean_window's refusal and
+compact_window (`stage_ms`), the jitted call and the copy back
+(`call_fetch_ms`), the answers widened (`widen_ms`) — once walking the
+launched width (`whole`: PERF.md PR 39's 2.27 ms row plus its host work),
+once the group's 58 live lanes (`prefix`). Both forms must hand the
+program the same bytes (`staged_same: true`).
 Exits non-zero off the chip: a CPU time is not a device time."""
 
 import json
@@ -76,6 +85,52 @@ def run(name, fn, args):
     return first, state
 
 
+def staged_group(depth, width):
+    """The carried compact group as the engine stages, launches, fetches
+    and widens it, by the launched width and by the live prefix."""
+    stack, _slots = group(depth, width, 7)
+    live = int((stack[0, 0] >= 0).sum())  # round 0 holds every key
+    step = jax.jit(D.decide_scan_carried_compact, donate_argnums=(0,))
+    state = D.make_table(C) + jnp.uint32(0)
+    staged = {}
+    for form, lanes, launched in (("whole", width, None),
+                                  ("prefix", live, width)):
+        parts = dict.fromkeys(
+            ("alloc_ms", "stage_ms", "call_fetch_ms", "widen_ms"), 0.0)
+        for rep in range(REPS + 1):  # the first call compiles: not counted
+            t0 = time.perf_counter()
+            src = np.zeros((depth, 9, lanes), np.int64)
+            src[:, 0, :] = -1
+            t1 = time.perf_counter()
+            src[...] = stack[..., :lanes]  # pack_window's part: not timed
+            t2 = time.perf_counter()
+            # hits 1..3: the lean wire refuses, as in hot10m.repeats1000
+            assert D.lean_window(src, C, launched) is None
+            compact = D.compact_window(src, launched)
+            t3 = time.perf_counter()
+            state, out = step(state, compact, NOW)
+            np.asarray(out)  # the wait and the copy; the array keeps it
+            t4 = time.perf_counter()
+            wide = D.widen_compact_out(out, NOW,
+                                       None if launched is None else live)
+            t5 = time.perf_counter()
+            assert wide.shape == (depth, 4, lanes)
+            if rep:
+                for key, dt in (("alloc_ms", t1 - t0), ("stage_ms", t3 - t2),
+                                ("call_fetch_ms", t4 - t3),
+                                ("widen_ms", t5 - t4)):
+                    parts[key] += dt / REPS * 1e3
+        staged[form] = compact
+        parts = {k: round(v, 3) for k, v in parts.items()}
+        print(json.dumps({
+            "group": f"rows  compact K{depth} W{width} live{live}",
+            "form": form, **parts,
+            "total_ms": round(sum(parts.values()), 3)}), flush=True)
+    same = bool((staged["whole"] == staged["prefix"]).all())
+    print(json.dumps({"staged_same": same}), flush=True)
+    return same
+
+
 def main():
     device = jax.devices()[0]
     print(json.dumps({"platform": device.platform,
@@ -83,6 +138,8 @@ def main():
     if device.platform != "tpu":
         return 2
     jax.config.update("jax_enable_compilation_cache", False)
+    if "--staged-only" in sys.argv[1:]:
+        return 0 if staged_group(32, 2048) else 1
     stagings = {
         "compact": (D.decide_scan_packed_compact,
                     D.decide_scan_carried_compact,
@@ -109,6 +166,7 @@ def main():
             print(json.dumps({"same": same}), flush=True)
             ok = ok and same
             del st_t, st_c
+    ok = staged_group(32, 2048) and ok
     return 0 if ok else 1
 
 

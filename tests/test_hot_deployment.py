@@ -117,24 +117,26 @@ class _Node:
         single, scan = (self.engine._dispatch_staged,
                         self.engine._dispatch_scan_staged)
 
-        def spy_single(packed, now_ms):
+        def spy_single(packed, now_ms, live=None):
             self.launched["single"] += 1
             self.launched["shapes"].add(packed.shape)
-            return single(packed, now_ms)
+            return single(packed, now_ms, live)
 
-        def spy_scan(stacked, now_ms, carried=False):
-            live = stacked[:, 0, :] >= 0
+        def spy_scan(stacked, now_ms, carried=False, live=None, width=None):
+            # the stack may hold its live lanes alone: `width` is launched
+            launched = (len(stacked), 9, width or stacked.shape[2])
+            held = stacked[:, 0, :] >= 0
             self.launched["scan"] += 1
-            self.launched["scan_rounds"] += int(live.any(axis=1).sum())
+            self.launched["scan_rounds"] += int(held.any(axis=1).sum())
             if carried:  # a lane holds one slot through the stack
                 self.launched["carried_rounds"] += int(
-                    live.any(axis=1).sum())
-                slots = np.where(live, stacked[:, 0, :], -1)
-                assert ((slots == slots.max(axis=0)) | ~live).all()
-            self.launched["scan_live"] += int(live.sum())
-            self.launched["scan_lanes"] += live.size
-            self.launched["shapes"].add(stacked.shape)
-            return scan(stacked, now_ms, carried)
+                    held.any(axis=1).sum())
+                slots = np.where(held, stacked[:, 0, :], -1)
+                assert ((slots == slots.max(axis=0)) | ~held).all()
+            self.launched["scan_live"] += int(held.sum())
+            self.launched["scan_lanes"] += launched[0] * launched[2]
+            self.launched["shapes"].add(launched)
+            return scan(stacked, now_ms, carried, live, width)
 
         self.engine._dispatch_staged = spy_single
         self.engine._dispatch_scan_staged = spy_scan
@@ -452,9 +454,11 @@ def test_every_scan_shape_the_combiner_launches_is_one_the_warm_up_compiled():
     warmed = {((k, 9, lo), True) for k in (2, 4, 8, 16, 32)} \
         | {((k, 9, hi), False) for k in (2, 4, 8)}
     shapes, real = [], eng._dispatch_scan_staged
-    eng._dispatch_scan_staged = lambda stacked, now_ms, carried=False: (
-        shapes.append((stacked.shape, carried)),
-        real(stacked, now_ms, carried))[1]
+    eng._dispatch_scan_staged = lambda stacked, now_ms, carried=False, \
+        live=None, width=None: (
+        shapes.append(((len(stacked), 9, width or stacked.shape[2]),
+                       carried)),
+        real(stacked, now_ms, carried, live, width))[1]
     gate, launch = threading.Event(), eng.launch_windows
     eng.launch_windows = lambda *a, **kw: (gate.wait(10), launch(*a, **kw))[1]
     comb = BackendCombiner(eng, depth=3, scan=scan)

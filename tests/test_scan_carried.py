@@ -163,11 +163,13 @@ def _repeating_call(rng, n, n_keys, skew=1.3):
 
 
 def _spy_scans(eng):
-    """(shape, carried) of every scan dispatch, in order."""
+    """(launched shape, carried) of every scan dispatch, in order: a stack
+    that holds its live lanes alone launches `width` wide."""
     seen, real = [], eng._dispatch_scan_staged
-    eng._dispatch_scan_staged = lambda stacked, now_ms, carried=False: (
-        seen.append((stacked.shape, carried)),
-        real(stacked, now_ms, carried))[1]
+    eng._dispatch_scan_staged = lambda stacked, now_ms, carried=False, \
+        live=None, width=None: (
+        seen.append(((len(stacked), 9, width or stacked.shape[2]), carried)),
+        real(stacked, now_ms, carried, live, width))[1]
     return seen
 
 
