@@ -1,0 +1,11 @@
+"""A call's time inside the native front, frame in to answer out in the cell
+whose every request is a new key: `front_call_ms`'s arithmetic
+(benchmarks/layer_metrics/front_call_ms.py); that metric lists its cells and
+this one is not among them."""
+
+from layer_metrics.front_call_ms import read  # noqa: F401
+
+LAYER = "wire front"
+SOURCE = "program_span"
+UNIT = "ms"
+MOVES = "call_p50_ms"

@@ -168,6 +168,8 @@ def load_library() -> ctypes.CDLL:
         lib.keydir_size.argtypes = [c.c_void_p]
         lib.keydir_evictions.restype = c.c_int64
         lib.keydir_evictions.argtypes = [c.c_void_p]
+        lib.keydir_churn_stats.restype = None
+        lib.keydir_churn_stats.argtypes = [c.c_void_p, c.c_void_p]
         lib.fnv1a_owner_batch.argtypes = [
             c.c_char_p, c.c_void_p, c.c_int32, c.c_int32, c.c_void_p,
         ]
@@ -690,6 +692,18 @@ class NativeKeyDirectory:
     @property
     def evictions(self) -> int:
         return int(self._lib.keydir_evictions(self._kd))
+
+    def churn_stats(self) -> dict:
+        """What the directory counts of keys coming and going, beside
+        `evictions()`: `inserts`, the keys it has given a slot (a restore's
+        and the serving path's fresh lanes alike), and the tombstone
+        rebuilds of the bucket array: how many, the nanoseconds they took
+        in all, the longest one (each walks every live entry under the
+        directory's mutex, so each is a stall)."""
+        out = (ctypes.c_int64 * 4)()
+        self._lib.keydir_churn_stats(self._kd, out)
+        return {"inserts": int(out[0]), "rebuilds": int(out[1]),
+                "rebuild_ns": int(out[2]), "rebuild_max_ns": int(out[3])}
 
     def lookup(self, keys: Sequence[str]) -> Tuple[List[int], List[bool]]:
         slots, fresh, inject = self.lookup_inject(keys)

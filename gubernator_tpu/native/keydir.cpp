@@ -18,6 +18,7 @@
 #include <Python.h>
 
 #include <atomic>
+#include <chrono>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
@@ -140,6 +141,7 @@ class KeyDir {
         Hold g(*this);
         ++gen_;
         int32_t ninj = 0;
+        int32_t inserted = 0;
         // Hash pass + software prefetch: at 10M+ entries every probe is a
         // DRAM miss (~100 ns), and the batch loop's per-key chain
         // (bucket -> entry -> LRU links) is serialized on them. Hashing
@@ -185,6 +187,7 @@ class KeyDir {
             if (e < 0) {  // over-committed: >capacity distinct keys pinned
                 for (int32_t j = i; j < n; ++j) slots_out[j] = -1;
                 if (n_inject != nullptr) *n_inject = ninj;
+                inserts_.fetch_add(inserted, std::memory_order_relaxed);
                 return i;
             }
             Entry& ent = entries_[e];
@@ -196,8 +199,10 @@ class KeyDir {
             lru_push_front(e);
             slots_out[i] = ent.slot;
             fresh_out[i] = 1;
+            ++inserted;
         }
         if (n_inject != nullptr) *n_inject = ninj;
+        inserts_.fetch_add(inserted, std::memory_order_relaxed);
         return n;
     }
 
@@ -393,6 +398,18 @@ class KeyDir {
         return capacity_ - static_cast<int64_t>(free_.size());
     }
     int64_t evictions() const { return evictions_; }
+    // out[0]: keys given a slot since the directory was made (every
+    // fresh_out = 1 of lookup_batch: a restore's inserts and the serving
+    // path's fresh lanes alike). out[1..3]: tombstone rebuilds of the
+    // bucket array, the nanoseconds they took in all, the longest one. A
+    // rebuild walks every LRU-linked entry under mu_, so each is a stall
+    // of whatever waits for the directory.
+    void churn_stats(int64_t* out) const {
+        out[0] = inserts_.load(std::memory_order_relaxed);
+        out[1] = rebuilds_.load(std::memory_order_relaxed);
+        out[2] = rebuild_ns_.load(std::memory_order_relaxed);
+        out[3] = rebuild_max_ns_.load(std::memory_order_relaxed);
+    }
     int64_t capacity() const { return capacity_; }
 
   private:
@@ -500,10 +517,18 @@ class KeyDir {
     }
 
     void rebuild_buckets() {
+        const auto t0 = std::chrono::steady_clock::now();
         buckets_.assign(nbuckets_, -1);
         tombstones_ = 0;
         for (int32_t e = lru_head_; e >= 0; e = entries_[e].lru_next) {
             insert_bucket(e);
+        }
+        const int64_t ns = std::chrono::duration_cast<std::chrono::nanoseconds>(
+            std::chrono::steady_clock::now() - t0).count();
+        rebuilds_.fetch_add(1, std::memory_order_relaxed);
+        rebuild_ns_.fetch_add(ns, std::memory_order_relaxed);
+        if (ns > rebuild_max_ns_.load(std::memory_order_relaxed)) {
+            rebuild_max_ns_.store(ns, std::memory_order_relaxed);
         }
     }
 
@@ -586,6 +611,11 @@ class KeyDir {
     uint64_t gen_ = 0;
     int64_t evictions_ = 0;
     uint64_t tombstones_ = 0;
+    // written under mu_ (lookup_batch, rebuild_buckets()), read without it
+    std::atomic<int64_t> inserts_{0};
+    std::atomic<int64_t> rebuilds_{0};
+    std::atomic<int64_t> rebuild_ns_{0};
+    std::atomic<int64_t> rebuild_max_ns_{0};
     // batch-hash scratch for lookup_batch's prefetch pass (under mu_)
     std::vector<uint64_t> hash_scratch_;
 };
@@ -676,6 +706,9 @@ void keydir_slots_live(void* kd, const int32_t* slots, int64_t n,
 int64_t keydir_size(void* kd) { return static_cast<KeyDir*>(kd)->size(); }
 int64_t keydir_evictions(void* kd) {
     return static_cast<KeyDir*>(kd)->evictions();
+}
+void keydir_churn_stats(void* kd, int64_t* out) {
+    static_cast<KeyDir*>(kd)->churn_stats(out);
 }
 
 // Batch fnv1a64 % n_owners for host-side owner routing

@@ -65,9 +65,29 @@ def _backend_vars(backend) -> dict:
     occ = key_table_size(backend)
     if occ is not None:
         out["key_table_size"] = occ
+    directory = _directory_vars(backend)
+    if directory is not None:
+        out["directory"] = directory
     reg = getattr(backend, "global_registry_size", None)
     if callable(reg):
         out["global_registry_size"] = int(reg())
+    return out
+
+
+def _directory_vars(backend) -> Optional[dict]:
+    """What the host directory counts of its own churn: LRU evictions
+    (`eviction_count`) and, on a single-table engine's native directory,
+    the keys it has given a slot (`inserts`: every fresh lane is one) and
+    the tombstone rebuilds of its bucket array (how many, their
+    nanoseconds in all, the longest). None where evictions are not
+    host-countable."""
+    evictions = eviction_count(backend)
+    if evictions is None:
+        return None
+    out = {"evictions": evictions}
+    churn = getattr(getattr(backend, "directory", None), "churn_stats", None)
+    if callable(churn):
+        out.update(churn())
     return out
 
 

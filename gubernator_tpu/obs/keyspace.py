@@ -66,16 +66,43 @@ CAPACITY_OCCUPANCY_FLOOR = 0.5
 # --------------------------------------------------------------- analysis
 
 
+# rows of the hit column analysed at a time: beside the column itself a
+# harvest holds a chunk's temporaries, not the table's (its whole-column
+# copy, compaction, index and partition arrays were +335 to +390 MB of
+# resident set for a tenth of a second at 10M rows; PERF.md section 6)
+_CHUNK = 1 << 20
+
+
+def _heaviest(counts: np.ndarray, m: int):
+    """(slots, total, nonzero): the `m` slots with the most hits among
+    those that have any, heaviest first; the hits of all that have any;
+    how many those are. One pass over `counts`, `_CHUNK` rows at a time."""
+    best = np.empty(0, np.int64)
+    total, nonzero = 0.0, 0
+    for lo in range(0, counts.size, _CHUNK):
+        part = counts[lo:lo + _CHUNK]
+        live = np.flatnonzero(part > 0)
+        if not live.size:
+            continue
+        hits = part[live]
+        total += float(hits.sum())
+        nonzero += int(live.size)
+        if live.size > m:
+            live = live[np.argpartition(hits, -m)[-m:]]
+        best = np.concatenate([best, live + lo])
+        if best.size > m:
+            best = best[np.argpartition(counts[best], -m)[-m:]]
+    best = best[np.argsort(counts[best], kind="stable")[::-1]]
+    return best, total, nonzero
+
+
 def concentration(counts: np.ndarray, fit_ranks: int = 100) -> dict:
     """Hit-mass concentration of one harvest's per-slot attempt counts:
     top-1/10/100 share of the tracked mass plus a Zipf exponent estimate
     (slope of log count vs log rank over the head of the curve)."""
-    counts = np.asarray(counts, np.float64)
-    counts = counts[counts > 0]
-    counts.sort()
-    counts = counts[::-1]
-    n = counts.size
-    total = float(counts.sum())
+    flat = np.asarray(counts).reshape(-1)
+    slots, total, n = _heaviest(flat, max(fit_ranks, 100))
+    counts = flat[slots].astype(np.float64)  # the head, heaviest first
     out = {
         "tracked_hits": int(total),
         "nonzero_slots": int(n),
@@ -259,13 +286,9 @@ class KeyspaceCartographer:
         """Top-K slots by attempted hits, reverse-walked to key strings
         through the host directory (absent entries — recycled mid-walk
         or the devdir engine's on-chip directory — keep key=None)."""
-        nz = np.nonzero(counts > 0)[0]
-        if nz.size == 0:
+        top, total, _ = _heaviest(counts, self.top_k)
+        if top.size == 0:
             return []
-        k = min(self.top_k, nz.size)
-        top = nz[np.argpartition(counts[nz], -k)[-k:]]
-        top = top[np.argsort(counts[top])[::-1]]
-        total = float(counts[counts > 0].sum())
         resolved: Dict[int, str] = {}
         if owner_capacity is not None:
             dirs = getattr(backend, "directories", None) or []
