@@ -1006,6 +1006,13 @@ class Engine:
         directory + no Store hooks (stores need per-round host calls)."""
         return self._prep_fast is not None and self.store is None
 
+    # launch_columnar_windows dispatches a group of K windows as ONE
+    # program under ONE hold of the engine lock, read back by one copy:
+    # what the peerlink pull loop asks before it hands a pull's run of
+    # one-window chunks over as a group (service/peerlink.py
+    # _columnar_run)
+    columnar_group_is_one_launch = True
+
     def submit_columnar(self, n: int, keys, key_off, name_len, hits, limit,
                         duration, algorithm, behavior, slow_mask: int,
                         now_ms: Optional[int] = None):
@@ -1158,8 +1165,12 @@ class Engine:
             now_ms = millisecond_now()
         from gubernator_tpu import native
 
-        w = max(_bucket_width(wc[0], self.min_width, self.max_width)
-                for wc in windows)
+        # a group launches `max_width` wide, the one width whose group
+        # shapes warmup_pipeline compiles (a scan at its windows' own
+        # bucket width would compile under the engine lock on a ladder);
+        # the host walks a launch's live prefix whatever its width
+        w = self.max_width if k_req > 1 else _bucket_width(
+            windows[0][0], self.min_width, self.max_width)
         kb = _bucket_pow2(k_req) if k_req > 1 else 1
         prof = self.profiler
         seams = prof.seams()  # host spans, while a capture runs
@@ -1231,7 +1242,14 @@ class Engine:
             if total:
                 live = max(n0 for n0, _lane_item, _leftover in metas)
                 if m == 1:
-                    staged = self._dispatch_staged(buf[0], now_ms, live)
+                    # a group its first window cut is that window alone:
+                    # at its own bucket width, as it launches by itself
+                    lone = buf[0]
+                    wb = _bucket_width(windows[0][0], self.min_width,
+                                       self.max_width)
+                    if wb < w:
+                        lone = np.ascontiguousarray(lone[:, :wb])
+                    staged = self._dispatch_staged(lone, now_ms, live)
                 else:
                     kb2 = _bucket_pow2(m)
                     stack = buf if kb2 == kb else buf[:kb2]

@@ -627,6 +627,12 @@ class ShardedEngine:
         (Engine.supports_columnar's mesh twin; nothing is stamped here)."""
         return self._prep_fast is not None and self.store is None
 
+    # launch_columnar_windows launches a window at a time here (one
+    # shard_map call and one lock hold each): a group is as many launches
+    # as lock-step makes, left in flight, so the peerlink pull loop keeps
+    # a pull's one-window chunks lock-step on this backend
+    columnar_group_is_one_launch = False
+
     def submit_columnar(self, n: int, keys, key_off, name_len, hits, limit,
                         duration, algorithm, behavior, slow_mask: int,
                         now_ms: Optional[int] = None):

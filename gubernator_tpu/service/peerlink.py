@@ -908,15 +908,20 @@ class PeerLinkService:
         return b
 
     def _worker(self) -> None:
-        """The serving loop: pull, handle, post. Columnar launches may
-        stay in flight ACROSS pull boundaries — while a group rides the
-        device its earlier rows are already on the wire (_post_span), and
-        the next pull preps into a DIFFERENT buffer set of the ring. A
-        set is reused only once no in-flight launch references it, so
-        with more sets than pipeline depth the ring blocks only when the
-        device is the bottleneck anyway. The worker polls for new frames
-        while work is in flight and counts a pull_boundary_stall each
-        time the poll comes back empty."""
+        """The serving loop: pull, handle, post. A pull's run of
+        one-window chunks (every pull at the shipped widths) is launched,
+        collected and posted inside the pull, a scan group at a time
+        (_columnar_run): nothing of it is in flight when the worker comes
+        back here. The launches of a chunk WIDER than one window
+        (_columnar_chunk's pipeline) may stay in flight ACROSS pull
+        boundaries — while a group rides the device its earlier rows are
+        already on the wire (_post_span), and the next pull preps into a
+        DIFFERENT buffer set of the ring. A set is reused only once no
+        in-flight launch references it, so with more sets than pipeline
+        depth the ring blocks only when the device is the bottleneck
+        anyway. The worker polls for new frames while work is in flight
+        and counts a pull_boundary_stall each time the poll comes back
+        empty."""
         depth = self._col_depth
         nsets = min(depth, 4) + 1
         sets = [self._mk_pull_bufs() for _ in range(nsets)]
@@ -929,6 +934,9 @@ class PeerLinkService:
             # while a launch still holds it
             "staging": [dict() for _ in range(depth + 2)],
             "seq": 0,
+            # the staging stacks of _columnar_run's groups, one a depth:
+            # a group is collected before the worker launches the next
+            "run_staging": {},
             "ctxs": [None] * nsets,  # the ctx last prepped into each set
             "cur": 0,
         }
@@ -1143,17 +1151,22 @@ class PeerLinkService:
                       ws: dict) -> None:
         """Decode -> handler calls -> fill the pull's response buffers.
         Every row posts to the wire THROUGH this call via _post_span —
-        per chunk for carrier/object chunks, per drained group for
-        columnar chunks, which may leave clean groups in flight in ws
-        when it returns.
+        per chunk for carrier/object chunks, per group for columnar
+        ones; only a chunk wider than one window may leave clean groups
+        in flight in ws when it returns.
 
         Peer-hop chunks ride the COLUMNAR path when the backend offers it
         (Engine.launch_columnar_windows / submit_columnar): the wire
-        columns go through the GIL-free C prep straight to the device —
-        scan-grouped and depth-pipelined where a chunk is wider than the
-        engine's widest window (_columnar_chunk) — and the response rows
-        scatter back into these buffers; no RateLimitReq/RateLimitResp
-        objects at all on the hot path. Items the columnar prep can't
+        columns go through the GIL-free C prep straight to the device and
+        the response rows scatter back into these buffers; no
+        RateLimitReq/RateLimitResp objects at all on the hot path. A run
+        of several chunks of one method — the calls of up to
+        MAX_BATCH_SIZE requests a pull brought together — is handed over
+        as scan groups, ONE launch a group (_columnar_run: one hold of
+        the engine lock, one enqueue, one wait, one copy back), where the
+        backend's group is one launch; a lone chunk is served lock-step,
+        and one wider than the engine's widest window scan-grouped and
+        depth-pipelined (_columnar_chunk). Items the columnar prep can't
         take (invalid, gregorian, GLOBAL/MULTI_REGION, duplicate
         occurrences) run through the request-object path AFTER the
         packed round."""
@@ -1231,12 +1244,16 @@ class PeerLinkService:
                         and self._columnar_chunk_lockstep(
                             m, eng, [(j, k)], k, b, errs, metas)):
                     self._object_chunk(m, j, k, b, errs, metas)
-            # the columnar path posts its own spans as groups drain (and
-            # may leave clean groups in flight); object chunks post whole
-            elif not (columnar_ok and self._columnar_chunk(
-                    m, eng, j, k, ctx, ws)):
-                self._object_chunk(m, j, k, b, errs, metas)
-                self._post_span(ctx, j, k)
+            elif (columnar_ok and run_ends[run] > k
+                    and self._groups_a_run(eng, k - j)):
+                # more chunks of this method behind this one: the run is
+                # handed over as scan groups, one launch a group, all of
+                # it collected and posted before the next chunk is cut
+                k = run_ends[run]
+                self._columnar_run(m, eng, j, k, ctx, ws)
+            else:
+                self._chunk_alone(m, eng if columnar_ok else None, j, k,
+                                  ctx, ws)
             j = k
 
         if lone_seed:
@@ -1323,12 +1340,136 @@ class PeerLinkService:
         return (adm is not None and adm.enabled
                 and adm.level() >= adm.SATURATED)
 
+    def _chunk_alone(self, m: int, eng, j: int, k: int, ctx: _PullCtx,
+                     ws: dict) -> None:
+        """One chunk by itself: columnar where `eng` (the columnar
+        backend, None where the chunk may not ride it) takes it, else
+        through the request-object path. The columnar path posts its own
+        spans as groups drain (and may leave clean groups in flight);
+        an object chunk posts whole."""
+        if not (eng is not None
+                and self._columnar_chunk(m, eng, j, k, ctx, ws)):
+            self._object_chunk(m, j, k, ctx.b, ctx.errs, ctx.metas)
+            self._post_span(ctx, j, k)
+
+    def _scan_cap(self, eng) -> int:
+        """The most windows one group launch may carry: the shared
+        GUBER_PIPELINE_SCAN setting, under the engine's own limit."""
+        return min(self._col_scan, int(getattr(eng, "_MAX_SCAN", 0) or 1))
+
+    def _groups_a_run(self, eng, widest: int) -> bool:
+        """Whether a run of chunks, the widest `widest` items, is served
+        as scan groups (_columnar_run): the backend says a group is one
+        launch (`columnar_group_is_one_launch`: Engine's is, the mesh's
+        is a launch a window), the group shapes are warm (the daemon warms
+        them where the pipeline depth is not 1), and a chunk is one window
+        of the ladder. Nothing here is a setting of its own."""
+        return (self._col_depth > 1 and self._scan_cap(eng) > 1
+                and getattr(eng, "columnar_group_is_one_launch", False)
+                and len(self._chunk_spans(eng, 0, widest)) == 1
+                and not self._saturated())
+
+    @staticmethod
+    def _group_size(n: int, cap: int) -> int:
+        """How many of a run's `n` one-window chunks still to serve go
+        into the next scan group, of at most `cap` windows. Scan depths
+        are compiled at powers of two, so a group is a power of two, or
+        one window short of one (3 rides a depth-4 program, 7 a depth-8
+        one): a padding round costs the chip one round (GIL-free) where a
+        launch more costs the host a lock hold, an enqueue, a wait and a
+        copy under the GIL; two padding rounds or more cost the chip more
+        than the launch costs the host (PERF.md section 6, PR 44), so 5
+        is 4 + 1 and 6 is 4 + 2."""
+        k = min(n, cap)
+        p = 1 << (k.bit_length() - 1)  # the largest power of two <= k
+        return k if k == 2 * p - 1 and 2 * p <= cap else p
+
+    def _columnar_run(self, m: int, eng, j: int, end: int, ctx: _PullCtx,
+                      ws: dict) -> None:
+        """Serve items [j, end) of a pull: a run of two or more chunks of
+        one columnar method, each one window of the ladder (several calls
+        of up to MAX_BATCH_SIZE requests pulled together: every pull of a
+        busy batch front). The chunks stay the windows they are served as
+        alone, cut where _handle_batch cuts them, but a group of them
+        (_group_size) is ONE launch: launch_columnar_windows preps them
+        in frame order under one hold of the engine lock and dispatches
+        one scan program whose rounds apply in that order on the table, so
+        a key that stands in two calls of the pull is decided call by
+        call as lock-step decides it, under one timestamp a group;
+        collect_columnar_windows waits once and copies back once. Every
+        group is collected and posted before the next is launched and
+        nothing is in flight when the run returns (the two pull workers
+        stagger as they do lock-step; launches left in flight collide on
+        the engine lock: PERF.md section 6, PR 31).
+
+        A window with leftovers is its group's last (the engine cuts
+        there): they retire through _leftover_items before the next window
+        is prepped, and the rest of the run is grouped anew; where a
+        group's FIRST window cuts, the run's traffic repeats keys inside a
+        call and the rest goes on chunk by chunk, as it would without this
+        path. An over-commit error-fills the chunk that failed, as
+        lock-step does, and the run goes on behind it."""
+        b = ctx.b
+        mt = self._metrics
+        cap = self._scan_cap(eng)
+        chunks = [(c, min(c + MAX_BATCH_SIZE, end))
+                  for c in range(j, end, MAX_BATCH_SIZE)]
+        ci = 0
+        grouping = True
+        while ci < len(chunks):
+            size = self._group_size(len(chunks) - ci, cap) \
+                if grouping else 1
+            group = chunks[ci:ci + size]
+            h = None
+            if size > 1:
+                h = eng.launch_columnar_windows(
+                    [self._col_window(b, c0, c1) for c0, c1 in group],
+                    _COLUMNAR_SLOW_MASK, staging=ws["run_staging"])
+            if h is None:
+                # a lone chunk, or a shape the engine refused (nothing
+                # mutated): today's path, chunk by chunk
+                for c0, c1 in group:
+                    self._chunk_alone(m, eng, c0, c1, ctx, ws)
+                ci += size
+                continue
+            win_metas, failed = h[0], h[1]
+            consumed = len(win_metas)
+            self.stats["columnar_windows"] += consumed
+            self.stats["columnar_groups"] += 1
+            if mt is not None:
+                mt.peerlink_columnar_windows.inc(consumed)
+                mt.peerlink_columnar_group_windows.observe(consumed)
+            if consumed:
+                served = group[:consumed]
+                leftovers = eng.collect_columnar_windows(
+                    h, [self._col_outs(b, c0, c1) for c0, c1 in served])
+                for (c0, _c1), left in zip(served, leftovers):
+                    if left is not None and len(left):
+                        self._leftover_items(m, c0, left.tolist(), b,
+                                             ctx.errs, ctx.metas)
+                self._post_span(ctx, served[0][0], served[-1][1])
+            ci += consumed
+            if failed is not None:
+                c0, c1 = chunks[ci]  # the window whose prep over-committed
+                self._col_error_fill(failed.encode(), c0, c1, b, ctx.errs)
+                self._post_span(ctx, c0, c1)
+                ci += 1
+            elif consumed < size:
+                self.stats["columnar_cuts"] += 1
+                if mt is not None:
+                    mt.peerlink_columnar_cuts.inc()
+                grouping = consumed > 1
+
     def _columnar_chunk(self, m: int, eng, j: int, k: int,
                         ctx: _PullCtx, ws: dict) -> bool:
-        """Serve one peer-hop chunk columnar-end-to-end. A chunk that
-        fits the engine's widest window (every chunk at the shipped
-        widths: 1000 items against 8192 lanes) is one span and is served
-        lock-step, posted before return. A wider chunk is PIPELINED: its
+        """Serve one peer-hop chunk by itself, columnar-end-to-end (a
+        run of several one-window chunks goes through _columnar_run and
+        comes here only chunk by chunk: a lone chunk, the rest behind a
+        first window that cut, a backend whose group is not one launch).
+        A chunk that fits the engine's widest window (every chunk at the
+        shipped widths: 1000 items against 8192 lanes) is one span and is
+        served lock-step, posted before return. A wider chunk is
+        PIPELINED: its
         sub-windows launch in scan groups of <= pipeline_scan windows
         (one device call each, models/engine.py launch_columnar_windows)
         into the WORKER-level pipeline (ws["inflight"], up to
@@ -1359,7 +1500,7 @@ class PeerLinkService:
                 self._post_span(ctx, j, k)
             return ok
         mt = self._metrics
-        scan = min(self._col_scan, int(getattr(eng, "_MAX_SCAN", 0) or 1))
+        scan = self._scan_cap(eng)
         staging = ws["staging"]
         inflight = ws["inflight"]
         wi = 0
@@ -1432,8 +1573,10 @@ class PeerLinkService:
 
     def _columnar_chunk_lockstep(self, m: int, eng, spans, k: int,
                                  b: dict, errs: list, metas: list) -> bool:
-        """The serial columnar path (single-window chunks, depth 1, or
-        engines without the launch/collect split): complete sub-window i
+        """The serial columnar path (a single-window chunk served by
+        itself: a pull of one chunk, a lone request, a chunk _columnar_run
+        does not group; depth 1; engines without the launch/collect split
+        or whose group is a launch a window): complete sub-window i
         before submitting i+1 — the C prep's duplicate tracking is
         per-submit, so a key demoted to the leftover tail of sub-window i
         must finish before a later sub-window packs its next occurrence.

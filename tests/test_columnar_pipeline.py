@@ -244,6 +244,40 @@ class TestPipelinedColumnarDifferential:
                           duration=60_000)], now_ms=NOW)
         assert after[0].remaining == 98
 
+    def test_a_group_launches_max_width_wide_and_a_cut_first_window_alone(
+            self):
+        """A group of K > 1 windows launches `max_width` wide whatever
+        its windows' own bucket widths are (the group shapes are compiled
+        at the top width only); a group whose FIRST window has leftovers
+        is that window alone and launches at its own bucket width, as
+        submit_columnar launches it."""
+        eng = _engine()  # ladder 8, 16
+        stacks, singles = [], []
+        scan, single = eng._dispatch_scan_staged, eng._dispatch_staged
+        eng._dispatch_scan_staged = lambda stacked, *a, **k: (
+            stacks.append(stacked.shape), scan(stacked, *a, **k))[1]
+        eng._dispatch_staged = lambda packed, *a, **k: (
+            singles.append(packed.shape), single(packed, *a, **k))[1]
+        wins = [[RateLimitReq(name="gw", unique_key=f"w{w}k{i}", hits=1,
+                              limit=9, duration=60_000) for i in range(5)]
+                for w in range(3)]
+        h = eng.launch_columnar_windows([cols_from(rs) for rs in wins],
+                                        SLOW, now_ms=NOW)
+        outs = [_outs(5) for _ in range(3)]
+        assert [len(x) for x in eng.collect_columnar_windows(h, outs)] == \
+            [0, 0, 0]
+        assert stacks == [(4, 9, 16)] and singles == []
+        assert all(o[2].tolist() == [8] * 5 for o in outs)
+        wins[0][4] = wins[0][0]  # the first window repeats a key: it cuts
+        h = eng.launch_columnar_windows([cols_from(rs) for rs in wins],
+                                        SLOW, now_ms=NOW)
+        assert len(h[0]) == 1
+        outs = [_outs(5)]
+        left, = eng.collect_columnar_windows(h, outs)
+        assert left.tolist() == [4]
+        assert stacks == [(4, 9, 16)] and singles == [(9, 8)]
+        assert outs[0][2][:4].tolist() == [7] * 4
+
     def test_over_commit_dispatches_prefix_and_reports(self):
         """Over-commit mid-group: the windows prepped before the failure
         still dispatch (their directory commits reached the device) and
@@ -471,6 +505,81 @@ def send_as_one_pull(svc, cli, frames, methods=None, pulls=None):
         svc._handle_batch = real
 
 
+def send_in_one_pull(svc, cli, frames, pulls=None):
+    """Send `frames` so that they reach the (single) worker in ONE pull:
+    a two-request primer frame (keys of its own) holds the worker inside
+    its pull until the IO thread has parsed every frame, so all of them
+    queue up and are pulled together. `pulls` collects (got, launches
+    still in flight when the pull's handling returned) of the pulls after
+    the primer's. Returns each frame's answers, in frame order."""
+    from gubernator_tpu.service.peerlink import METHOD_GET_PEER_RATE_LIMITS
+
+    entered, gate = threading.Event(), threading.Event()
+    real = svc._handle_batch
+    handled = [0]  # items of the pulls after the primer's, once recorded
+
+    def gated(got, b, ctx, ws):
+        primer = not entered.is_set()
+        entered.set()
+        if primer:
+            gate.wait(10.0)
+        try:
+            return real(got, b, ctx=ctx, ws=ws)
+        finally:
+            if not primer:
+                if pulls is not None:
+                    pulls.append((got, len(ws["inflight"])))
+                handled[0] += got
+
+    svc._handle_batch = gated
+    try:
+        tag = time.monotonic_ns()
+        head = cli.call_async(METHOD_GET_PEER_RATE_LIMITS, [RateLimitReq(
+            name="primer", unique_key=f"{tag}_{i}", hits=0, limit=1,
+            duration=60_000) for i in range(2)])[0]
+        assert entered.wait(10.0)
+        futs = [cli.call_async(METHOD_GET_PEER_RATE_LIMITS, f)[0]
+                for f in frames]
+        deadline = time.time() + 10
+        while (svc.wire_pending_count() < len(frames) + 1
+               and time.time() < deadline):
+            time.sleep(0.002)
+        assert svc.wire_pending_count() == len(frames) + 1
+        gate.set()
+        head.result(30.0)
+        out = [f.result(30.0) for f in futs]
+        # the answers leave inside the pull's handling: wait for its end
+        deadline = time.time() + 10
+        while (handled[0] < sum(len(f) for f in frames)
+               and time.time() < deadline):
+            time.sleep(0.001)
+        return out
+    finally:
+        gate.set()
+        svc._handle_batch = real
+
+
+def lockstep_backend(eng):
+    """`eng` as a backend whose group is NOT one launch (the mesh engine
+    says so of itself): the pull loop keeps one-window chunks lock-step."""
+    eng.columnar_group_is_one_launch = False
+    return eng
+
+
+def spy_launches(eng, svc):
+    """Record what the pull loop asks of the engine: the window count of
+    every launch_columnar_windows call, and the spans of every
+    _columnar_chunk_lockstep call."""
+    groups, lockstep = [], []
+    real_launch, real_lock = (eng.launch_columnar_windows,
+                              svc._columnar_chunk_lockstep)
+    eng.launch_columnar_windows = lambda wins, *a, **k: (
+        groups.append(len(wins)), real_launch(wins, *a, **k))[1]
+    svc._columnar_chunk_lockstep = lambda *a, **k: (
+        lockstep.append(a[2]), real_lock(*a, **k))[1]
+    return groups, lockstep
+
+
 def oracle_rows(table, reqs, now_ms):
     """ops/oracle.py's answers for `reqs`, one after another against
     `table`, as (status, limit, remaining, reset_time) rows."""
@@ -482,9 +591,13 @@ def oracle_rows(table, reqs, now_ms):
 def _shared_key_frames(rng, n_frames, n, leftovers):
     """`n_frames` frames of `n` requests: keys distinct inside a frame,
     a hot set shared by all of them (the hot keys of several calls), both
-    algorithms. With `leftovers`, every other frame also repeats one of
-    its own keys, carries a gregorian and a GLOBAL request: lanes the C
-    prep demotes to the object path."""
+    algorithms. With `leftovers` (True: every other frame; or the frames'
+    indices), a frame also repeats one of its own keys, carries a
+    gregorian and a GLOBAL request: lanes the C prep demotes to the
+    object path."""
+    if leftovers is True:
+        leftovers = range(0, n_frames, 2)
+    leftovers = set(leftovers or ())
     frames = []
     for f in range(n_frames):
         keys = [f"hot{i}" for i in rng.permutation(6)[:4]]
@@ -495,7 +608,7 @@ def _shared_key_frames(rng, n_frames, n, leftovers):
             algorithm=(Algorithm.LEAKY_BUCKET if k.startswith("hot")
                        and int(k[3:]) % 2 else Algorithm.TOKEN_BUCKET))
             for k in keys]
-        if leftovers and f % 2 == 0:
+        if f in leftovers:
             reqs[-1] = RateLimitReq(name="mf", unique_key=keys[0], hits=1,
                                     limit=30, duration=60_000,
                                     algorithm=reqs[0].algorithm)
@@ -551,46 +664,243 @@ class TestWireLevelDifferential:
             sp.close()
             ip.close()
 
+    @pytest.mark.parametrize("grouped", [True, False],
+                             ids=["group", "lockstep_backend"])
     @pytest.mark.parametrize("leftovers", [False, True],
                              ids=["clean", "leftovers"])
     def test_multi_frame_pull_of_one_window_chunks(self, monkeypatch,
-                                                   leftovers):
+                                                   leftovers, grouped):
         """The shape of a batch1000 pull: several frames pulled together,
         each a chunk that fits ONE window, keys shared between frames.
-        Every chunk is served lock-step inside the pull (submit_/
-        complete_columnar: nothing is launched into the worker pipeline)
-        and every column of every answer is that of run_lockstep on a
-        twin engine and of ops/oracle.py, in frame order — leaky buckets
-        and reset_time included. With leftovers (a key repeated inside
-        its frame, gregorian, GLOBAL) the chunk's tail retires through
-        the object path before the next frame, which asks for the same
-        hot keys, is prepped."""
+        The run of chunks is handed to the engine as ONE scan group a
+        pull (launch_/collect_columnar_windows, collected inside the
+        pull: nothing is in flight at its end), and every column of every
+        answer is that of run_lockstep on a twin engine and of
+        ops/oracle.py, in frame order — leaky buckets and reset_time
+        included. With leftovers (a key repeated inside its frame,
+        gregorian, GLOBAL) a frame's tail retires through the object path
+        before the next frame, which asks for the same hot keys, is
+        prepped: the group is cut there. On a backend whose group is not
+        one launch (`lockstep_backend`: the mesh's statement) every chunk
+        is served lock-step inside the pull (submit_/complete_columnar)
+        and nothing is launched."""
         clock = pin_engine_clock(monkeypatch)
         chunk_cap(monkeypatch, 16)
-        ip, sp, cp = _serve(_engine(), pipeline_depth=3, pipeline_scan=4,
+        eng = _engine() if grouped else lockstep_backend(_engine())
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4,
                             workers=1)
-        lockstep = []
-        real = sp._columnar_chunk_lockstep
-        sp._columnar_chunk_lockstep = lambda *a, **k: (
-            lockstep.append(a[2]), real(*a, **k))[1]
+        groups, lockstep = spy_launches(eng, sp)
         twin = _engine()
         table = {}
         rng = np.random.default_rng(53)
+        pulls = []
         try:
             for it in range(4):
                 clock["now"] = NOW + it * 900
-                frames = _shared_key_frames(rng, 5, 16, leftovers)
-                got = send_as_one_pull(sp, cp, frames)
+                frames = _shared_key_frames(rng, 4, 16, leftovers)
+                got = send_in_one_pull(sp, cp, frames, pulls)
                 for f, (reqs, out) in enumerate(zip(frames, got)):
                     want = reference_rows(twin, reqs, clock["now"])
                     assert_served_rows(out, want, reqs, (it, f))
                     assert oracle_rows(table, reqs, clock["now"]) == want
-            assert len(lockstep) == 20  # every chunk, one span each
+            assert pulls == [(64, 0)] * 4  # one pull, nothing in flight
             assert all(len(spans) == 1 for spans in lockstep)
-            assert sp.stats["columnar_windows"] == 0
+            if not grouped:
+                assert groups == []
+                assert len(lockstep) == 4 + 16  # primers, then every chunk
+                assert sp.stats["columnar_windows"] == 0
+            elif not leftovers:
+                assert groups == [4] * 4  # one group a run
+                assert len(lockstep) == 4  # the primers
+                assert sp.stats["columnar_windows"] == 16
+                assert sp.stats["columnar_groups"] == 4
+                assert sp.stats["columnar_cuts"] == 0
+            else:
+                # frame 0 has leftovers: the first window cuts its group
+                # and the rest of the run goes on chunk by chunk
+                assert groups == [4] * 4
+                assert len(lockstep) == 4 + 12
+                assert sp.stats["columnar_windows"] == 4
+                assert sp.stats["columnar_cuts"] == 4
+            assert sp.stats["pull_boundary_stalls"] == 0
+            assert sp.stats["columnar_fill_stalls"] == 0
+            assert sp.stats["errors"] == 0
+        finally:
+            del eng.launch_columnar_windows
+            cp.close()
+            sp.close()
+            ip.close()
+
+    @pytest.mark.parametrize("n_frames, groups_want", [
+        (2, [2]), (3, [3]), (4, [4]), (5, [4]), (6, [4, 2]), (7, [7]),
+        (9, [8])])
+    def test_a_pull_of_n_frames_is_served_in_groups_by_n_alone(
+            self, monkeypatch, n_frames, groups_want):
+        """A pull of n one-window frames, keys shared between frames,
+        clean: the run is cut into groups by n alone (a power of two, or
+        one window short of one; a chunk left over is served alone), each
+        group one launch collected inside the pull, and every column of
+        every answer is run_lockstep's on a twin engine and
+        ops/oracle.py's, in frame order, on a pinned clock."""
+        clock = pin_engine_clock(monkeypatch)
+        chunk_cap(monkeypatch, 16)
+        eng = _engine()
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=8,
+                            workers=1)
+        groups, lockstep = spy_launches(eng, sp)
+        twin, table = _engine(), {}
+        rng = np.random.default_rng(59 + n_frames)
+        pulls = []
+        try:
+            for it in range(3):
+                clock["now"] = NOW + it * 900
+                frames = _shared_key_frames(rng, n_frames, 16, False)
+                got = send_in_one_pull(sp, cp, frames, pulls)
+                for f, (reqs, out) in enumerate(zip(frames, got)):
+                    want = reference_rows(twin, reqs, clock["now"])
+                    assert_served_rows(out, want, reqs, (it, f))
+                    assert oracle_rows(table, reqs, clock["now"]) == want
+            assert pulls == [(16 * n_frames, 0)] * 3
+            assert groups == groups_want * 3
+            alone = n_frames - sum(groups_want)
+            assert len(lockstep) == 3 * (1 + alone)  # the primer, the rest
+            assert sp.stats["columnar_windows"] == 3 * sum(groups_want)
+            assert sp.stats["columnar_groups"] == 3 * len(groups_want)
+            assert sp.stats["columnar_cuts"] == 0
             assert sp.stats["pull_boundary_stalls"] == 0
             assert sp.stats["errors"] == 0
         finally:
+            del eng.launch_columnar_windows
+            cp.close()
+            sp.close()
+            ip.close()
+
+    @pytest.mark.parametrize("where, groups_want, windows, alone", [
+        # the first window cuts: the rest of the run chunk by chunk
+        ("first", [4], 1, 4),
+        # a middle one: the group is cut behind it, the rest grouped anew
+        ("middle", [4, 2], 5, 0),
+        # the last of the run: served alone, nothing behind it to cut
+        ("last", [4], 4, 1)])
+    def test_a_frame_with_leftovers_cuts_its_group(self, monkeypatch, where,
+                                                   groups_want, windows,
+                                                   alone):
+        """Five frames in one pull, one of them with lanes the C prep
+        demotes (a key repeated inside the frame, gregorian, GLOBAL): its
+        window is its group's last, its tail retires through the object
+        path before the next frame — which asks for the same hot keys —
+        is prepped, and the answers are lock-step's and the oracle's."""
+        clock = pin_engine_clock(monkeypatch)
+        chunk_cap(monkeypatch, 16)
+        eng = _engine()
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=8,
+                            workers=1)
+        groups, lockstep = spy_launches(eng, sp)
+        twin, table = _engine(), {}
+        rng = np.random.default_rng(67)
+        at = {"first": 0, "middle": 2, "last": 4}[where]
+        pulls = []
+        try:
+            frames = _shared_key_frames(rng, 5, 16, [at])
+            got = send_in_one_pull(sp, cp, frames, pulls)
+            for f, (reqs, out) in enumerate(zip(frames, got)):
+                want = reference_rows(twin, reqs, clock["now"])
+                assert_served_rows(out, want, reqs, (where, f))
+                assert oracle_rows(table, reqs, clock["now"]) == want
+            assert pulls == [(80, 0)]
+            assert groups == groups_want
+            assert len(lockstep) == 1 + alone
+            assert sp.stats["columnar_windows"] == windows
+            assert sp.stats["columnar_cuts"] == (0 if where == "last" else 1)
+            assert sp.stats["leftover_items"] == 3
+            assert sp.stats["errors"] == 0
+        finally:
+            del eng.launch_columnar_windows
+            cp.close()
+            sp.close()
+            ip.close()
+
+    def test_over_commit_in_the_middle_of_a_group(self, monkeypatch):
+        """The second of four frames over-commits the directory: the
+        window before it was prepped and is dispatched and decided, the
+        failing frame error-fills whole (as a lock-step chunk does), and
+        the two frames behind it are served as a group of their own."""
+        chunk_cap(monkeypatch, 16)
+        eng = _engine()
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=8,
+                            workers=1)
+        groups, lockstep = spy_launches(eng, sp)
+        real = native.prep_pack_columnar
+        calls = {"n": 0}
+
+        def failing(directory, n, *args):
+            # the primer's lone chunk is prep 1; the group's windows 2..
+            calls["n"] += 1
+            if calls["n"] == 3:
+                return (native.PREP_OVERCOMMIT, None, None,
+                        np.empty((0, 8), np.int64))
+            return real(directory, n, *args)
+
+        frames = [[RateLimitReq(name="oc", unique_key=f"f{f}k{i}", hits=1,
+                                limit=50, duration=60_000)
+                   for i in range(16)] for f in range(4)]
+        pulls = []
+        try:
+            native.prep_pack_columnar = failing
+            got = send_in_one_pull(sp, cp, frames, pulls)
+        finally:
+            native.prep_pack_columnar = real
+            del eng.launch_columnar_windows
+            cp.close()
+            sp.close()
+            ip.close()
+        assert pulls == [(64, 0)]
+        assert groups == [4, 2] and len(lockstep) == 1
+        for f in (0, 2, 3):
+            assert [(r.error, r.remaining) for r in got[f]] == \
+                [("", 49)] * 16, f
+        assert all("over-committed" in r.error for r in got[1])
+        assert sp.stats["columnar_windows"] == 3
+        assert sp.stats["columnar_cuts"] == 0
+
+    def test_a_group_collect_that_raises_answers_the_whole_pull(
+            self, monkeypatch):
+        """The readback of a pull's group raises: _recover_batch answers
+        every row of the pull with the internal-failure reply (nothing
+        of it was posted), no frame is stranded, and the worker serves
+        the next pull."""
+        chunk_cap(monkeypatch, 16)
+        eng = _engine()
+        ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=8,
+                            workers=1)
+        real_collect = eng.collect_columnar_windows
+        calls = {"n": 0}
+
+        def first_raises(handle, outs):
+            calls["n"] += 1
+            if calls["n"] == 1:
+                raise RuntimeError("injected readback failure")
+            return real_collect(handle, outs)
+
+        frames = [[RateLimitReq(name="gc", unique_key=f"f{f}k{i}", hits=1,
+                                limit=20, duration=60_000)
+                   for i in range(16)] for f in range(3)]
+        try:
+            eng.collect_columnar_windows = first_raises
+            got = send_in_one_pull(sp, cp, frames)
+            for out in got:
+                assert {(r.error, r.remaining) for r in out} == \
+                    {("peerlink: internal batch failure", 0)}
+            assert sp.stats["errors"] == 1
+            assert sp.wire_pending_count() == 0
+            # the group's hits were applied at its launch; the pull after
+            # it is served, a second hit a key
+            again = send_in_one_pull(sp, cp, frames)
+            for out in again:
+                assert [(r.error, r.remaining) for r in out] == \
+                    [("", 18)] * 16
+        finally:
+            del eng.collect_columnar_windows
             cp.close()
             sp.close()
             ip.close()
@@ -834,39 +1144,47 @@ class TestWireLevelDifferential:
 
 
 class TestOneWindowCounters:
-    """What the serving counters say: only a chunk wider than one window
-    is launched into the worker pipeline and counts columnar windows; a
-    chunk that fits one window (every chunk at the shipped widths) and a
-    lone request are served lock-step and count none."""
+    """What the serving counters say: a pull's run of one-window chunks
+    (every chunk at the shipped widths) is one group a run, collected
+    inside the pull; a chunk wider than one window is launched into the
+    worker pipeline and may stay in flight; a lone request, and every
+    chunk on a backend whose group is not one launch, is served lock-step
+    and counts none."""
 
-    @pytest.mark.parametrize("windows", [1, 2])
+    @pytest.mark.parametrize("windows, grouped", [
+        (1, True), (1, False), (2, True)], ids=["1", "1-lockstep", "2"])
     def test_windows_count_launched_chunks_and_the_boundary_stalls(
-            self, monkeypatch, windows):
-        """Chunks of one window are served inside their pull: no columnar
-        window, no launch in flight when the worker goes back to the
-        queue, so no stall of either kind. Chunks of two windows are one
-        launch each: the windows count, four launches meet a pipe of
-        three (fill stall), and the last of a pull is still in flight
-        when the worker polls and finds nothing (boundary stall)."""
+            self, monkeypatch, windows, grouped):
+        """Chunks of one window: the pull's four are ONE group, collected
+        inside the pull, so the windows count, nothing is in flight when
+        the worker goes back to the queue and there is no stall of either
+        kind; on a backend whose group is not one launch they are served
+        lock-step and count nothing. Chunks of two windows are one launch
+        each into the worker pipeline: the windows count, four launches
+        meet a pipe of three (fill stall), and the last of a pull is
+        still in flight when the worker polls and finds nothing (boundary
+        stall)."""
         n = 16 * windows
         chunk_cap(monkeypatch, n)
-        eng = _engine()
+        eng = _engine() if grouped else lockstep_backend(_engine())
         ip, sp, cp = _serve(eng, pipeline_depth=3, pipeline_scan=4,
                             workers=1)
+        pulls = []
         try:
             for it in range(3):
                 frames = [[RateLimitReq(name="ct", unique_key=f"p{it}f{f}k{i}",
                                         hits=1, limit=9, duration=60_000)
                            for i in range(n)] for f in range(4)]
-                for out in send_as_one_pull(sp, cp, frames):
+                for out in send_in_one_pull(sp, cp, frames, pulls):
                     assert [(r.error, r.remaining) for r in out] == \
                         [("", 8)] * n
             assert sp.stats["columnar_cuts"] == 0
             assert (sp.wire_debug()["pull_boundary_stalls"]
                     == sp.stats["pull_boundary_stalls"])
             if windows == 1:
-                assert sp.stats["columnar_windows"] == 0
-                assert sp.stats["columnar_groups"] == 0
+                assert pulls == [(4 * n, 0)] * 3  # none in flight at its end
+                assert sp.stats["columnar_windows"] == (12 if grouped else 0)
+                assert sp.stats["columnar_groups"] == (3 if grouped else 0)
                 assert sp.stats["pull_boundary_stalls"] == 0
                 assert sp.stats["columnar_fill_stalls"] == 0
             else:
@@ -926,8 +1244,9 @@ class TestChunkCut:
         """_handle_batch cuts a pull into chunks with one pass over the
         method column: every chunk is one method, at most the cap, and
         ends only where the method changes, the cap is reached or the
-        pull ends — whatever frames the pull happened to hold. The
-        answers say every item was served once, in order."""
+        pull ends — whatever frames the pull happened to hold, and
+        whether a chunk is served alone or as a window of its run's group.
+        The answers say every item was served once, in order."""
         from gubernator_tpu.service.peerlink import (
             METHOD_GET_PEER_RATE_LIMITS,
             METHOD_GET_RATE_LIMITS,
@@ -948,7 +1267,15 @@ class TestChunkCut:
             seen.append((b, m, j, k, b["method"][j:k].tolist()))
             return real_obj(m, j, k, b, errs, metas, direct)
 
+        def spy_win(b, s0, s1):
+            # a window of a run's group (_columnar_run): a chunk as cut
+            seen.append((b, int(b["method"][s0]), s0, s1,
+                         b["method"][s0:s1].tolist()))
+            return real_win(b, s0, s1)
+
+        real_win = sp._col_window
         sp._columnar_chunk, sp._object_chunk = spy_col, spy_obj
+        sp._col_window = spy_win
         sizes = [(METHOD_GET_PEER_RATE_LIMITS, 10),
                  (METHOD_GET_PEER_RATE_LIMITS, 10),
                  (METHOD_GET_RATE_LIMITS, 5),

@@ -356,8 +356,12 @@ class TestTheLadderChangesShapesNeverAnswers:
         assert line and float(line[0].split()[1]) == link["leftover_items"]
 
 
-def test_keys_distinct_in_a_call_leave_the_meters_at_zero(monkeypatch):
-    """`node10m.batch1000`'s shape: nothing is handed back, nothing scans."""
+def test_keys_distinct_in_a_call_hand_nothing_back_and_scan_as_groups(
+        monkeypatch):
+    """`node10m.batch1000`'s shape: nothing is handed back, a call is one
+    window and one round, and the only scans are the pull loop's groups
+    (service/peerlink.py _columnar_run): the calls pulled together, one
+    round a call on the table's carry, launched `max_width` wide."""
     tr = _traffic(11, distinct_in_call=True, zipf_exponent=0.99)
     items, table = _residents(tr)
     calls = _calls(tr)
@@ -365,10 +369,16 @@ def test_keys_distinct_in_a_call_leave_the_meters_at_zero(monkeypatch):
     try:
         assert _serve_pool(node, calls) == _oracle_rows(table, calls)
         link, st = node.service.stats, node.engine.stats
-        assert link["leftover_items"] == 0
-        assert (st.scan_dispatches, st.scan_rounds, st.scan_lanes_live,
-                st.scan_lanes) == (0, 0, 0, 0)
+        assert link["leftover_items"] == 0 and link["columnar_cuts"] == 0
         assert st.rounds == st.batches == CALLS
+        grouped = link["columnar_windows"]
+        assert 0 < grouped <= CALLS and link["columnar_groups"] > 0
+        assert st.scan_dispatches == link["columnar_groups"]
+        assert st.scan_rounds == grouped and st.scan_rounds_carried == 0
+        assert st.scan_lanes_live == grouped * ITEMS
+        assert st.scan_lanes % WIDEST == 0  # max_width wide, pads counted
+        assert {s for s in node.launched["shapes"] if len(s) == 3} \
+            <= {(k, 9, WIDEST) for k in (2, 4, 8)}
         body = node.instance.profiler.endpoint_body()
         assert body["phases"]["leftover"]["n"] == 0
     finally:
@@ -485,6 +495,58 @@ def test_every_scan_shape_the_combiner_launches_is_one_the_warm_up_compiled():
         assert [fn._cache_size() for fn in programs] == compiled
     finally:
         comb.close()
+
+
+@pytest.mark.parametrize("lo, hi", [(8192, 8192), (2048, 8192), (64, 8192)],
+                         ids=["8192", "2048..8192", "64..8192"])
+def test_every_group_shape_a_pull_launches_is_one_the_warm_up_compiled(
+        monkeypatch, lo, hi):
+    """The pull loop hands a pull's run of one-window chunks to the engine
+    as scan groups (service/peerlink.py _columnar_run), and a group
+    launches `max_width` wide whatever its windows' own bucket width is:
+    the table-carried group shapes are the ones `warmup_pipeline` compiles,
+    at the top width only. So on the benchmark's one-width ladder, on the
+    hot cell's and on the shipped one (at a small table), after the two
+    warm-ups a pull of 2 to 8 clean 1000-request frames compiles nothing:
+    not under the engine lock, not anywhere."""
+    from gubernator_tpu.types import RateLimitReq
+    from gubernator_tpu.utils.platform import CompileWatch
+
+    from test_columnar_pipeline import send_in_one_pull
+
+    scan = 8  # the daemon's GUBER_PIPELINE_SCAN
+    chunk_cap(monkeypatch, 1000)  # MAX_BATCH_SIZE, whatever the module pinned
+    eng = Engine(capacity=2 * hi, min_width=lo, max_width=hi)
+    if not eng.supports_columnar():
+        pytest.skip("native columnar prep unavailable")
+    eng.warmup()
+    eng.warmup_pipeline(max_group=scan)
+    shapes, real = [], eng._dispatch_scan_staged
+    eng._dispatch_scan_staged = lambda stacked, now_ms, carried=False, \
+        live=None, width=None: (
+        shapes.append(((len(stacked), 9, width or stacked.shape[2]),
+                       carried)),
+        real(stacked, now_ms, carried, live, width))[1]
+    instance, service, client = _serve(eng, workers=1, pipeline_depth=3,
+                                       pipeline_scan=scan)
+    watch = CompileWatch()
+    try:
+        for n_frames in range(2, 9):
+            frames = [[RateLimitReq(name="gw", unique_key=f"k{i}", hits=1,
+                                    limit=1000, duration=60_000)
+                       for i in range(1000)] for _ in range(n_frames)]
+            got = send_in_one_pull(service, client, frames)
+            assert [len(out) for out in got] == [1000] * n_frames
+            assert not any(r.error for out in got for r in out)
+        assert watch.facts()["count"] == 0
+        assert {s for s, _c in shapes} == {(k, 9, hi) for k in (2, 4, 8)}
+        assert not any(c for _s, c in shapes)  # the table as the carry
+        assert service.stats["columnar_windows"] == 2 + 3 + 4 + 4 + 6 + 7 + 8
+    finally:
+        watch.close()
+        client.close()
+        service.close()
+        instance.close()
 
 
 @pytest.mark.parametrize("lo, hi, programs, carried", [
