@@ -3,6 +3,8 @@ the largest `peak_bytes_in_use` of `engine.device.memory` in /v1/debug/vars
 (`device.memory_stats()`), read after the window. It holds the table and
 whatever a decide program keeps beside it."""
 
+from scrape_math import device_peak_bytes
+
 LAYER = "device program"
 SOURCE = "program_counter"
 UNIT = "MB"
@@ -10,9 +12,5 @@ MOVES = "decisions_per_s"
 
 
 def read(scrapes, trace):
-    memory = scrapes["after"]["vars"]["engine"]["device"].get("memory")
-    peaks = [m.get("peak_bytes_in_use") for m in memory or []]
-    peaks = [p for p in peaks if p is not None]
-    if not peaks:
-        return None
-    return max(peaks) / 1e6
+    peak = device_peak_bytes(scrapes["after"])
+    return None if peak is None else peak / 1e6

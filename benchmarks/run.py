@@ -31,7 +31,11 @@ sys.path[:0] = [HERE, REPO]
 
 import numpy as np  # noqa: E402
 
+import host_pressure  # noqa: E402
+import open_math  # noqa: E402
+import scrape_math  # noqa: E402
 from daemon import Daemon, RunFailed, inspect  # noqa: E402
+from open_math import percentile  # noqa: E402
 
 READY_TIMEOUT_S = 1000.0  # a cold compile cache: ~450 s (PERF.md)
 
@@ -58,9 +62,22 @@ def load_cell(name: str, repo: str = REPO):
         mix = json.load(f)
     if mix["config"] != cell["config"] or mix["traffic"] != cell["traffic"]:
         raise RunFailed(f"workloads/{name}.json disagrees with BENCHMARK.json")
-    if mix["transport"] != "grpc" or mix["loop"] != "closed":
-        raise RunFailed("only transport grpc and closed loops are driven yet")
+    if mix["transport"] != "grpc" or mix["loop"] not in ("closed", "open"):
+        raise RunFailed("only transport grpc and the loops 'closed' and "
+                        "'open' are driven yet")
+    rate = mix.get("rate_per_s")
+    if mix["loop"] == "open" and not (
+            isinstance(rate, (int, float)) and rate > 0):
+        raise RunFailed(
+            f"workloads/{name}.json: an open loop needs a positive rate_per_s "
+            "(decisions a second, all clients together; Poisson arrivals)")
     return cell, conf, mix, manifest
+
+
+def listed(manifest: dict, group: str, cell: str) -> list:
+    """The `group` metrics (`end_to_end`, `per_layer`) that the manifest
+    lists for `cell`: an entry without a `workloads` list covers every cell."""
+    return [m for m in manifest[group] if cell in m.get("workloads", [cell])]
 
 
 def load_reader(name: str, here: str = HERE):
@@ -69,12 +86,6 @@ def load_reader(name: str, here: str = HERE):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
-
-
-def percentile(sorted_values, q: float) -> float:
-    """Nearest-rank percentile of an ascending array."""
-    k = max(int(np.ceil(q * len(sorted_values))) - 1, 0)
-    return float(sorted_values[k])
 
 
 def build_native() -> None:
@@ -112,6 +123,8 @@ def main() -> int:
     if args.rehearse:
         settings.update(conf["rehearse"]["daemon_env"])
         residents = int(conf["rehearse"]["resident_keys"])
+        # what of the mix a tiny CPU daemon cannot take (an open loop's rate)
+        mix = {**mix, **mix.get("rehearse", {})}
 
     build_native()
     run_dir = os.path.join(REPO, ".bench_run", args.workload)
@@ -169,8 +182,10 @@ def main() -> int:
         win_end = win_start + args.seconds
         for _, conn in workers:
             conn.send((warm_start, win_start, win_end))
+        watch = host_pressure.Watch(win_start)
         time.sleep(max(win_start - time.time(), 0))
         before = daemon.scrape()
+        host0 = host_pressure.counters()
         capture = None
         if args.trace:
             cap_s = min(float(mix["trace_seconds"]), args.seconds * 0.6)
@@ -179,6 +194,8 @@ def main() -> int:
             capture = daemon.capture(cap_s)
             capture["seconds"] = cap_s
         time.sleep(max(win_end - time.time(), 0))
+        host = host_pressure.diff(host0, host_pressure.counters())
+        host["parent"] = watch.stop(0.0, args.seconds)
         after = daemon.scrape()
         memory = daemon.rss_mb()
         results = [_expect(conn, "done", float(mix["call_timeout_s"]) + 300)
@@ -210,11 +227,23 @@ def main() -> int:
         "daemon_rss_mb": (memory["peak_rss_mb"], "MB"),
         "setup_s": (win_start - t_start, "s"),
     }
+    open_loop = {}
+    if mix["loop"] == "open":
+        # the window's calls are those due in it; latency counts from there
+        open_loop = {
+            "offered_decisions": attempted,
+            "unanswered": sum(r["unanswered"] for r in results),
+            **open_math.window(
+                *(np.concatenate([r[k] for r in results])
+                  for k in ("due_ns", "lat_ns", "lag_ns")),
+                win_start, args.seconds)}
     say(step="window", seconds=args.seconds, calls=calls,
         latency_samples=len(lat), samples_beyond_p99=int(len(lat) * 0.01),
         latency_ms=latency_ms,
         decisions=decisions, attempted=attempted, failed=failed,
-        all_calls=sum(r["all_calls"] for r in results))
+        all_calls=sum(r["all_calls"] for r in results), host=host,
+        background=scrape_math.background_units(before, after),
+        **open_loop)
 
     # ---- correct
     import check
@@ -247,15 +276,16 @@ def main() -> int:
     # ---- the result line
     device = {"platform": dev["platform"], "kind": dev["device_kind"],
               "count": dev["visible_device_count"],
-              # the daemon reports no memory_stats(); this is the table it
-              # holds on the fullest chip, not the allocator's peak (PERF.md §7)
-              "memory_peak_bytes": max(dev["table_bytes_per_device"])}
+              # the allocator's peak on the fullest chip after the window
+              # (`hbm_peak_mb`'s reading); a CPU reports none: its table
+              "memory_peak_bytes": int(
+                  scrape_math.device_peak_bytes(last)
+                  or max(dev["table_bytes_per_device"]))}
     line = {"correct": bool(sound) and not args.rehearse,
             "attempted": attempted, "failed": failed, "device": device}
     if args.rehearse:
         line["rehearsal"] = True
-    names = {m["name"] for m in manifest["end_to_end"]
-             if args.workload in m.get("workloads", [args.workload])}
+    names = {m["name"] for m in listed(manifest, "end_to_end", args.workload)}
     line["end_to_end" if args.trace else "metrics"] = {
         k: {"value": v, "unit": u} for k, (v, u) in end_to_end.items()
         if k in names}
@@ -269,14 +299,14 @@ def main() -> int:
             "before": before, "after": after, "window_s": args.seconds,
             "settings": settings, "device_kind": dev["device_kind"],
             "loadgen": {"cpu_s": [r["cpu_s"] for r in results],
-                        "processes": n_proc},
+                        "processes": n_proc,
+                        "answered_decisions": decisions, "host": host,
+                        **open_loop},
             "boot": {"ready_s": ready_s, "restore_s": restore_s},
             "latency_ms": latency_ms,
         }
         metrics = {}
-        for m in manifest["per_layer"]:
-            if args.workload not in m.get("workloads", [args.workload]):
-                continue
+        for m in listed(manifest, "per_layer", args.workload):
             try:
                 value = load_reader(m["name"]).read(scrapes, trace)
             except KeyError as e:
@@ -289,6 +319,11 @@ def main() -> int:
         line["metrics"] = metrics
         device["busy_s"], device["window_s"] = trace["busy_s"], trace["window_s"]
         line["breakdown"] = trace["breakdown"]
+    # each number compared beside its limit: last in the result's line, and
+    # the last lines on standard error
+    line["compared"] = compared
+    for name, c in compared.items():
+        print(f"compared {name}: {json.dumps(c)}", file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

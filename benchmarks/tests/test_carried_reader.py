@@ -2,10 +2,7 @@
 manifest; on the recorded scrapes of the cell's traffic, taken before the
 daemon counted `scan_rounds_carried`, it gives None and does not raise (a
 parent's daemon), and with the counter it is the counters' ratio.
-
-`test_hot_cell.py` pins the list of this cell's readers as PR 38 left it
-(`READERS`); this one is not in it, so that file's two list comparisons
-wait for a `benchmark` PR that appends the name there."""
+"""
 
 import copy
 import json
@@ -29,12 +26,13 @@ def scrapes():
 def test_the_reader_is_found_by_name_and_agrees_with_the_manifest():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    entry = manifest["per_layer"][-1]
-    assert (entry["name"], entry["workloads"]) == (NAME, [CELL])
+    entry = {m["name"]: m for m in manifest["per_layer"]}[NAME]
+    assert entry["workloads"] == [CELL]
     reader = run.load_reader(NAME)
     assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == \
         (entry["layer"], entry["unit"], entry["moves"], entry["source"])
-    assert entry["layer"] in {m["layer"] for m in manifest["per_layer"][:-1]}
+    assert entry["layer"] in {m["layer"] for m in manifest["per_layer"]
+                              if m is not entry}
 
 
 def test_a_daemon_without_the_counter_gives_none(scrapes):

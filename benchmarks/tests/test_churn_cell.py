@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 import run
-from conftest import BENCH, HERE, REPO
+from conftest import BENCH, HERE, REPO, listed, reads_on_a_cpu
 from keymodel import NEW_BASE
 from traffic import Traffic
 
@@ -114,19 +114,18 @@ def test_the_cell_its_configuration_and_its_readers_are_found_by_name(
     assert len(mine) == len(names) == 38
     assert all(n.startswith("churn.") or n == "call_p99_ms.churn"
                for n in names)
-    for m in mine:
+    # its own readers and those of later PRs that list it beside others
+    for m in manifest["per_layer"]:
+        if CELL not in m["workloads"]:
+            continue
         reader = run.load_reader(m["name"])
         assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == \
             (m["layer"], m["unit"], m["moves"], m["source"])
     # every layer named is one the benchmark had
     had = {m["layer"] for m in manifest["per_layer"] if m not in mine}
     assert {m["layer"] for m in mine} <= had
-    # no accepted metric's list names the new cell
-    assert not [m["name"] for m in manifest["per_layer"] + manifest[
-        "end_to_end"] if CELL in m.get("workloads", []) and m not in mine]
     # the cell reports the four end-to-end metrics every cell reports
-    assert {m["name"] for m in manifest["end_to_end"]
-            if CELL in m.get("workloads", [CELL])} == {
+    assert listed(manifest, "end_to_end", CELL) == {
         "decisions_per_s", "call_p50_ms", "daemon_rss_mb", "setup_s"}
     assert {m["moves"] for m in mine} == {
         "decisions_per_s", "call_p50_ms", "daemon_rss_mb", "setup_s"}
@@ -262,9 +261,12 @@ def test_on_the_parent_no_reader_raises_and_the_new_ones_give_none(
 @pytest.fixture()
 def scratch_checkout(tmp_path):
     """The benchmark and the program in a scratch checkout, with the cell's
-    pool and warm-up cut to a rehearsal's size (3 s of warm traffic, 256
-    pooled calls a client: 2M keys, which a CPU daemon does not get through
-    in 8 s). Nothing else of the cell differs."""
+    pool and warm-up cut to a rehearsal's size (2 s of warm traffic, 512
+    pooled calls a client: 4M keys. A CPU daemon alone on this sandbox
+    decides ~300k a second since PR 44, so the 2M keys this was first cut
+    to wrapped within its 8 s, and a key asked again after its eviction is
+    a mismatch the oracle is right to count). Nothing else of the cell
+    differs."""
     root = tmp_path / "checkout"
     shutil.copytree(BENCH, root / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
@@ -272,18 +274,18 @@ def scratch_checkout(tmp_path):
     os.symlink(os.path.join(REPO, "gubernator_tpu"), root / "gubernator_tpu")
     path = root / "benchmarks/workloads" / (CELL + ".json")
     mix = json.loads(path.read_text())
-    mix.update(warm_seconds=3, pool_calls_per_client=256)
+    mix.update(warm_seconds=2, pool_calls_per_client=512)
     path.write_text(json.dumps(mix))
     return root
 
 
 def test_traced_rehearsal_of_the_churn_cell_evicts_and_prints_every_metric(
-        scratch_checkout, mine):
+        scratch_checkout, manifest):
     env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=os.environ.get(
         "JAX_COMPILATION_CACHE_DIR", os.path.join(REPO, ".jax_cache")))
     r = subprocess.run(
         [sys.executable, str(scratch_checkout / "benchmarks/run.py"),
-         "--workload", CELL, "--seed", str(2**31 + 43), "--seconds", "5",
+         "--workload", CELL, "--seed", str(2**31 + 43), "--seconds", "4",
          "--trace", "1", "--rehearse"],
         cwd=scratch_checkout, env=env, capture_output=True, text=True,
         timeout=900)
@@ -297,12 +299,11 @@ def test_traced_rehearsal_of_the_churn_cell_evicts_and_prints_every_metric(
     assert check["compared"]["audited_answers"]["value"] >= 1000
     assert result["correct"] is False and result["rehearsal"] is True
     assert result["failed"] == 0 and result["attempted"] > 0
-    assert set(result["end_to_end"]) == {
-        "decisions_per_s", "call_p50_ms", "daemon_rss_mb", "setup_s"}
-    # every reader but the roofline, whose peaks know no CPU, and the
-    # allocator's peak, which a CPU does not report
-    assert set(result["metrics"]) == {m["name"] for m in mine} - {
-        "churn.decide_roofline", "churn.hbm_peak_mb"}
+    assert set(result["end_to_end"]) == listed(manifest, "end_to_end", CELL)
+    # every reader the manifest lists for the cell but the roofline, whose
+    # peaks know no CPU, and the allocator's peak, which a CPU does not report
+    assert set(result["metrics"]) == set(filter(
+        reads_on_a_cpu, listed(manifest, "per_layer", CELL)))
     assert out["reader_skipped"]["name"] == "churn.decide_roofline"
     m = {k: v["value"] for k, v in result["metrics"].items()}
     assert m["churn.evictions_per_decision"] > 0.9

@@ -9,13 +9,16 @@ import os
 import subprocess
 import sys
 
-from conftest import BENCH, REPO
+from conftest import BENCH, REPO, listed, reads_on_a_cpu
+
+CELL = "mesh40m.batch1000"
 
 
 def test_traced_rehearsal_of_the_mesh_cell_is_well_formed():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
     r = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
-         "mesh40m.batch1000", "--seed", str(2**31 + 133), "--seconds", "3",
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", CELL, "--seed", str(2**31 + 133), "--seconds", "3",
          "--trace", "1", "--rehearse"],
         cwd=REPO, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
@@ -30,14 +33,11 @@ def test_traced_rehearsal_of_the_mesh_cell_is_well_formed():
     device = result["device"]
     assert device["platform"] == "cpu" and device["count"] == 4
     assert 0 < device["busy_s"] <= device["window_s"]
-    assert set(result["end_to_end"]) == {
-        "decisions_per_s", "call_p50_ms", "daemon_rss_mb", "setup_s"}
-    # every reader but the roofline, whose peaks know no CPU
-    assert set(result["metrics"]) == {
-        "mesh.route_ms_per_window", "mesh.pack_ms_per_window",
-        "mesh.shard_skew", "mesh.readback_ms_per_window",
-        "mesh.demux_ms_per_window", "mesh.device_ms_per_window",
-        "mesh.device_idle_share", "mesh.idle_share.host", "call_p99_ms.mesh"}
+    assert set(result["end_to_end"]) == listed(manifest, "end_to_end", CELL)
+    # every reader the manifest lists for the cell but the roofline, whose
+    # peaks know no CPU
+    assert set(result["metrics"]) == set(filter(
+        reads_on_a_cpu, listed(manifest, "per_layer", CELL)))
     assert out["reader_skipped"]["name"] == "mesh.decide_roofline"
     m = {k: v["value"] for k, v in result["metrics"].items()}
     assert m["mesh.shard_skew"] >= 1.0

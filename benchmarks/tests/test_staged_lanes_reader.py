@@ -33,7 +33,8 @@ def test_the_reader_is_found_by_name_and_agrees_with_the_manifest():
     entries = [m for m in manifest["per_layer"] if m["name"] == NAME]
     assert len(entries) == 1
     entry = entries[0]
-    assert entry["workloads"] == CELLS and entry["better"] == "lower"
+    assert entry["workloads"][:len(CELLS)] == CELLS  # later cells after
+    assert entry["better"] == "lower"
     reader = run.load_reader(NAME)
     assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == \
         (entry["layer"], entry["unit"], entry["moves"], entry["source"])

@@ -27,7 +27,11 @@ def test_the_reader_is_found_by_name_and_agrees_with_the_manifest():
     entries = [m for m in manifest["per_layer"] if m["name"] == NAME]
     assert len(entries) == 1
     entry = entries[0]
-    assert entry["workloads"] == [w["name"] for w in manifest["workloads"]][:5]
+    # the five cells the benchmark had when PR 44 added it, and whichever
+    # later cells were appended to its list
+    cells = [w["name"] for w in manifest["workloads"]]
+    assert entry["workloads"][:5] == cells[:5]
+    assert set(entry["workloads"]) <= set(cells)
     assert entry["better"] == "higher"
     reader = run.load_reader(NAME)
     assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == \

@@ -9,7 +9,8 @@ Parameters of a mix's `key_model`:
                     to the K hottest ranks, the rest follow the Zipf law
   new_key_share     share of requests to keys the daemon has never seen
 and of the mix itself: `behaviors` ([{behavior, share}], fixed per key),
-`requests_per_call`, `pool_calls_per_client`.
+`requests_per_call`, `pool_calls_per_client`; where `loop` is `open` also
+`rate_per_s` (decisions a second, all clients together, as Poisson arrivals).
 """
 
 from __future__ import annotations
@@ -93,6 +94,19 @@ class Traffic:
             pick = rng.random(n) < float(hot["share"])
             ranks[pick] = rng.integers(0, int(hot["ranks"]), int(pick.sum()))
         return ranks
+
+    # ---- one client's schedule (open loops)
+
+    def arrival_offsets_ns(self, client: int, n: int) -> np.ndarray:
+        """The instants, in ns after the client's start, at which its first
+        `n` calls are due: exponential gaps whose mean gives each of the
+        mix's clients a 1/clients share of `rate_per_s`, so the clients
+        together are one Poisson stream at the mix's rate. A pure function
+        of (mix, seed, client)."""
+        mean_gap_s = int(self.p["clients"]) * int(
+            self.p["requests_per_call"]) / float(self.p["rate_per_s"])
+        rng = np.random.default_rng([self.seed, client, 0x64756573])
+        return np.cumsum(rng.exponential(mean_gap_s * 1e9, n)).astype(np.int64)
 
     # ---- one client's pool
 
