@@ -52,7 +52,9 @@ from gubernator_tpu.ops.decide import (
     decide_scan_carried_compact,
     decide_scan_carried_lean,
     decide_scan_packed_lean,
+    LEAN_REFUSALS,
     lean_capacity_ok,
+    lean_stage,
     lean_window,
     staging_policy,
     decide_packed,
@@ -186,7 +188,14 @@ class EngineStats:
     walked to make the staged arrays (depth x the launch's live prefix; x
     the launched width where the wide format ships whole): beside
     `scan_lanes` and the kernel telemetry's widths, what the host touched
-    of what it launched."""
+    of what it launched.
+
+    The lean counters say why a deployment is off the 4-byte lane
+    (ops/decide.py lean_stage): `lean_refused_<reason>`, one of
+    LEAN_REFUSALS, counts the launches under GUBER_STAGING=auto that the
+    lane refused for that reason (the first that held; a refused launch
+    rides compact or wide), and `lean_tuples` is the most config rows a
+    lean launch's table has held, of LEAN_MAX_CFG."""
 
     STAGES = ("prep", "lookup", "store", "pack", "device", "demux")
 
@@ -205,6 +214,8 @@ class EngineStats:
         self.staged_bytes = 0
         self.staged_lanes = 0
         self.fetched_bytes = 0
+        self.lean_refused = {why: 0 for why in LEAN_REFUSALS}
+        self.lean_tuples = 0
         self.stage_ns = {s: 0 for s in self.STAGES}
 
     def note_scan(self, rounds: int, live: int, lanes: int,
@@ -229,7 +240,10 @@ class EngineStats:
                  scan_lanes=self.scan_lanes,
                  staged_bytes=self.staged_bytes,
                  staged_lanes=self.staged_lanes,
-                 fetched_bytes=self.fetched_bytes)
+                 fetched_bytes=self.fetched_bytes,
+                 lean_tuples=self.lean_tuples)
+        for why, n in self.lean_refused.items():
+            d[f"lean_refused_{why}"] = n
         for s, ns in self.stage_ns.items():
             d[f"{s}_ns"] = ns
         return d
@@ -469,11 +483,12 @@ class Engine:
         wide, compact, lean = fns
         kernel, fn, staged = tag + "_wide", wide, None
         if self._staging != "wide":
-            if self._lean_ok:
-                staged = lean_window(src, self.capacity, w)
+            staged, refused, tuples = lean_stage(src, self.capacity, w)
             if staged is not None:
                 kernel, fn = tag + "_lean", lean
+                self.stats.lean_tuples = max(self.stats.lean_tuples, tuples)
             else:
+                self.stats.lean_refused[refused] += 1
                 c = compact_window(src, w)
                 if c is not None:
                     kernel, fn, staged = tag + "_compact", compact, (c,)

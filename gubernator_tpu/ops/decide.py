@@ -958,9 +958,9 @@ class InternCache:
 # 8 B/decision: ONE i32 word per lane. The config table absorbs algorithm
 # and behavior alongside (limit, duration), hits = 1 is implied, and the
 # slot rides in the low 24 bits (table <= 2^24 - 1 slots; the 10M-key
-# north-star uses 10,000,001 < 16,777,215). 4 B up + 8 B back (the serving
-# loop's two-row response) = 12 B/decision round trip vs interned's 16 —
-# the wire lever DESIGN.md "Next wire lever" specs for link-bound rigs.
+# north-star uses 10,000,001 < 16,777,215). 4 B up against interned's 8
+# and compact's 20; the answers come back as the compact i32[4, B] rows,
+# 16 B a lane, whichever narrow format carried the window.
 #
 # lane word layout (i32; bit 31 participates in the config id, so the
 # word may be negative — every decode masks):
@@ -1067,43 +1067,66 @@ def decide_scan_carried_lean(
         lambda resp: _compact_response(resp, now_ms), now_ms)
 
 
+# Why a launch left the lean lane, in the order lean_stage looks: the names
+# of EngineStats' `lean_refused_*` counters (docs/observability.md).
+LEAN_REFUSALS = ("capacity", "hits", "gregorian", "range", "tuples")
+
+
 def lean_window(packed, capacity: int, width=None):
     """Wide i64[9, W] (or [K, 9, W]) staging -> (lean i32[W] / [K, W] lane
     words, i64[LEAN_MAX_CFG, 4] config table), or None when any non-padding
-    lane is ineligible: hits != 1, gregorian, limit/duration outside
-    [0, 2^31), behavior past 6 bits, algorithm past 1 bit, slot too wide
-    for 24 bits, or > LEAN_MAX_CFG distinct (limit, duration, algorithm,
-    behavior) tuples. Padding lanes emit the 0xFFFFFF sentinel and occupy
-    no config row. `width` as in compact_window: `packed` is the launch's
-    live prefix, the lane words come back `width` wide.
+    lane is ineligible: lean_stage without the reason."""
+    return lean_stage(packed, capacity, width)[0]
 
-    Host cost ~120 ns/item (masks + two 1-D uniques) to drop the wire
-    from 72 to 4 B/lane — clearly worth it on link-bound paths
-    (NIC-attached chips, the mesh engine's [R,S,...] buffer) and
-    roughly break-even against the host budget on a locally-attached
-    single chip; the C serving emitter (keydir_prep_pack_lean) writes
-    lean directly and pays none of this."""
+
+def lean_stage(packed, capacity: int, width=None):
+    """lean_window's conversion and, where it refuses, why: ((lanes, cfg),
+    None, config rows used), or (None, reason, 0) with the first of
+    LEAN_REFUSALS that holds of a non-padding lane: `capacity` a slot too
+    wide for 24 bits (the table's, or a lane's), `hits` != 1, `gregorian`,
+    `range` a limit or duration outside [0, 2^31), a behavior past 6 bits
+    or an algorithm past 1, `tuples` more than LEAN_MAX_CFG distinct
+    (limit, duration, algorithm, behavior) rows. Padding lanes emit the
+    0xFFFFFF sentinel and occupy no config row. `width` as in
+    compact_window: `packed` is the launch's live prefix, the lane words
+    come back `width` wide.
+
+    This is the served path's converter: the C prep (native/keydir.cpp
+    keydir_prep_pack_columnar, keydir_prep_pack_fast) writes the wide
+    buffer, and Engine._launch converts its live prefix here, in numpy,
+    under the engine lock (masks + two 1-D uniques over the live lanes;
+    the profiler's `stage` phase: onehit10m.batch1000 is the cell that
+    measures it, 0.57 ms a launch of ~2,600 live lanes on the v5e's host
+    where compact_window's launch pays 0.33; PERF.md section 6, PR 46).
+    It drops the wire from
+    72 to 4 B/lane; the answers come back as the compact i32[4, B] rows,
+    16 B a lane. A launch with one `hits` != 1 lane pays one mask and
+    leaves. The C emitter that writes lean directly
+    (keydir_prep_pack_lean) has no caller on a served path: tests only."""
     if not lean_capacity_ok(capacity):
-        return None
+        return None, "capacity", 0
     slot = packed[..., 0, :]
     live = slot >= 0
     if (slot >= _LEAN_PAD).any():
-        return None
-    hits = packed[..., 1, :]
+        return None, "capacity", 0
+    # the masks in the order of LEAN_REFUSALS: the commonest refusal (a
+    # deployment whose requests carry hits other than 1) leaves first
+    if ((packed[..., 1, :] != 1) & live).any():
+        return None, "hits", 0
     limit = packed[..., 2, :]
     dur = packed[..., 3, :]
     algo = packed[..., 4, :]
     beh = packed[..., 5, :]
+    if (((beh & int(Behavior.DURATION_IS_GREGORIAN)) != 0) & live).any():
+        return None, "gregorian", 0
     bad = (
-        (hits != 1)
-        | (limit < 0) | (limit > _I32_MAX)
+        (limit < 0) | (limit > _I32_MAX)
         | (dur < 0) | (dur > _I32_MAX)
         | ((algo & ~1) != 0)
         | ((beh & ~_META_BEHAVIOR_MASK) != 0)
-        | ((beh & int(Behavior.DURATION_IS_GREGORIAN)) != 0)
     )
     if bool((bad & live).any()):
-        return None
+        return None, "range", 0
     # intern the (limit, duration, algorithm, behavior) tuples via TWO
     # 1-D uniques over injective packed keys — np.unique(axis=0) on the
     # stacked tuples costs ~1.9 µs/item (structured-view sort), two
@@ -1114,7 +1137,7 @@ def lean_window(packed, capacity: int, width=None):
     u2, inv = np.unique(inv1.astype(np.int64) * 128 + meta7,
                         return_inverse=True)
     if u2.size > LEAN_MAX_CFG:
-        return None
+        return None, "tuples", 0
     cfg = np.zeros((LEAN_MAX_CFG, 4), np.int64)
     pairs = u1[u2 >> 7]
     cfg[: u2.size, 0] = pairs >> 31
@@ -1135,11 +1158,11 @@ def lean_window(packed, capacity: int, width=None):
     lanes = lanes.astype(np.uint32).view(np.int32)
     n = lanes.shape[-1]
     if width is None or width == n:
-        return lanes, cfg
+        return (lanes, cfg), None, int(u2.size)
     out = np.empty(lanes.shape[:-1] + (width,), np.int32)
     out[..., n:] = _LEAN_PAD
     out[..., :n] = lanes
-    return out, cfg
+    return (out, cfg), None, int(u2.size)
 
 
 def pack_window(items, slots, fresh, width: int, out=None):

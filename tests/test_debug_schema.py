@@ -108,6 +108,32 @@ def test_engine_section_names_the_device(instance):
     assert dev["table_layout"] == "u32[C,16]"
 
 
+def test_engine_stats_say_why_a_launch_left_the_lean_lane(instance):
+    """`engine.stats` carries `lean_tuples` and one `lean_refused_<reason>`
+    a reason of ops/decide.py LEAN_REFUSALS on `Engine` (docs/
+    observability.md "Why is my deployment off the lean lane"); integers,
+    zero before any launch. `ShardedEngine` keeps neither (its funnel
+    counts `lean_windows`), and the benchmark's readers give None there
+    (benchmarks/onehit_math.py)."""
+    from gubernator_tpu.obs.introspect import _backend_vars
+    from gubernator_tpu.ops.decide import LEAN_REFUSALS
+    from gubernator_tpu.parallel import ShardedEngine
+
+    stats = debug_vars(instance)["engine"]["stats"]
+    lean = {k: v for k, v in stats.items() if k.startswith("lean_")}
+    assert lean == {"lean_tuples": 0,
+                    **{"lean_refused_" + why: 0 for why in LEAN_REFUSALS}}
+    assert LEAN_REFUSALS == ("capacity", "hits", "gregorian", "range",
+                             "tuples")
+    mesh = ShardedEngine(n_shards=2, capacity_per_shard=256, min_width=8,
+                         max_width=8)
+    try:
+        assert not [k for k in _backend_vars(mesh)["stats"]
+                    if k.startswith("lean_refused") or k == "lean_tuples"]
+    finally:
+        mesh.close()
+
+
 def test_flight_recorder_and_anomaly_shapes(instance):
     dv = debug_vars(instance)
     assert {"enabled", "capacity", "size", "dropped",

@@ -107,6 +107,10 @@ def _window_shapes(name, s):
             s((32, D.COMPACT_ROWS, WIDTH), I32), now),
         "decide_scan_carried_lean": (
             s((32, WIDTH), I32), s((D.LEAN_MAX_CFG, 4), I64), now),
+        # a pull's run of three calls on the lean lane: the launch of
+        # every window of onehit10m.batch1000 (PERF.md section 6, PR 46)
+        "decide_scan_packed_lean": (
+            s((4, WIDTH), I32), s((D.LEAN_MAX_CFG, 4), I64), now),
     }[name]
 
 
@@ -114,7 +118,8 @@ class TestOneChip:
     @pytest.mark.parametrize("name", [
         "decide_packed", "decide_packed_compact", "decide_packed_lean",
         "decide_scan_packed", "decide_scan_carried",
-        "decide_scan_carried_compact", "decide_scan_carried_lean"])
+        "decide_scan_carried_compact", "decide_scan_carried_lean",
+        "decide_scan_packed_lean"])
     def test_decide_compiles_and_aliases_the_table(self, one_chip, name):
         table = one_chip((CAPACITY, D.TABLE_ROW_WORDS), U32)
         compiled = jax.jit(getattr(D, name), donate_argnums=(0,)).lower(
